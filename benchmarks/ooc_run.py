@@ -144,16 +144,6 @@ def main():
     ap.add_argument("--data-dir", default="/tmp/dpark_ooc")
     args = ap.parse_args()
 
-    if args.master == "tpu" and os.environ.get("DPARK_TPU_PLATFORM",
-                                               "cpu") == "cpu":
-        # default to the virtual CPU mesh unless a real device is asked
-        os.environ.setdefault("DPARK_TPU_PLATFORM", "cpu")
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + " --xla_force_host_platform_device_count=8"
-            ).strip()
-
     from dpark_tpu import DparkContext, conf
     ctx = DparkContext(args.master)
 
@@ -178,6 +168,12 @@ def main():
     out["hbm_budget_gb"] = round(conf.SHUFFLE_HBM_BUDGET / (1 << 30), 2)
     ex = getattr(ctx.scheduler, "executor", None)
     if ex is not None:
+        # the platform jax found (DPARK_TPU_PLATFORM overrides), named
+        # on the line so a CPU-mesh run is never read as a device's
+        dev = ex.mesh.devices.flat[0]
+        out["platform"] = dev.platform
+        out["device_kind"] = dev.device_kind
+        out["chips"] = ex.ndev
         out["hbm_used_gb"] = round(
             (ex._store_bytes + ex._result_bytes) / (1 << 30), 3)
     # overlapped wave pipeline: aggregate ingest/compute/exchange/spill

@@ -161,10 +161,17 @@ class AotCachePlane:
                 blob = pickle.dumps((payload, in_tree, out_tree),
                                     protocol=pickle.HIGHEST_PROTOCOL)
                 header = dict(self._version())
+                # the devices the executable was compiled FOR: a
+                # sliced mesh (tpu:1 on a four-chip host) must load
+                # onto the same devices, not onto every device of the
+                # backend (deserialize_and_load's default)
                 header.update(key=dk, sig=sig,
                               compile_ms=round(float(compile_ms), 3),
                               nbytes=len(blob),
-                              created=round(time.time(), 3))
+                              created=round(time.time(), 3),
+                              devices=[int(d.id) for d in compiled
+                                       .runtime_executable()
+                                       .local_devices()])
                 with atomic_file(self._entry_path(dk)) as f:
                     f.write(frame_jsonl(header))
                     f.write(b"%08x %08x\n" % (_crc(blob), len(blob)))
@@ -254,9 +261,12 @@ class AotCachePlane:
             if int(crc_hex, 16) != _crc(blob):
                 raise ValueError("payload crc mismatch")
             payload, in_tree, out_tree = pickle.loads(blob)
+            import jax
             from jax.experimental import serialize_executable
+            by_id = {d.id: d for d in jax.devices()}
             return serialize_executable.deserialize_and_load(
-                payload, in_tree, out_tree)
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in header["devices"]])
         except Exception as e:
             logger.debug("aot entry %s unusable: %s", dk, e)
             self._bump("load_errors")

@@ -125,18 +125,24 @@ def _lex_sort(ops, num_keys):
 
     Formulated as permutation-compose + gather on every backend: XLA's
     multi-operand Sort lowers (on TPU) to a comparison network whose
-    cost grows with total operand bytes — real-chip profiling (round 3,
-    v5e) measured a 4-operand i64 sort at 16M rows ~40x slower than a
-    single i32 sort.  Successive 2-operand (key, iota) argsorts
-    radix-compose the permutation instead, and every operand is
-    gathered exactly once; this also carries rank>1 payloads, which
-    XLA Sort cannot."""
-    order = jnp.arange(ops[0].shape[0], dtype=jnp.int32)
+    cost — to compile and to run — grows with total operand bytes.
+    Successive 2-operand (key, iota) sorts radix-compose the
+    permutation instead, and every operand is gathered exactly once;
+    this also carries rank>1 payloads, which XLA Sort cannot.
+
+    The iota is i32 and sorted with lax.sort_key_val directly:
+    jnp.argsort carries an int64 iota under jax_enable_x64, and the
+    TPU compiler takes ~20-30% longer over the same sort with the
+    64-bit payload (v5e smoke log, PR 21: 48 s vs 38 s to compile one
+    stable i64-key sort of 4M rows; every stage program holds 2-3)."""
+    iota = lax.iota(jnp.int32, ops[0].shape[0])
+    order = None
     for k in range(num_keys - 1, -1, -1):
-        # keep indices i32: under jax_enable_x64 argsort returns i64,
-        # and 64-bit gather indices hit the same emulated-i64 tax
-        order = order[jnp.argsort(ops[k][order],
-                                  stable=True).astype(jnp.int32)]
+        key = ops[k] if order is None else ops[k][order]
+        _, perm = lax.sort_key_val(key, iota, is_stable=True)
+        order = perm if order is None else order[perm]
+    if order is None:           # no key: nothing to sort by
+        return tuple(ops)
     return tuple(o[order] for o in ops)
 
 

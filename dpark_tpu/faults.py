@@ -42,7 +42,7 @@ Per-site parameters:
     kind=K    what a firing does:
                 raise    raise FaultInjected (an OSError) [default]
                 enospc   raise OSError(ENOSPC) — disk full
-                oom      raise XlaRuntimeError("RESOURCE_EXHAUSTED...")
+                oom      raise JaxRuntimeError("RESOURCE_EXHAUSTED...")
                 corrupt  flip a byte of the site's payload bytes
                          (crc framing downstream must catch it)
                 delay    sleep ms= milliseconds, then proceed
@@ -89,23 +89,13 @@ class FaultInjected(OSError):
 
 
 def _oom_error():
-    """A device-OOM-shaped error: the REAL XlaRuntimeError type when
-    jax is importable (so production except-clauses are exercised
-    verbatim), else a name-matched stand-in — the degradation
-    classifier matches type name and the RESOURCE_EXHAUSTED message,
-    which both forms carry."""
-    msg = ("RESOURCE_EXHAUSTED: injected device OOM (chaos plane); "
-           "allocating 0B exceeds 0B HBM")
-    try:
-        import jaxlib.xla_extension as _xe
-        return _xe.XlaRuntimeError(msg)
-    except Exception:
-        pass
-
-    class XlaRuntimeError(RuntimeError):
-        pass
-
-    return XlaRuntimeError(msg)
+    """A device-OOM-shaped error of the real class XLA raises, so the
+    degradation ladder's production except-clauses are exercised
+    verbatim."""
+    import jax
+    return jax.errors.JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: injected device OOM (chaos plane); "
+        "allocating 0B exceeds 0B HBM")
 
 
 def corrupt_bytes(data):

@@ -54,8 +54,12 @@ def get_lib():
                 _build()
             lib = ctypes.CDLL(_SO)
         except (OSError, subprocess.CalledProcessError) as e:
-            logger.info("native library unavailable (%s); pure-Python "
-                        "fallbacks in use", e)
+            stderr = getattr(e, "stderr", None) or b""
+            logger.warning(
+                "native library unavailable (%s); pure-Python "
+                "fallbacks in use%s", e,
+                "\n" + stderr.decode("utf-8", "replace")
+                if stderr else "")
             return None
         lib.phash_i64.restype = ctypes.c_uint32
         lib.phash_i64.argtypes = [ctypes.c_int64]

@@ -696,7 +696,15 @@ def test_service_bulk_result_streams_per_tenant():
         for t in ts:
             t.join(timeout=120)
         assert got.get("a") == expect and got.get("b") == expect, got
+        # the server counts a stream's bytes AFTER its last chunk is
+        # written, so a client can hold its result before the counter
+        # moves: wait for both tenants, bounded
+        deadline = time.time() + 10
         st = c1.stats()
+        while time.time() < deadline and not (
+                st["bulk"].get("tenant-a") and st["bulk"].get("tenant-b")):
+            time.sleep(0.05)
+            st = c1.stats()
         assert st["bulk"].get("tenant-a", 0) > 0, st
         assert st["bulk"].get("tenant-b", 0) > 0, st
         # result streams also land in the bulk plane's per-peer sent

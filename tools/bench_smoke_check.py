@@ -51,7 +51,6 @@ def main():
     env.setdefault("BENCH_REPLAN_KEYS", "12000")
     env.setdefault("BENCH_TABLE_ROWS", "200000")
     env.setdefault("BENCH_RECOVERY_PAIRS", "20000")
-    env.setdefault("BENCH_PROBE_ATTEMPTS", "1")
     env.setdefault("BENCH_PROBE_TIMEOUT", "120")
     env.setdefault("BENCH_PLATFORM", "cpu")
     flags = env.get("XLA_FLAGS", "")
