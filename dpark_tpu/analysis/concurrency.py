@@ -405,6 +405,19 @@ PLANE_SEAMS = (
     ("resultcache.py", "stats", "_PLANE"),
     ("backend/tpu/executor.py", "_ProgramCache.__setitem__",
      "aotcache._PLANE"),
+    # the host-side spans of the array path (plan, launch, eager,
+    # readback, egest, ingest, hbm.spill): off, each site is this one
+    # check
+    ("backend/tpu/__init__.py", "TPUScheduler._analyze", "trace._PLANE"),
+    ("backend/tpu/executor.py", "JAXExecutor._launch", "trace._PLANE"),
+    ("backend/tpu/executor.py", "JAXExecutor._source_outs",
+     "trace._PLANE"),
+    ("backend/tpu/executor.py", "JAXExecutor._spill_shuffle_to_disk",
+     "trace._PLANE"),
+    ("backend/tpu/executor.py", "JAXExecutor._check_cached_keys",
+     "trace._PLANE"),
+    ("backend/tpu/layout.py", "host_read", "trace._PLANE"),
+    ("backend/tpu/layout.py", "egest", "trace._PLANE"),
 )
 
 

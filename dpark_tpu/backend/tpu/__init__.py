@@ -9,6 +9,7 @@ whose user code is not jnp-traceable fall back to the in-process object
 path — graceful degradation, never an error (SURVEY.md 7.2 item 1).
 """
 
+from dpark_tpu import trace
 from dpark_tpu.env import env
 from dpark_tpu.schedule import DAGScheduler, _run_task_inline
 from dpark_tpu.task import ResultTask
@@ -180,8 +181,7 @@ class TPUScheduler(DAGScheduler):
             with self._analyze_lock:
                 analysis_gap = False
                 try:
-                    plan = fuse.analyze_stage(stage, self.executor.ndev,
-                                              self.executor)
+                    plan = self._analyze(stage)
                 except Exception as e:
                     logger.warning("analysis failed for %s: %s: %s",
                                    stage, type(e).__name__, e)
@@ -275,6 +275,19 @@ class TPUScheduler(DAGScheduler):
         if adapt_sig is not None and all_ok:
             adapt.observe_path(adapt_sig, "host",
                                (_time.time() - t0) * 1e3)
+
+    def _analyze(self, stage):
+        """fuse.analyze_stage; with the trace plane on, one `plan` span
+        (driver planning of one stage; `ok`: a plan came back)."""
+        from dpark_tpu.backend.tpu import fuse
+        ex = self.executor
+        plane = trace._PLANE
+        if plane is not None:
+            with trace.span("plan", "exec", ok=False) as sp:
+                plan = fuse.analyze_stage(stage, ex.ndev, ex)
+                sp.args["ok"] = plan is not None
+                return plan
+        return fuse.analyze_stage(stage, ex.ndev, ex)
 
     def _spill_write_failed(self, stage, tasks, report, e):
         """ENOSPC & co mid-spill: NOT a device fault, and the object

@@ -372,6 +372,13 @@ class _RunPremerger:
             self._thread.join(timeout=10)
 
 
+def _export_read(arr):
+    """One blocking read of the host bridge, which serves bucket
+    exports to host-path stages and the spill of dead stores (there
+    the `readback` lies inside an `hbm.spill` span)."""
+    return layout.host_read(arr, site="bridge.export")
+
+
 class _StoreInFlight(Exception):
     """An HBM shuffle store whose producing stage has not registered
     its outputs yet — the eviction scan must skip it, not drop it."""
@@ -642,6 +649,13 @@ class JAXExecutor:
         # tracked separately so single-chip runs measure ingest padding
         # under its own name, not as bogus wire padding
         self.ingest_slot_rows = 0
+        # always-on host-side count (one integer add, traced or not):
+        # calls of a compiled stage program through _launch.  Blocking
+        # device-to-host reads are `host_reads`, below.  Both are plain
+        # `+= 1`, exact for one thread of jobs: the export server's
+        # threads reach host_read too, and a read-modify-write that
+        # races there can lose a count.
+        self.program_launches = 0
         # count arrays whose host sum is deferred (the ndev==1 fast
         # path must not pay a blocking readback per wave just for this
         # metric); flushed on first metric read, or opportunistically
@@ -744,6 +758,12 @@ class JAXExecutor:
                 logger.warning("profiler trace unavailable: %s", e)
 
     @property
+    def host_reads(self):
+        """Blocking device-to-host reads (calls of layout.host_read) in
+        this process so far; a job's delta is its round trips."""
+        return layout.HOST_READS
+
+    @property
     def exchange_real_rows(self):
         """Valid rows offered for exchange.  Reading flushes deferred
         per-wave count arrays (one batched readback at metric-read
@@ -752,16 +772,8 @@ class JAXExecutor:
         if self._pending_real_counts:
             pending, self._pending_real_counts = \
                 self._pending_real_counts, []
-            if all(getattr(c, "is_fully_addressable", True)
-                   for c in pending):
-                # one batched readback (the ndev==1 fast path only ever
-                # defers fully-addressable arrays)
-                for c in jax.device_get(pending):
-                    self._exchange_real_rows += int(np.asarray(c).sum())
-            else:
-                for c in pending:
-                    self._exchange_real_rows += int(
-                        layout.host_read(c).sum())
+            for c in layout.host_read(pending, site="exchange.real_rows"):
+                self._exchange_real_rows += int(c.sum())
         return self._exchange_real_rows
 
     @exchange_real_rows.setter
@@ -998,11 +1010,12 @@ class JAXExecutor:
             return None
         cap = leaves[0].shape[1]
         probe = self._compile_minmax(len(cand), cap)
-        ranges = probe(counts, *[leaves[li] for li in cand])
+        ranges = self._launch("minmax", probe, counts,
+                              *[leaves[li] for li in cand])
         plan = [None] * len(leaves)
         i32 = np.iinfo(np.int32)
         for li, rng in zip(cand, ranges):
-            r = layout.host_read(rng)                # (ndev, 2)
+            r = layout.host_read(rng, site="narrow.minmax")  # (ndev, 2)
             lo, hi = int(r[:, 0].min()), int(r[:, 1].max())
             if lo >= i32.min and hi <= i32.max:
                 plan[li] = "int32"
@@ -1099,6 +1112,18 @@ class JAXExecutor:
     # ------------------------------------------------------------------
     # running
     # ------------------------------------------------------------------
+    def _launch(self, program, fn, *args):
+        """Call a compiled stage program.  The `launch` span is the
+        host's enqueue wall (jit cache lookup, argument handling, a
+        compile if one happens); the device runs on after the return,
+        and whoever reads the result waits for it in a `readback`."""
+        self.program_launches += 1
+        plane = trace._PLANE
+        if plane is not None:
+            with trace.span("launch", "exec", program=program):
+                return fn(*args)
+        return fn(*args)
+
     def run_stage(self, plan):
         """Execute the whole stage for all partitions at once.
 
@@ -1160,11 +1185,17 @@ class JAXExecutor:
             slices = pc._slices
             if getattr(plan, "reslice", False):
                 slices = _reslice_parts(slices, self.ndev)
+            sp = trace._NOOP
+            plane = trace._PLANE
+            if plane is not None:
+                sp = trace.span("ingest", "exec",
+                                rows=sum(len(p) for p in slices))
             # any shuffle write pads with the key sentinel; a real key
             # equal to it must force the host path
-            batch = layout.ingest(self.mesh, slices, plan.in_treedef,
-                                  plan.in_specs,
-                                  key_leaf=0 if keyed else None)
+            with sp:
+                batch = layout.ingest(self.mesh, slices, plan.in_treedef,
+                                      plan.in_specs,
+                                      key_leaf=0 if keyed else None)
             return self._run_narrow(plan, batch)
         if plan.source[0] == "cached":
             meta = self.result_cache[plan.source[1].id]
@@ -1218,7 +1249,7 @@ class JAXExecutor:
         args = (batch.counts,) + ((bounds,) if bounds is not None
                                   else ()) + tuple(batch.cols)
         self._capture_cost(plan, jitted, args)
-        return jitted(*args)
+        return self._launch("narrow", jitted, *args)
 
     def _capture_cost(self, plan, jitted, args):
         """Static program cost profile at first dispatch (ISSUE 15):
@@ -1562,7 +1593,9 @@ class JAXExecutor:
         """Round-trip one HBM shuffle store into the standard on-disk
         bucket layout (shard containers when coding is active) and
         re-point its map-output locations at the files.  Runs under
-        the mesh lock (the export reads device slices)."""
+        the mesh lock (the export reads device slices).  One
+        `hbm.spill` span, beside the `hbm.release` event (reason
+        `spill`) that drop_shuffle emits."""
         from dpark_tpu.env import env
         from dpark_tpu.shuffle import LocalFileShuffle
         store = self.shuffle_store[sid]
@@ -1572,30 +1605,39 @@ class JAXExecutor:
                 # the producing stage hasn't completed/registered yet:
                 # its buckets are in flight — treat as pinned
                 raise _StoreInFlight(sid)
-            n_reduce = int(store.get(
-                "n_reduce",
-                layout.host_read(store["counts"]).shape[-1]))
-            uri = None
-            for map_id, old in enumerate(locs):
-                if old is None or not str(old).startswith("hbm://"):
-                    continue        # lost or already host-resident
-                buckets = [self._export_bucket(sid, map_id, r)
-                           for r in range(n_reduce)]
-                uri = LocalFileShuffle.write_buckets(
-                    sid, map_id, buckets)
-            if uri is None:
-                uri = LocalFileShuffle.get_server_uri()
-            new_locs = [uri if (l and str(l).startswith("hbm://"))
-                        else l for l in locs]
-            env.map_output_tracker.register_outputs(sid, new_locs)
-            notify = self._spill_notify
-            if notify is not None:
-                # the owning scheduler re-points its Stage.output_locs
-                # so a later job reusing the stage sees disk locations
-                notify(sid, uri)
-            logger.info("spilled HBM shuffle %d (%d bytes) to disk "
-                        "buckets at %s", sid, store["nbytes"], uri)
-            self.drop_shuffle(sid, reason="spill")
+            sp = trace._NOOP
+            plane = trace._PLANE
+            if plane is not None:
+                sp = trace.span("hbm.spill", "exec", sid=sid,
+                                bytes=store["nbytes"])
+            with sp:
+                n_reduce = int(store.get(
+                    "n_reduce",
+                    _export_read(store["counts"]).shape[-1]))
+                uri = None
+                for map_id, old in enumerate(locs):
+                    if old is None or not str(old).startswith("hbm://"):
+                        continue        # lost or already host-resident
+                    # the rows are a temporary: they die here, inside
+                    # the span, not when this frame is torn down (4-10
+                    # ms a store on the chip's host)
+                    uri = LocalFileShuffle.write_buckets(
+                        sid, map_id, [self._export_bucket(sid, map_id, r)
+                                      for r in range(n_reduce)])
+                if uri is None:
+                    uri = LocalFileShuffle.get_server_uri()
+                new_locs = [uri if (l and str(l).startswith("hbm://"))
+                            else l for l in locs]
+                env.map_output_tracker.register_outputs(sid, new_locs)
+                notify = self._spill_notify
+                if notify is not None:
+                    # the owning scheduler re-points its
+                    # Stage.output_locs so a later job reusing the
+                    # stage sees disk locations
+                    notify(sid, uri)
+                logger.info("spilled HBM shuffle %d (%d bytes) to disk "
+                            "buckets at %s", sid, store["nbytes"], uri)
+                self.drop_shuffle(sid, reason="spill")
 
     def _finish_stage(self, plan, outs):
         if plan.epilogue is None:
@@ -1621,9 +1663,11 @@ class JAXExecutor:
                 if plan.group_output:
                     counts = layout.host_read(
                         self._distinct_key_counts(
-                            batch, nk=getattr(plan, "src_nk", 1) or 1))
+                            batch, nk=getattr(plan, "src_nk", 1) or 1),
+                        site="finish.counts")
                 else:
-                    counts = layout.host_read(batch.counts)
+                    counts = layout.host_read(batch.counts,
+                                              site="finish.counts")
                 return ("counts", [int(c) for c in counts])
             monoid = getattr(plan, "reduce_monoid", None)
             if (monoid is not None and not plan.group_output
@@ -1643,9 +1687,11 @@ class JAXExecutor:
                 # can differ from the local master in low-order bits —
                 # parity checks must compare floats with a tolerance
                 # (ADVICE r4; test_parity_fuzz does)
-                vals, lo, hi = (layout.host_read(a) for a in
-                                self._monoid_reduce(batch, monoid))
-                counts = layout.host_read(batch.counts)
+                vals, lo, hi = (
+                    layout.host_read(a, site="finish.reduced") for a in
+                    self._monoid_reduce(batch, monoid))
+                counts = layout.host_read(batch.counts,
+                                          site="finish.counts")
                 intk = vals.dtype.kind == "i"
                 safe = True
                 if intk and monoid == "add":
@@ -1731,7 +1777,8 @@ class JAXExecutor:
             if c.ndim == 2 and np.dtype(c.dtype).kind == "i":
                 try:
                     r = layout.host_read(
-                        layout._masked_minmax(c, batch.counts))
+                        layout._masked_minmax(c, batch.counts),
+                        site="topk.minmax")
                     ranges.append((int(r[0]), int(r[1])))
                 except Exception:
                     ranges.append(None)
@@ -1845,7 +1892,8 @@ class JAXExecutor:
                             in_specs=(P(AXIS),) * (1 + nk),
                             out_specs=(P(AXIS),))
             self._compiled[key] = jax.jit(fn)
-        (out,) = self._compiled[key](batch.counts, *kcols)
+        (out,) = self._launch("distinct", self._compiled[key],
+                              batch.counts, *kcols)
         return out
 
     def _register_shuffle(self, dep, plan, store):
@@ -1899,7 +1947,8 @@ class JAXExecutor:
                     or plan.source[0] != "ingest":
                 return
             rows_in = sum(len(s) for s in plan.source[1]._slices or ())
-            rows_out = int(layout.host_read(counts).sum())
+            rows_out = int(layout.host_read(
+                counts, site="adapt.combine_ratio").sum())
             if rows_in:
                 adapt.record_combine_ratio(site, rows_in, rows_out)
         except Exception as e:
@@ -1924,7 +1973,7 @@ class JAXExecutor:
         args = ([bounds] if bounds is not None else []) + list(cnt_rounds)
         for r in range(rounds):
             args.extend(recv_rounds[r])
-        return reduce_fn(*args)
+        return self._launch("reduce", reduce_fn, *args)
 
     # ------------------------------------------------------------------
     # device segmented apply (fuse.SegMapOp — ISSUE 4 tentpole): an
@@ -1946,7 +1995,7 @@ class JAXExecutor:
         else:
             counts, hist, leaves = self._seg_exchange_sorted(store, nk)
             batch = layout.Batch(store["out_treedef"], leaves, counts)
-            hist_np = layout.host_read(hist)
+            hist_np = layout.host_read(hist, site="segmap.hist")
             self._observe_seg_skew(dep, batch, hist_np)
         op = plan.ops[0]
         extra = ()
@@ -1972,7 +2021,8 @@ class JAXExecutor:
             site = getattr(dep, "adapt_site", None)
             if not site:
                 return
-            rows = int(layout.host_read(batch.counts).sum())
+            rows = int(layout.host_read(
+                batch.counts, site="adapt.seg_skew").sum())
             per_bucket = np.asarray(hist_np).max(axis=0)
             nonzero = np.nonzero(per_bucket)[0]
             if not rows or not len(nonzero):
@@ -2045,7 +2095,7 @@ class JAXExecutor:
                 self._compiled[key] = jax.jit(fn)
             (hist,) = self._compiled[key](batch.counts,
                                           *batch.cols[:nk])
-        gmax = layout.host_read(hist).max(axis=0)
+        gmax = layout.host_read(hist, site="segmap.hist").max(axis=0)
         lay = tuple((b, 1 << b, layout.round_capacity(int(g)))
                     for b, g in enumerate(gmax.tolist()) if g)
         return lay or ((0, 1, 8),)
@@ -2116,7 +2166,8 @@ class JAXExecutor:
         """Per-device concatenation of same-spec Batches into one."""
         if len(batches) == 1:
             return batches[0]
-        counts = [layout.host_read(b.counts) for b in batches]
+        counts = [layout.host_read(b.counts, site="concat.counts")
+                  for b in batches]
         total = np.sum(np.stack(counts), axis=0)
         cap_out = layout.round_capacity(int(total.max()) or 1)
         caps = tuple(b.cap for b in batches)
@@ -2504,8 +2555,10 @@ class JAXExecutor:
         compute), slice per logical partition, and hand runs to the
         background writer (or write inline when it's disabled)."""
         t0 = stats.now()
-        counts = layout.host_read(sorted_batch.counts)
-        cols = [layout.host_read(l) for l in sorted_batch.cols]
+        counts = layout.host_read(sorted_batch.counts,
+                                  site="wave_spill.counts")
+        cols = [layout.host_read(l, site="wave_spill.col")
+                for l in sorted_batch.cols]
         read_done = stats.now()
         for d in range(self.ndev):
             n = int(counts[d])
@@ -2735,7 +2788,7 @@ class JAXExecutor:
         args = list(cnt_rounds)
         for r in range(rounds):
             args.extend(recv_rounds[r])
-        return self._compiled[key](*args)
+        return self._launch(tag, self._compiled[key], *args)
 
     def _rid_prefixed_treedef(self, plan):
         """plan.out_treedef with the rid column prepended FLAT: egested
@@ -2896,7 +2949,7 @@ class JAXExecutor:
             # per-bucket array, leaves gain the source-device axis
             recv = [l.reshape((1, 1) + l.shape[1:]) for l in leaves]
             return [recv], [counts], cap
-        host_counts = layout.host_read(counts)
+        host_counts = layout.host_read(counts, site="exchange.counts")
         max_run = int(host_counts.max()) if host_counts.size else 1
         mean = int(host_counts.sum()) // max(1, host_counts.size)
         # slot sizing: fine (1/16-octave) classes — power-of-two slots
@@ -2937,7 +2990,8 @@ class JAXExecutor:
                 fn = self._compile_exchange(
                     tuple(str(l.dtype) for l in leaves), nleaves, slot,
                     cap, narrow=narrow, donate=True)
-            outs = fn(offsets, counts, sent, *leaves)
+            outs = self._launch("exchange", fn, offsets, counts, sent,
+                                *leaves)
             recv_cnt, sent = outs[0], outs[1]
             recv_rounds.append(list(outs[3:]))
             cnt_rounds.append(recv_cnt)
@@ -3031,7 +3085,8 @@ class JAXExecutor:
         program's state_cap compile key sticky.  The counts readback
         was issued async at merge time; reading it here is (near-)free."""
         leaves, counts = state
-        host_n = int(layout.host_read(counts).max() or 1)
+        host_n = int(layout.host_read(
+            counts, site="stream.state_counts").max() or 1)
         want_cap = layout.round_capacity(host_n)
         if leaves[0].shape[1] > want_cap:
             leaves = [l[:, :want_cap] for l in leaves]
@@ -3155,10 +3210,11 @@ class JAXExecutor:
                             in_specs=(P(AXIS),) * (2 + 2 * nk),
                             out_specs=(P(AXIS),))
             self._compiled[count_key] = jax.jit(fn)
-        (totals,) = self._compiled[count_key](
+        (totals,) = self._launch(
+            "join_count", self._compiled[count_key],
             cnt_a, cnt_b, *lv_a[:nk], *lv_b[:nk])
         cap_out = layout.round_capacity(
-            int(layout.host_read(totals).max() or 1))
+            int(layout.host_read(totals, site="join.totals").max() or 1))
 
         exp_key = ("join_expand", cap_a, cap_b, cap_out, na, nb, nk,
                    tuple(str(l.dtype) for l in lv_a + lv_b))
@@ -3186,7 +3242,8 @@ class JAXExecutor:
                             in_specs=(P(AXIS),) * (2 + na + nb),
                             out_specs=(P(AXIS),) * n_out)
             self._compiled[exp_key] = jax.jit(fn)
-        outs = self._compiled[exp_key](cnt_a, cnt_b, *lv_a, *lv_b)
+        outs = self._launch("join_expand", self._compiled[exp_key],
+                            cnt_a, cnt_b, *lv_a, *lv_b)
         counts, leaves = outs[0], list(outs[1:])
 
         # rows are (k..., va..., vb...); records are (k, (va, vb)) with
@@ -3276,7 +3333,7 @@ class JAXExecutor:
             if map_id != 0:
                 return {"no_combine": False}, []
             with self._export_lock:
-                counts = layout.host_read(store["counts"])
+                counts = _export_read(store["counts"])
                 cnt = int(counts[reduce_id])
                 if not cnt:
                     return {"no_combine": False}, []
@@ -3286,8 +3343,8 @@ class JAXExecutor:
             return {"no_combine": False}, mats
         wrap = bool(store.get("no_combine"))
         with self._export_lock:
-            counts = layout.host_read(store["counts"])
-            offsets = layout.host_read(store["offsets"])
+            counts = _export_read(store["counts"])
+            offsets = _export_read(store["offsets"])
             off = int(offsets[map_id, reduce_id])
             cnt = int(counts[map_id, reduce_id])
             if not cnt:
@@ -3378,7 +3435,7 @@ class JAXExecutor:
             if map_id != 0:
                 return []
             with self._export_lock:
-                counts = layout.host_read(store["counts"])
+                counts = _export_read(store["counts"])
                 cnt = int(counts[reduce_id])
                 if not cnt:
                     return []
@@ -3444,16 +3501,16 @@ class JAXExecutor:
             if map_id != 0:
                 return []
             with self._export_lock:
-                counts = layout.host_read(store["counts"])
-                offsets = layout.host_read(store["offsets"])
+                counts = _export_read(store["counts"])
+                offsets = _export_read(store["offsets"])
                 rows = []
                 for dev in range(counts.shape[0]):
                     rows.extend(self._export_one(store, dev, reduce_id,
                                                  counts, offsets))
             return self._maybe_decode(store, rows)
         with self._export_lock:
-            counts = layout.host_read(store["counts"])
-            offsets = layout.host_read(store["offsets"])
+            counts = _export_read(store["counts"])
+            offsets = _export_read(store["offsets"])
             rows = self._export_one(store, map_id, reduce_id, counts,
                                     offsets)
         return self._maybe_decode(store, rows)
@@ -3465,9 +3522,9 @@ class JAXExecutor:
         process-spanning leaf replicates through host_read first (the
         host bridge is the slow path — correctness over bytes here)."""
         if getattr(arr, "is_fully_addressable", True):
-            return np.asarray(jax.device_get(
-                lax.slice_in_dim(arr, dev, dev + 1, axis=0)))[0]
-        return layout.host_read(arr)[dev]
+            arr = lax.slice_in_dim(arr, dev, dev + 1, axis=0)
+            dev = 0
+        return _export_read(arr)[dev]
 
     def _export_one(self, store, dev, reduce_id, counts, offsets):
         """One device's bucket for one reduce partition as host rows."""
@@ -3540,21 +3597,26 @@ class JAXExecutor:
                 import shutil
                 shutil.rmtree(store["spool_dir"], ignore_errors=True)
 
-    @staticmethod
-    def _check_cached_keys(batch):
+    def _check_cached_keys(self, batch):
         """Cached batches feeding a shuffle get the same sentinel guard as
         ingest: a real key equal to the padding sentinel (or inf/nan)
-        would be silently dropped by the reduce — force host fallback."""
-        import jax.numpy as jnp
-        keys = batch.cols[0]
-        counts = batch.counts
-        valid = jnp.arange(keys.shape[1])[None, :] < counts[:, None]
-        if jnp.issubdtype(keys.dtype, jnp.floating):
-            bad = jnp.any(valid & (jnp.isinf(keys) | jnp.isnan(keys)))
-        else:
-            sent = jnp.iinfo(keys.dtype).max
-            bad = jnp.any(valid & (keys == sent))
-        if bool(layout.host_read(bad)):
+        would be silently dropped by the reduce — force host fallback.
+        The test is a handful of eager jnp operations, each an XLA
+        dispatch of its own and none a compiled stage program: one
+        `eager` span (site `keycheck`) covers them, and
+        program_launches does not count them."""
+        keys, counts = batch.cols[0], batch.counts
+        sp = trace._NOOP
+        plane = trace._PLANE
+        if plane is not None:
+            sp = trace.span("eager", "exec", site="keycheck")
+        with sp:
+            valid = jnp.arange(keys.shape[1])[None, :] < counts[:, None]
+            if jnp.issubdtype(keys.dtype, jnp.floating):
+                bad = jnp.any(valid & (jnp.isinf(keys) | jnp.isnan(keys)))
+            else:
+                bad = jnp.any(valid & (keys == jnp.iinfo(keys.dtype).max))
+        if bool(layout.host_read(bad, site="keycheck")):
             raise ValueError("cached key equals the device sentinel; "
                              "taking the host path")
 

@@ -1508,7 +1508,7 @@ def _meta_row_estimate(meta):
     if counts is None:
         return None
     try:
-        return int(layout.host_read(counts).sum())
+        return int(layout.host_read(counts, site="plan.rows").sum())
     except Exception:
         return None
 

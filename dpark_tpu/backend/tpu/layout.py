@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dpark_tpu import conf
+from dpark_tpu import trace
 
 AXIS = conf.MESH_AXIS
 # int64 sentinel: keys must be < 2**63 - 1; ingest() rejects the sentinel
@@ -57,13 +58,42 @@ def put_sharded(arr, sharding):
 
 _REPLICATORS = {}
 
+# calls of host_read in this process, traced or not: every blocking
+# control read of the array path goes through it, so a job's delta is
+# its number of host<->device round trips (JAXExecutor.host_reads).
+# A bare `+= 1`: exact while one thread runs jobs; the export server's
+# threads read too, and a racing add can lose a count
+HOST_READS = 0
 
-def host_read(x):
-    """device -> host numpy for metric/sizing readbacks.  A global
-    array whose shards live on other processes cannot be device_get
-    directly; replicate it across the mesh first (one all_gather) —
-    every rank then reads the SAME value, which also keeps multi-rank
-    scheduler decisions (slot sizing, round counts) deterministic."""
+
+def host_read(x, site=""):
+    """device -> host numpy for metric/sizing readbacks: the array
+    path's one blocking read.  `x` is an array, or a list of arrays
+    fetched in one transfer (a list of numpy arrays comes back).
+    `site` is the caller's short literal: it names the `readback` span
+    that times the wait for the device plus the copy when the trace
+    plane is on."""
+    global HOST_READS
+    HOST_READS += 1
+    plane = trace._PLANE
+    if plane is not None:
+        leaves = x if isinstance(x, list) else (x,)
+        with trace.span("readback", "exec", site=site, bytes=sum(
+                int(getattr(a, "nbytes", 0)) for a in leaves)):
+            return _to_host(x)
+    return _to_host(x)
+
+
+def _to_host(x):
+    """A global array whose shards live on other processes cannot be
+    device_get directly; replicate it across the mesh first (one
+    all_gather) — every rank then reads the SAME value, which also
+    keeps multi-rank scheduler decisions (slot sizing, round counts)
+    deterministic."""
+    if isinstance(x, list):
+        if all(getattr(a, "is_fully_addressable", True) for a in x):
+            return [np.asarray(a) for a in jax.device_get(x)]
+        return [_to_host(a) for a in x]
     if getattr(x, "is_fully_addressable", True):
         return np.asarray(jax.device_get(x))
     mesh = x.sharding.mesh           # Mesh is hashable — key by value,
@@ -259,18 +289,32 @@ def _egest_read(c, dev_counts):
     if (conf.NARROW_EXCHANGE and c.ndim == 2
             and c.dtype == jnp.int64
             and int(c.nbytes) >= conf.EGEST_NARROW_MIN_BYTES):
-        lo, hi = host_read(_masked_minmax(c, dev_counts))
+        lo, hi = host_read(_masked_minmax(c, dev_counts),
+                           site="egest.minmax")
         i32 = np.iinfo(np.int32)
         if lo >= i32.min and hi <= i32.max:
-            return host_read(_cast_i32(c))
-    return host_read(c)
+            return host_read(_cast_i32(c), site="egest.col")
+    return host_read(c, site="egest.col")
 
 
 def egest(batch):
     """Sharded Batch -> list of per-partition row lists (host).
     Multi-controller meshes replicate through host_read, so every rank
-    egests the same full result set."""
-    counts = host_read(batch.counts)
+    egests the same full result set.  With the trace plane on, one
+    `egest` span: the result's readbacks (nested `readback` spans) plus
+    building the Python rows."""
+    plane = trace._PLANE
+    if plane is not None:
+        with trace.span("egest", "exec", bytes=sum(
+                int(c.nbytes) for c in batch.cols)) as sp:
+            out = _egest_rows(batch)
+            sp.args["rows"] = sum(len(rows) for rows in out)
+            return out
+    return _egest_rows(batch)
+
+
+def _egest_rows(batch):
+    counts = host_read(batch.counts, site="egest.counts")
     total = sum(int(c.nbytes) for c in batch.cols)
     if total >= conf.EGEST_WARN_BYTES:
         from dpark_tpu.utils.log import get_logger

@@ -41,6 +41,44 @@ Span taxonomy (name / cat):
                                        timeline of a multiproc run)
     stage.exec, wave         "exec"    device stage execution and the
                                        per-wave stream pipeline
+    plan                     "exec"    fuse.analyze_stage of one stage
+                                       on the tpu master's driver
+                                       (args: ok — a plan came back)
+    launch                   "exec"    the host's call of a compiled
+                                       stage program, JAXExecutor.
+                                       _launch (args: program — narrow,
+                                       reduce, exchange, minmax,
+                                       join_count, join_expand,
+                                       wave_sort, wave_prereduce,
+                                       distinct): jit cache lookup,
+                                       argument handling, a compile if
+                                       one happens; NOT device time.
+                                       One span is one count of the
+                                       executor's program_launches
+    eager                    "exec"    the host's dispatch of eager jnp
+                                       operations outside any compiled
+                                       program, several XLA dispatches
+                                       under one span and none counted
+                                       in program_launches (args: site
+                                       — keycheck, the cached-key guard
+                                       JAXExecutor._check_cached_keys)
+    readback                 "exec"    one blocking device-to-host
+                                       read, layout.host_read (args:
+                                       site — the caller's literal,
+                                       e.g. exchange.counts; bytes):
+                                       the host waited for the device
+                                       to produce the value, then the
+                                       copy
+    egest                    "exec"    layout.egest: a result batch to
+                                       Python rows (args: rows, bytes);
+                                       its readbacks nest inside
+    ingest                   "exec"    layout.ingest of a stage's host
+                                       numpy source (args: rows)
+    hbm.spill                "exec"    one dead HBM shuffle store to
+                                       disk buckets, JAXExecutor.
+                                       _spill_shuffle_to_disk (args:
+                                       sid, bytes); its readbacks nest
+                                       inside
     compile, dispatch        "exec"    program cache misses / program
                                        dispatches (instant events)
     phase.ingest_tokenize,   "phase"   per-stage phase totals emitted
@@ -98,6 +136,13 @@ the scheduler (``ctx()``), so deep callees (a shuffle fetch inside a
 worker task) parent correctly without plumbing ids through every
 signature.
 
+While a span() is open (not emit()/event(), which are stamped after
+the fact) the thread also holds a jax.profiler.TraceAnnotation of the
+same name and scalar args, so with DPARK_XPROF_DIR set (or under any
+jax.profiler session) the program's spans stand in the profiler's own
+trace, on its clock, above the device's operations.  Processes that
+never loaded jax hold none.
+
 On top: ``to_chrome()`` exports merged Chrome trace-event JSON (load
 in Perfetto via chrome://tracing or ui.perfetto.dev), and
 ``critical_path()`` runs a longest-path analysis over the stage DAG
@@ -107,6 +152,7 @@ with per-phase blocked fractions.  ``tools/dtrace`` is the CLI.
 import json
 import os
 import socket
+import sys
 import threading
 import time
 from collections import deque
@@ -290,10 +336,27 @@ class TracePlane:
                     setattr(self, attr, None)
 
 
+def _annotation(name, args):
+    """An entered jax.profiler.TraceAnnotation carrying the span's name
+    and args (non-scalars as strings), or None in a process that has
+    not loaded jax: no profiler session can be running there, and a
+    worker must not import jax for a span's sake."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:            # no jax here, or one half imported
+        return None
+    mark = profiler.TraceAnnotation(name, **{
+        k: v if isinstance(v, (bool, int, float, str)) else str(v)
+        for k, v in args.items()})
+    mark.__enter__()
+    return mark
+
+
 class _Span:
     """Context manager emitting one complete span on exit (errors ride
-    as an `error` arg so a failed fetch is visible on the timeline)."""
-    __slots__ = ("plane", "name", "cat", "args", "t0")
+    as an `error` arg so a failed fetch is visible on the timeline).
+    While it is open the same interval is a TraceAnnotation in the jax
+    profiler's trace (see _annotation)."""
+    __slots__ = ("plane", "name", "cat", "args", "t0", "mark")
 
     def __init__(self, plane, name, cat, args):
         self.plane = plane
@@ -302,10 +365,13 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        self.mark = _annotation(self.name, self.args)
         self.t0 = time.time()
         return self
 
     def __exit__(self, etype, evalue, tb):
+        if self.mark is not None:
+            self.mark.__exit__(etype, evalue, tb)
         args = self.args
         if etype is not None:
             args = dict(args, error=etype.__name__)
