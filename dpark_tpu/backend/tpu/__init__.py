@@ -112,6 +112,9 @@ class TPUScheduler(DAGScheduler):
             # (ISSUE 9 satellite): a later job reusing an available
             # stage must see the disk uris, not stale hbm:// ones
             self.executor._spill_notify = self._on_store_spilled
+            # a store lives as long as its ShuffleDependency: eviction
+            # releases what nothing can read before it picks victims
+            self.executor._release_unreachable = self._release_unreachable
             logger.info("tpu master on %d %s device(s)",
                         len(devices), devices[0].platform)
 
@@ -123,6 +126,31 @@ class TPUScheduler(DAGScheduler):
             if loc and str(loc).startswith("hbm://"):
                 stage.output_locs[i] = uri
 
+    def _release_unreachable(self):
+        """The base's drain under the mesh lock, which every other
+        writer of the executor's store accounting holds (a stage of
+        another driver thread's job may be registering or evicting).
+        Never waits for it: while another job's stage holds the mesh
+        the queue stays, and that stage's own eviction, which calls
+        this with the lock held, drains it before it picks victims."""
+        if not self._unreachable:
+            return
+        ex = self.executor
+        if ex is None:
+            return super()._release_unreachable()
+        lock = ex._mesh_lock
+        if lock.try_enter():
+            try:
+                super()._release_unreachable()
+            finally:
+                lock.__exit__(None, None, None)
+
+    def _shuffle_unreachable(self, sid):
+        ex = self.executor
+        if ex is not None and sid in ex.shuffle_store:
+            ex.stores_released += 1
+            ex.drop_shuffle(sid, reason="unreachable")
+
     def _job_started(self, record):
         """Pin this job's HBM buckets against disk spill and snapshot
         the program-cache counters.  The snapshot is only the FALLBACK
@@ -130,12 +158,14 @@ class TPUScheduler(DAGScheduler):
         hits/misses per job exactly (the probing thread's job stamp),
         so concurrent jobs' record["program_cache"] deltas no longer
         overlap — the PR 9 caveat is closed."""
+        super()._job_started(record)
         ex = self.executor
         if ex is not None:
             ex.live_jobs.add(record["id"])
             record["_pc_base"] = True
 
     def _job_finished(self, record):
+        super()._job_finished(record)
         ex = self.executor
         if ex is None:
             return

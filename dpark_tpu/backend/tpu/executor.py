@@ -15,6 +15,7 @@ through the ordinary ShuffleFetcher protocol.
 import os
 import threading
 import time
+import types
 from collections import OrderedDict
 
 import numpy as np
@@ -536,6 +537,15 @@ class _MeshLock:
         self.t_created = time.time()
 
     def __enter__(self):
+        self._enter(True)
+        return self
+
+    def try_enter(self):
+        """Enter only if that takes no waiting (the lock is free, or
+        this thread's already); False otherwise.  Leave by __exit__."""
+        return self._enter(False)
+
+    def _enter(self, blocking):
         tls = self._tls
         depth = getattr(tls, "depth", 0)
         if depth:
@@ -543,21 +553,27 @@ class _MeshLock:
             # busy interval
             self._lock.acquire()
             tls.depth = depth + 1
-            return self
+            return True
         # lockcheck plane: one global load + `is None` check when off;
-        # noted BEFORE the acquire so a strict-mode cycle raises as a
-        # stack trace instead of wedging here
-        locks.note_acquire("executor.mesh")
+        # noted BEFORE a blocking acquire so a strict-mode cycle raises
+        # as a stack trace instead of wedging here (a try cannot
+        # wedge: noted once held, the edges are the same)
+        if blocking:
+            locks.note_acquire("executor.mesh")
         t0 = time.time()
         wait = 0.0
         if not self._lock.acquire(False):
+            if not blocking:
+                return False
             self._lock.acquire()
             wait = time.time() - t0
+        if not blocking:
+            locks.note_acquire("executor.mesh")
         tls.depth = 1
         tls.t_request = t0
         tls.t_acquired = time.time()
         tls.wait = wait
-        return self
+        return True
 
     def __exit__(self, *exc):
         tls = self._tls
@@ -656,6 +672,9 @@ class JAXExecutor:
         # threads reach host_read too, and a read-modify-write that
         # races there can lose a count.
         self.program_launches = 0
+        # stores dropped because their ShuffleDependency died (the
+        # scheduler's drain): the release engaging, once a store
+        self.stores_released = 0
         # count arrays whose host sum is deferred (the ndev==1 fast
         # path must not pay a blocking readback per wave just for this
         # metric); flushed on first metric read, or opportunistically
@@ -737,6 +756,9 @@ class JAXExecutor:
         # scheduler hook: called as (sid, uri) after an HBM store is
         # spilled to disk so stage output locations follow the move
         self._spill_notify = None
+        # scheduler hook: drop the stores whose dependency died, so
+        # eviction never spills what nothing can read
+        self._release_unreachable = None
         # coded-shuffle shard serving (ISSUE 6): each hbm bucket is
         # lazily serialized + erasure-encoded ONCE, then individual
         # framed shards answer per-shard fetches.  Builds serialize
@@ -841,27 +863,36 @@ class JAXExecutor:
         return merge_fn, monoid
 
     @staticmethod
-    def _epilogue_block(plan, lv, n, n_dst, merge_fn, monoid, bounds):
+    def _epilogue_params(plan):
+        """What _epilogue_block needs of a shuffle-writing plan, as
+        plain values: a compiled program's closure outlives the job
+        in the program cache, and must hold neither the plan nor,
+        through it, the ShuffleDependency (whose death releases the
+        store)."""
+        return (getattr(plan, "epi_nk", 1) or 1,
+                plan.epilogue[1].partitioner.num_partitions,
+                plan.epi_spec)
+
+    @staticmethod
+    def _epilogue_block(epi, lv, n, n_dst, merge_fn, monoid, bounds):
         """Shared shuffle-write tail: destination assignment (hash or
         range bounds over the LOGICAL partition count r <= mesh size) +
-        bucketize[-combine].  Composite (tuple) keys occupy the first
-        plan.epi_nk columns: destinations hash over all of them with
-        the pair-extended phash, and the combine merges rows equal in
-        every key column."""
-        nk = getattr(plan, "epi_nk", 1) or 1
+        bucketize[-combine].  `epi` is _epilogue_params(plan).
+        Composite (tuple) keys occupy the first nk columns:
+        destinations hash over all of them with the pair-extended
+        phash, and the combine merges rows equal in every key
+        column."""
+        nk, r, epi_spec = epi
         k = lv[0]
-        r = plan.epilogue[1].partitioner.num_partitions
         valid = jnp.arange(k.shape[0]) < n
-        if plan.epi_spec is not None and plan.epi_spec[0] == "range":
+        if epi_spec is not None and epi_spec[0] == "range":
             if nk == 1:
-                dst = collectives.range_dst(k, bounds,
-                                            plan.epi_spec[1],
+                dst = collectives.range_dst(k, bounds, epi_spec[1],
                                             n_dst, valid, r=r)
             else:
                 bcols = [bounds[:, i] for i in range(nk)]
                 dst = collectives.range_dst_cols(
-                    lv[:nk], bcols, plan.epi_spec[1], n_dst, valid,
-                    r=r)
+                    lv[:nk], bcols, epi_spec[1], n_dst, valid, r=r)
         else:
             dst = collectives.hash_dst_cols(lv[:nk], n_dst, valid,
                                             r=r)
@@ -875,12 +906,14 @@ class JAXExecutor:
             k2s, v2 = sorted_lv[:nk], sorted_lv[nk:]
         return (cnts, offs) + tuple(k2s) + tuple(v2)
 
-    def _widen_entry(self, plan, lv):
-        """Cast program inputs up to the spec dtypes: ingest may ship
-        int64 leaves over the host->device wire as i32 (layout.ingest's
-        fit scan); compute always runs at spec width."""
+    @staticmethod
+    def _widen_entry(in_specs, lv):
+        """Cast program inputs up to the spec dtypes (plan.in_specs):
+        ingest may ship int64 leaves over the host->device wire as i32
+        (layout.ingest's fit scan); compute always runs at spec
+        width."""
         return [v if v.dtype == dt else v.astype(dt)
-                for v, (dt, _) in zip(lv, plan.in_specs)]
+                for v, (dt, _) in zip(lv, in_specs)]
 
     def _compile_narrow(self, plan, cap, nleaves_in, in_dtypes=(),
                         donate=False, extra_key=()):
@@ -904,21 +937,23 @@ class JAXExecutor:
         epilogue = plan.epilogue
         n_dst = self.ndev
         has_bounds = plan.epi_bounds is not None
-        merge_fn = monoid = None
+        merge_fn = monoid = epi = None
         if epilogue is not None:
             merge_fn, monoid = self._epilogue_merge(plan)
+            epi = self._epilogue_params(plan)
+        in_specs = plan.in_specs
 
         def per_device(counts, *rest):
             n = counts[0]
             bounds = rest[0][0] if has_bounds else None
             leaves = rest[1:] if has_bounds else rest
-            lv = self._widen_entry(plan, [l[0] for l in leaves])
+            lv = self._widen_entry(in_specs, [l[0] for l in leaves])
             for op in ops:
                 lv, n = op.apply(lv, n)
-            if epilogue is None:
+            if epi is None:
                 return (jnp.expand_dims(n, 0),) + tuple(
                     jnp.expand_dims(l, 0) for l in lv)
-            out = self._epilogue_block(plan, lv, n, n_dst, merge_fn,
+            out = self._epilogue_block(epi, lv, n, n_dst, merge_fn,
                                        monoid, bounds)
             return tuple(jnp.expand_dims(o, 0) for o in out)
 
@@ -1049,9 +1084,10 @@ class JAXExecutor:
         epilogue = plan.epilogue
         n_dst = self.ndev
         has_bounds = plan.epi_bounds is not None
-        out_merge_fn = out_monoid = None
+        out_merge_fn = out_monoid = epi = None
         if epilogue is not None:
             out_merge_fn, out_monoid = self._epilogue_merge(plan)
+            epi = self._epilogue_params(plan)
 
         src_nk = getattr(plan, "src_nk", 1) or 1
 
@@ -1078,10 +1114,10 @@ class JAXExecutor:
                 n = jnp.sum(mask).astype(jnp.int32)
             for op in ops:
                 lv, n = op.apply(lv, n)
-            if epilogue is None:
+            if epi is None:
                 return (jnp.expand_dims(n, 0),) + tuple(
                     jnp.expand_dims(l, 0) for l in lv)
-            out = self._epilogue_block(plan, lv, n, n_dst, out_merge_fn,
+            out = self._epilogue_block(epi, lv, n, n_dst, out_merge_fn,
                                        out_monoid, bounds)
             return tuple(jnp.expand_dims(o, 0) for o in out)
 
@@ -1530,9 +1566,20 @@ class JAXExecutor:
         jobs still grow (keep_sid) stays pinned.  Cached results still
         drop — they recompute on next use and have no disk format.
         A spill that fails (disk full) falls back to dropping the
-        store, which is exactly the old lineage-recovery contract."""
+        store, which is exactly the old lineage-recovery contract.
+
+        LIFETIME (ISSUE 25): a store lives as long as the RDD that
+        shuffled it.  The stores of chains nobody holds any more are
+        released first (the scheduler's `_release_unreachable`, which
+        also runs when a job starts and when one finishes), so what
+        is left to spill here is what something can still read: a
+        dropped chain frees its HBM at the next job, a held one
+        spills under pressure as before."""
         # the budget is PER DEVICE (conf.py) and the byte counters sum
         # whole sharded arrays: a four-chip mesh holds four budgets
+        release = self._release_unreachable
+        if release is not None:
+            release()
         budget = conf.SHUFFLE_HBM_BUDGET * self.ndev
         pinned = set()      # in-flight stores (outputs not registered)
         while self._store_bytes + self._result_bytes > budget:
@@ -2498,12 +2545,13 @@ class JAXExecutor:
             merge_fn, monoid = self._merge_probe(plan)
 
         nk = getattr(plan, "epi_nk", 1) or 1
+        in_specs = plan.in_specs
 
         def per_device(counts, *rest):
             n = counts[0]
             bounds = rest[0][0] if has_bounds else None
             leaves = rest[1:] if has_bounds else rest
-            lv = self._widen_entry(plan, [l[0] for l in leaves])
+            lv = self._widen_entry(in_specs, [l[0] for l in leaves])
             for op in ops:
                 lv, n = op.apply(lv, n)
             k = lv[0]
@@ -3115,29 +3163,26 @@ class JAXExecutor:
         """No-combine exchange leaving the result ON DEVICE: per-device
         key-sorted rows as (counts, leaves...) global arrays."""
 
-        class _GatherPlan:
-            source = ("hbm", dep)
-            ops = []
-            epilogue = None
-            src_combine = False
-            group_output = False
-            epi_spec = None
-            epi_bounds = None
-            epi_nk = 1
+        # an instance, not a class made per call: a class is part of
+        # reference cycles (its __dict__, its mro), so only the cyclic
+        # collector would free it, and `source` holds the dependency
+        # whose death releases the store
+        plan = types.SimpleNamespace(
+            source=("hbm", dep), ops=[], epilogue=None,
+            src_combine=False, group_output=False, epi_spec=None,
+            epi_bounds=None, epi_nk=1,
             # sort gathered rows by the FULL key (tuple keys span
             # key_cols columns) so cogroup/join consumers see the same
             # lexicographic order the host merge expects
-            src_nk = store.get("key_cols", 1) or 1
-            in_treedef = store["out_treedef"]
-            in_specs = store["out_specs"]
-            out_treedef = store["out_treedef"]
-            out_specs = store["out_specs"]
-            stage = None
-            program_key = ("gather", src_nk,
-                           tuple((str(dt), shape)
-                                 for dt, shape in store["out_specs"]))
-
-        outs = self._run_exchange_and_reduce(_GatherPlan)
+            src_nk=store.get("key_cols", 1) or 1,
+            in_treedef=store["out_treedef"],
+            in_specs=store["out_specs"],
+            out_treedef=store["out_treedef"],
+            out_specs=store["out_specs"], stage=None)
+        plan.program_key = ("gather", plan.src_nk,
+                            tuple((str(dt), shape)
+                                  for dt, shape in store["out_specs"]))
+        outs = self._run_exchange_and_reduce(plan)
         return outs[0], list(outs[1:])          # counts, leaves
 
     def run_device_join(self, dep_a, dep_b):

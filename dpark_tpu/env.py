@@ -30,6 +30,9 @@ class MapOutputTracker:
     def get_outputs(self, shuffle_id):
         return self.locs.get(shuffle_id)
 
+    def remove_outputs(self, shuffle_id):
+        self.locs.pop(shuffle_id, None)
+
     def invalidate_host(self, shuffle_id, host):
         locs = self.locs.get(shuffle_id, [])
         for i, uri in enumerate(locs):

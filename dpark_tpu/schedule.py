@@ -9,11 +9,13 @@ LocalScheduler and MultiProcessScheduler masters (SURVEY.md sections 2.1,
 dispatch belongs to the DCN layer (see backend/).
 """
 
+import collections
 import multiprocessing
 import pickle
 import queue
 import threading
 import traceback
+import weakref
 
 import sys
 
@@ -50,16 +52,34 @@ class Stage:
 
     def __init__(self, rdd, shuffle_dep, parents):
         self.id = next(Stage._next_id)
-        self.rdd = rdd
-        self.shuffle_dep = shuffle_dep          # None for a result stage
+        # a stage OWNS neither its rdd nor its dependency: the user's
+        # RDD chain does (run_job holds the final rdd for the life of
+        # a job, and the lineage holds every dependency below it), so
+        # a map stage that outlives its job in shuffle_to_stage keeps
+        # no chain alive — once the chain is dropped the dependency
+        # dies and its shuffle state is released (_release_unreachable)
+        self._rdd = weakref.ref(rdd)
+        self._shuffle_dep = None if shuffle_dep is None \
+            else weakref.ref(shuffle_dep)      # None for a result stage
         self.parents = parents
         self.num_partitions = len(rdd.splits)
         # per-map-partition output URI when this is a shuffle map stage
         self.output_locs = [None] * self.num_partitions
 
     @property
+    def rdd(self):
+        return self._rdd()
+
+    @property
+    def shuffle_dep(self):
+        """The live ShuffleDependency (None for a result stage, and
+        for a map stage whose dependency nothing holds any more)."""
+        ref = self._shuffle_dep
+        return None if ref is None else ref()
+
+    @property
     def is_shuffle_map(self):
-        return self.shuffle_dep is not None
+        return self._shuffle_dep is not None
 
     @property
     def is_available(self):
@@ -87,6 +107,11 @@ class DAGScheduler:
     def __init__(self):
         from dpark_tpu.env import env
         self.shuffle_to_stage = {}
+        # ids of shuffles whose ShuffleDependency nothing holds any
+        # more: appended by the dependency's weakref.finalize (any
+        # thread, at any decref: no lock, nothing but the append) and
+        # drained by _release_unreachable
+        self._unreachable = collections.deque()
         self.started = False
         self.profile = None            # MergedProfile when --profile
         # host health, SHARED with the shuffle fetcher's replica choice
@@ -157,7 +182,32 @@ class DAGScheduler:
             if stage is None:
                 stage = self.new_stage(dep.rdd, dep)
                 self.shuffle_to_stage[dep.shuffle_id] = stage
+                # an RDD that comes back from a pickle has no ctx and
+                # runs no job, so this object is the shuffle's only
+                # dependency a job can ever meet
+                weakref.finalize(dep, self._unreachable.append,
+                                 dep.shuffle_id).atexit = False
             return stage
+
+    def _release_unreachable(self):
+        """Forget every shuffle whose dependency died since the last
+        call: its stage, its map-output locations and (the hook) the
+        master's own state for it.  A shuffle's state lives exactly as
+        long as its ShuffleDependency is reachable from the user's
+        RDDs; called when a job starts and when one finishes."""
+        pending = self._unreachable
+        while pending:
+            try:
+                sid = pending.popleft()
+            except IndexError:          # another driver thread got it
+                return
+            self.shuffle_to_stage.pop(sid, None)
+            env.map_output_tracker.remove_outputs(sid)
+            self._shuffle_unreachable(sid)
+
+    def _shuffle_unreachable(self, sid):
+        """Hook: nothing can read shuffle `sid` again (the tpu master
+        frees its HBM store)."""
 
     def get_parent_stages(self, rdd):
         with self._graph_lock:
@@ -379,6 +429,11 @@ class DAGScheduler:
             self._trace_job_span(record, job_t0)
             self._finalize_health(record)
             self._job_finished(record)
+            # submit_stage calls itself through this frame's cell, a
+            # cycle that holds final_rdd (submit_missing_tasks' cell):
+            # break it, so a chain the caller drops dies by reference
+            # count and not when the cyclic collector next runs
+            submit_stage = None
 
     def _new_job_record(self, final_rdd, parts, stages=1):
         import time as _time
@@ -464,10 +519,12 @@ class DAGScheduler:
     def _job_started(self, record):
         """Hook: a job record was minted (the tpu master pins the
         job's HBM buckets and snapshots program-cache counters)."""
+        self._release_unreachable()
 
     def _job_finished(self, record):
         """Hook: the job finalized (counters attributed, pins
         released)."""
+        self._release_unreachable()
 
     def _finalize_health(self, record):
         """Health-plane job hook (ISSUE 14): per-tenant SLO accounting
@@ -1399,8 +1456,12 @@ class DAGScheduler:
                         "task for partition %d of stage %d failed %d times; "
                         "last error:\n%s" % (task.partition, task.stage_id,
                                              failures[key], payload))
-                logger.warning("task %r failed (try %d): %s",
-                               task, failures[key], str(payload)[:200])
+                # repr now: a handler that keeps its records (pytest's
+                # capture, a MemoryHandler) must not keep the task,
+                # and through it the job's RDD chain
+                logger.warning("task %s failed (try %d): %s",
+                               repr(task), failures[key],
+                               str(payload)[:200])
                 # a retry is a FRESH attempt with its own task id — no
                 # shared-object mutation between attempts, so completion
                 # attribution stays unambiguous when dispatch crosses

@@ -479,6 +479,9 @@ class JobServer:
             if item is _STOP:
                 return
             self._execute(item)
+            # an idle slot must not hold its last stage's tasks, and
+            # through them the job's RDD chain and dependencies
+            del item
 
     def _execute(self, item):
         from dpark_tpu import adapt, trace
@@ -698,7 +701,12 @@ def serve(addr="127.0.0.1:0", master=None, server=None):
         from dpark_tpu import serialize
         fn = serialize.loads(base64.b64decode(payload))
         ctx = _context_for(srv, "remote:%s" % client, slo_ms=slo_ms)
-        return compress(pickle.dumps(fn(ctx), -1))
+        try:
+            return compress(pickle.dumps(fn(ctx), -1))
+        finally:
+            # the function's chains died with its frame: their stores
+            # go now, not at some tenant's next job
+            srv.scheduler._release_unreachable()
 
     def handle(req):
         kind = req[0]

@@ -49,6 +49,16 @@ def pytest_collection_modifyitems(config, items):
             item.add_marker(skip)
 
 
+def shuffled_on_device(ctx):
+    """Did a job of this context write a shuffle on the array path?
+    Read from the job records: the executor's shuffle_store holds only
+    the stores of chains that are still held (a store lives as long as
+    the RDD that shuffled it)."""
+    return any(st.get("shuffle") and str(st.get("kind")).startswith("array")
+               for rec in ctx.scheduler.history
+               for st in rec["stage_info"])
+
+
 def load_tool(name):
     """Import one of the extensionless tools/ CLIs (dtrace, ...) or a
     tools/*.py script as a module — shared by every tool-driving

@@ -645,9 +645,10 @@ def test_executor_cols_export_matches_rows(tmp_path):
     ctx = DparkContext("tpu:2")
     ctx.start()
     try:
-        got = dict(ctx.parallelize([(i % 11, i % 5)
-                                    for i in range(2000)], 2)
-                   .reduceByKey(lambda a, b: a + b, 2).collect())
+        # held: the store lives as long as the RDD that shuffled it
+        rdd = ctx.parallelize([(i % 11, i % 5) for i in range(2000)], 2) \
+            .reduceByKey(lambda a, b: a + b, 2)
+        got = dict(rdd.collect())
         assert len(got) == 11
         ex = ctx.scheduler.executor
         assert ex.shuffle_store, "job did not ride the array path"

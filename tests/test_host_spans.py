@@ -59,12 +59,19 @@ def _cached(c, keys, values, ndev):
     return rdd
 
 
-def _agg_job(c):
+def _agg_job(c, hold=None):
+    """`hold`, a list, keeps every job's chain: a store lives as long
+    as the RDD that shuffled it, and only a held store can spill."""
     ndev = c.scheduler.executor.ndev
     keys = np.arange(4096, dtype=np.int64) % 37
     table = _cached(c, keys, np.ones(4096, np.int64), ndev)
-    return lambda: sorted(
-        table.map(_mod8).reduceByKey(_add, ndev).collect())
+
+    def job():
+        rdd = table.map(_mod8).reduceByKey(_add, ndev)
+        if hold is not None:
+            hold.append(rdd)
+        return sorted(rdd.collect())
+    return job
 
 
 def _join_job(c):
@@ -197,14 +204,15 @@ def test_one_chip_identity_exchange_metric_read_is_a_readback():
 
 
 # ---------------------------------------------------------------------------
-# (b) the spill of dead stores
+# (b) the spill of held stores (the store of a dropped chain is released)
 # ---------------------------------------------------------------------------
 
 def test_spill_spans_match_the_release_events(tctx2):
     """One `hbm.spill` span a spilled store, beside the `hbm.release`
     event (reason `spill`) the HBM accounts already fold: same stores,
     same bytes."""
-    job = _agg_job(tctx2)
+    held = []
+    job = _agg_job(tctx2, hold=held)
     expected = job()
     trace.configure("ring")
     old = conf.SHUFFLE_HBM_BUDGET

@@ -123,9 +123,12 @@ def test_stage_fingerprint_stable_across_builds(ctx):
     identically; a different partitioner width does not."""
     from dpark_tpu.schedule import Stage
 
+    held = []       # a stage owns neither its rdd nor its dependency
+
     def stage_of(width):
         r = ctx.parallelize([(1, 2)], 2).reduceByKey(operator.add,
                                                      width)
+        held.append(r)
         dep = r.dependencies[0]
         return Stage(dep.rdd, dep, [])
 

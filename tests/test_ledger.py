@@ -356,8 +356,9 @@ def test_hbm_release_settles_after_tracing_turned_off(tctx2):
     sink's residency entry — else the live gauge reports freed memory
     forever and the byte-seconds never accrue."""
     trace.configure("ring")
-    dict(tctx2.parallelize(_device_data(6000), 2)
-         .reduceByKey(lambda a, b: a + b, 2).collect())
+    rdd = tctx2.parallelize(_device_data(6000), 2) \
+        .reduceByKey(lambda a, b: a + b, 2)       # held: so is its store
+    dict(rdd.collect())
     assert ledger.snapshot()["hbm_live_bytes"] > 0
     trace.configure("off")
     ex = tctx2.scheduler.executor
@@ -374,8 +375,9 @@ def test_hbm_release_settles_after_tracing_turned_off(tctx2):
 def test_hbm_byte_seconds_on_device_store_drop(tctx2):
     trace.configure("ring")
     try:
-        got = dict(tctx2.parallelize(_device_data(8000), 2)
-                   .reduceByKey(lambda a, b: a + b, 2).collect())
+        rdd = tctx2.parallelize(_device_data(8000), 2) \
+            .reduceByKey(lambda a, b: a + b, 2)   # held: so is its store
+        got = dict(rdd.collect())
         assert len(got) == 37
         ex = tctx2.scheduler.executor
         assert ledger.snapshot()["hbm_live_bytes"] > 0

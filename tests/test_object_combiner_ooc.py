@@ -53,8 +53,9 @@ def test_untraceable_merge_streams_columnar(tctx, small_chunks):
     i = np.arange(n, dtype=np.int64)
     keys = (i * 7) % 97
     vals = (i % 5 + 1) * 6
-    got = dict(tctx.parallelize(Columns(keys, vals), 8)
-               .reduceByKey(math.gcd, 24).collect())
+    rdd = tctx.parallelize(Columns(keys, vals), 8) \
+        .reduceByKey(math.gcd, 24)              # held: so is its store
+    got = dict(rdd.collect())
     assert got == _expect_gcd(keys, vals)
     stores = tctx.scheduler.executor.shuffle_store
     assert any(s.get("host_combine") for s in stores.values()), \
@@ -67,8 +68,9 @@ def test_untraceable_merge_streams_r_le_mesh(tctx, small_chunks):
     i = np.arange(n, dtype=np.int64)
     keys = i % 53
     vals = (i % 7 + 1) * 10
-    got = dict(tctx.parallelize(Columns(keys, vals), 8)
-               .reduceByKey(math.gcd, 4).collect())
+    rdd = tctx.parallelize(Columns(keys, vals), 8) \
+        .reduceByKey(math.gcd, 4)               # held: so is its store
+    got = dict(rdd.collect())
     assert got == _expect_gcd(keys, vals)
     stores = tctx.scheduler.executor.shuffle_store
     assert any(s.get("host_combine") for s in stores.values())
@@ -78,10 +80,12 @@ def test_untraceable_merge_small_stays_in_core(tctx):
     """Small inputs keep the in-core path (no spill directory)."""
     from dpark_tpu import Columns
     i = np.arange(400, dtype=np.int64)
-    got = dict(tctx.parallelize(Columns(i % 11, i % 3 + 1), 8)
-               .reduceByKey(math.gcd, 4).collect())
+    rdd = tctx.parallelize(Columns(i % 11, i % 3 + 1), 8) \
+        .reduceByKey(math.gcd, 4)               # held: so is its store
+    got = dict(rdd.collect())
     assert got == _expect_gcd(i % 11, i % 3 + 1)
     stores = tctx.scheduler.executor.shuffle_store
+    assert stores
     assert not any(s.get("host_combine") for s in stores.values())
 
 

@@ -344,7 +344,8 @@ def test_forced_ooc_columnar_parity(seed):
                     assert ks == sorted(ks), (master, seed)
                 outs.append(sorted(got))
                 if master == "tpu" and red != "sort":
-                    assert c.scheduler.executor.shuffle_store, \
+                    from tests.conftest import shuffled_on_device
+                    assert shuffled_on_device(c), \
                         "did not ride the device"
             finally:
                 c.stop()
@@ -407,7 +408,8 @@ def test_tuple_key_parity_small_mesh():
         got, exp = both(lambda c: c.parallelize(data, 2)
                         .reduceByKey(operator.add, 2).collect())
         assert got == exp
-        assert tctx.scheduler.executor.shuffle_store, \
+        from tests.conftest import shuffled_on_device
+        assert shuffled_on_device(tctx), \
             "tuple-key reduce did not ride the device"
         got, exp = both(lambda c: [
             (k, sorted(v)) for k, v in

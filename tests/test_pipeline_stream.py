@@ -71,9 +71,9 @@ def test_streamed_combine_parity_pipeline_on_off(tctx2, tiny_waves):
     got = {}
     for depth, donate, writer in _pipeline_modes():
         _set_mode(depth, donate, writer)
-        got[depth] = dict(
-            tctx2.parallelize(Columns(keys, vals), 2)
-            .reduceByKey(lambda a, b: a + b, 2).collect())
+        rdd = tctx2.parallelize(Columns(keys, vals), 2) \
+            .reduceByKey(lambda a, b: a + b, 2)   # held: so is its store
+        got[depth] = dict(rdd.collect())
         ex = tctx2.scheduler.executor
         assert any(s.get("pre_reduced")
                    for s in ex.shuffle_store.values()), "did not stream"
@@ -94,8 +94,9 @@ def test_streamed_nocombine_parity_pipeline_on_off(tctx2, tiny_waves):
     got = {}
     for depth, donate, writer in _pipeline_modes():
         _set_mode(depth, donate, writer)
-        got[depth] = tctx2.parallelize(Columns(keys, vals), 2) \
-            .sortByKey(numSplits=8).collect()
+        rdd = tctx2.parallelize(Columns(keys, vals), 2) \
+            .sortByKey(numSplits=8)               # held: so is its store
+        got[depth] = rdd.collect()
         ex = tctx2.scheduler.executor
         assert any("host_runs" in s
                    for s in ex.shuffle_store.values()), "did not spill"
@@ -134,9 +135,9 @@ def test_premerge_runs_in_background(tctx2, tiny_waves):
     the first reduce fetch."""
     keys = np.arange(15000, dtype=np.int64) % 97
     vals = np.arange(15000, dtype=np.int64) % 13
-    got = {k: sorted(v) for k, v in
-           tctx2.parallelize(Columns(keys, vals), 2)
-           .groupByKey(8).collect()}
+    rdd = tctx2.parallelize(Columns(keys, vals), 2) \
+        .groupByKey(8)                            # held: so is its store
+    got = {k: sorted(v) for k, v in rdd.collect()}
     expect = {}
     for k, v in zip(keys.tolist(), vals.tolist()):
         expect.setdefault(k, []).append(v)
@@ -171,9 +172,9 @@ def test_cancellation_mid_stream_shuts_down_threads(tctx2, tiny_waves):
     keys = np.arange(8000, dtype=np.int64) % 53
     keys[6500] = KEY_SENTINEL          # wave ~13 of 16 fails at ingest
     vals = np.ones(8000, dtype=np.int64)
-    got = {k: sorted(v) for k, v in
-           tctx2.parallelize(Columns(keys, vals), 2)
-           .groupByKey(8).collect()}
+    rdd = tctx2.parallelize(Columns(keys, vals), 2) \
+        .groupByKey(8)            # held: a streamed store would stay
+    got = {k: sorted(v) for k, v in rdd.collect()}
     # object fallback computed the right answer (sentinel key included)
     assert got[int(KEY_SENTINEL)] == [1]
     assert sum(len(v) for v in got.values()) == 8000

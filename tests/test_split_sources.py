@@ -232,7 +232,8 @@ def test_csvfile_rides_device_text_path(tmp_path):
         got = dict(tctx.csvFile(p)
                    .map(lambda row: (row[0], int(row[1])))
                    .reduceByKey(lambda a, b: a + b, 4).collect())
-        assert tctx.scheduler.executor.shuffle_store, "host fallback"
+        from tests.conftest import shuffled_on_device
+        assert shuffled_on_device(tctx), "host fallback"
         lctx = DparkContext("local")
         expect = dict(lctx.csvFile(p)
                       .map(lambda row: (row[0], int(row[1])))

@@ -32,8 +32,12 @@ def tiny_waves():
 
 
 def _spilled(tctx):
-    ex = tctx.scheduler.executor
-    return any("host_runs" in s for s in ex.shuffle_store.values())
+    """Did a shuffle stage stream through spilled runs?  From the job
+    records (kind `array+spill`): a store lives only as long as the
+    RDD that shuffled it, which most tests here do not keep."""
+    return any(st.get("kind") == "array+spill"
+               for rec in tctx.scheduler.history
+               for st in rec["stage_info"])
 
 
 def test_streamed_sortbykey(tctx, tiny_waves):
@@ -91,11 +95,14 @@ def test_streamed_text_wordcount(tctx, tiny_waves, tmp_path):
         for _ in range(3000):
             f.write(" ".join(rng.choices(words, k=6)) + "\n")
 
+    held = []                   # the chains: a store lives with its
+
     def run(ctx):
-        return dict(ctx.textFile(p, splitSize=2000)
+        held.append(ctx.textFile(p, splitSize=2000)
                     .flatMap(lambda line: line.split())
                     .map(lambda w: (w, 1))
-                    .reduceByKey(lambda a, b: a + b, 8).collect())
+                    .reduceByKey(lambda a, b: a + b, 8))
+        return dict(held[-1].collect())
 
     from dpark_tpu import DparkContext
     got = run(tctx)
@@ -175,10 +182,10 @@ def test_streamed_generic_combiner(tctx, tiny_waves):
     n = 12000
     keys = (np.arange(n, dtype=np.int64) * 13) % 37
     vals = np.arange(n, dtype=np.int64) % 9
-    got = dict(tctx.parallelize(Columns(keys, vals), 8)
-               .mapValue(lambda v: (v, 1))
-               .reduceByKey(lambda a, b: (a[0] + b[0], a[1] + b[1]), 8)
-               .collect())
+    rdd = tctx.parallelize(Columns(keys, vals), 8) \
+        .mapValue(lambda v: (v, 1)) \
+        .reduceByKey(lambda a, b: (a[0] + b[0], a[1] + b[1]), 8)
+    got = dict(rdd.collect())             # rdd held: so is its store
     ex = tctx.scheduler.executor
     assert any(s.get("pre_reduced")
                for s in ex.shuffle_store.values()), "did not stream"
@@ -196,8 +203,9 @@ def test_logical_partitions_beyond_mesh(tctx, tiny_waves):
     rng = np.random.RandomState(11)
     keys = rng.randint(0, 10**6, 20000).astype(np.int64)
     vals = np.arange(20000, dtype=np.int64)
-    got = tctx.parallelize(Columns(keys, vals), 8) \
-              .sortByKey(numSplits=32).collect()
+    rdd = tctx.parallelize(Columns(keys, vals), 8) \
+        .sortByKey(numSplits=32)              # held: so is its store
+    got = rdd.collect()
     assert _spilled(tctx)
     store = [s for s in tctx.scheduler.executor.shuffle_store.values()
              if "host_runs" in s][0]
@@ -235,14 +243,15 @@ def test_traceable_monoid_beyond_mesh(tctx, tiny_waves):
     i = np.arange(n, dtype=np.int64)
     keys = (i * 13) % 37
     vals = i % 7
-    got = dict(tctx.parallelize(Columns(keys, vals), 8)
-               .reduceByKey(lambda a, b: a + b, 24).collect())
+    rdd = tctx.parallelize(Columns(keys, vals), 8) \
+        .reduceByKey(lambda a, b: a + b, 24)  # held: so is its store
+    got = dict(rdd.collect())
     assert _spilled(tctx)
     store = [s for s in tctx.scheduler.executor.shuffle_store.values()
              if "host_runs" in s][0]
     assert store["host_combine"]
     # 5 waves x <=37 distinct keys: far fewer spilled rows than input
-    assert _spilled_rows(tctx) <= 37 * 8, _spilled_rows(tctx)
+    assert 0 < _spilled_rows(tctx) <= 37 * 8, _spilled_rows(tctx)
     expect = {}
     for k, v in zip(keys.tolist(), vals.tolist()):
         expect[k] = expect.get(k, 0) + v
@@ -256,12 +265,12 @@ def test_traceable_generic_merge_beyond_mesh(tctx, tiny_waves):
     i = np.arange(n, dtype=np.int64)
     keys = (i * 31) % 101
     vals = i % 9
-    got = dict(tctx.parallelize(Columns(keys, vals), 8)
-               .mapValue(lambda v: (v, 1))
-               .reduceByKey(lambda a, b: (a[0] + b[0], a[1] + b[1]), 32)
-               .collect())
+    rdd = tctx.parallelize(Columns(keys, vals), 8) \
+        .mapValue(lambda v: (v, 1)) \
+        .reduceByKey(lambda a, b: (a[0] + b[0], a[1] + b[1]), 32)
+    got = dict(rdd.collect())             # rdd held: so is its store
     assert _spilled(tctx)
-    assert _spilled_rows(tctx) <= 101 * 8, _spilled_rows(tctx)
+    assert 0 < _spilled_rows(tctx) <= 101 * 8, _spilled_rows(tctx)
     expect = {}
     for k, v in zip(keys.tolist(), vals.tolist()):
         s, c = expect.get(k, (0, 0))

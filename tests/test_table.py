@@ -172,7 +172,7 @@ def test_sql_order_by_variants(ctx, sales):
 @pytest.mark.mesh
 def test_sql_group_by_rides_device_shuffle():
     """VERDICT r3 #8: ctx.sql GROUP BY sum/count/avg/min/max compiles
-    onto the monoid device shuffle (shuffle_store populated, wire bytes
+    onto the monoid device shuffle (a shuffle stage of kind array, wire bytes
     moved) — the Table DSL inherits the core's speed, with results
     matching the host-computed expectation exactly."""
     from dpark_tpu import DparkContext
@@ -185,8 +185,10 @@ def test_sql_group_by_rides_device_shuffle():
             "select g, sum(x) as sx, count(*) as c, avg(y) as ay, "
             "min(x) as mn, max(y) as mx from t group by g order by g",
             t=t).collect()
+        from tests.conftest import shuffled_on_device
         ex = tctx.scheduler.executor
-        assert ex.shuffle_store, "SQL group-by did not ride the device"
+        assert shuffled_on_device(tctx), \
+            "SQL group-by did not ride the device"
         assert ex.exchange_wire_bytes > 0, "no device exchange ran"
         exp = {}
         for g, x, y in rows:
@@ -301,8 +303,9 @@ def test_sql_join_group_rides_device():
                     continue
                 kinds.add((s_["rdd"], s_.get("kind")))
         assert ("CoGroupedRDD", "array") not in kinds
-        ex = tctx.scheduler.executor
-        assert ex.shuffle_store, "SQL join+group did not ride the device"
+        from tests.conftest import shuffled_on_device
+        assert shuffled_on_device(tctx), \
+            "SQL join+group did not ride the device"
         arr = {v for _, v in kinds}
         assert "array" in arr, kinds
     finally:

@@ -45,8 +45,9 @@ def _local_counts(path, **kw):
 
 
 def _text_path_used(tctx):
-    ex = tctx.scheduler.executor
-    return bool(ex.shuffle_store) and hasattr(ex, "token_dict")
+    from tests.conftest import shuffled_on_device
+    return shuffled_on_device(tctx) \
+        and hasattr(tctx.scheduler.executor, "token_dict")
 
 
 def test_canonical_wordcount_rides_device(tctx, corpus):
@@ -102,7 +103,8 @@ def test_int_key_text_chain_no_encoding(tctx, tmp_path):
     expect = run(lctx)
     lctx.stop()
     assert got == expect
-    assert tctx.scheduler.executor.shuffle_store
+    from tests.conftest import shuffled_on_device
+    assert shuffled_on_device(tctx)
 
 
 def test_group_by_key_words(tctx, corpus):
@@ -421,7 +423,8 @@ def test_tabular_source_rides_device(tctx, tmp_path):
                     .reduceByKey(lambda a, b: a + b, 4).collect())
 
     got = run(tctx)
-    assert tctx.scheduler.executor.shuffle_store, "host fallback"
+    from tests.conftest import shuffled_on_device
+    assert shuffled_on_device(tctx), "host fallback"
     lctx = DparkContext("local")
     expect = run(lctx)
     lctx.stop()

@@ -17,7 +17,8 @@ def tctx():
 
 
 def _used_array_path(tctx):
-    return len(tctx.scheduler.executor.shuffle_store) > 0
+    from tests.conftest import shuffled_on_device
+    return shuffled_on_device(tctx)
 
 
 def test_parallelize_collect_roundtrip(tctx):
@@ -404,8 +405,9 @@ def test_streamed_shuffle_out_of_core(tctx):
         i = np.arange(n, dtype=np.int64)
         keys = (i * 2654435761) % 37
         vals = np.ones(n, dtype=np.int64)
-        got = dict(tctx.parallelize(Columns(keys, vals), 8)
-                   .reduceByKey(lambda a, b: a + b, 8).collect())
+        rdd = tctx.parallelize(Columns(keys, vals), 8) \
+            .reduceByKey(lambda a, b: a + b, 8)   # held: so is its store
+        got = dict(rdd.collect())
         expect = {}
         for k in np.unique(keys):
             expect[int(k)] = int((keys == k).sum())
