@@ -441,11 +441,29 @@ def _rule_monoid_multileaf(r, report):
         "scalar value per record" % kind)
 
 
-def _key_fallback_reason(key, hash_keys=True):
+def _fixed_bytes_width(rdd, _depth=0):
+    """Widest S<w> column of the Columns source a narrow chain reads,
+    or None: a `bytes` key over such a source is a fixed-width
+    byte string (the column, or a slice of it), which the device
+    carries as int64 words."""
+    from dpark_tpu import rdd as _rdd
+    while _depth < 16 and not isinstance(rdd, _rdd.ParallelCollection):
+        rdd = getattr(rdd, "prev", None)
+        _depth += 1
+    widths = [c.dtype.itemsize
+              for s in getattr(rdd, "_slices", None) or ()
+              for c in getattr(s, "columns", None) or ()
+              if getattr(c, "dtype", None) is not None
+              and c.dtype.kind == "S"]
+    return max(widths) if widths else None
+
+
+def _key_fallback_reason(key, hash_keys=True, fixed_width=None):
     """Why this record KEY keeps a shuffle off the array path, or None
-    when the key shape classifies (scalar numeric, or a flat numeric
+    when the key shape classifies (scalar numeric, a flat numeric
     tuple of 2..conf.MAX_KEY_LEAVES leaves — the composite keys the
-    device path now carries end to end).  Mirrors layout.key_width /
+    device path now carries end to end — or `bytes` from a fixed-width
+    S<w> column of `fixed_width` bytes).  Mirrors layout.key_width /
     fuse's epilogue checks without importing jax: `hash_keys` is True
     for hash-partitioned shuffles, whose device routing additionally
     needs INT leaves (portable_hash has no device twin for floats);
@@ -471,10 +489,20 @@ def _key_fallback_reason(key, hash_keys=True):
                     if hash_keys else None)
         return "non-numeric"
 
+    if isinstance(key, bytes) and fixed_width is not None:
+        if fixed_width > 8 * conf.MAX_KEY_LEAVES:
+            return ("byte-string column of %d bytes is over the device "
+                    "limit of 8 * conf.MAX_KEY_LEAVES = %d"
+                    % (fixed_width, 8 * conf.MAX_KEY_LEAVES))
+        if not hash_keys:
+            return ("range shuffle (sortByKey) over string or "
+                    "byte-string keys has no device form")
+        return None
     if isinstance(key, (str, bytes)):
         return ("string key: only text-source chains ride the device "
-                "(dictionary-encoded); everything else takes the "
-                "object path")
+                "(dictionary-encoded) and fixed-width byte strings (a "
+                "numpy S<w> column of Columns); everything else takes "
+                "the object path")
     if isinstance(key, tuple):
         if not getattr(conf, "TUPLE_KEYS", True):
             return "tuple key with conf.TUPLE_KEYS disabled"
@@ -514,10 +542,12 @@ def _rule_host_fallback_key(r, report):
     if not rows:
         return                      # not cheaply probeable: stay quiet
     hash_keys = isinstance(r.partitioner, HashPartitioner)
+    fixed_width = _fixed_bytes_width(r.parent)
     for row in rows:
         if not (isinstance(row, tuple) and len(row) == 2):
             continue
-        reason = _key_fallback_reason(row[0], hash_keys=hash_keys)
+        reason = _key_fallback_reason(row[0], hash_keys=hash_keys,
+                                      fixed_width=fixed_width)
         if reason is None:
             continue
         severity = "info" if isinstance(row[0], (str, bytes)) \
@@ -525,7 +555,8 @@ def _rule_host_fallback_key(r, report):
         report.add(
             "host-fallback-key", severity, r.scope_name,
             "this shuffle leaves the array path: %s" % reason,
-            "key by ints/floats or a flat numeric tuple ((k1, k2), v) "
+            "key by ints/floats, a flat numeric tuple ((k1, k2), v) or "
+            "a fixed-width byte-string column (numpy S<w> in Columns) "
             "to stay on the device; see the README device-path "
             "support matrix")
         return

@@ -538,8 +538,12 @@ class TPUScheduler(DAGScheduler):
             nk = layout.key_width(treedef, specs, kinds="if")
             sample = jtu.tree_unflatten(treedef,
                                         list(range(len(specs))))
-            if nk is None or len(sample) != 2:
-                return None          # records must be (k, value) pairs
+            if nk is None or len(sample) != 2 \
+                    or layout.column_groups(treedef,
+                                            len(specs)) is not None:
+                # records must be (k, value) pairs; byte-string
+                # records join on the host (the bridge rebuilds bytes)
+                return None
             key_sigs.append((nk, tuple(np.dtype(dt)
                                        for dt, _ in specs[:nk])))
         if key_sigs[0] != key_sigs[1]:

@@ -21,7 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dpark_tpu.utils.phash import phash_device, phash_device_cols
+from dpark_tpu.utils.phash import (
+    phash_device, phash_device_bytes, phash_device_cols)
 
 def _sentinel(dtype):
     """Max value of the key dtype — padding rows sort last.  ingest()
@@ -43,14 +44,20 @@ def hash_dst(key, n_dst, valid, r=None):
     return jnp.where(valid, dst, n_dst)
 
 
-def hash_dst_cols(key_cols, n_dst, valid, r=None):
+def hash_dst_cols(key_cols, n_dst, valid, r=None, bytes_width=None):
     """hash_dst over a COMPOSITE key (one or more key columns): the
     destination is the pair-extended portable hash over all columns —
     bit-identical to host HashPartitioner.get_partition((k1, ..., kn))
     — so tuple-keyed shuffles land where the host partitioner (lookup,
-    co-partitioned joins) expects."""
+    co-partitioned joins) expects.  With `bytes_width` the columns are
+    the big-endian words of ONE fixed-width byte-string key
+    (layout.ByteStr) and the hash is the host's own of the `bytes`
+    object: rows land where get_partition(b"...") says."""
     r = n_dst if r is None else r
-    h = phash_device_cols(list(key_cols))
+    if bytes_width is not None:
+        h = phash_device_bytes(list(key_cols), bytes_width)
+    else:
+        h = phash_device_cols(list(key_cols))
     dst = (h % jnp.uint32(r)).astype(jnp.int32)
     return jnp.where(valid, dst, n_dst)
 

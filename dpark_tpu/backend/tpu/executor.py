@@ -786,6 +786,18 @@ class JAXExecutor:
         return layout.HOST_READS
 
     @property
+    def bytes_rows_packed(self):
+        """Rows whose byte-string columns were packed into device words
+        at ingest, in this process so far (layout._pack_parts)."""
+        return layout.BYTES_ROWS_PACKED
+
+    @property
+    def bytes_rows_unpacked(self):
+        """Rows whose byte strings were rebuilt as host `bytes` at a
+        host exit (layout.host_columns: egest and the export bridge)."""
+        return layout.BYTES_ROWS_UNPACKED
+
+    @property
     def exchange_real_rows(self):
         """Valid rows offered for exchange.  Reading flushes deferred
         per-wave count arrays (one batched readback at metric-read
@@ -894,8 +906,9 @@ class JAXExecutor:
                 dst = collectives.range_dst_cols(
                     lv[:nk], bcols, epi_spec[1], n_dst, valid, r=r)
         else:
-            dst = collectives.hash_dst_cols(lv[:nk], n_dst, valid,
-                                            r=r)
+            dst = collectives.hash_dst_cols(
+                lv[:nk], n_dst, valid, r=r,
+                bytes_width=fuse.epi_bytes_width(epi_spec))
         if merge_fn is not None or monoid is not None:
             k2s, v2, cnts, offs = collectives.bucketize_combine_keys(
                 lv[:nk], lv[nk:], n, n_dst, merge_fn, monoid=monoid,
@@ -2546,6 +2559,7 @@ class JAXExecutor:
 
         nk = getattr(plan, "epi_nk", 1) or 1
         in_specs = plan.in_specs
+        bytes_width = fuse.epi_bytes_width(plan.epi_spec)
 
         def per_device(counts, *rest):
             n = counts[0]
@@ -2566,8 +2580,8 @@ class JAXExecutor:
                     rid = collectives.range_dst_cols(
                         lv[:nk], bcols, ascending, r, valid, r=r)
             else:
-                rid = collectives.hash_dst_cols(lv[:nk], r, valid,
-                                                r=r)
+                rid = collectives.hash_dst_cols(
+                    lv[:nk], r, valid, r=r, bytes_width=bytes_width)
             if carry_rid and (merge_fn is not None
                               or monoid is not None):
                 cols, cnts, offs = collectives.bucketize_combine_rid(
@@ -3486,8 +3500,9 @@ class JAXExecutor:
                     return []
                 mats = [self._read_dev_slice(l, reduce_id)[:cnt]
                         for l in store["leaves"]]
+            treedef, mats = layout.host_columns(store["out_treedef"],
+                                                mats)
             lists = [m.tolist() for m in mats]
-            treedef = store["out_treedef"]
             rows = [jax.tree_util.tree_unflatten(
                 treedef, [pl[i] for pl in lists]) for i in range(cnt)]
             return self._maybe_decode(store, rows)
@@ -3503,9 +3518,10 @@ class JAXExecutor:
             cols = self._partition_run_cols(store, reduce_id)
             if cols is None:
                 return []
+            treedef, cols = layout.host_columns(store["out_treedef"],
+                                                cols)
             lists = [c.tolist() for c in cols]
             flat2 = jax.tree_util.tree_structure((0, 0))
-            treedef = store["out_treedef"]
             if store.get("host_combine"):
                 # fold the user's merge_combiners over each sorted key
                 # group: values in the runs are already CREATED
@@ -3577,9 +3593,9 @@ class JAXExecutor:
         cnt = int(counts[dev, reduce_id])
         if not cnt:
             return []
-        treedef = store["out_treedef"]
         mats = [self._read_dev_slice(l, dev)[off:off + cnt]
                 for l in store["leaves"]]
+        treedef, mats = layout.host_columns(store["out_treedef"], mats)
         lists = [m.tolist() for m in mats]
         wrap = store.get("no_combine", False)
         rows = []
@@ -3650,17 +3666,28 @@ class JAXExecutor:
         dispatch of its own and none a compiled stage program: one
         `eager` span (site `keycheck`) covers them, and
         program_launches does not count them."""
-        keys, counts = batch.cols[0], batch.counts
+        counts = batch.counts
+        # every key column of a composite (or byte-string) key; one
+        # column takes exactly the operations it always took
+        nk = layout.key_width(
+            batch.treedef, [(np.dtype(c.dtype), tuple(c.shape[2:]))
+                            for c in batch.cols], kinds="if") or 1
         sp = trace._NOOP
         plane = trace._PLANE
         if plane is not None:
             sp = trace.span("eager", "exec", site="keycheck")
         with sp:
-            valid = jnp.arange(keys.shape[1])[None, :] < counts[:, None]
-            if jnp.issubdtype(keys.dtype, jnp.floating):
-                bad = jnp.any(valid & (jnp.isinf(keys) | jnp.isnan(keys)))
-            else:
-                bad = jnp.any(valid & (keys == jnp.iinfo(keys.dtype).max))
+            bad = None
+            for keys in batch.cols[:nk]:
+                valid = jnp.arange(keys.shape[1])[None, :] \
+                    < counts[:, None]
+                if jnp.issubdtype(keys.dtype, jnp.floating):
+                    hit = jnp.any(valid & (jnp.isinf(keys)
+                                           | jnp.isnan(keys)))
+                else:
+                    hit = jnp.any(valid
+                                  & (keys == jnp.iinfo(keys.dtype).max))
+                bad = hit if bad is None else bad | hit
         if bool(layout.host_read(bad, site="keycheck")):
             raise ValueError("cached key equals the device sentinel; "
                              "taking the host path")

@@ -73,8 +73,17 @@ def partitioner_spec(part):
         except Exception:
             return None
         if bounds.dtype == object or bounds.dtype.kind in "USO":
-            return None
+            return _fallback("range shuffle (sortByKey) over string or "
+                             "byte-string keys has no device form")
         return ("range", bool(part.ascending))
+    return None
+
+
+def epi_bytes_width(epi_spec):
+    """Byte width of a hash epilogue's byte-string key — epi_spec
+    ("hash", "bytes", width), set by analyze_stage — else None."""
+    if epi_spec is not None and epi_spec[1:2] == ("bytes",):
+        return epi_spec[2]
     return None
 
 
@@ -344,6 +353,11 @@ class SortOp:
 
     def probe(self, treedef, specs):
         nk = layout.key_width(treedef, specs, kinds="if")
+        if nk is not None and layout.bytes_key_width(
+                treedef, len(specs)) is not None:
+            # a byte string's words order as its bytes only for ASCII
+            _fallback("sort over a byte-string key has no device form")
+            nk = None
         if nk is None:
             raise TypeError("sort needs a numeric scalar (or flat "
                             "numeric tuple) key")
@@ -1034,8 +1048,59 @@ def _sample_record(pc):
     """First record of a ParallelCollection (driver-side only)."""
     for s in pc._slices:
         if s:
-            return s[0]
+            cols = [np.asarray(c)
+                    for c in getattr(s, "columns", None) or ()]
+            if not any(c.dtype.kind == "S" for c in cols):
+                return s[0]
+            # a fixed-width byte-string column: its first element as a
+            # 0-d array keeps the COLUMN's width (np.bytes_ would strip
+            # it to this row's length); layout.record_spec reads it
+            row = tuple(c[0:1].reshape(()) if c.dtype.kind == "S"
+                        else c[0] for c in cols)
+            return row[0] if len(row) == 1 else row
     return None
+
+
+def _string_leaf_reason(specs):
+    """Why a record spec with a string leaf keeps the host path, or
+    None when it has none (fixed-width byte strings within the limit
+    never show here: record_spec made them ByteStr words)."""
+    from dpark_tpu import conf
+    for dt, _ in specs:
+        if dt == np.dtype(object) or dt.kind in "USO":
+            if dt.kind == "S" and dt.itemsize > 8 * conf.MAX_KEY_LEAVES:
+                return ("byte-string column of %d bytes is over the "
+                        "device limit of 8 * conf.MAX_KEY_LEAVES = %d"
+                        % (dt.itemsize, 8 * conf.MAX_KEY_LEAVES))
+            return ("string leaf (dtype %s): only fixed-width byte "
+                    "strings (a numpy S<w> column of Columns) ride "
+                    "the device" % dt)
+    return None
+
+
+def _bytes_source_reason(pc, treedef, specs):
+    """Why an ingest source with byte-string columns keeps the host
+    path, or None.  Proven once per ParallelCollection: no word of any
+    byte-string column equals the int64 padding sentinel (bytes 7f ff
+    ff ff ff ff ff ff) — a slice of a string never makes such a word
+    where the string had none, so no derived key can collide with
+    padding."""
+    if layout.column_groups(treedef, len(specs)) is None:
+        return None
+    reason = getattr(pc, "_tpu_bytes_reason", False)
+    if reason is False:
+        reason = None
+        for s in pc._slices:
+            for c in getattr(s, "columns", None) or ():
+                c = np.asarray(c)
+                if c.dtype.kind == "S" and len(c) and (
+                        layout.pack_bytes(c)
+                        == layout.KEY_SENTINEL).any():
+                    reason = ("a byte-string word equals the device "
+                              "key sentinel (bytes 7f ff ff ff ff ff "
+                              "ff ff)")
+        pc._tpu_bytes_reason = reason
+    return reason
 
 
 # ----------------------------------------------------------------------
@@ -1388,9 +1453,9 @@ def _analyze_union_parent(parent, ndev, executor_or_store, cached_ids,
             treedef, specs = layout.record_spec(sample)
         except (TypeError, ValueError):
             return None
-        for dt, _ in specs:
-            if dt == np.dtype(object) or dt.kind in "USO":
-                return None
+        if _string_leaf_reason(specs) \
+                or _bytes_source_reason(src_rdd, treedef, specs):
+            return None
         source = ("ingest", src_rdd)
     elif isinstance(src_rdd, ShuffledRDD):
         dep = src_rdd.dep
@@ -1481,6 +1546,9 @@ def _analyze_join_source(join_rdd, ndev, executor_or_store):
         nk = layout.key_width(treedef, specs, kinds="if")
         if nk is None or len(specs) < nk + 1:
             return None      # join kernels need (k, v) / ((k...), v)
+        if layout.column_groups(treedef, len(specs)) is not None:
+            return _fallback("device join over byte-string records "
+                             "is not implemented")
         sample = jtu.tree_unflatten(treedef, list(range(len(specs))))
         if len(sample) != 2:
             return None
@@ -1608,9 +1676,10 @@ def analyze_stage(stage, ndev, executor_or_store):
             treedef, specs = layout.record_spec(sample)
         except (TypeError, ValueError):
             return None
-        for dt, _ in specs:
-            if dt == np.dtype(object) or dt.kind in "USO":
-                return None
+        reason = _string_leaf_reason(specs) \
+            or _bytes_source_reason(source_rdd, treedef, specs)
+        if reason:
+            return _fallback(reason)
         source = ("ingest", source_rdd)
         src_combine = False
     elif isinstance(source_rdd, ShuffledRDD):
@@ -1750,7 +1819,12 @@ def analyze_stage(stage, ndev, executor_or_store):
             if epi_nk is None:
                 return _fallback(
                     "hash shuffle needs an int scalar (or flat "
-                    "int-tuple, <= conf.MAX_KEY_LEAVES columns) key")
+                    "int-tuple, <= conf.MAX_KEY_LEAVES columns) or "
+                    "fixed-width byte-string key")
+            width = layout.bytes_key_width(cur_treedef, len(cur_specs))
+            if width is not None:
+                # destinations by the host's own hash of the bytes
+                epi_spec = ("hash", "bytes", width)
         else:
             epi_nk = layout.key_width(cur_treedef, cur_specs,
                                       kinds="if")

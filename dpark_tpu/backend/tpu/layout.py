@@ -64,6 +64,240 @@ _REPLICATORS = {}
 # A bare `+= 1`: exact while one thread runs jobs; the export server's
 # threads read too, and a racing add can lose a count
 HOST_READS = 0
+# rows whose fixed-width byte strings were packed into device words at
+# ingest / rebuilt as host bytes at a host exit (bare `+=`, like
+# HOST_READS; JAXExecutor.bytes_rows_packed / bytes_rows_unpacked)
+BYTES_ROWS_PACKED = 0
+BYTES_ROWS_UNPACKED = 0
+
+
+class ByteStr:
+    """A fixed-width byte string on the array path (a numpy ``S<w>``
+    column): `width` NUL-padded bytes held as ceil(width / 8) int64
+    words, big-endian, so that a word compares as its bytes do (for
+    ASCII the signed word order is byte order) and a key of X bytes is
+    ceil(X / 8) ordinary int key columns.  A registered pytree node:
+    the words are the leaves, the width rides in the treedef.
+
+    Inside a traced user function it stands in for the `bytes` object
+    the local master passes: a static slice ``s[a:b]`` is the bytes
+    slice (a string shorter than the slice is the whole string:
+    trailing NULs are padding, as in numpy), ``s[i]`` is the byte as an
+    int, ``==``/``!=`` compare with `bytes` or another ByteStr.
+    Anything else (len, iteration, ordering, negative or traced
+    indices: all depend on the string's true length or order) raises
+    and keeps the host path."""
+
+    __slots__ = ("width", "words")
+    __hash__ = None
+
+    def __init__(self, width, words):
+        self.width = int(width)
+        self.words = tuple(words)
+
+    def __repr__(self):
+        return "ByteStr[%d]%r" % (self.width, self.words)
+
+    def _byte(self, i):
+        return (self.words[i // 8] >> (8 * (7 - i % 8))) & 0xFF
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            if i.step not in (None, 1):
+                raise TypeError("ByteStr slices have step 1")
+            lo = 0 if i.start is None else i.start
+            hi = self.width if i.stop is None else i.stop
+            if not all(isinstance(x, int) and x >= 0 for x in (lo, hi)):
+                raise TypeError("ByteStr slice bounds must be static "
+                                "non-negative ints")
+            hi = min(hi, self.width)
+            return self._window(min(lo, hi), hi)
+        if isinstance(i, bool) or not isinstance(i, int):
+            raise TypeError("ByteStr index must be a static int")
+        if i < 0:
+            raise TypeError("a negative ByteStr index counts from the "
+                            "row's own end; host path")
+        if i >= self.width:
+            raise IndexError("ByteStr index out of range")
+        return self._byte(i)
+
+    def _window(self, lo, hi):
+        """Bytes [lo, hi) as a ByteStr of width hi - lo."""
+        width = hi - lo
+        shift = 8 * (lo % 8)
+        words = []
+        for j in range(-(-width // 8)):
+            a = lo // 8 + j
+            w = self.words[a]
+            if shift:
+                w = w << shift
+                if a + 1 < len(self.words):
+                    # logical right shift of the next word's top bytes
+                    w = w | ((self.words[a + 1] >> (64 - shift))
+                             & ((1 << shift) - 1))
+            words.append(w)
+        tail = width % 8
+        if tail:
+            words[-1] = words[-1] & -(1 << (8 * (8 - tail)))
+        return ByteStr(width, words)
+
+    def _same(self, other):
+        if isinstance(other, bytes):
+            if len(other) > self.width or other.endswith(b"\0"):
+                # host strings are at most `width` long and carry no
+                # trailing NUL: never equal
+                return jnp.zeros((), bool)
+            other = ByteStr(self.width, pack_bytes(
+                np.array([other], "S%d" % max(self.width, 1)))[0])
+        if not isinstance(other, ByteStr):
+            return None
+        n = max(len(self.words), len(other.words))
+        a = self.words + (0,) * (n - len(self.words))
+        b = other.words + (0,) * (n - len(other.words))
+        same = jnp.ones((), bool)
+        for x, y in zip(a, b):
+            same = same & (x == y)
+        return same
+
+    def __eq__(self, other):
+        same = self._same(other)
+        return NotImplemented if same is None else same
+
+    def __ne__(self, other):
+        same = self._same(other)
+        return NotImplemented if same is None else ~same
+
+    def __len__(self):
+        raise TypeError("len() of a device byte string depends on the "
+                        "row; host path")
+
+    def __iter__(self):
+        raise TypeError("a device byte string does not iterate; host "
+                        "path")
+
+
+jax.tree_util.register_pytree_node(
+    ByteStr, lambda s: (s.words, s.width),
+    lambda width, words: ByteStr(width, words))
+
+
+def bytes_words(width):
+    return -(-int(width) // 8)
+
+
+def pack_bytes(col):
+    """numpy S<w> array (n,) -> (n, ceil(w / 8)) int64 big-endian
+    words, NUL-padded."""
+    col = np.ascontiguousarray(col)
+    w = col.dtype.itemsize
+    nw = bytes_words(w)
+    buf = np.zeros((len(col), nw * 8), np.uint8)
+    buf[:, :w] = col.view(np.uint8).reshape(len(col), w)
+    return buf.view(">i8").astype(np.int64)
+
+
+def unpack_bytes(words, width):
+    """Word columns (each of shape `shape`, any int dtype) -> S<width>
+    array of that shape (numpy strips the NUL padding at .tolist())."""
+    stacked = np.stack([np.asarray(w) for w in words],
+                       axis=-1).astype(">i8")
+    raw = np.ascontiguousarray(
+        stacked.view(np.uint8)[..., :width])
+    return raw.view("S%d" % width).reshape(stacked.shape[:-1])
+
+
+def _is_bytestr(x):
+    return isinstance(x, ByteStr)
+
+
+def column_groups(treedef, nleaves):
+    """(host treedef, groups) when the record holds ByteStr nodes, else
+    None.  The host sees each ByteStr as ONE column (an S<w> array, a
+    `bytes` object in a row): `groups` lists, per host column, a leaf
+    index or a ByteStr whose words are leaf indices; the host treedef
+    has a plain leaf where the node was."""
+    if "ByteStr" not in str(treedef):
+        return None
+    sample = jax.tree_util.tree_unflatten(treedef, list(range(nleaves)))
+    groups, outer = jax.tree_util.tree_flatten(sample,
+                                               is_leaf=_is_bytestr)
+    return outer, groups
+
+
+def host_columns(treedef, cols, rows=None):
+    """(treedef, numpy columns) as a host exit sees them: the word
+    columns of every ByteStr node become one S<w> column.  Unchanged
+    when the record holds no byte string.  One `bytes.unpack` span
+    with the trace plane on; BYTES_ROWS_UNPACKED counts `rows` (the
+    valid rows, where the columns carry padding)."""
+    global BYTES_ROWS_UNPACKED
+    found = column_groups(treedef, len(cols))
+    if found is None:
+        return treedef, cols
+    outer, groups = found
+    if rows is None:
+        rows = len(cols[0])
+    width = max(g.width for g in groups if _is_bytestr(g))
+    BYTES_ROWS_UNPACKED += rows
+    sp = trace._NOOP
+    if trace._PLANE is not None:
+        sp = trace.span("bytes.unpack", "exec", rows=rows, width=width)
+    with sp:
+        out = [unpack_bytes([cols[i] for i in g.words], g.width)
+               if _is_bytestr(g) else cols[g] for g in groups]
+    return outer, out
+
+
+def _pack_parts(partitions, treedef, specs):
+    """Columnar partitions whose S<w> columns become the word columns
+    of their ByteStr nodes (so that every part carries one column a
+    leaf).  One `bytes.pack` span; BYTES_ROWS_PACKED counts the rows.
+    Byte strings reach the device from Columns only: the width is the
+    column's, which rows of `bytes` objects do not have."""
+    global BYTES_ROWS_PACKED
+    found = column_groups(treedef, len(specs))
+    if found is None:
+        return partitions
+    from dpark_tpu.rdd import _ColumnarSlice
+    _, groups = found
+    rows = words = width = 0
+    out = []
+    sp = trace._NOOP
+    if trace._PLANE is not None:
+        sp = trace.span("bytes.pack", "exec")
+    with sp:
+        for part in partitions:
+            cols = getattr(part, "columns", None)
+            if not len(part) or (cols is not None
+                                 and len(cols) == len(specs)
+                                 and not any(np.asarray(c).dtype.kind
+                                             == "S" for c in cols)):
+                out.append(part)        # empty, or already word columns
+                continue
+            if cols is None or len(cols) != len(groups):
+                raise ValueError("byte-string records reach the device "
+                                 "as Columns; taking the host path")
+            leaf_cols = [None] * len(specs)
+            for g, c in zip(groups, cols):
+                if not _is_bytestr(g):
+                    leaf_cols[g] = c
+                    continue
+                c = np.asarray(c)
+                if c.dtype.kind != "S" or c.dtype.itemsize != g.width:
+                    raise ValueError("column dtype %s where S%d was "
+                                     "planned; taking the host path"
+                                     % (c.dtype, g.width))
+                packed = pack_bytes(c)
+                for j, li in enumerate(g.words):
+                    leaf_cols[li] = packed[:, j]
+                rows += len(c)
+                words += len(g.words)
+                width = max(width, g.width)
+            out.append(_ColumnarSlice(leaf_cols))
+        if sp is not trace._NOOP:
+            sp.args.update(rows=rows, width=width, words=words)
+    BYTES_ROWS_PACKED += rows
+    return out
 
 
 def host_read(x, site=""):
@@ -138,13 +372,23 @@ class Batch:
         self.ndev = cols[0].shape[0]
         self.cap = cols[0].shape[1]
 
-    def unflatten_record(self, leaves):
-        return jax.tree_util.tree_unflatten(self.treedef, leaves)
-
 
 def record_spec(sample):
     """(treedef, leaf dtypes/shapes) for a sample record."""
     leaves, treedef = jax.tree_util.tree_flatten(sample)
+    # a 0-d S<w> array stands for a fixed-width byte-string COLUMN
+    # (fuse._sample_record): it becomes a ByteStr node of int64 words.
+    # A bare `bytes` object has no column width, and a column over the
+    # limit keeps its S dtype: both are what every caller declines
+    limit = 8 * conf.MAX_KEY_LEAVES
+    columns = [isinstance(l, np.ndarray) and l.dtype.kind == "S"
+               and 0 < l.dtype.itemsize <= limit for l in leaves]
+    if any(columns):
+        leaves, treedef = jax.tree_util.tree_flatten(
+            jax.tree_util.tree_unflatten(treedef, [
+                ByteStr(l.dtype.itemsize,
+                        [np.int64(0)] * bytes_words(l.dtype.itemsize))
+                if is_col else l for l, is_col in zip(leaves, columns)]))
     specs = []
     for leaf in leaves:
         arr = np.asarray(leaf)
@@ -176,6 +420,7 @@ def ingest(mesh, partitions, treedef, specs, key_leaf=None,
     """
     ndev = mesh.devices.size
     assert len(partitions) == ndev, (len(partitions), ndev)
+    partitions = _pack_parts(partitions, treedef, specs)
     counts = np.array([len(p) for p in partitions], dtype=np.int32)
     cap = max(round_capacity(int(counts.max()) if len(counts) else 1),
               cap_floor)
@@ -324,11 +569,14 @@ def _egest_rows(batch):
             "device (reduceByKey/aggregate) before collect(), or "
             "saveAs* sinks", total / (1 << 20))
     host_cols = [_egest_read(c, batch.counts) for c in batch.cols]
+    # byte strings leave as one S<w> column each (bytes in the rows)
+    treedef, host_cols = host_columns(batch.treedef, host_cols,
+                                      rows=int(counts.sum()))
     # fast paths: scalar records, and arbitrarily-nested TUPLE records
     # (e.g. join's (k, (a, b))) rebuild with zips instead of a per-row
     # tree_unflatten
     sample = jax.tree_util.tree_unflatten(
-        batch.treedef, list(range(len(batch.cols))))
+        treedef, list(range(len(host_cols))))
     all_2d = all(c.ndim == 2 for c in host_cols)
 
     def _tuple_only(struct):
@@ -344,7 +592,7 @@ def _egest_rows(batch):
         return list(zip(*parts))
 
     zipable = all_2d and _tuple_only(sample)
-    bare_scalar = (len(batch.cols) == 1 and sample == 0 and all_2d)
+    bare_scalar = (len(host_cols) == 1 and sample == 0 and all_2d)
     out = []
     for d in range(batch.ndev):
         n = int(counts[d])
@@ -358,8 +606,8 @@ def _egest_rows(batch):
             else:
                 per_leaf = [c[d, :n].tolist() for c in host_cols]
                 for i in range(n):
-                    rows.append(batch.unflatten_record(
-                        [pl[i] for pl in per_leaf]))
+                    rows.append(jax.tree_util.tree_unflatten(
+                        treedef, [pl[i] for pl in per_leaf]))
         out.append(rows)
     return out
 
@@ -383,11 +631,18 @@ def key_width(treedef, specs, kinds="i"):
     if not (isinstance(sample, tuple) and len(sample) >= 2):
         return None
     key = sample[0]
-    if key == 0:
+    if isinstance(key, ByteStr):
+        # a fixed-width byte string: its words are the key columns
+        nk = len(key.words)
+        if nk > conf.MAX_KEY_LEAVES \
+                or key.words != tuple(range(nk)):
+            return None
+    elif isinstance(key, int) and key == 0:
         nk = 1
     elif (conf.TUPLE_KEYS and isinstance(key, tuple)
           and 2 <= len(key) <= conf.MAX_KEY_LEAVES
-          and all(key[i] == i for i in range(len(key)))):
+          and all(isinstance(key[i], int) and key[i] == i
+                  for i in range(len(key)))):
         nk = len(key)
     else:
         return None
@@ -395,6 +650,17 @@ def key_width(treedef, specs, kinds="i"):
         if shape != () or dt.kind not in kinds:
             return None
     return nk
+
+
+def bytes_key_width(treedef, nleaves):
+    """Byte width of the key when it is a ByteStr, else None."""
+    if "ByteStr" not in str(treedef):
+        return None
+    sample = jax.tree_util.tree_unflatten(treedef, list(range(nleaves)))
+    if isinstance(sample, tuple) and len(sample) >= 2 \
+            and isinstance(sample[0], ByteStr):
+        return sample[0].width
+    return None
 
 
 def key_leaf_index(treedef, specs):

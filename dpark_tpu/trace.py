@@ -74,6 +74,14 @@ Span taxonomy (name / cat):
                                        its readbacks nest inside
     ingest                   "exec"    layout.ingest of a stage's host
                                        numpy source (args: rows)
+    bytes.pack               "exec"    the S<w> columns of an ingest
+                                       become int64 word columns,
+                                       layout._pack_parts (args: rows,
+                                       width, words); inside `ingest`
+    bytes.unpack             "exec"    word columns rebuilt as host
+                                       bytes, layout.host_columns
+                                       (args: rows, width): inside
+                                       `egest`, or at the export bridge
     hbm.spill                "exec"    one dead HBM shuffle store to
                                        disk buckets, JAXExecutor.
                                        _spill_shuffle_to_disk (args:

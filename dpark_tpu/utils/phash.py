@@ -212,3 +212,27 @@ def phash_device_cols(cols):
     h = h * jnp.uint32(_M2)
     h ^= h >> 16
     return h
+
+
+def phash_device_bytes(word_cols, width):
+    """Device twin of portable_hash(bytes) for a fixed-width byte-string
+    key held as big-endian int64 WORD columns (backend/tpu/layout.py
+    ByteStr): FNV-1a over the bytes up to the last non-NUL one (the
+    host hashes the NUL-stripped `bytes` object), then fmix32 —
+    bit-identical, so byte-keyed shuffles land where the host
+    HashPartitioner (lookup, co-partitioned joins) expects."""
+    import jax.numpy as jnp
+    octets = [((word_cols[i // 8] >> (8 * (7 - i % 8))) & 0xFF)
+              .astype(jnp.uint32) for i in range(width)]
+    length = jnp.zeros(word_cols[0].shape, jnp.int32)
+    for i, b in enumerate(octets):
+        length = jnp.where(b != 0, i + 1, length)
+    h = jnp.full(word_cols[0].shape, _FNV_OFFSET, jnp.uint32)
+    for i, b in enumerate(octets):
+        h = jnp.where(i < length, (h ^ b) * jnp.uint32(_FNV_PRIME), h)
+    h ^= h >> 16
+    h = h * jnp.uint32(_M1)
+    h ^= h >> 13
+    h = h * jnp.uint32(_M2)
+    h ^= h >> 16
+    return h
