@@ -132,10 +132,14 @@ def _lex_sort(ops, num_keys):
 
     Formulated as permutation-compose + gather on every backend: XLA's
     multi-operand Sort lowers (on TPU) to a comparison network whose
-    cost — to compile and to run — grows with total operand bytes.
-    Successive 2-operand (key, iota) sorts radix-compose the
-    permutation instead, and every operand is gathered exactly once;
-    this also carries rank>1 payloads, which XLA Sort cannot.
+    cost — to compile and to run — grows with total operand bytes
+    (read off the compiler's output; the two forms were never timed
+    against each other on a chip).  Successive 2-operand (key, iota)
+    sorts radix-compose the permutation instead, and every operand is
+    gathered exactly once; this also carries rank>1 payloads, which
+    XLA Sort cannot.  On the v5e at 2M rows such a sort runs in 3.9 ms
+    and a gather of one int64 column in 34 (float32: 19): the gathers
+    are the cost (PR 27's chip runs).
 
     The iota is i32 and sorted with lax.sort_key_val directly:
     jnp.argsort carries an int64 iota under jax_enable_x64, and the
@@ -166,24 +170,44 @@ def compact(leaves, mask):
     return list(sorted_ops[1:]), jnp.sum(mask).astype(jnp.int32)
 
 
+_DST_LOOP_MAX = 16      # destinations a per-bucket Python loop serves
+
+
+def _bucket_counts(d, nb):
+    """Rows of `d` in each bucket 0..nb-1, with no scatter: a compare
+    and a sum per bucket while the loop is short, boundaries of the
+    column (which must then be SORTED) past that.  jnp.bincount is a
+    scatter-add of ones, which the v5e runs a row at a time: 127 ms
+    for 2M rows into three bins, where these take 0.6 (PR 27's chip
+    runs; ledger PR 26, `fusion.14 u32[n_dst + 1]`: 125 ms a job)."""
+    if nb <= _DST_LOOP_MAX + 1:             # + the sentinel bucket
+        return jnp.stack([jnp.sum(d == b, dtype=jnp.int32)
+                          for b in range(nb)])
+    edges = jnp.searchsorted(d, jnp.arange(nb + 1, dtype=d.dtype))
+    return jnp.diff(edges).astype(jnp.int32)
+
+
 def _dst_order(dst, n_dst):
-    """Stable permutation grouping rows by destination WITHOUT a
-    comparison sort: per-bucket cumsum ranks + one scatter (a counting
-    sort over the tiny destination domain — mesh size + the sentinel
-    bucket).  XLA:CPU's sort runs ~4x slower than these O(n) passes at
-    a million rows (measured while profiling the segmented apply);
-    output is bit-identical to jnp.argsort(dst, stable=True)."""
+    """(order, counts[n_dst + 1]): the stable permutation grouping rows
+    by destination WITHOUT a comparison sort: per-bucket cumsum ranks +
+    one scatter (a counting sort over the tiny destination domain —
+    mesh size + the sentinel bucket).  Chosen on XLA:CPU, whose sort
+    ran ~4x slower than these O(n) passes at a million rows (round 3,
+    while profiling the segmented apply).  On the v5e this scatter
+    (distinct indices: not the serial kind) takes 10.6 ms at 2M rows
+    and a stable sort_key_val(dst, iota) 3.9, after 11 s of compile
+    (PR 27's chip runs); no cell runs this path yet.  Output is
+    bit-identical to jnp.argsort(dst, stable=True)."""
     cap = dst.shape[0]
-    counts = jnp.bincount(dst, length=n_dst + 1)
-    offs = jnp.concatenate([jnp.zeros((1,), counts.dtype),
-                            jnp.cumsum(counts)[:-1]])
+    counts = _bucket_counts(dst, n_dst + 1)
+    offs = jnp.cumsum(counts) - counts
     pos = jnp.zeros((cap,), jnp.int32)
     for b in range(n_dst + 1):
         m = dst == b
         rank = jnp.cumsum(m.astype(jnp.int32)) - 1
-        pos = jnp.where(m, offs[b].astype(jnp.int32) + rank, pos)
+        pos = jnp.where(m, offs[b] + rank, pos)
     return jnp.zeros((cap,), jnp.int32).at[pos].set(
-        jnp.arange(cap, dtype=jnp.int32))
+        jnp.arange(cap, dtype=jnp.int32)), counts
 
 
 def bucketize(key, leaves, n, n_dst, dst=None, r=None):
@@ -196,15 +220,14 @@ def bucketize(key, leaves, n, n_dst, dst=None, r=None):
     valid = jnp.arange(cap) < n
     if dst is None:
         dst = hash_dst(key, n_dst, valid, r)
-    if n_dst <= 16:
-        order = _dst_order(dst, n_dst)
+    if n_dst <= _DST_LOOP_MAX:
+        order, counts = _dst_order(dst, n_dst)
     else:
-        order = jnp.argsort(dst, stable=True).astype(jnp.int32)
-    sorted_leaves = _take(leaves, order)
-    counts = jnp.bincount(dst, length=n_dst + 1)[:n_dst].astype(jnp.int32)
-    offsets = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(counts)[:-1]])
-    return sorted_leaves, counts, offsets
+        sd, order = lax.sort_key_val(dst, lax.iota(jnp.int32, cap),
+                                     is_stable=True)
+        counts = _bucket_counts(sd, n_dst + 1)
+    counts = counts[:n_dst]
+    return _take(leaves, order), counts, jnp.cumsum(counts) - counts
 
 
 def exchange_round(axis, leaves, offsets, counts, sent, slot,
@@ -291,41 +314,27 @@ def flatten_received(recv_rounds, cnt_rounds, key_index=0):
     return flat, mask
 
 
-_SEGMENT_OPS = {}
-
-
-def _segment_op(kind):
-    if not _SEGMENT_OPS:
-        from jax import ops as jops
-        _SEGMENT_OPS.update({
-            "add": jops.segment_sum, "min": jops.segment_min,
-            "max": jops.segment_max, "mul": jops.segment_prod})
-    return _SEGMENT_OPS[kind]
-
-
-def _monoid_segment_totals(starts, val_leaves, kind):
-    """Single-pass per-segment reduction for a classified monoid: one
-    scatter instead of the log-n associative scan.  Returns per-segment
-    totals indexed by segment id (= cumsum(starts)-1)."""
-    seg = jnp.cumsum(starts.astype(jnp.int32)) - 1
-    op = _segment_op(kind)
-    m = starts.shape[0]
-    return seg, [op(v, seg, num_segments=m) for v in val_leaves]
-
-
 def segmented_combine(starts, val_leaves, merge_leaves):
     """Inclusive segmented scan: scanned[i] = reduction of values from the
-    segment start through i.  starts: (m,) bool segment-start flags."""
-    def comb(a, b):
-        fa, va = a
-        fb, vb = b
-        merged = merge_leaves(va, vb)
-        out = [jnp.where(_bcast(fb, mg), nb, mg)
-               for mg, nb in zip(merged, vb)]
-        return (fa | fb, out)
+    segment start through i.  starts: (m,) bool segment-start flags,
+    starts[0] set.  log2(m) shift-and-merge steps, each an elementwise
+    pass over contiguous slices (rows nearer the front than a step's
+    distance are flagged by then, so what the shift wraps in is never
+    merged).  lax.associative_scan's strided halving took the v5e's
+    compiler 235-250 s for one 2M-row scan and ran in 4.9-7.6 ms; these
+    steps compile in 2 s and run in 0.9-1.3 ms (PR 27's chip runs)."""
+    vs, flags, d = list(val_leaves), starts, 1
 
-    _, scanned = lax.associative_scan(comb, (starts, list(val_leaves)))
-    return scanned
+    def shift(x):
+        return jnp.concatenate([x[:d], x[:-d]])
+
+    while d < starts.shape[0]:
+        merged = merge_leaves([shift(v) for v in vs], vs)
+        vs = [jnp.where(_bcast(flags, v), v, mg)
+              for v, mg in zip(vs, merged)]
+        flags = flags | shift(flags)
+        d *= 2
+    return vs
 
 
 def bucketize_combine(key, val_leaves, n, n_dst, merge_leaves,
@@ -377,29 +386,37 @@ def _changed_adjacent(cols):
     return changed
 
 
+_MONOID_OPS = {"add": jnp.add, "min": jnp.minimum, "max": jnp.maximum,
+               "mul": jnp.multiply}
+
+
 def _segment_merge(key_cols, val_leaves, keep_valid, merge_leaves,
                    monoid):
     """Shared segment-combine core over rows sorted by `key_cols`:
-    merge values of adjacent rows equal in ALL key columns, keeping one
-    representative row per segment (keep_valid(row_flags) restricts
-    which rows qualify).
+    merge values of adjacent rows equal in ALL key columns, keeping the
+    LAST row of each segment (keep_valid(row_flags) restricts which
+    rows qualify), where a scan along the sorted rows leaves the
+    segment's total: of the monoid's own operation if the merge is
+    classified, else of the traced user function.  No scatter and no
+    gather: the segment_sum + gather-back this replaces ("one scatter
+    instead of the log-n scan", a choice made on XLA:CPU) cost the v5e
+    68 ns a row inside the stage programs (ledger PR 26) and 207 ms
+    alone for 2M int64 rows, where the scan takes 1.2 (PR 27's chip
+    runs).
 
     Returns (keep_mask, reduced_val_leaves), both row-aligned with the
     input order — callers compact kept rows to the front with their
     own pack sort and derive counts from the mask."""
     changed = _changed_adjacent(key_cols)
     starts = jnp.concatenate([jnp.ones((1,), bool), changed])
-    vs = list(val_leaves)
+    is_last = jnp.concatenate([changed, jnp.ones((1,), bool)])
     if monoid is not None:
-        seg, totals = _monoid_segment_totals(starts, vs, monoid)
-        keep = keep_valid(starts)
-        reduced = [t[seg] for t in totals]
-    else:
-        scanned = segmented_combine(starts, vs, merge_leaves)
-        is_last = jnp.concatenate([changed, jnp.ones((1,), bool)])
-        keep = keep_valid(is_last)
-        reduced = scanned
-    return keep, reduced
+        op = _MONOID_OPS[monoid]
+
+        def merge_leaves(a, b):
+            return [op(x, y) for x, y in zip(a, b)]
+    reduced = segmented_combine(starts, val_leaves, merge_leaves)
+    return keep_valid(is_last), reduced
 
 
 def _bucketize_combine_cols(dst, key_cols, val_leaves, n_dst,
@@ -439,11 +456,9 @@ def _bucketize_combine_cols(dst, key_cols, val_leaves, n_dst,
     k_fulls = [jnp.where(keep, k, _sentinel(k.dtype)) for k in ks]
     packed = _lex_sort((~keep, dd_full) + tuple(k_fulls)
                        + tuple(reduced), 1)
-    dd = packed[1]
-    counts = jnp.bincount(dd, length=n_dst + 1)[:n_dst].astype(jnp.int32)
-    offsets = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(counts)[:-1]])
-    return list(packed[2:2 + nk]), list(packed[2 + nk:]), counts, offsets
+    counts = _bucket_counts(packed[1], n_dst + 1)[:n_dst]
+    return (list(packed[2:2 + nk]), list(packed[2 + nk:]), counts,
+            jnp.cumsum(counts) - counts)
 
 
 def bucketize_combine_rid(rid, key_cols, val_leaves, n, n_dst,

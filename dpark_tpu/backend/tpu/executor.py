@@ -41,6 +41,16 @@ def _plan_sig(plan):
     except Exception:
         return "?"
 
+
+def _combine_arg(merge_fn, monoid):
+    """The `combine=` of a `compile` event: what the program's segment
+    merge (collectives._segment_merge) scans with — the monoid's own
+    operation, the traced user function, or nothing to combine."""
+    if monoid is not None:
+        return "seg_scan"
+    return "none" if merge_fn is None else "user_scan"
+
+
 AXIS = conf.MESH_AXIS
 
 
@@ -830,9 +840,9 @@ class JAXExecutor:
         """(merge_fn, monoid) for a combining shuffle write, or
         (None, None) for the no-combine (list-aggregator) mode.
 
-        The two are independent: a PROVABLE monoid combines through
-        segment scatters even when the user's function itself does not
-        trace (``max(a, b)`` forces a tracer bool) — discarding the
+        The two are independent: a PROVABLE monoid combines through a
+        scan of its own operation even when the user's function does
+        not trace (``max(a, b)`` forces a tracer bool) — discarding the
         monoid with the failed trace crashed the streamed combine (r5
         fuzz finding).  Untraceable AND unclassified merges exchange
         raw created combiners.
@@ -943,9 +953,6 @@ class JAXExecutor:
         if key in self._compiled:
             return self._compiled[key]
         faults.hit("executor.compile")     # chaos site: per cache miss
-        if trace._PLANE is not None:
-            trace.event("compile", "exec", program="narrow", cap=cap,
-                        sig=_plan_sig(plan))
         ops = plan.ops
         epilogue = plan.epilogue
         n_dst = self.ndev
@@ -954,6 +961,10 @@ class JAXExecutor:
         if epilogue is not None:
             merge_fn, monoid = self._epilogue_merge(plan)
             epi = self._epilogue_params(plan)
+        if trace._PLANE is not None:
+            trace.event("compile", "exec", program="narrow", cap=cap,
+                        sig=_plan_sig(plan),
+                        combine=_combine_arg(merge_fn, monoid))
         in_specs = plan.in_specs
 
         def per_device(counts, *rest):
@@ -1103,6 +1114,10 @@ class JAXExecutor:
             epi = self._epilogue_params(plan)
 
         src_nk = getattr(plan, "src_nk", 1) or 1
+        if trace._PLANE is not None:    # combine= is the source reduce's
+            trace.event("compile", "exec", program="reduce", slot=slot,
+                        sig=_plan_sig(plan),
+                        combine=_combine_arg(merge_fn, monoid))
 
         def per_device(*args):
             bounds = args[0][0] if has_bounds else None
@@ -2326,8 +2341,8 @@ class JAXExecutor:
         tok_depth = max(1, conf.STREAM_PIPELINE_DEPTH)
         if no_combine:
             return ("nocombine", _prefetch_iter(waves, depth=tok_depth))
-        # monoids combine via segment scatters; any other TRACEABLE
-        # merge streams through the segmented associative scan — ONE
+        # monoids combine by a segmented scan of their own operation,
+        # any other TRACEABLE merge by one of the user's function — ONE
         # probe (shared with compile time), memoized per plan
         merge_fn, _ = self._merge_probe(plan)
         if monoid is not None or merge_fn is not None:
@@ -2465,9 +2480,9 @@ class JAXExecutor:
 
     def _run_streamed_shuffle(self, plan, waves):
         dep = plan.epilogue[1]
-        # classified monoids combine through segment scatters; any
-        # other TRACEABLE user merge runs as a segmented associative
-        # scan (_stream_mode verified it traces, same memoized probe)
+        # classified monoids combine by a segmented scan of their own
+        # operation; any other TRACEABLE user merge by one of the user's
+        # function (_stream_mode verified it traces, same memoized probe)
         merge_fn, monoid = self._merge_probe(plan)
         donate = self._donation_enabled()
         stats = _StreamStats(conf.STREAM_PIPELINE_DEPTH, donate)
@@ -3065,8 +3080,8 @@ class JAXExecutor:
     def _merge_into_state(self, plan, state, recv, monoid,
                           merge_fn=None, donate=False):
         """Combine received rows (and the running state) into the new
-        per-device unique-key state: one segment scatter for classified
-        monoids, a segmented associative scan of the traced user merge
+        per-device unique-key state: a segmented scan of the monoid's
+        operation for classified monoids, of the traced user merge
         otherwise.  `donate` releases the OLD state leaves (replaced by
         the program's output) and the receive buffers (dead after the
         merge) for in-place reuse; the per-round counts stay live (the

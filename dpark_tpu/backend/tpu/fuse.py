@@ -490,10 +490,12 @@ class SegAggOp:
         if kind in ("min", "max") and vals.dtype.kind == "f":
             mask_v = valid & ~jnp.isnan(vals)   # NaN caveat: see class
         vals = jnp.where(mask_v, vals, ident_v)
-        op = collectives._segment_op(op_kind)
+        from jax import ops as jops
+        op = {"add": jops.segment_sum, "min": jops.segment_min,
+              "max": jops.segment_max}[op_kind]
         agg = op(vals, seg, num_segments=cap)
         if kind == "mean":
-            cnt = collectives._segment_op("add")(
+            cnt = jops.segment_sum(
                 jnp.where(valid, jnp.ones((cap,), jnp.int64),
                           jnp.zeros((), jnp.int64)),
                 seg, num_segments=cap)
@@ -503,9 +505,8 @@ class SegAggOp:
         # per-segment keys: min over the segment (all equal within a
         # segment, for every key column); empty segments keep the
         # sentinel in column 0 and sit past the valid prefix
-        seg_min = collectives._segment_op("min")
-        out_ks = [seg_min(ks, seg, num_segments=cap)]
-        out_ks += [seg_min(kc, seg, num_segments=cap)
+        out_ks = [jops.segment_min(ks, seg, num_segments=cap)]
+        out_ks += [jops.segment_min(kc, seg, num_segments=cap)
                    for kc in leaves[1:nk]]
         return out_ks + [agg], n_out
 

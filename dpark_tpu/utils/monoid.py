@@ -8,8 +8,8 @@ jax-free by design: the tpu backend registers its jnp callables via
 register_direct() on import, so the linter classifies identically on
 installs without jax (minus jnp identities that cannot occur there).
 
-A classified monoid unlocks single-pass segment scatters instead of
-the generic O(log n)-pass associative scan — but a wrong answer here
+A classified monoid stands in for the user's function in the device
+combine, which then need not trace — but a wrong answer here
 silently replaces the user's function, so only provable matches
 qualify (round-1 advisor finding: the old 8-random-int-probe
 classifier could mistake e.g. a saturating add for plain add):
@@ -23,7 +23,7 @@ classifier could mistake e.g. a saturating add for plain add):
   functions that are equivalent to a monoid but written differently).
 
 Everything else classifies as None and runs through the traced user
-function (correct, just not single-pass).
+function.
 """
 
 import operator
