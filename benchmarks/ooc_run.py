@@ -82,7 +82,7 @@ def _ooc_group_fn(vs):
 
 
 def run_groupmap(ctx, gb, n_parts, reduce_parts=None):
-    """Streamed variant of the bench.py group_mapvalues A/B: the
+    """Streamed group_mapvalues A/B: the
     no-combine groupByKey write runs through the spilled-run wave
     stream (chunked waves, key-sorted runs on disk), then the SAME
     mapValues(traceable fn) consumer runs once with conf.SEG_MAP on
@@ -183,33 +183,31 @@ def main():
         out["pipeline"] = pipe
     # per-phase wall-time table (ingest/tokenize, narrow, exchange,
     # spill, export) + every recorded why-the-array-path-was-left
-    # reason: the bench-smoke CI job gates both schema fields
+    # reason
     phases = getattr(ctx.scheduler, "phase_table", lambda: None)()
     if phases is not None:
         out["phases"] = phases
     out["fallback_reasons"] = getattr(
         ctx.scheduler, "fallback_reasons", lambda: [])()
     # chaos/recovery accounting (ISSUE 5): per-site injected fault
-    # counters + degrade/resubmit/retry summary, same shape as the
-    # bench.py OOC line
+    # counters + degrade/resubmit/retry summary
     recovery = getattr(ctx.scheduler, "recovery_summary",
                        lambda: {})() or {}
     out["faults"] = recovery.pop("faults", {})
-    # coded-shuffle decode counters (ISSUE 6), same shape as bench.py
+    # coded-shuffle decode counters (ISSUE 6)
     out["decodes"] = recovery.pop("decodes", {})
     out["degrades"] = recovery
     # adaptive-execution accounting (ISSUE 7): mode, store hit/steer
-    # counters, and the decisions taken — same shape as the bench.py
-    # OOC line, schema-gated by tools/bench_smoke_check.py
+    # counters, and the decisions taken
     from dpark_tpu import adapt
     out["adapt"] = adapt.summary()
     # trace plane (ISSUE 8): span counts + critical-path summary of
-    # the longest traced job, same shape as the bench.py OOC line
+    # the longest traced job
     from dpark_tpu import trace
     out["trace"] = trace.summary()
     # health plane (ISSUE 14): per-site latency-tail summaries + event
-    # rates, same shape as the bench.py OOC line (empty sites when
-    # nothing was traced — the sketches fold off the trace plane)
+    # rates (empty sites when nothing was traced — the sketches fold
+    # off the trace plane)
     from dpark_tpu import health
     out["health"] = health.summary()
     ctx.stop()

@@ -2,8 +2,7 @@
 fixed p99 batch latency, plus the window-scaling A/B that proves the
 pane plane's complexity claims.
 
-Two JSON lines (schema-gated by tools/bench_smoke_check.py and the CI
-`stream` job):
+Two JSON lines (schema-gated by the CI `stream` job):
 
   stream_rate             ramp the per-batch record count over a
                           reduceByKeyAndWindow pipeline driven by the
@@ -23,7 +22,7 @@ Two JSON lines (schema-gated by tools/bench_smoke_check.py and the CI
                           path's.
 
 Sizes shrink under --smoke (CI boxes grade schema, not throughput;
-BENCH_*.json records honest numbers from quiet machines).  The tick
+a rate that counts is a cell of the chip benchmark under perf/).  The tick
 walls recorded here also seed the adapt store's pane-cost entries
 (adapt.record_pane_cost), so a DPARK_ADAPT=on run after this bench
 picks tree-vs-flat split points from these observations.
@@ -178,13 +177,6 @@ def bench_stream_rate(smoke):
 def main(argv=None):
     argv = sys.argv[1:] if argv is None else argv
     smoke = "--smoke" in argv
-    if os.environ.get("BENCH_PLATFORM"):
-        try:
-            import jax
-            jax.config.update("jax_platforms",
-                              os.environ["BENCH_PLATFORM"])
-        except Exception:
-            pass
     print(json.dumps(bench_window_scaling(smoke)), flush=True)
     print(json.dumps(bench_stream_rate(smoke)), flush=True)
     return 0

@@ -37,7 +37,7 @@ if HERE not in sys.path:
 # first job runs at the issue's floor and PageRank at half the table's
 # edges.  At the table's sizes the same jobs passed in 1330 s + the
 # service job (PR 21, smoke log).
-N_KEYS = 65_536                 # reduceByKey key domain (bench.py's own)
+N_KEYS = 65_536                 # reduceByKey key domain
 PAIRS_PER_CHIP = 1 << 24        # 16M i64 pairs = 256 MiB (table: 64M)
 WAVE_ROWS = 2 << 20             # pinned wave: 16M / 2M = 8 waves
 SORT_PER_CHIP = 1 << 24         # 16M (table: 16M)

@@ -667,13 +667,19 @@ def _stage_key(sig):
     return "%s|%s" % (sig[0], sig[1])
 
 
+# the object path must beat the device path by this factor of observed
+# ms before the cost model declines the array path (ties keep the
+# device: its compile cost amortizes across runs)
+PATH_MARGIN = 0.8
+
+
 def choose_path(sig):
     """Cost-model path choice for an analyzable stage: given the plan
     signature (program id, shape class) from fuse.plan_adapt_signature,
     return a decision dict ({"choice": "object"|"device", "reason",
     "predicted_ms"}) when BOTH paths have recorded ms for this program
     class, else None (no history -> static behavior: the array path).
-    The host must beat the device by conf.ADAPT_PATH_MARGIN to win —
+    The host must beat the device by PATH_MARGIN to win —
     ties keep the device (its compile cost amortizes).  Observe mode
     logs the would-be choice (applied: false) and returns None."""
     try:
@@ -691,8 +697,7 @@ def choose_path(sig):
             _counters["store_misses"] += 1
             return None
         _counters["store_hits"] += 1
-        margin = float(getattr(conf, "ADAPT_PATH_MARGIN", 0.8))
-        if h_ms < d_ms * margin:
+        if h_ms < d_ms * PATH_MARGIN:
             choice, predicted = "object", h_ms
             reason = ("cost model: object path predicted cheaper "
                       "(host ~%.1fms vs device ~%.1fms observed for "
@@ -763,11 +768,18 @@ def record_skew(site, rows, groups, max_group, parts):
         logger.debug("record_skew failed: %s", e)
 
 
+# dominant-group fraction (max group rows / total rows) above which an
+# observed histogram counts as skewed, and the widening factor applied
+# to the DEFAULT reduce width on the next run of that program
+SKEW_FRAC = 0.5
+SKEW_WIDEN = 2
+
+
 def suggest_partitions(site, default_n):
     """Reduce-side width for a combineByKey/groupByKey whose caller
     took the DEFAULT parallelism: when the last recorded histogram for
     this call site shows one dominant key group (max_group/rows >=
-    conf.ADAPT_SKEW_FRAC), widen by conf.ADAPT_SKEW_WIDEN so the
+    SKEW_FRAC), widen by SKEW_WIDEN so the
     non-dominant keys spread thinner around the hot partition.
     Explicit user numSplits are never overridden (callers only consult
     this on the default path)."""
@@ -780,11 +792,10 @@ def suggest_partitions(site, default_n):
         if ent is None or not ent.get("rows"):
             return default_n
         frac = ent["max_group"] / max(1, ent["rows"])
-        if frac < float(getattr(conf, "ADAPT_SKEW_FRAC", 0.5)):
+        if frac < SKEW_FRAC:
             return default_n
         _counters["store_hits"] += 1
-        widened = max(default_n + 1, default_n * int(
-            getattr(conf, "ADAPT_SKEW_WIDEN", 2)))
+        widened = max(default_n + 1, default_n * SKEW_WIDEN)
         reason = ("observed skew at %s: dominant group ~%d of %d rows "
                   "(%.0f%%) — widening the reduce side %d -> %d"
                   % (site, ent["max_group"], ent["rows"], frac * 100,
@@ -816,11 +827,17 @@ def record_combine_ratio(site, rows_in, rows_out):
         logger.debug("record_combine_ratio failed: %s", e)
 
 
+# observed combine ratio (distinct keys / rows) above which map-side
+# pre-aggregation is priced OFF (nearly every key distinct: the
+# combine pass costs a sort and saves no exchange bytes)
+COMBINE_MAX_RATIO = 0.6
+
+
 def map_side_combine(site, kind):
     """Should the groupByKey aggregate rewrite apply map-side combine
     for this site?  True (the static default) without history; False
     when the OBSERVED combine ratio says pre-aggregation barely
-    shrinks the exchange (ratio > conf.ADAPT_COMBINE_MAX_RATIO —
+    shrinks the exchange (ratio > COMBINE_MAX_RATIO —
     nearly every key is distinct, so the combine pass costs a sort and
     saves no wire bytes).  Observe mode logs the would-be choice and
     keeps the static default."""
@@ -833,7 +850,7 @@ def map_side_combine(site, kind):
         if ent is None or ent.get("ratio") is None:
             return True
         ratio = ent["ratio"]
-        limit = float(getattr(conf, "ADAPT_COMBINE_MAX_RATIO", 0.6))
+        limit = COMBINE_MAX_RATIO
         if ratio <= limit:
             return True
         _counters["store_hits"] += 1

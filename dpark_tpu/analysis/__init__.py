@@ -37,6 +37,12 @@ __all__ = ["ConcurrencyPass", "Finding", "PlanLintError", "Report",
 # exactly once; error-severity refusal still triggers every submit
 _reported = set()
 
+# pre-flight walk budget in lineage nodes: plans bigger than this are
+# linted over a truncated prefix (logged at debug) so per-tick lint
+# cost on long-running streams stays bounded — streaming lineages grow
+# until checkpoint truncation and each tick submits a fresh final rdd
+MAX_NODES = 500
+
 
 def preflight(rdd, master="local", func=None):
     """Lint the lineage of `rdd` (plan rules + closure rules over every
@@ -53,14 +59,12 @@ def preflight(rdd, master="local", func=None):
     report = Report()
     try:
         import itertools
-        from dpark_tpu import conf
         from dpark_tpu.analysis.plan_rules import iter_lineage as _il
-        cap = int(getattr(conf, "LINT_MAX_NODES", 500)) or 500
-        lineage = list(itertools.islice(_il(rdd), cap + 1))
-        if len(lineage) > cap:
-            lineage = lineage[:cap]
-            logger.debug("preflight walk capped at %d lineage nodes "
-                         "(LINT_MAX_NODES)", cap)
+        lineage = list(itertools.islice(_il(rdd), MAX_NODES + 1))
+        if len(lineage) > MAX_NODES:
+            lineage = lineage[:MAX_NODES]
+            logger.debug("preflight walk capped at %d lineage nodes",
+                         MAX_NODES)
         fcode = getattr(func, "__code__", None)
         cache_key = (len(lineage), mode,
                      (fcode.co_filename, fcode.co_firstlineno)
@@ -74,7 +78,7 @@ def preflight(rdd, master="local", func=None):
             # verdict is replayed so a refused plan stays refused on
             # re-submission.  (Streaming ticks build a FRESH final rdd
             # per batch and miss this cache; their per-tick cost is
-            # bounded by the LINT_MAX_NODES walk cap instead.)
+            # bounded by the MAX_NODES walk cap instead.)
             report = cached[1]
             if mode == "error" and report.errors():
                 raise PlanLintError(report)

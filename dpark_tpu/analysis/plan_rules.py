@@ -65,7 +65,7 @@ The walk reads graph structure only (dependencies / partitioner /
 cache flags) — it never touches RDD.splits (which can promote lazy
 checkpoints) and never runs jobs.  Record probing for monoid-multileaf
 reads only data already resident on the driver (parallelize slices);
-user functions are never executed unless conf.LINT_PROBE == "deep".
+user functions are never executed.
 """
 
 from dpark_tpu.analysis.report import Report
@@ -164,13 +164,12 @@ def _leaf_is_scalar(leaf):
 
 def _peek_source_records(rdd, k=4, _depth=0):
     """Up to k records WITHOUT running a job: reads data already
-    resident on the driver (parallelize slices), looks through unions,
-    and — only under conf.LINT_PROBE == "deep" — replays narrow
-    per-record functions over the probe rows (user functions may have
-    side effects, e.g. accumulators, so execution is opt-in).  Returns
-    a list of records, possibly empty, or None when the source is not
-    cheaply probeable."""
-    from dpark_tpu import conf, rdd as _rdd
+    resident on the driver (parallelize slices) and looks through
+    unions.  User functions are never replayed over the probe rows
+    (they may have side effects, e.g. accumulators).  Returns a list
+    of records, possibly empty, or None when the source is not cheaply
+    probeable."""
+    from dpark_tpu import rdd as _rdd
     if _depth > 16:
         return None
     if isinstance(rdd, _rdd.ParallelCollection):
@@ -192,28 +191,7 @@ def _peek_source_records(rdd, k=4, _depth=0):
             rows = _peek_source_records(parent, k, _depth + 1)
             if rows:
                 return rows
-        return None
-    if getattr(conf, "LINT_PROBE", "shallow") != "deep":
-        return None
-    per_record = {
-        _rdd.MappedRDD: lambda f, rows: [f(r) for r in rows],
-        _rdd.FilteredRDD: lambda f, rows: [r for r in rows if f(r)],
-        _rdd.FlatMappedRDD: lambda f, rows: [o for r in rows
-                                             for o in f(r)],
-        _rdd.MappedValuesRDD: lambda f, rows: [(r[0], f(r[1]))
-                                               for r in rows],
-        _rdd.KeyedRDD: lambda f, rows: [(f(r), r) for r in rows],
-    }
-    fn = per_record.get(type(rdd))
-    if fn is None:
-        return None
-    parent_rows = _peek_source_records(rdd.prev, k, _depth + 1)
-    if not parent_rows:
-        return parent_rows
-    try:
-        return fn(rdd.f, parent_rows)[:k]
-    except Exception:
-        return None
+    return None
 
 
 # ---------------------------------------------------------------------------
@@ -504,8 +482,6 @@ def _key_fallback_reason(key, hash_keys=True, fixed_width=None):
                 "numpy S<w> column of Columns); everything else takes "
                 "the object path")
     if isinstance(key, tuple):
-        if not getattr(conf, "TUPLE_KEYS", True):
-            return "tuple key with conf.TUPLE_KEYS disabled"
         if len(key) < 2 or len(key) > conf.MAX_KEY_LEAVES:
             return ("tuple key with %d leaves (device path carries "
                     "flat tuples of 2..conf.MAX_KEY_LEAVES=%d)"
@@ -570,10 +546,9 @@ def _rule_host_fallback_group(r, report):
     through SegAggOp/the combiner rewrite, traceable padding-invariant
     functions through SegMapOp.  Reported reasons mirror the runtime
     ``fallback_reason`` exactly: SEG_MAP disabled, unsupported value
-    pytree, data-dependent control flow (AST, no execution), and —
-    only under conf.LINT_PROBE == "deep", because the check EXECUTES
-    the user function on synthetic samples — the exact runtime
-    classifier's non-traceable / not-padding-invariant verdicts."""
+    pytree, data-dependent control flow (AST, no execution).  The
+    runtime classifier's not-padding-invariant verdict needs the user
+    function EXECUTED on samples, which a pre-flight never does."""
     import numbers
     from dpark_tpu import conf, rdd as _rdd
     if not isinstance(r, _rdd.MappedValuesRDD):
@@ -623,22 +598,6 @@ def _rule_host_fallback_group(r, report):
                           "(data-dependent Python control flow)")
         except Exception:
             pass
-    if reason is None and rows \
-            and getattr(conf, "LINT_PROBE", "shallow") == "deep":
-        import sys
-        if "jax" in sys.modules:
-            try:
-                import numpy as _np
-                from dpark_tpu.backend.tpu import fuse as _fuse
-                vdt = _np.asarray(rows[0][1]).dtype
-                vdt = _np.dtype(_np.int64) if vdt.kind in "iu" \
-                    else _np.dtype(_np.float32)
-                pad, why, _ = _fuse.classify_seg_map(
-                    f_check, vdt, state=state_update is not None)
-                if pad is None:
-                    reason = why
-            except Exception:
-                pass
     if reason is None:
         return
     report.add(
@@ -726,12 +685,12 @@ def _rule_static_code_hint(rdd, report):
     exchange, superseding the pin), with DPARK_ADAPT off, and with no
     recorded fetch tails."""
     try:
-        from dpark_tpu import adapt, coding, conf
+        from dpark_tpu import adapt, coding
         from dpark_tpu.health import Sketch
         if not adapt.enabled() or coding.adaptive_enabled():
             return
-        ratio_bar = float(getattr(conf, "CODE_ADAPT_TAIL_RATIO", 3.0))
-        min_n = int(getattr(conf, "CODE_ADAPT_MIN_SAMPLES", 8) or 1)
+        ratio_bar = coding.ADAPT_TAIL_RATIO
+        min_n = coding.ADAPT_MIN_SAMPLES
         worst = None                          # (ratio, peer)
         for site, digest in adapt.site_tails().items():
             site = str(site)

@@ -371,7 +371,7 @@ class TPUScheduler(DAGScheduler):
                 self.note_stage(stage.id,
                                 fallback_reason=SPMD_CPU_FALLBACK)
                 return False
-            if not (conf.DEGRADE and _device_error(e)):
+            if not _device_error(e):
                 logger.warning(
                     "array path failed for %s (%s); object fallback",
                     stage, e)

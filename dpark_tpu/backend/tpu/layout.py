@@ -621,8 +621,8 @@ def key_width(treedef, specs, kinds="i"):
     w)``).  Every key leaf must be a scalar whose dtype kind is in
     `kinds` ("i" for hash shuffles — portable_hash semantics are only
     reproduced on device for ints — "if" for range repartitioning).
-    Nested key pytrees, >conf.MAX_KEY_LEAVES columns, or a disabled
-    conf.TUPLE_KEYS return None (host fallback)."""
+    Nested key pytrees or >conf.MAX_KEY_LEAVES columns return None
+    (host fallback)."""
     from dpark_tpu import conf
     if not specs:
         return None
@@ -639,7 +639,7 @@ def key_width(treedef, specs, kinds="i"):
             return None
     elif isinstance(key, int) and key == 0:
         nk = 1
-    elif (conf.TUPLE_KEYS and isinstance(key, tuple)
+    elif (isinstance(key, tuple)
           and 2 <= len(key) <= conf.MAX_KEY_LEAVES
           and all(isinstance(key[i], int) and key[i] == i
                   for i in range(len(key)))):

@@ -63,6 +63,13 @@ __all__ = [
 ALGO_XOR = 0
 ALGO_RS = 1
 
+# a recorded peer counts as a straggler when its persisted fetch-tail
+# sketch shows p99/p50 at or above this ratio (and at least
+# ADAPT_MIN_SAMPLES observations); below it with a bounded p99 the
+# exchange is priced tight and runs uncoded
+ADAPT_TAIL_RATIO = 3.0
+ADAPT_MIN_SAMPLES = 8
+
 SHARD_MAGIC = b"DSH1"
 CONTAINER_MAGIC = b"DCC1"
 
@@ -691,12 +698,10 @@ def choose_code(peers, tails, fault_rates=None, static_spec=None):
     p99), recorded against the observed wall by decision point 6."""
     from dpark_tpu import conf
     from dpark_tpu.health import Sketch
-    ratio_bar = float(getattr(conf, "CODE_ADAPT_TAIL_RATIO", 3.0))
-    min_n = int(getattr(conf, "CODE_ADAPT_MIN_SAMPLES", 8) or 1)
     worst = None                      # (ratio, p50_ms, p99_ms, peer)
     for peer in sorted(set(peers or ())):
         sk = Sketch.from_dict((tails or {}).get(peer) or {})
-        if sk.n < min_n or sk.sum <= 0:
+        if sk.n < ADAPT_MIN_SAMPLES or sk.sum <= 0:
             continue
         p50 = sk.quantile(0.50) or 0.0
         p99 = sk.quantile(0.99) or 0.0
@@ -710,15 +715,15 @@ def choose_code(peers, tails, fault_rates=None, static_spec=None):
         return (None, "no recorded tails for peers %s"
                 % (sorted(set(peers or ())),), None)
     ratio, p50_ms, p99_ms, peer = worst
-    if decoded or ratio >= ratio_bar:
+    if decoded or ratio >= ADAPT_TAIL_RATIO:
         spec = getattr(conf, "CODE_ADAPT_ESCALATE", "rs(4,2)")
         why = ("%d decode(s) consumed parity here" % decoded
                if decoded else
                "peer %s tail p99/p50 %.1f >= %.1f" % (peer, ratio,
-                                                      ratio_bar))
+                                                      ADAPT_TAIL_RATIO))
         return spec, "escalate: " + why, round(p50_ms, 3)
     return ("off", "tight tails: worst peer %s p99/p50 %.1f < %.1f"
-            % (peer, ratio, ratio_bar), round(p99_ms, 3))
+            % (peer, ratio, ADAPT_TAIL_RATIO), round(p99_ms, 3))
 
 
 def _init_from_conf():

@@ -6,6 +6,12 @@ cites are at file-granularity only (SURVEY.md section 2.1).
 
 TPU additions beyond the reference: mesh shape, HBM budget knobs, and the
 device-bucket padding policy used by the all_to_all shuffle.
+
+An environment read stays here only if a test, a cell, chip_smoke.py, an
+example, benchmarks/ or a tools/d* script sets or reads it, or it is a
+deployment setting (path, address, credential, a server's capacity or
+timeout), or it is a plane's one documented mode switch; anything else is
+a constant beside the code that uses it (tests/test_repo_inventory.py).
 """
 
 import os
@@ -55,14 +61,6 @@ COMPRESS = "auto"
 # full grammar and the list of named sites.
 DPARK_FAULTS = os.environ.get("DPARK_FAULTS", "")
 
-# device-path graceful degradation: a JaxRuntimeError /
-# RESOURCE_EXHAUSTED from a stage program first retries the stage with
-# a HALVED wave budget (stream_chunk_rows), then falls back to the
-# object path for that stage only — recorded as a per-stage
-# `degrade_reason`, never a job abort.  "0" disables (the error then
-# still falls back to the object path, without the halved retry).
-DEGRADE = os.environ.get("DPARK_DEGRADE", "1") != "0"
-
 # erasure-coded shuffle exchange (dpark_tpu/coding.py — ISSUE 6):
 #   off      no parity (default; zero hot-path cost)
 #   xor      4 data shards + 1 XOR parity per bucket/spill payload
@@ -87,22 +85,13 @@ SHUFFLE_SHARD_ATTEMPTS = int(os.environ.get(
 # per-peer fetch-tail sketches and observed decode/fault rates instead
 # of paying DPARK_SHUFFLE_CODE's static parity tax everywhere —
 # exchanges whose recorded peers straggle (p99/p50 over
-# CODE_ADAPT_TAIL_RATIO) or decoded from parity before escalate to
+# coding.ADAPT_TAIL_RATIO) or decoded from parity before escalate to
 # CODE_ADAPT_ESCALATE, exchanges whose peers are uniformly tight drop
 # to uncoded.  Requires DPARK_ADAPT=on to steer; under
 # DPARK_ADAPT=observe choices are logged (applied=false) and the
 # static code runs, bit-identical.  The writer's self-describing frame
 # geometry makes mixed per-shuffle codes safe on the wire.
 CODE_ADAPT = os.environ.get("DPARK_CODE_ADAPT", "0") == "1"
-
-# a recorded peer counts as a straggler when its persisted fetch-tail
-# sketch shows p99/p50 at or above this ratio (and at least
-# CODE_ADAPT_MIN_SAMPLES observations); below it with a bounded p99
-# the exchange is priced tight and runs uncoded
-CODE_ADAPT_TAIL_RATIO = float(os.environ.get(
-    "DPARK_CODE_ADAPT_TAIL_RATIO", "3.0"))
-CODE_ADAPT_MIN_SAMPLES = int(os.environ.get(
-    "DPARK_CODE_ADAPT_MIN_SAMPLES", "8") or 1)
 
 # the code an escalated exchange runs (parse_code grammar); the
 # no-history / insufficient-samples default stays DPARK_SHUFFLE_CODE
@@ -135,50 +124,23 @@ DPARK_ADAPT_DIR = os.environ.get(
 ADAPT_STORE_MAX_BYTES = int(os.environ.get(
     "DPARK_ADAPT_STORE_MAX_BYTES", str(1 << 22)) or 0)
 
-# the object path must beat the device path by this factor of observed
-# ms before the cost model declines the array path (ties keep the
-# device: its compile cost amortizes across runs)
-ADAPT_PATH_MARGIN = float(os.environ.get("DPARK_ADAPT_PATH_MARGIN",
-                                         "0.8"))
-
-# dominant-group fraction (max group rows / total rows) above which an
-# observed histogram counts as skewed, and the widening factor applied
-# to the DEFAULT reduce width on the next run of that program
-ADAPT_SKEW_FRAC = float(os.environ.get("DPARK_ADAPT_SKEW_FRAC", "0.5"))
-ADAPT_SKEW_WIDEN = int(os.environ.get("DPARK_ADAPT_SKEW_WIDEN",
-                                      "2") or 2)
-
 # mid-job re-planning at the stage boundary (ISSUE 19): "1" lets the
 # scheduler re-partition a reduce side BEFORE launching it when the
 # completed map stage's on-disk bucket sizes show hash-collision skew
 # the plan-time guess missed (dominant-bucket byte fraction >=
-# REPLAN_SKEW_FRAC) — a same-width salted re-split stage re-keys the
-# buckets without recomputing any map task (resubmits == recomputes ==
-# 0) and the choice lands as `replan_reason` on the job record plus an
+# schedule.REPLAN_SKEW_FRAC) — a same-width salted re-split stage
+# re-keys the buckets without recomputing any map task (resubmits ==
+# recomputes == 0) and the choice lands as `replan_reason` on the job record plus an
 # adapt "replan" record, so the NEXT run of the same call site salts
 # its partitioner at plan time and skips the mid-job re-split.
 # Requires DPARK_ADAPT=on to steer; observe mode records the would-be
 # re-plan (applied=false) and launches the original reduce side.
 REPLAN = os.environ.get("DPARK_REPLAN", "0") == "1"
 
-# dominant-bucket byte fraction (largest reduce bucket / total bucket
-# bytes across the exchange) at or above which the completed map side
-# counts as skewed enough to re-split; buckets must be file://-local
-# for the driver to size them (device HBM exchanges never re-split —
-# their skew signal is the SegMapOp histogram, adapt decision point 3)
-REPLAN_SKEW_FRAC = float(os.environ.get(
-    "DPARK_REPLAN_SKEW_FRAC", "0.6"))
-
 # floor on total exchange bytes before a re-plan is considered: tiny
 # exchanges re-split slower than they run
 REPLAN_MIN_BYTES = int(os.environ.get(
     "DPARK_REPLAN_MIN_BYTES", "4096") or 0)
-
-# observed combine ratio (distinct keys / rows) above which map-side
-# pre-aggregation is priced OFF (nearly every key distinct: the
-# combine pass costs a sort and saves no exchange bytes)
-ADAPT_COMBINE_MAX_RATIO = float(os.environ.get(
-    "DPARK_ADAPT_COMBINE_MAX_RATIO", "0.6"))
 
 # deterministic stand-in for a device HBM ceiling (bench/test aid): a
 # streamed wave budget above this many rows/device raises the same
@@ -542,15 +504,11 @@ INGEST_THREADS = int(os.environ.get("DPARK_INGEST_THREADS", "0") or 0)
 # tuple of up to MAX_KEY_LEAVES numeric scalars — ((user, item), v),
 # ((src, dst), w) — classify onto the array path end to end (hash
 # destinations via the pair-extended phash, sort/segment/combine over
-# all key columns, tuple repacked at egest).  "0" disables (tuple keys
-# then take the host object path, the pre-PR behavior — useful when
-# bisecting).  Nested key tuples and non-numeric key leaves always
-# fall back; the `host-fallback-key` lint rule reports why.
-TUPLE_KEYS = os.environ.get("DPARK_TUPLE_KEYS", "1") != "0"
-
-# widest flat tuple key the device path accepts: each extra key leaf is
-# one more sort operand in every shuffle program, so keep this small
-# (2-3 covers the (user, item) / (src, dst) shapes real jobs use)
+# all key columns, tuple repacked at egest).  Nested key tuples and
+# non-numeric key leaves fall back; the `host-fallback-key` lint rule
+# reports why.  Each extra key leaf is one more sort operand in every
+# shuffle program, so keep this small (2-3 covers the (user, item) /
+# (src, dst) shapes real jobs use)
 MAX_KEY_LEAVES = int(os.environ.get("DPARK_MAX_KEY_LEAVES", "4") or 4)
 
 # default dtype for device-side values
@@ -595,13 +553,6 @@ SEG_MAP = os.environ.get("DPARK_SEG_MAP", "1") != "0"
 # structural identity, so steady-state streams pay once).
 SEG_MIN_ROWS_PER_TRACE = int(os.environ.get(
     "DPARK_SEG_MIN_ROWS_PER_TRACE", "0") or 0)
-
-# general traceable updateStateByKey on device: state rides as
-# HBM-resident columns and each batch cogroups with its padded value
-# segments through the same SegMapOp machinery (update(prev, values)
-# traced twice — with a prev scalar and with the literal None).  "0"
-# keeps the host cogroup path.
-SEG_STATE = os.environ.get("DPARK_SEG_STATE", "1") != "0"
 
 # device->host egest: int64 scalar columns at least this large are
 # min/max-probed and ride the link as int32 when every valid value fits
@@ -663,65 +614,18 @@ TRACE_SPOOL_MAX_BYTES = int(os.environ.get(
 # contract: off-mode job results are bit-identical to on).
 DPARK_HEALTH = os.environ.get("DPARK_HEALTH", "on")
 
-# bounded sketch registries: at most this many per-site sketches (past
-# the cap, new sites fold into their base site name) and this many
-# per-(job, stage) fetch sketches (oldest evicts) — streaming
+# bounded sketch registry: at most this many per-site sketches (past
+# the cap, new sites fold into their base site name) — streaming
 # aggregation must hold bounded memory no matter how long the process
 # serves
 HEALTH_MAX_SITES = int(os.environ.get("DPARK_HEALTH_MAX_SITES",
                                       "256") or 256)
-HEALTH_STAGE_SKETCHES = int(os.environ.get(
-    "DPARK_HEALTH_STAGE_SKETCHES", "256") or 256)
-
-# minimum seconds between site-tail persists into the adapt store
-# (health.persist_site_tails runs at job finish; a streaming job
-# finishing one tick-job per second must not append per tick).
-# Deltas are persisted, so the throttle trades freshness, not truth.
-HEALTH_PERSIST_MIN_S = float(os.environ.get(
-    "DPARK_HEALTH_PERSIST_S", "30") or 0)
-
-# /api/health grading thresholds (yellow, red) — evidence ships with
-# every verdict so an operator sees the number AND the bar it crossed
-HEALTH_FETCH_P99_YELLOW_MS = float(os.environ.get(
-    "DPARK_HEALTH_FETCH_P99_YELLOW_MS", "250"))
-HEALTH_FETCH_P99_RED_MS = float(os.environ.get(
-    "DPARK_HEALTH_FETCH_P99_RED_MS", "1000"))
-HEALTH_DCN_P99_YELLOW_MS = float(os.environ.get(
-    "DPARK_HEALTH_DCN_P99_YELLOW_MS", "500"))
-HEALTH_DCN_P99_RED_MS = float(os.environ.get(
-    "DPARK_HEALTH_DCN_P99_RED_MS", "2000"))
-HEALTH_WAVE_P99_YELLOW_MS = float(os.environ.get(
-    "DPARK_HEALTH_WAVE_P99_YELLOW_MS", "5000"))
-HEALTH_WAVE_P99_RED_MS = float(os.environ.get(
-    "DPARK_HEALTH_WAVE_P99_RED_MS", "30000"))
-HEALTH_SPILL_P99_YELLOW_MS = float(os.environ.get(
-    "DPARK_HEALTH_SPILL_P99_YELLOW_MS", "500"))
-HEALTH_SPILL_P99_RED_MS = float(os.environ.get(
-    "DPARK_HEALTH_SPILL_P99_RED_MS", "5000"))
-HEALTH_ERROR_RATE_YELLOW = float(os.environ.get(
-    "DPARK_HEALTH_ERROR_RATE_YELLOW", "0.01"))
-HEALTH_ERROR_RATE_RED = float(os.environ.get(
-    "DPARK_HEALTH_ERROR_RATE_RED", "0.10"))
 
 # per-tenant SLO accounting (service.py — ISSUE 14): the default
 # per-job latency target in ms for tenants that declare none
 # explicitly (ServiceClient(..., slo_ms=) / ClientScheduler slo_ms).
 # 0 = no SLO tracked for undeclared tenants.
 SERVICE_SLO_MS = float(os.environ.get("DPARK_SERVICE_SLO", "0") or 0)
-
-# attainment target backing the burn-rate math: a burn of 1.0 means
-# violations are consuming the (1 - target) error budget exactly as
-# fast as allowed; 2.0 means twice as fast (the classic multi-window
-# burn alert).  Windows are the short/long burn horizons in seconds.
-SERVICE_SLO_TARGET = float(os.environ.get("DPARK_SERVICE_SLO_TARGET",
-                                          "0.99"))
-SERVICE_SLO_WINDOWS = tuple(
-    float(w) for w in os.environ.get("DPARK_SERVICE_SLO_WINDOWS",
-                                     "60,600").split(",") if w)
-SERVICE_SLO_BURN_YELLOW = float(os.environ.get(
-    "DPARK_SERVICE_SLO_BURN_YELLOW", "1.0"))
-SERVICE_SLO_BURN_RED = float(os.environ.get(
-    "DPARK_SERVICE_SLO_BURN_RED", "2.0"))
 
 # ---------------------------------------------------------------------------
 # resource attribution plane (dpark_tpu/ledger.py — ISSUE 15)
@@ -758,13 +662,6 @@ LEDGER_MAX_KEYS = int(os.environ.get("DPARK_LEDGER_MAX_KEYS",
 #   off      capture nothing
 LEDGER_COST = os.environ.get("DPARK_LEDGER_COST", "lower")
 
-# conservation grading: attributed per-tenant device-seconds must sum
-# to at least this fraction of the measured mesh-busy time (the
-# mesh-lock hold total) before /api/health grades attribution yellow —
-# device time the ledger cannot name is untracked consumption
-LEDGER_CONSERVE_YELLOW = float(os.environ.get(
-    "DPARK_LEDGER_CONSERVE_YELLOW", "0.9"))
-
 # concurrency sanitizer plane (dpark_tpu/locks.py — ISSUE 16): the
 # named-lock registry records per-thread lock acquisition order and
 # merges it into a process-wide graph, reporting lock-order cycles
@@ -792,10 +689,8 @@ SHUFFLE_FETCH_WAIT_S = float(os.environ.get(
 # "" (the default) keeps the ring armed but writes nothing.
 DPARK_FLIGHT_DIR = os.environ.get("DPARK_FLIGHT_DIR", "")
 
-# flight ring capacity and the per-process dump cap (a crash loop
-# must not fill the disk with snapshots)
-FLIGHT_RING_EVENTS = int(os.environ.get("DPARK_FLIGHT_RING", "512")
-                         or 512)
+# the per-process dump cap (a crash loop must not fill the disk with
+# snapshots)
 FLIGHT_MAX_DUMPS = int(os.environ.get("DPARK_FLIGHT_MAX_DUMPS", "16")
                        or 0)
 
@@ -837,19 +732,6 @@ DPARK_LINT = os.environ.get("DPARK_LINT", "warn")
 # plan-wide-depth rule: more chained shuffles than this on one
 # uncheckpointed lineage path draws a warning (0 disables the rule)
 LINT_WIDE_DEPTH = int(os.environ.get("DPARK_LINT_WIDE_DEPTH", "4"))
-
-# pre-flight walk budget in lineage nodes: plans bigger than this are
-# linted over a truncated prefix (logged at debug) so per-tick lint
-# cost on long-running streams stays bounded — streaming lineages grow
-# until checkpoint truncation and each tick submits a fresh final rdd
-LINT_MAX_NODES = int(os.environ.get("DPARK_LINT_MAX_NODES", "500"))
-
-# monoid-multileaf record probing: "shallow" reads only data already
-# resident on the driver (parallelize slices / unions of them);
-# "deep" additionally replays narrow per-record user functions over
-# the <=4 probe rows (opt-in: user functions may carry side effects,
-# e.g. accumulator bumps); "off" disables probing entirely
-LINT_PROBE = os.environ.get("DPARK_LINT_PROBE", "shallow")
 
 
 def load_conf(path):

@@ -1759,8 +1759,7 @@ class StateDStream(DerivedDStream):
                     return reduced.cache()
                 return prev.union(reduced) \
                     .reduceByKey(op, self.numSplits).cache()
-        from dpark_tpu import conf
-        if self._monoid_op is None and conf.SEG_STATE \
+        if self._monoid_op is None \
                 and self._seg_state is None and batch is not None:
             self._seg_state = self._classify_seg_state(batch)
         if self._monoid_op is None and self._seg_state:

@@ -199,10 +199,14 @@ def _peer_of(args):
     return u.split("/", 1)[0].rsplit(":", 1)[0] or "local"
 
 
+# per-(job, stage) fetch sketches kept; the oldest evicts
+STAGE_SKETCHES = 256
+
+
 class HealthSink:
     """The in-process streaming aggregator.  fold() is called from
     TracePlane.record with every emitted record; everything is bounded
-    (HEALTH_MAX_SITES site sketches, HEALTH_STAGE_SKETCHES per-stage
+    (conf.HEALTH_MAX_SITES site sketches, STAGE_SKETCHES per-stage
     fetch sketches) and guarded by one lock."""
 
     def __init__(self):
@@ -256,9 +260,7 @@ class HealthSink:
                 if key != (None, None):
                     ssk = self.stage_fetch.get(key)
                     if ssk is None:
-                        cap = int(getattr(conf, "HEALTH_STAGE_SKETCHES",
-                                          256) or 256)
-                        if len(self._stage_order) >= cap:
+                        if len(self._stage_order) >= STAGE_SKETCHES:
                             old = self._stage_order.pop(0)
                             self.stage_fetch.pop(old, None)
                         ssk = self.stage_fetch[key] = Sketch()
@@ -454,11 +456,18 @@ def summary():
 # consumer 1: per-site tails -> the adapt store (ROADMAP item 5)
 # ---------------------------------------------------------------------------
 
+# minimum seconds between site-tail persists into the adapt store
+# (persist_site_tails runs at job finish; a streaming job finishing
+# one tick-job per second must not append per tick).  Deltas are
+# persisted, so the throttle trades freshness, not truth.
+PERSIST_MIN_S = 30.0
+
+
 def persist_site_tails(force=False):
     """Append each site's UNPERSISTED observations to the adapt store
     as a digest delta (the store folds deltas by bucket addition, so
     repeated persists never double-count).  Throttled to once per
-    conf.HEALTH_PERSIST_MIN_S unless forced.  Returns the number of
+    PERSIST_MIN_S unless forced.  Returns the number of
     sites written."""
     s = _SINK
     if s is None:
@@ -468,10 +477,8 @@ def persist_site_tails(force=False):
         if not adapt.enabled():
             return 0
         now = time.time()
-        min_s = float(getattr(conf, "HEALTH_PERSIST_MIN_S", 30.0)
-                      or 0.0)
         with s.lock:
-            if not force and now - s._last_persist < min_s:
+            if not force and now - s._last_persist < PERSIST_MIN_S:
                 return 0
             s._last_persist = now
         # the MERGED view: local sketches plus worker-process digests
@@ -511,6 +518,19 @@ def persist_site_tails(force=False):
 # ---------------------------------------------------------------------------
 # grading: the /api/health verdicts (reused offline by dtrace --health)
 # ---------------------------------------------------------------------------
+
+# grading thresholds (yellow, red) — evidence ships with every verdict
+# so an operator sees the number AND the bar it crossed
+THRESHOLDS = {
+    "fetch_p99_ms": (250.0, 1000.0),
+    "dcn_p99_ms": (500.0, 2000.0),
+    "wave_p99_ms": (5000.0, 30000.0),
+    "spill_p99_ms": (500.0, 5000.0),
+    "error_rate": (0.01, 0.10),
+    # per-tenant SLO burn (service.SLO_TARGET's error budget)
+    "slo_burn": (1.0, 2.0),
+}
+
 
 def _grade_of(value, yellow, red):
     if value is None:
@@ -554,8 +574,7 @@ def grade(site_digests, rates, tenants=None, counters=None,
         return max(vals, key=lambda kv: kv[1])
 
     # shuffle fetch: worst per-peer p99 + failure rate over fetches
-    fy = float(getattr(conf, "HEALTH_FETCH_P99_YELLOW_MS", 250.0))
-    fr = float(getattr(conf, "HEALTH_FETCH_P99_RED_MS", 1000.0))
+    fy, fr = THRESHOLDS["fetch_p99_ms"]
     site, p99 = tail("fetch.bucket")
     fetches = sum(d["n"] for s, d in sites.items()
                   if s.startswith("fetch.bucket"))
@@ -565,8 +584,7 @@ def grade(site_digests, rates, tenants=None, counters=None,
     fails = max(rates.get("fetch.error", 0),
                 rates.get("fetch.failed", 0))
     fail_rate = fails / fetches if fetches else 0.0
-    ey = float(getattr(conf, "HEALTH_ERROR_RATE_YELLOW", 0.01))
-    er = float(getattr(conf, "HEALTH_ERROR_RATE_RED", 0.10))
+    ey, er = THRESHOLDS["error_rate"]
     out["shuffle_fetch"] = {
         "grade": _worst(_grade_of(p99, fy, fr),
                         _grade_of(fail_rate if fetches else None,
@@ -583,8 +601,7 @@ def grade(site_digests, rates, tenants=None, counters=None,
     dcn_n = sum(d["n"] for s, d in sites.items()
                 if s.startswith("dcn."))
     dcn_rate = dcn_fails / dcn_n if dcn_n else 0.0
-    dy = float(getattr(conf, "HEALTH_DCN_P99_YELLOW_MS", 500.0))
-    dr = float(getattr(conf, "HEALTH_DCN_P99_RED_MS", 2000.0))
+    dy, dr = THRESHOLDS["dcn_p99_ms"]
     out["dcn"] = {
         "grade": _worst(_grade_of(p99, dy, dr),
                         _grade_of(dcn_rate if dcn_n else None,
@@ -607,8 +624,7 @@ def grade(site_digests, rates, tenants=None, counters=None,
                      "thresholds": {"failure_rate": [ey, er]}}}
     # executor: wave tail + degrade events
     site, p99 = tail("wave:")
-    wy = float(getattr(conf, "HEALTH_WAVE_P99_YELLOW_MS", 5000.0))
-    wr = float(getattr(conf, "HEALTH_WAVE_P99_RED_MS", 30000.0))
+    wy, wr = THRESHOLDS["wave_p99_ms"]
     degrades = rates.get("stage.degrade", 0)
     out["executor"] = {
         "grade": _worst(_grade_of(p99, wy, wr),
@@ -625,8 +641,7 @@ def grade(site_digests, rates, tenants=None, counters=None,
             ledger_data["top_programs"]
     # spill I/O
     site, p99 = tail("spill.")
-    sy = float(getattr(conf, "HEALTH_SPILL_P99_YELLOW_MS", 500.0))
-    sr = float(getattr(conf, "HEALTH_SPILL_P99_RED_MS", 5000.0))
+    sy, sr = THRESHOLDS["spill_p99_ms"]
     out["spill"] = {
         "grade": _grade_of(p99, sy, sr),
         "evidence": {"worst_site": site, "p99_ms": p99,
@@ -680,8 +695,7 @@ def grade(site_digests, rates, tenants=None, counters=None,
                                                    0) or 0)}}
     # per-tenant SLO (only when a service with declared SLOs is live)
     if tenants:
-        by = float(getattr(conf, "SERVICE_SLO_BURN_YELLOW", 1.0))
-        br = float(getattr(conf, "SERVICE_SLO_BURN_RED", 2.0))
+        by, br = THRESHOLDS["slo_burn"]
         worst = "green"
         for t in tenants.values():
             burn = max((t.get("burn") or {}).values() or [0.0])

@@ -42,6 +42,14 @@ logger = get_logger("schedule")
 # renderer cumulates these into Prometheus le= buckets
 PHASE_BUCKETS = (0.05, 0.25, 1.0, 5.0, 30.0, 120.0)
 
+# dominant-bucket byte fraction (largest reduce bucket / total bucket
+# bytes across the exchange) at or above which a completed map side
+# counts as skewed enough for _maybe_replan to re-split; buckets must
+# be file://-local for the driver to size them (device HBM exchanges
+# never re-split — their skew signal is the SegMapOp histogram, adapt
+# decision point 3)
+REPLAN_SKEW_FRAC = 0.6
+
 
 class Stage:
     # itertools.count: atomic under the GIL — concurrent drivers on a
@@ -829,7 +837,7 @@ class DAGScheduler:
         if total < conf.REPLAN_MIN_BYTES:
             return
         frac = max(sizes) / float(total)
-        if frac < conf.REPLAN_SKEW_FRAC:
+        if frac < REPLAN_SKEW_FRAC:
             return
         child, consumer = self._replan_consumer(stage, dep, waiting)
         if child is None:
@@ -1150,7 +1158,7 @@ class DAGScheduler:
     def pipeline_summary(self):
         """The overlapped-wave-pipeline snapshot of the DEEPEST streamed
         stage across the job history (most waves), per-wave detail
-        dropped — the aggregate consumers (bench.py, benchmarks/) report:
+        dropped — the aggregate consumers (benchmarks/) report:
         ingest/compute/exchange/spill ms + device-idle fraction.
         None when no stage streamed."""
         best = None

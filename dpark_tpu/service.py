@@ -82,12 +82,21 @@ class _JobState:
         self.record = record
 
 
+# attainment target backing the burn-rate math: a burn of 1.0 means
+# violations are consuming the (1 - target) error budget exactly as
+# fast as allowed; 2.0 means twice as fast (the classic multi-window
+# burn alert; health.THRESHOLDS["slo_burn"] grades it) over the short
+# and the long burn horizon in seconds
+SLO_TARGET = 0.99
+SLO_WINDOWS = (60.0, 600.0)
+
+
 class _Tenant:
     """Per-tenant SLO accounting (ISSUE 14): tenants declare a per-job
     latency target (``ServiceClient(..., slo_ms=)`` /
     ``DPARK_SERVICE_SLO``); the server tracks lifetime attainment and
     a multi-window burn rate — how fast violations consume the
-    ``1 - SERVICE_SLO_TARGET`` error budget (burn 1.0 = exactly as
+    ``1 - SLO_TARGET`` error budget (burn 1.0 = exactly as
     fast as allowed; 2.0 = twice as fast, the classic paging
     threshold).  The window deque is bounded by the longest burn
     horizon, so a resident server's memory stays flat."""
@@ -104,14 +113,14 @@ class _Tenant:
         if not ok:
             self.violations += 1
         self.window.append((now, ok))
-        horizon = max(conf.SERVICE_SLO_WINDOWS or (600.0,))
+        horizon = max(SLO_WINDOWS)
         while self.window and self.window[0][0] < now - horizon:
             self.window.popleft()
 
     def stats(self, now):
-        budget = max(1e-9, 1.0 - float(conf.SERVICE_SLO_TARGET))
+        budget = max(1e-9, 1.0 - SLO_TARGET)
         burn = {}
-        for w in (conf.SERVICE_SLO_WINDOWS or (600.0,)):
+        for w in SLO_WINDOWS:
             recent = [ok for ts, ok in self.window if ts >= now - w]
             rate = (sum(1 for ok in recent if not ok) / len(recent)
                     if recent else 0.0)

@@ -177,8 +177,7 @@ MODES = ("off", "ring", "spool")
 # append at failure sites, which are rare by definition), so a
 # post-mortem flight dump has the recent warning context no matter
 # what DPARK_TRACE was.  health.flight_dump snapshots it.
-_FLIGHT = deque(maxlen=max(16, int(
-    getattr(conf, "FLIGHT_RING_EVENTS", 512) or 512)))
+_FLIGHT = deque(maxlen=512)
 
 # see TracePlane.run: disambiguates runs minted in the same millisecond
 import itertools
