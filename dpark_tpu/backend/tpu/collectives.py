@@ -123,38 +123,39 @@ def range_dst_cols(key_cols, bounds_cols, ascending, n_dst, valid,
     return jnp.where(valid, dst, n_dst)
 
 
-def _take(leaves, idx):
-    return [leaf[idx] for leaf in leaves]
-
-
 def _lex_sort(ops, num_keys):
-    """Stable lexicographic sort of `ops` by its first num_keys operands.
+    """Stable lexicographic sort of `ops` by its first num_keys operands
+    (rank 1, most significant first); every other operand moves with
+    its row.
 
-    Formulated as permutation-compose + gather on every backend: XLA's
-    multi-operand Sort lowers (on TPU) to a comparison network whose
-    cost — to compile and to run — grows with total operand bytes
-    (read off the compiler's output; the two forms were never timed
-    against each other on a chip).  Successive 2-operand (key, iota)
-    sorts radix-compose the permutation instead, and every operand is
-    gathered exactly once; this also carries rank>1 payloads, which
-    XLA Sort cannot.  On the v5e at 2M rows such a sort runs in 3.9 ms
-    and a gather of one int64 column in 34 (float32: 19): the gathers
-    are the cost (PR 27's chip runs).
+    ONE multi-operand lax.sort carries the rows.  XLA's sort takes only
+    operands of the keys' shape, so a leaf of rank > 1 is gathered
+    through an i32 iota that rides the same sort; records of scalar
+    leaves lower no gather at all.
 
-    The iota is i32 and sorted with lax.sort_key_val directly:
-    jnp.argsort carries an int64 iota under jax_enable_x64, and the
-    TPU compiler takes ~20-30% longer over the same sort with the
-    64-bit payload (v5e smoke log, PR 21: 48 s vs 38 s to compile one
-    stable i64-key sort of 4M rows; every stage program holds 2-3)."""
-    iota = lax.iota(jnp.int32, ops[0].shape[0])
-    order = None
-    for k in range(num_keys - 1, -1, -1):
-        key = ops[k] if order is None else ops[k][order]
-        _, perm = lax.sort_key_val(key, iota, is_stable=True)
-        order = perm if order is None else order[perm]
-    if order is None:           # no key: nothing to sort by
-        return tuple(ops)
-    return tuple(o[order] for o in ops)
+    Timed alone on the v5e against the form this replaced (a 2-operand
+    (key, iota) sort per key column, the permutations composed, every
+    operand gathered through the result), PR 29's chip runs at 2M rows:
+    the sort carrying 2 / 4 / 6 / 8 32-bit words (an int64 column is
+    two) 5.1 / 7.3-7.8 / 9.9-10.3 / 13.0-13.1 ms, the gathers 50-77 /
+    84-148 / 117-258 / 150-356; the map-side combine's order sort
+    (dst, key | value, int64) 9.0 against 133.8.  1.3 ms a word,
+    linear, int32 and int64 leading key alike, so the payloads ride in
+    one group.  The compiler pays instead: 16-25 s for the 2-word sort
+    and about 10 s more for every further word, at 65,536 rows as at
+    2M (the replaced form: 8-37 s)."""
+    ops = tuple(ops)
+    if num_keys == 0:           # no key: nothing to sort by
+        return ops
+    wide = any(o.ndim > 1 for o in ops)
+    carried = [o for o in ops if o.ndim == 1]
+    if wide:
+        carried.append(lax.iota(jnp.int32, ops[0].shape[0]))
+    carried = lax.sort(carried, num_keys=num_keys, is_stable=True)
+    if not wide:
+        return tuple(carried)
+    order, flat = carried[-1], iter(carried[:-1])
+    return tuple(o[order] if o.ndim > 1 else next(flat) for o in ops)
 
 
 def _bcast(flag, leaf):
@@ -164,8 +165,9 @@ def _bcast(flag, leaf):
 
 
 def compact(leaves, mask):
-    """Move rows where mask is True to the front (stable); returns
-    (leaves, new_count)."""
+    """Move rows where mask is True to the front (stable: one _lex_sort
+    by the inverted mask, the leaves riding it); returns (leaves,
+    new_count)."""
     sorted_ops = _lex_sort((~mask,) + tuple(leaves), 1)
     return list(sorted_ops[1:]), jnp.sum(mask).astype(jnp.int32)
 
@@ -187,31 +189,9 @@ def _bucket_counts(d, nb):
     return jnp.diff(edges).astype(jnp.int32)
 
 
-def _dst_order(dst, n_dst):
-    """(order, counts[n_dst + 1]): the stable permutation grouping rows
-    by destination WITHOUT a comparison sort: per-bucket cumsum ranks +
-    one scatter (a counting sort over the tiny destination domain —
-    mesh size + the sentinel bucket).  Chosen on XLA:CPU, whose sort
-    ran ~4x slower than these O(n) passes at a million rows (round 3,
-    while profiling the segmented apply).  On the v5e this scatter
-    (distinct indices: not the serial kind) takes 10.6 ms at 2M rows
-    and a stable sort_key_val(dst, iota) 3.9, after 11 s of compile
-    (PR 27's chip runs); no cell runs this path yet.  Output is
-    bit-identical to jnp.argsort(dst, stable=True)."""
-    cap = dst.shape[0]
-    counts = _bucket_counts(dst, n_dst + 1)
-    offs = jnp.cumsum(counts) - counts
-    pos = jnp.zeros((cap,), jnp.int32)
-    for b in range(n_dst + 1):
-        m = dst == b
-        rank = jnp.cumsum(m.astype(jnp.int32)) - 1
-        pos = jnp.where(m, offs[b] + rank, pos)
-    return jnp.zeros((cap,), jnp.int32).at[pos].set(
-        jnp.arange(cap, dtype=jnp.int32)), counts
-
-
 def bucketize(key, leaves, n, n_dst, dst=None, r=None):
-    """Sort one device's rows by destination partition.
+    """Sort one device's rows by destination partition: the stable
+    _lex_sort by `dst` alone, the leaves riding it.
 
     Returns (sorted_leaves, counts[n_dst], offsets[n_dst]).  Invalid rows
     sort into a sentinel bucket past the end.
@@ -220,14 +200,9 @@ def bucketize(key, leaves, n, n_dst, dst=None, r=None):
     valid = jnp.arange(cap) < n
     if dst is None:
         dst = hash_dst(key, n_dst, valid, r)
-    if n_dst <= _DST_LOOP_MAX:
-        order, counts = _dst_order(dst, n_dst)
-    else:
-        sd, order = lax.sort_key_val(dst, lax.iota(jnp.int32, cap),
-                                     is_stable=True)
-        counts = _bucket_counts(sd, n_dst + 1)
-    counts = counts[:n_dst]
-    return _take(leaves, order), counts, jnp.cumsum(counts) - counts
+    sorted_ops = _lex_sort((dst,) + tuple(leaves), 1)
+    counts = _bucket_counts(sorted_ops[0], n_dst + 1)[:n_dst]
+    return list(sorted_ops[1:]), counts, jnp.cumsum(counts) - counts
 
 
 def exchange_round(axis, leaves, offsets, counts, sent, slot,
@@ -368,7 +343,7 @@ def bucketize_combine_keys(key_cols, val_leaves, n, n_dst, merge_leaves,
         dst = hash_dst_cols(key_cols, n_dst, valid, r)
     ks = [jnp.where(valid, key_cols[0], _sentinel(key_cols[0].dtype))]
     ks += key_cols[1:]
-    # composite keys: one hash ordering pass instead of n key argsorts
+    # composite keys: one hash column to order by instead of n key columns
     # (the reduce side re-sorts by the true key columns; see
     # _bucketize_combine_cols on why adjacency is sufficient here)
     order_col = (phash_device_cols(key_cols) if len(key_cols) > 1
@@ -428,14 +403,14 @@ def _bucketize_combine_cols(dst, key_cols, val_leaves, n_dst,
 
     `order_col` (optional, composite keys): a single synthetic
     ordering column (e.g. the 32-bit composite key hash) used INSTEAD
-    of the n key columns for the sort — one argsort pass regardless of
-    key width.  Correct because the map-side combine only needs equal
-    keys ADJACENT within their destination run (boundaries are still
-    detected by comparing every real key column, so a hash collision
-    merely splits one group into two partial combiners — the reduce
-    side merges them anyway).  Do NOT use it where callers require
-    true key-sorted output (the spilled-run stream's export relies on
-    lexicographic run order)."""
+    of the n key columns for the sort — one comparison key regardless
+    of key width (the key columns ride as payloads).  Correct because
+    the map-side combine only needs equal keys ADJACENT within their
+    destination run (boundaries are still detected by comparing every
+    real key column, so a hash collision merely splits one group into
+    two partial combiners — the reduce side merges them anyway).  Do
+    NOT use it where callers require true key-sorted output (the
+    spilled-run stream's export relies on lexicographic run order)."""
     nk = len(key_cols)
     if order_col is not None:
         sorted_ops = _lex_sort(

@@ -51,6 +51,21 @@ def _combine_arg(merge_fn, monoid):
     return "none" if merge_fn is None else "user_scan"
 
 
+def _sort_arg(plan, sorts):
+    """The `sort=` of a `compile` event: how the program's orderings
+    (collectives._lex_sort: shuffle write, reduce, filter, sort) move
+    their rows — every operand `carried` through the one sort,
+    `carried+gathered` where a leaf of rank > 1 (which XLA's sort
+    cannot carry) is gathered behind it, `none` without an ordering.
+    Read off the plan's record specs, as the sort sees them at trace
+    time."""
+    if not sorts:
+        return "none"
+    wide = any(shape for _, shape in
+               tuple(plan.in_specs) + tuple(plan.out_specs))
+    return "carried+gathered" if wide else "carried"
+
+
 AXIS = conf.MESH_AXIS
 
 
@@ -964,7 +979,10 @@ class JAXExecutor:
         if trace._PLANE is not None:
             trace.event("compile", "exec", program="narrow", cap=cap,
                         sig=_plan_sig(plan),
-                        combine=_combine_arg(merge_fn, monoid))
+                        combine=_combine_arg(merge_fn, monoid),
+                        sort=_sort_arg(plan, epilogue is not None or any(
+                            isinstance(op, (fuse.SortOp, fuse.FilterOp))
+                            for op in ops)))
         in_specs = plan.in_specs
 
         def per_device(counts, *rest):
@@ -1117,7 +1135,8 @@ class JAXExecutor:
         if trace._PLANE is not None:    # combine= is the source reduce's
             trace.event("compile", "exec", program="reduce", slot=slot,
                         sig=_plan_sig(plan),
-                        combine=_combine_arg(merge_fn, monoid))
+                        combine=_combine_arg(merge_fn, monoid),
+                        sort=_sort_arg(plan, True))
 
         def per_device(*args):
             bounds = args[0][0] if has_bounds else None
@@ -1863,7 +1882,7 @@ class JAXExecutor:
 
     def _device_topk(self, plan, batch, kspec, n, smallest):
         """Per-device top-n of a result batch by the classified key:
-        one stable argsort per device, n rows kept (ties resolve by
+        one stable sort per device, n rows kept (ties resolve by
         device row order — top()'s tie membership is already
         partition-order-dependent on every master)."""
         cap = batch.cap

@@ -14,9 +14,13 @@ The contracts under test:
   counts and offsets equal.
 * ORDER — an unclassified, non-commutative merge sees a segment's rows
   in input order.
-* NO SCATTER — the lowered `narrow` and `reduce` programs of three
+* NO SCATTER — the lowered `narrow` and `reduce` programs of four
   reduceByKey shapes hold none, and their `compile` events say which
   form of the merge they were built with.
+* NO GATHER (ISSUE 29) — the same programs pull no column through a
+  permutation: their orderings carry the rows (`sort == "carried"`);
+  a record with a rank-2 value gathers that leaf alone
+  (`carried+gathered`).
 """
 
 import functools
@@ -299,13 +303,52 @@ def test_stage_programs_hold_no_scatter_and_name_their_combine(
     for program, text in launches:
         assert "sort" in text, program      # the text is the program's
         assert "scatter" not in text, program
-    assert {(a["program"], a["combine"]) for a in compiles} == {
-        ("narrow", form), ("reduce", form)}
+        assert not _column_gathers(text), program
+    assert {(a["program"], a["combine"], a["sort"]) for a in compiles} == {
+        ("narrow", form, "carried"), ("reduce", form, "carried")}
+
+
+def _column_gathers(text):
+    """The lowered text's gathers of a whole column of rows (a result
+    of 64 elements or more: every padded capacity of these jobs is
+    larger, every per-destination lookup smaller)."""
+    out = []
+    for line in text.splitlines():
+        if "stablehlo.gather" in line or "stablehlo.dynamic_gather" in line:
+            dims = line.rsplit("tensor<", 1)[1].split(">")[0].split("x")[:-1]
+            if int(np.prod([int(d) for d in dims] or [1])) >= 64:
+                out.append(line.strip())
+    return out
+
+
+def _vector_pair(r):
+    return (r[0], jnp.stack([r[1], r[1] * 2]))
+
+
+def test_a_rank2_value_is_gathered_behind_the_carried_sort(
+        launches, compiles_of):
+    data = _int_pairs()
+    rows, compiles = compiles_of(
+        lambda c: c.parallelize(data, 2).map(_vector_pair)
+        .reduceByKey(operator.add, 2).collect())
+    ref = {}
+    for k, v in zip(*data.arrays):
+        ref[k.item()] = ref.get(k.item(), 0) + np.array([v, 2 * v])
+    assert {k: list(v) for k, v in rows} == {
+        k: v.tolist() for k, v in ref.items()}
+    assert {p for p, _ in launches} == {"narrow", "reduce"}
+    for program, text in launches:
+        gathers = _column_gathers(text)
+        assert gathers, program
+        assert all("x2xi64>" in g.rsplit("->", 1)[1] for g in gathers), (
+            program, gathers)
+    assert {(a["program"], a["sort"]) for a in compiles} == {
+        ("narrow", "carried+gathered"), ("reduce", "carried+gathered")}
 
 
 def test_a_program_that_combines_nothing_says_none(compiles_of):
     n, compiles = compiles_of(
         lambda c: c.parallelize(_int_pairs(), 2).map(_pair).count())
     assert n == 400
-    assert [(a["program"], a["combine"]) for a in compiles] == [
-        ("narrow", "none")]
+    assert [(a["program"], a["combine"], a["sort"]) for a in compiles] == [
+        ("narrow", "none", "none")]
