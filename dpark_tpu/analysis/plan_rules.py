@@ -70,6 +70,10 @@ user functions are never executed.
 
 from dpark_tpu.analysis.report import Report
 
+# backend/tpu/layout.BYTES_WIDTH_MAX, repeated: this module never
+# imports jax (tests/test_bytes_join.py holds the two together)
+BYTES_WIDTH_MAX = 128
+
 
 # ---------------------------------------------------------------------------
 # lineage traversal
@@ -441,7 +445,11 @@ def _key_fallback_reason(key, hash_keys=True, fixed_width=None):
     when the key shape classifies (scalar numeric, a flat numeric
     tuple of 2..conf.MAX_KEY_LEAVES leaves — the composite keys the
     device path now carries end to end — or `bytes` from a fixed-width
-    S<w> column of `fixed_width` bytes).  Mirrors layout.key_width /
+    S<w> column of `fixed_width` <= BYTES_WIDTH_MAX bytes: a hash
+    shuffle's key, and with it the key of a join's two sides, which
+    the device join matches by a one-word hash and then the bytes when
+    both sides have one width; byte strings in the VALUES never show
+    here).  Mirrors layout.key_width /
     fuse's epilogue checks without importing jax: `hash_keys` is True
     for hash-partitioned shuffles, whose device routing additionally
     needs INT leaves (portable_hash has no device twin for floats);
@@ -468,10 +476,10 @@ def _key_fallback_reason(key, hash_keys=True, fixed_width=None):
         return "non-numeric"
 
     if isinstance(key, bytes) and fixed_width is not None:
-        if fixed_width > 8 * conf.MAX_KEY_LEAVES:
+        if fixed_width > BYTES_WIDTH_MAX:
             return ("byte-string column of %d bytes is over the device "
-                    "limit of 8 * conf.MAX_KEY_LEAVES = %d"
-                    % (fixed_width, 8 * conf.MAX_KEY_LEAVES))
+                    "limit of layout.BYTES_WIDTH_MAX = %d"
+                    % (fixed_width, BYTES_WIDTH_MAX))
         if not hash_keys:
             return ("range shuffle (sortByKey) over string or "
                     "byte-string keys has no device form")

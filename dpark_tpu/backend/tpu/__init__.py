@@ -490,9 +490,10 @@ class TPUScheduler(DAGScheduler):
     def _resident_nocombine_deps(self, cg):
         """All of a CoGroupedRDD's inputs as HBM-resident no-combine
         shuffle deps, or None (narrow side / host-resident / combining).
-        Keep in sync with fuse._analyze_join_source, the array-path twin
-        of this eligibility (it additionally rejects encoded keys and
-        r > mesh, which the host-seeding paths here tolerate)."""
+        fuse._analyze_join_source, the array-path twin, additionally
+        rejects encoded keys and r > mesh, which the host-seeding paths
+        here tolerate; the records' own check is fuse.join_sides for
+        both."""
         from dpark_tpu.backend.tpu import fuse
         deps = []
         for kind, obj in cg._dep_kinds:
@@ -525,28 +526,8 @@ class TPUScheduler(DAGScheduler):
         deps = self._resident_nocombine_deps(cg)
         if deps is None:
             return None
-        # join kernels require (k, v) records whose key is a scalar or
-        # flat numeric tuple, with the SAME width and dtypes both sides
-        from dpark_tpu.backend.tpu import layout
-        import numpy as np
-        import jax.tree_util as jtu
-        key_sigs = []
-        for dep in deps:
-            store = self.executor.shuffle_store[dep.shuffle_id]
-            treedef = store["out_treedef"]
-            specs = store["out_specs"]
-            nk = layout.key_width(treedef, specs, kinds="if")
-            sample = jtu.tree_unflatten(treedef,
-                                        list(range(len(specs))))
-            if nk is None or len(sample) != 2 \
-                    or layout.column_groups(treedef,
-                                            len(specs)) is not None:
-                # records must be (k, value) pairs; byte-string
-                # records join on the host (the bridge rebuilds bytes)
-                return None
-            key_sigs.append((nk, tuple(np.dtype(dt)
-                                       for dt, _ in specs[:nk])))
-        if key_sigs[0] != key_sigs[1]:
+        if fuse.join_sides([self.executor.shuffle_store[d.shuffle_id]
+                            for d in deps]) is None:
             return None
         rows_per_part = self.executor.run_device_join(deps[0], deps[1])
         for p, rows in enumerate(rows_per_part):

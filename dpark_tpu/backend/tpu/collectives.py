@@ -62,6 +62,23 @@ def hash_dst_cols(key_cols, n_dst, valid, r=None, bytes_width=None):
     return jnp.where(valid, dst, n_dst)
 
 
+def key_hash64(key_cols, valid):
+    """One int64 word for a key of several int64 columns (the words of
+    a byte-string key): a multiply-xorshift chain over the columns,
+    below the padding sentinel on valid rows and equal to it on the
+    rest.  Equal keys hash alike; keys that differ may too, so whoever
+    matches by it compares the columns afterwards (the device join)."""
+    h = jnp.full(key_cols[0].shape, 0x9E3779B97F4A7C15, jnp.uint64)
+    for c in key_cols:
+        h = (h ^ c.astype(jnp.uint64)) * jnp.uint64(0xBF58476D1CE4E5B9)
+        h = h ^ (h >> 32)
+    h = h * jnp.uint64(0x94D049BB133111EB)
+    h = (h ^ (h >> 29)) >> 1
+    sent = _sentinel(jnp.int64)
+    return jnp.where(valid, jnp.minimum(h.astype(jnp.int64), sent - 1),
+                     sent)
+
+
 def range_dst(key, bounds, ascending, n_dst, valid, r=None):
     """Destination partition by sorted bounds (RangePartitioner): the
     device twin of host bisect_left over the sampled bounds."""
@@ -147,15 +164,87 @@ def _lex_sort(ops, num_keys):
     ops = tuple(ops)
     if num_keys == 0:           # no key: nothing to sort by
         return ops
-    wide = any(o.ndim > 1 for o in ops)
-    carried = [o for o in ops if o.ndim == 1]
-    if wide:
-        carried.append(lax.iota(jnp.int32, ops[0].shape[0]))
+    rank1 = [o for o in ops if o.ndim == 1]
+    # a wide row: only the keys ride the sort, the rest of the row
+    # follows behind the iota as whole rows, however few its words (a
+    # gather costs a column what it costs a row of 16 words)
+    rows = sum(_words(o) for o in rank1) > _CARRIED_WORDS
+    behind = rows or any(o.ndim > 1 for o in ops)
+    carried = rank1[:num_keys] if rows else rank1
+    if behind:
+        carried = carried + [lax.iota(jnp.int32, ops[0].shape[0])]
     carried = lax.sort(carried, num_keys=num_keys, is_stable=True)
-    if not wide:
+    if not behind:
         return tuple(carried)
-    order, flat = carried[-1], iter(carried[:-1])
+    *flat, order = carried
+    if rows:
+        flat += _take_whole_rows(rank1[num_keys:], order)
+    flat = iter(flat)
     return tuple(o[order] if o.ndim > 1 else next(flat) for o in ops)
+
+
+# 32-bit words that one lax.sort carries as operands of their own, or
+# that are gathered a column at a time.  Every sort and join of the
+# benchmark's int-pair and two-word-key cells stays under it (7 at
+# most); past it a row moves as rows of one u32[n, words] array: the
+# TPU's compiler takes about 10 s for every word a sort carries (a
+# 100-byte string is 26), and a gather costs a ROW what it costs a word
+_CARRIED_WORDS = 8
+# words of a row that one gather moves: on the v5e a gather of rows of
+# u32[n, 31] takes 39.7 ms at 1M rows, of int64[n, 15] (two planes of
+# 15 words) 14.7, of u32[n, 10] under 8 (PR 31's chip table)
+_ROW_WORDS = 16
+
+
+def _words(col):
+    return max(1, col.dtype.itemsize // 4)
+
+
+def _stack_rows(cols):
+    """Rank-1 columns -> u32[n, words] (an 8-byte column is two)."""
+    parts = []
+    for c in cols:
+        if c.dtype.itemsize == 8:
+            parts.append(lax.bitcast_convert_type(c, jnp.uint32))
+        elif c.dtype.itemsize == 4:
+            parts.append(lax.bitcast_convert_type(c, jnp.uint32)[:, None])
+        else:
+            parts.append(c.astype(jnp.uint32)[:, None])
+    return jnp.concatenate(parts, axis=1)
+
+
+def _unstack_rows(mat, like):
+    out, at = [], 0
+    for c in like:
+        if c.dtype.itemsize == 8:
+            out.append(lax.bitcast_convert_type(mat[:, at:at + 2], c.dtype))
+        elif c.dtype.itemsize == 4:
+            out.append(lax.bitcast_convert_type(mat[:, at], c.dtype))
+        else:
+            out.append(mat[:, at].astype(c.dtype))
+        at += _words(c)
+    return out
+
+
+def take_rows(cols, idx):
+    """Rows `idx` of parallel rank-1 columns (an index may repeat: the
+    join's expansion): a gather a column while they are few, whole
+    rows past _CARRIED_WORDS."""
+    cols = list(cols)
+    if sum(_words(c) for c in cols) <= _CARRIED_WORDS:
+        return [c[idx] for c in cols]
+    return _take_whole_rows(cols, idx)
+
+
+def _take_whole_rows(cols, idx):
+    """take_rows through one u32[n, words] array, _ROW_WORDS words a
+    gather."""
+    if not cols:
+        return []
+    mat = _stack_rows(cols)
+    return _unstack_rows(jnp.concatenate(
+        [mat[:, at:at + _ROW_WORDS][idx]
+         for at in range(0, mat.shape[1], _ROW_WORDS)], axis=1), cols)
 
 
 def _bcast(flag, leaf):

@@ -56,13 +56,19 @@ def _sort_arg(plan, sorts):
     (collectives._lex_sort: shuffle write, reduce, filter, sort) move
     their rows — every operand `carried` through the one sort,
     `carried+gathered` where a leaf of rank > 1 (which XLA's sort
-    cannot carry) is gathered behind it, `none` without an ordering.
-    Read off the plan's record specs, as the sort sees them at trace
-    time."""
+    cannot carry) is gathered behind it, `keys+rows` where the record
+    is wider than the sort carries (the keys and an iota ride it, the
+    row follows as whole rows), `none` without an ordering.  Read off
+    the plan's record specs, as the sort sees them at trace time (the
+    wider of the records in and out, and one word for `dst`)."""
     if not sorts:
         return "none"
-    wide = any(shape for _, shape in
-               tuple(plan.in_specs) + tuple(plan.out_specs))
+    specs = tuple(plan.in_specs), tuple(plan.out_specs)
+    if 1 + max(sum(max(1, np.dtype(dt).itemsize // 4)
+                   for dt, shape in side if not shape)
+               for side in specs) > collectives._CARRIED_WORDS:
+        return "keys+rows"
+    wide = any(shape for side in specs for _, shape in side)
     return "carried+gathered" if wide else "carried"
 
 
@@ -700,6 +706,14 @@ class JAXExecutor:
         # stores dropped because their ShuffleDependency died (the
         # scheduler's drain): the release engaging, once a store
         self.stores_released = 0
+        # the device join: pairs it emitted (the totals `join.totals`
+        # reads), and pairs of a byte-string join whose one-word hash
+        # matched and whose bytes did not (dropped on the device; the
+        # count rides the NEXT join's `join.totals` read, never a read
+        # of its own, so it lags one join)
+        self.join_rows_out = 0
+        self.join_pairs_dropped = 0
+        self._join_dropped = None
         # count arrays whose host sum is deferred (the ndev==1 fast
         # path must not pay a blocking readback per wave just for this
         # metric); flushed on first metric read, or opportunistically
@@ -1132,6 +1146,7 @@ class JAXExecutor:
             epi = self._epilogue_params(plan)
 
         src_nk = getattr(plan, "src_nk", 1) or 1
+        src_hash = getattr(plan, "src_hash", False)
         if trace._PLANE is not None:    # combine= is the source reduce's
             trace.event("compile", "exec", program="reduce", slot=slot,
                         sig=_plan_sig(plan),
@@ -1155,8 +1170,15 @@ class JAXExecutor:
                 lv = list(ks) + list(vs)
             else:
                 # no-combine repartition: sort rows by the FULL key
-                # (every column of a tuple key), valid first
-                packed = collectives._lex_sort(tuple(flat), src_nk)
+                # (every column of a tuple key), valid first; the
+                # join's byte-string sides by ONE word, a hash of the
+                # key's words that then leads the row
+                nk = src_nk
+                if src_hash:
+                    flat = [collectives.key_hash64(flat[:src_nk], mask)] \
+                        + flat
+                    nk = 1
+                packed = collectives._lex_sort(tuple(flat), nk)
                 lv = list(packed)
                 n = jnp.sum(mask).astype(jnp.int32)
             for op in ops:
@@ -3207,14 +3229,21 @@ class JAXExecutor:
     # entirely on device (two-phase: count totals, then a static-capacity
     # gather program) — replaces the host merge for a.join(b)
     # ------------------------------------------------------------------
-    def _exchange_sorted(self, dep, store):
+    def _exchange_sorted(self, dep, store, by_hash=False):
         """No-combine exchange leaving the result ON DEVICE: per-device
-        key-sorted rows as (counts, leaves...) global arrays."""
+        key-sorted rows as (counts, leaves...) global arrays.  With
+        `by_hash` the rows are ordered by one int64 word instead, a
+        hash of the key's columns (collectives.key_hash64), which is
+        returned as the leading leaf: a byte-string key of 13 words
+        orders and matches as one."""
 
         # an instance, not a class made per call: a class is part of
         # reference cycles (its __dict__, its mro), so only the cyclic
         # collector would free it, and `source` holds the dependency
         # whose death releases the store
+        out_specs = list(store["out_specs"])
+        if by_hash:
+            out_specs.insert(0, (np.dtype(np.int64), ()))
         plan = types.SimpleNamespace(
             source=("hbm", dep), ops=[], epilogue=None,
             src_combine=False, group_output=False, epi_spec=None,
@@ -3222,14 +3251,15 @@ class JAXExecutor:
             # sort gathered rows by the FULL key (tuple keys span
             # key_cols columns) so cogroup/join consumers see the same
             # lexicographic order the host merge expects
-            src_nk=store.get("key_cols", 1) or 1,
+            src_nk=store.get("key_cols", 1) or 1, src_hash=by_hash,
             in_treedef=store["out_treedef"],
             in_specs=store["out_specs"],
             out_treedef=store["out_treedef"],
-            out_specs=store["out_specs"], stage=None)
+            out_specs=out_specs, stage=None)
         plan.program_key = ("gather", plan.src_nk,
                             tuple((str(dt), shape)
-                                  for dt, shape in store["out_specs"]))
+                                  for dt, shape in store["out_specs"])) \
+            + (("by_hash",) if by_hash else ())
         outs = self._run_exchange_and_reduce(plan)
         return outs[0], list(outs[1:])          # counts, leaves
 
@@ -3254,7 +3284,17 @@ class JAXExecutor:
     def device_join_batch(self, dep_a, dep_b):
         """Inner join of two HBM no-combine shuffles as a device Batch
         of (k, (va, vb)) rows — the array-path "join" source (keys stay
-        on device; downstream ops + shuffle writes ride the mesh)."""
+        on device; downstream ops + shuffle writes ride the mesh).
+        With the trace plane on, one `join` span around its exchanges,
+        launches and the `join.totals` read."""
+        if trace._PLANE is None:
+            return self._device_join_batch(dep_a, dep_b)[0]
+        with trace.span("join", "exec") as sp:
+            batch, args = self._device_join_batch(dep_a, dep_b)
+            sp.args.update(args)
+            return batch
+
+    def _device_join_batch(self, dep_a, dep_b):
         store_a = self.shuffle_store[dep_a.shuffle_id]
         store_b = self.shuffle_store[dep_b.shuffle_id]
         if store_a.get("encoded_keys", False) != \
@@ -3262,15 +3302,31 @@ class JAXExecutor:
             # ids on one side, user ints on the other: id equality would
             # be spurious — the host path compares decoded keys
             raise ValueError("mixed encoded/plain join keys")
-        cnt_a, lv_a = self._exchange_sorted(dep_a, store_a)
-        cnt_b, lv_b = self._exchange_sorted(dep_b, store_b)
+        # composite (tuple) keys span the first nk columns on BOTH
+        # sides (fuse.join_sides verified that widths and dtypes
+        # agree); key matching runs a lexicographic binary search
+        # instead of jnp.searchsorted.  A byte-string key matches by
+        # ONE word first, then its bytes: both sides come ordered by a
+        # 64-bit hash of the key's words that leads their leaves (k0:
+        # where the record begins), the ranges are found on it (mk
+        # matched columns), and the expansion compares every key word
+        # of each pair it emits and drops the pairs that differ, so
+        # the answer is exact whatever the hash does
+        nk = store_a.get("key_cols", 1) or 1
+        key_bytes = layout.bytes_key_width(store_a["out_treedef"],
+                                           len(store_a["out_specs"]))
+        hashed = key_bytes is not None
+        k0, mk = (1, 1) if hashed else (0, nk)
+        cnt_a, lv_a = self._exchange_sorted(dep_a, store_a, hashed)
+        cnt_b, lv_b = self._exchange_sorted(dep_b, store_b, hashed)
         na, nb = len(lv_a), len(lv_b)
         cap_a, cap_b = lv_a[0].shape[1], lv_b[0].shape[1]
-        # composite (tuple) keys span the first nk columns on BOTH
-        # sides (fuse._analyze_join_source / _precompute_join verified
-        # the widths and dtypes agree); key matching runs a
-        # lexicographic binary search instead of jnp.searchsorted
-        nk = store_a.get("key_cols", 1) or 1
+
+        # 1M lookups of jnp.searchsorted's default binary search take
+        # the v5e 475 ms, the same by one sort of both arrays 19 (PR
+        # 31's chip table); the int-keyed programs keep the search they
+        # were measured with (ROADMAP S6)
+        method = "sort" if hashed else "scan"
 
         def _key_ranges(a, b, A, B):
             """(lo, hi) match ranges of each A row in the key-sorted B
@@ -3280,75 +3336,120 @@ class JAXExecutor:
             sent = collectives._sentinel(A[0].dtype)
             A0 = jnp.where(jnp.arange(cap_a) < a, A[0], sent)
             B0 = jnp.where(jnp.arange(cap_b) < b, B[0], sent)
-            if nk == 1:
-                return (jnp.searchsorted(B0, A0, side="left"),
-                        jnp.searchsorted(B0, A0, side="right"))
-            acols = [A0] + list(A[1:nk])
-            bcols = [B0] + list(B[1:nk])
+            if mk == 1:
+                return (jnp.searchsorted(B0, A0, side="left",
+                                         method=method),
+                        jnp.searchsorted(B0, A0, side="right",
+                                         method=method))
+            acols = [A0] + list(A[1:mk])
+            bcols = [B0] + list(B[1:mk])
             return (collectives.lex_searchsorted(bcols, acols, "left"),
                     collectives.lex_searchsorted(bcols, acols,
                                                  "right"))
 
-        count_key = ("join_count", cap_a, cap_b, na, nb, nk,
-                     tuple(str(l.dtype) for l in lv_a + lv_b))
+        dtypes = tuple(str(l.dtype) for l in lv_a + lv_b)
+        count_key = ("join_count", cap_a, cap_b, na, nb, mk, dtypes)
         if count_key not in self._compiled:
             def count_dev(ca, cb, *keys):
                 a, b = ca[0], cb[0]
-                A = [k[0] for k in keys[:nk]]
-                B = [k[0] for k in keys[nk:]]
+                A = [k[0] for k in keys[:mk]]
+                B = [k[0] for k in keys[mk:]]
                 lo, hi = _key_ranges(a, b, A, B)
                 per = jnp.where(jnp.arange(cap_a) < a, hi - lo, 0)
-                return (jnp.expand_dims(jnp.sum(per), 0),)
+                out = (jnp.sum(per),)
+                if hashed:      # the ranges go on to the expansion
+                    out += (lo, per)
+                return tuple(jnp.expand_dims(o, 0) for o in out)
             fn = _shard_map(count_dev, self.mesh,
-                            in_specs=(P(AXIS),) * (2 + 2 * nk),
-                            out_specs=(P(AXIS),))
+                            in_specs=(P(AXIS),) * (2 + 2 * mk),
+                            out_specs=(P(AXIS),) * (3 if hashed else 1))
             self._compiled[count_key] = jax.jit(fn)
-        (totals,) = self._launch(
+        totals, *ranges = self._launch(
             "join_count", self._compiled[count_key],
-            cnt_a, cnt_b, *lv_a[:nk], *lv_b[:nk])
-        cap_out = layout.round_capacity(
-            int(layout.host_read(totals, site="join.totals").max() or 1))
+            cnt_a, cnt_b, *lv_a[:mk], *lv_b[:mk])
+        # the pairs the LAST byte-string join dropped ride this read
+        pending, self._join_dropped = self._join_dropped, None
+        if pending is None:
+            totals = layout.host_read(totals, site="join.totals")
+        else:
+            totals, dropped = layout.host_read([totals, pending],
+                                               site="join.totals")
+            self.join_pairs_dropped += int(dropped.sum())
+        self.join_rows_out += int(totals.sum())
+        cap_out = layout.round_capacity(int(totals.max() or 1))
 
-        exp_key = ("join_expand", cap_a, cap_b, cap_out, na, nb, nk,
-                   tuple(str(l.dtype) for l in lv_a + lv_b))
+        exp_key = ("join_expand", cap_a, cap_b, cap_out, na, nb, mk,
+                   dtypes) + ((("hashed", nk),) if hashed else ())
         if exp_key not in self._compiled:
             def expand_dev(ca, cb, *leaves):
                 a, b = ca[0], cb[0]
+                if hashed:
+                    lo, per = leaves[0][0], leaves[1][0]
+                    leaves = leaves[2:]
                 A = [l[0] for l in leaves[:na]]
                 B = [l[0] for l in leaves[na:]]
-                lo, hi = _key_ranges(a, b, A, B)
-                per = jnp.where(jnp.arange(cap_a) < a, hi - lo, 0)
+                if not hashed:
+                    lo, hi = _key_ranges(a, b, A, B)
+                    per = jnp.where(jnp.arange(cap_a) < a, hi - lo, 0)
                 offs = jnp.cumsum(per) - per          # exclusive
                 total = jnp.sum(per)
                 t = jnp.arange(cap_out)
                 # source A row for each output slot
                 i = jnp.clip(
-                    jnp.searchsorted(offs + per, t, side="right"),
+                    jnp.searchsorted(offs + per, t, side="right",
+                                     method=method),
                     0, cap_a - 1)
-                j = t - offs[i]
-                bi = jnp.clip(lo[i] + j, 0, cap_b - 1)
-                out = [x[i] for x in A] + [x[bi] for x in B[nk:]]
-                return (jnp.expand_dims(total, 0),) + tuple(
-                    jnp.expand_dims(o, 0) for o in out)
-            n_out = 1 + na + (nb - nk)
+                if hashed:      # one gather for the two columns
+                    offs_i, lo_i = jnp.stack([offs, lo], axis=1)[i].T
+                    bi = jnp.clip(lo_i + t - offs_i, 0, cap_b - 1)
+                else:
+                    j = t - offs[i]
+                    bi = jnp.clip(lo[i] + j, 0, cap_b - 1)
+                out = collectives.take_rows(A[k0:], i)
+                if not hashed:
+                    out += collectives.take_rows(B[nk:], bi)
+                    return (jnp.expand_dims(total, 0),) + tuple(
+                        jnp.expand_dims(o, 0) for o in out)
+                picked = collectives.take_rows(B[k0:], bi)
+                same = t < total
+                for x, y in zip(out[:nk], picked[:nk]):
+                    same = same & (x == y)
+                out = out + picked[nk:]
+                kept = jnp.sum(same).astype(total.dtype)
+                # a hash that matched where the bytes do not: pack the
+                # true pairs to the front (a sort; never taken while
+                # 64 bits tell the keys apart)
+                out = lax.cond(
+                    kept < total,
+                    lambda: collectives.compact(out, same)[0],
+                    lambda: list(out))
+                return tuple(jnp.expand_dims(o, 0)
+                             for o in [kept, total - kept] + out)
+            n_in = 2 + len(ranges) + na + nb
+            # count [, dropped], a's record, b's values
+            n_out = 1 + int(hashed) + (na - k0) + (nb - k0 - nk)
             fn = _shard_map(expand_dev, self.mesh,
-                            in_specs=(P(AXIS),) * (2 + na + nb),
+                            in_specs=(P(AXIS),) * n_in,
                             out_specs=(P(AXIS),) * n_out)
             self._compiled[exp_key] = jax.jit(fn)
         outs = self._launch("join_expand", self._compiled[exp_key],
-                            cnt_a, cnt_b, *lv_a, *lv_b)
+                            cnt_a, cnt_b, *ranges, *lv_a, *lv_b)
         counts, leaves = outs[0], list(outs[1:])
+        if hashed:
+            self._join_dropped, leaves = leaves[0], leaves[1:]
 
         # rows are (k..., va..., vb...); records are (k, (va, vb)) with
         # the key subtree (scalar or flat tuple) taken from side a
         import jax.tree_util as jtu
         ta = store_a["out_treedef"]
         tb = store_b["out_treedef"]
-        sample_a = jtu.tree_unflatten(ta, list(range(na)))
-        sample_b = jtu.tree_unflatten(tb, list(range(nb)))
+        sample_a = jtu.tree_unflatten(ta, list(range(na - k0)))
+        sample_b = jtu.tree_unflatten(tb, list(range(nb - k0)))
         joined_sample = (sample_a[0], (sample_a[1], sample_b[1]))
         out_treedef = jtu.tree_structure(joined_sample)
-        return layout.Batch(out_treedef, leaves, counts)
+        return layout.Batch(out_treedef, leaves, counts), {
+            "rows_out": int(totals.sum()), "key_bytes": key_bytes or 8 * nk,
+            "cap_a": cap_a, "cap_b": cap_b}
 
     # ------------------------------------------------------------------
     # host bridge

@@ -1066,13 +1066,12 @@ def _string_leaf_reason(specs):
     """Why a record spec with a string leaf keeps the host path, or
     None when it has none (fixed-width byte strings within the limit
     never show here: record_spec made them ByteStr words)."""
-    from dpark_tpu import conf
     for dt, _ in specs:
         if dt == np.dtype(object) or dt.kind in "USO":
-            if dt.kind == "S" and dt.itemsize > 8 * conf.MAX_KEY_LEAVES:
+            if dt.kind == "S" and dt.itemsize > layout.BYTES_WIDTH_MAX:
                 return ("byte-string column of %d bytes is over the "
-                        "device limit of 8 * conf.MAX_KEY_LEAVES = %d"
-                        % (dt.itemsize, 8 * conf.MAX_KEY_LEAVES))
+                        "device limit of layout.BYTES_WIDTH_MAX = %d"
+                        % (dt.itemsize, layout.BYTES_WIDTH_MAX))
             return ("string leaf (dtype %s): only fixed-width byte "
                     "strings (a numpy S<w> column of Columns) ride "
                     "the device" % dt)
@@ -1540,34 +1539,52 @@ def _analyze_join_source(join_rdd, ndev, executor_or_store):
     if deps[0].partitioner.num_partitions > ndev:
         return None
     metas = [hbm_sids[d.shuffle_id] for d in deps]
-    samples = []
-    nks = []
-    for meta in metas:
-        treedef, specs = meta["out_treedef"], meta["out_specs"]
-        nk = layout.key_width(treedef, specs, kinds="if")
-        if nk is None or len(specs) < nk + 1:
-            return None      # join kernels need (k, v) / ((k...), v)
-        if layout.column_groups(treedef, len(specs)) is not None:
-            return _fallback("device join over byte-string records "
-                             "is not implemented")
-        sample = jtu.tree_unflatten(treedef, list(range(len(specs))))
-        if len(sample) != 2:
-            return None
-        samples.append(sample)
-        nks.append(nk)
-    if nks[0] != nks[1]:
-        return None              # key widths must agree across sides
-    nk = nks[0]
-    a_key = [np.dtype(dt) for dt, _ in metas[0]["out_specs"][:nk]]
-    b_key = [np.dtype(dt) for dt, _ in metas[1]["out_specs"][:nk]]
-    if a_key != b_key:
-        return None              # id-vs-int equality would be spurious
+    sides = join_sides(metas)
+    if sides is None:
+        return None
+    nk, samples = sides
     joined = (samples[0][0], (samples[0][1], samples[1][1]))
     treedef = jtu.tree_structure(joined)
     specs = (list(metas[0]["out_specs"][:nk])
              + list(metas[0]["out_specs"][nk:])
              + list(metas[1]["out_specs"][nk:]))
     return treedef, specs, (deps[0], deps[1])
+
+
+def join_sides(metas):
+    """The ONE admission of the device join's records (the array
+    path's join source and the driver-seeded precompute both ask
+    here): each store holds (k, v) pairs whose key is a numeric scalar,
+    a flat numeric tuple or a fixed-width byte string, with the same
+    key columns, dtypes and (byte strings) width on both sides; byte
+    strings may sit anywhere in the values.  Returns (key columns,
+    the two sample records of leaf indices) or None, with the reason
+    where the two keys disagree."""
+    import jax.tree_util as jtu
+    samples, sigs = [], []
+    for meta in metas:
+        treedef, specs = meta["out_treedef"], meta["out_specs"]
+        nk = layout.key_width(treedef, specs, kinds="if")
+        if nk is None or len(specs) < nk + 1:
+            return None      # join kernels need (k, v) / ((k...), v)
+        sample = jtu.tree_unflatten(treedef, list(range(len(specs))))
+        if len(sample) != 2:
+            return None
+        samples.append(sample)
+        sigs.append((nk, [np.dtype(dt) for dt, _ in specs[:nk]],
+                     layout.bytes_key_width(treedef, len(specs))))
+    if sigs[0] != sigs[1]:
+        # ids against ints, an int against a string, S8 against S16:
+        # equality of the words would be spurious or never hold
+        (_, _, wa), (_, _, wb) = sigs
+        if wa is not None or wb is not None:
+            return _fallback(
+                "device join needs byte-string keys of one width on "
+                "both sides (%s against %s)" % tuple(
+                    "S%d" % w if w is not None else "no byte string"
+                    for w in (wa, wb)))
+        return None
+    return sigs[0][0], samples
 
 
 def _meta_row_estimate(meta):

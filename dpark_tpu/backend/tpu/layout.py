@@ -69,6 +69,12 @@ HOST_READS = 0
 # HOST_READS; JAXExecutor.bytes_rows_packed / bytes_rows_unpacked)
 BYTES_ROWS_PACKED = 0
 BYTES_ROWS_UNPACKED = 0
+# widest S<w> column that is a device leaf: 16 words, room for the
+# 100-byte URLs of the field's web-log tables.  A string's own limit
+# (conf.MAX_KEY_LEAVES bounds tuple keys): past a few words a row no
+# longer rides a sort as one operand a word (collectives._lex_sort),
+# so a word costs run time and not minutes of compile
+BYTES_WIDTH_MAX = 128
 
 
 class ByteStr:
@@ -380,9 +386,8 @@ def record_spec(sample):
     # (fuse._sample_record): it becomes a ByteStr node of int64 words.
     # A bare `bytes` object has no column width, and a column over the
     # limit keeps its S dtype: both are what every caller declines
-    limit = 8 * conf.MAX_KEY_LEAVES
     columns = [isinstance(l, np.ndarray) and l.dtype.kind == "S"
-               and 0 < l.dtype.itemsize <= limit for l in leaves]
+               and 0 < l.dtype.itemsize <= BYTES_WIDTH_MAX for l in leaves]
     if any(columns):
         leaves, treedef = jax.tree_util.tree_flatten(
             jax.tree_util.tree_unflatten(treedef, [
@@ -621,8 +626,8 @@ def key_width(treedef, specs, kinds="i"):
     w)``).  Every key leaf must be a scalar whose dtype kind is in
     `kinds` ("i" for hash shuffles — portable_hash semantics are only
     reproduced on device for ints — "if" for range repartitioning).
-    Nested key pytrees or >conf.MAX_KEY_LEAVES columns return None
-    (host fallback)."""
+    Nested key pytrees or tuples of >conf.MAX_KEY_LEAVES columns return
+    None (host fallback)."""
     from dpark_tpu import conf
     if not specs:
         return None
@@ -633,9 +638,9 @@ def key_width(treedef, specs, kinds="i"):
     key = sample[0]
     if isinstance(key, ByteStr):
         # a fixed-width byte string: its words are the key columns
+        # (as many as BYTES_WIDTH_MAX allows: record_spec's limit)
         nk = len(key.words)
-        if nk > conf.MAX_KEY_LEAVES \
-                or key.words != tuple(range(nk)):
+        if key.words != tuple(range(nk)):
             return None
     elif isinstance(key, int) and key == 0:
         nk = 1

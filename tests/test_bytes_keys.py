@@ -425,7 +425,9 @@ def _declined(case):
                 .map(_resident).sortByKey().collect(),
                 "range shuffle (sortByKey) over string")
     if case == "too_wide":
-        col = np.array([r + b"/" + b"x" * 24 for r in _ips(40)], "S40")
+        wide = layout.BYTES_WIDTH_MAX + 8
+        col = np.array([r + b"/" + b"x" * (wide - 16) for r in _ips(40)],
+                       "S%d" % wide)
         return (lambda c: sorted(c.parallelize(Columns(col, vals), 1)
                                  .map(_whole)
                                  .reduceByKey(operator.add, 1).collect()),
@@ -526,6 +528,6 @@ def test_the_lint_rule_agrees_with_admission():
     assert "range" in _key_fallback_reason(
         np.bytes_(b"1.2.3.4"), hash_keys=False, fixed_width=16)
     assert "limit" in _key_fallback_reason(
-        np.bytes_(b"1.2.3.4"), fixed_width=8 * conf.MAX_KEY_LEAVES + 8)
+        np.bytes_(b"1.2.3.4"), fixed_width=layout.BYTES_WIDTH_MAX + 8)
     assert "string key" in _key_fallback_reason(b"1.2.3.4")
     assert "string key" in _key_fallback_reason("1.2.3.4")

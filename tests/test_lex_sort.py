@@ -152,3 +152,73 @@ def test_a_rank2_leaf_is_the_only_operand_gathered():
     gathers = [line for line in text.splitlines()
                if "stablehlo.gather" in line]
     assert len(gathers) == 1 and "x3xf32" in gathers[0]
+
+
+# -- wide rows (ISSUE 31): past _CARRIED_WORDS the keys and an iota ride
+# the sort and the rest of the row follows as whole rows --------------
+
+def _wide_row(rng, with_rank2):
+    """A row of 13 + 2 int64 words, a float32, a bool and an int32 (the
+    query-3 visit and then some), and optionally a rank-2 leaf."""
+    ops = [rng.integers(-2 ** 62, 2 ** 62, N) for _ in range(15)]
+    ops.append(rng.random(N).astype(np.float32) - np.float32(0.5))
+    ops.append(rng.random(N) < 0.5)
+    ops.append(rng.integers(-9, 9, N).astype(np.int32))
+    if with_rank2:
+        ops.insert(3, rng.integers(0, 9, (N, 2)))
+    return ops
+
+
+@pytest.mark.parametrize("with_rank2", (False, True),
+                         ids=("scalar", "rank2"))
+@pytest.mark.parametrize("num_keys", (1, 2))
+@pytest.mark.parametrize("dtype", KEY_DTYPES)
+def test_a_wide_row_follows_its_keys(dtype, num_keys, with_rank2):
+    rng = np.random.default_rng(
+        77 + 10 * KEY_DTYPES.index(dtype) + num_keys)
+    ops = [_key(dtype, rng, j) for j in range(num_keys)] \
+        + _wide_row(rng, with_rank2)
+    assert sum(collectives._words(jnp.asarray(o)) for o in ops
+               if o.ndim == 1) > collectives._CARRIED_WORDS
+    got = jax.jit(collectives._lex_sort, static_argnums=1)(
+        tuple(ops), num_keys)
+    _assert_same(got, _compose_and_gather(ops, num_keys))
+
+
+def test_a_wide_row_lowers_to_a_keys_sort_and_row_gathers():
+    ops = (jnp.zeros(N, jnp.int32),) + tuple(
+        jnp.zeros(N, jnp.int64) for _ in range(15)) \
+        + (jnp.zeros(N, jnp.float32),)
+    text = jax.jit(collectives._lex_sort, static_argnums=1).lower(
+        ops, 1).as_text()
+    sorts = [line for line in text.splitlines() if "stablehlo.sort" in line]
+    assert len(sorts) == 1 and sorts[0].count("tensor<") <= 6  # key, iota
+    gathers = [line for line in text.splitlines()
+               if "stablehlo.gather" in line]
+    # 31 words: whole rows, collectives._ROW_WORDS words at a time
+    assert len(gathers) == 2
+    assert "x16xui32" in gathers[0] and "x15xui32" in gathers[1]
+
+
+def test_the_cells_rows_stay_carried():
+    """The widest sort of the accepted cells (uservisits.agg's map order
+    sort: dst, hash, two key words, a float32) is 7 words: one sort, no
+    gather, as PR 29 left it."""
+    ops = (jnp.zeros(N, jnp.int32), jnp.zeros(N, jnp.uint32),
+           jnp.zeros(N, jnp.int64), jnp.zeros(N, jnp.int64),
+           jnp.zeros(N, jnp.float32))
+    assert sum(collectives._words(o) for o in ops) \
+        <= collectives._CARRIED_WORDS
+    text = jax.jit(collectives._lex_sort, static_argnums=1).lower(
+        ops, 2).as_text()
+    assert text.count("stablehlo.sort") == 1 and "gather" not in text
+
+
+@pytest.mark.parametrize("wide", (False, True), ids=("few", "many"))
+def test_take_rows_by_an_index_that_repeats(wide):
+    rng = np.random.default_rng(5 + wide)
+    cols = _wide_row(rng, False) if wide else \
+        [rng.integers(-9, 9, N), rng.random(N).astype(np.float32)]
+    idx = rng.integers(0, N, 2 * N).astype(np.int32)
+    got = jax.jit(collectives.take_rows)(cols, idx)
+    _assert_same(got, [c[idx] for c in cols])
