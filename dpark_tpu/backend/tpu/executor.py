@@ -3304,14 +3304,16 @@ class JAXExecutor:
             raise ValueError("mixed encoded/plain join keys")
         # composite (tuple) keys span the first nk columns on BOTH
         # sides (fuse.join_sides verified that widths and dtypes
-        # agree); key matching runs a lexicographic binary search
-        # instead of jnp.searchsorted.  A byte-string key matches by
-        # ONE word first, then its bytes: both sides come ordered by a
-        # 64-bit hash of the key's words that leads their leaves (k0:
-        # where the record begins), the ranges are found on it (mk
-        # matched columns), and the expansion compares every key word
-        # of each pair it emits and drops the pairs that differ, so
-        # the answer is exact whatever the hash does
+        # agree).  Every key kind matches the same way, by the one
+        # merge sort of collectives.join_ranges over the mk matched
+        # columns, and the count program hands the ranges it found on
+        # to the expansion.  What a byte-string key adds is what its
+        # type forces: both sides come ordered by a 64-bit hash of the
+        # key's words that leads their leaves (k0: where the record
+        # begins) and is the one matched column, and the expansion
+        # compares every key word of each pair it emits and drops the
+        # pairs that differ, so the answer is exact whatever the hash
+        # does
         nk = store_a.get("key_cols", 1) or 1
         key_bytes = layout.bytes_key_width(store_a["out_treedef"],
                                            len(store_a["out_specs"]))
@@ -3321,50 +3323,28 @@ class JAXExecutor:
         cnt_b, lv_b = self._exchange_sorted(dep_b, store_b, hashed)
         na, nb = len(lv_a), len(lv_b)
         cap_a, cap_b = lv_a[0].shape[1], lv_b[0].shape[1]
-
-        # 1M lookups of jnp.searchsorted's default binary search take
-        # the v5e 475 ms, the same by one sort of both arrays 19 (PR
-        # 31's chip table); the int-keyed programs keep the search they
-        # were measured with (ROADMAP S6)
-        method = "sort" if hashed else "scan"
-
-        def _key_ranges(a, b, A, B):
-            """(lo, hi) match ranges of each A row in the key-sorted B
-            rows.  Only key column 0 needs the sentinel: invalid rows
-            sort last on it, and comparisons against them resolve on
-            column 0 alone (no valid key ever carries the sentinel)."""
-            sent = collectives._sentinel(A[0].dtype)
-            A0 = jnp.where(jnp.arange(cap_a) < a, A[0], sent)
-            B0 = jnp.where(jnp.arange(cap_b) < b, B[0], sent)
-            if mk == 1:
-                return (jnp.searchsorted(B0, A0, side="left",
-                                         method=method),
-                        jnp.searchsorted(B0, A0, side="right",
-                                         method=method))
-            acols = [A0] + list(A[1:mk])
-            bcols = [B0] + list(B[1:mk])
-            return (collectives.lex_searchsorted(bcols, acols, "left"),
-                    collectives.lex_searchsorted(bcols, acols,
-                                                 "right"))
+        rec_a, rec_b = lv_a[k0:], lv_b[k0:]     # behind the hash word
+        nra = len(rec_a)
 
         dtypes = tuple(str(l.dtype) for l in lv_a + lv_b)
         count_key = ("join_count", cap_a, cap_b, na, nb, mk, dtypes)
         if count_key not in self._compiled:
+            if trace._PLANE is not None:
+                trace.event("compile", "exec", program="join_count",
+                            cap_a=cap_a, cap_b=cap_b, match="merge",
+                            ranges="handed")
+
             def count_dev(ca, cb, *keys):
-                a, b = ca[0], cb[0]
-                A = [k[0] for k in keys[:mk]]
-                B = [k[0] for k in keys[mk:]]
-                lo, hi = _key_ranges(a, b, A, B)
-                per = jnp.where(jnp.arange(cap_a) < a, hi - lo, 0)
-                out = (jnp.sum(per),)
-                if hashed:      # the ranges go on to the expansion
-                    out += (lo, per)
-                return tuple(jnp.expand_dims(o, 0) for o in out)
+                lo, per = collectives.join_ranges(
+                    [k[0] for k in keys[:mk]], [k[0] for k in keys[mk:]],
+                    ca[0], cb[0])
+                return tuple(jnp.expand_dims(o, 0)
+                             for o in (jnp.sum(per), lo, per))
             fn = _shard_map(count_dev, self.mesh,
                             in_specs=(P(AXIS),) * (2 + 2 * mk),
-                            out_specs=(P(AXIS),) * (3 if hashed else 1))
+                            out_specs=(P(AXIS),) * 3)
             self._compiled[count_key] = jax.jit(fn)
-        totals, *ranges = self._launch(
+        totals, lo, per = self._launch(
             "join_count", self._compiled[count_key],
             cnt_a, cnt_b, *lv_a[:mk], *lv_b[:mk])
         # the pairs the LAST byte-string join dropped ride this read
@@ -3381,37 +3361,24 @@ class JAXExecutor:
         exp_key = ("join_expand", cap_a, cap_b, cap_out, na, nb, mk,
                    dtypes) + ((("hashed", nk),) if hashed else ())
         if exp_key not in self._compiled:
-            def expand_dev(ca, cb, *leaves):
-                a, b = ca[0], cb[0]
-                if hashed:
-                    lo, per = leaves[0][0], leaves[1][0]
-                    leaves = leaves[2:]
-                A = [l[0] for l in leaves[:na]]
-                B = [l[0] for l in leaves[na:]]
-                if not hashed:
-                    lo, hi = _key_ranges(a, b, A, B)
-                    per = jnp.where(jnp.arange(cap_a) < a, hi - lo, 0)
-                offs = jnp.cumsum(per) - per          # exclusive
-                total = jnp.sum(per)
-                t = jnp.arange(cap_out)
-                # source A row for each output slot
-                i = jnp.clip(
-                    jnp.searchsorted(offs + per, t, side="right",
-                                     method=method),
-                    0, cap_a - 1)
-                if hashed:      # one gather for the two columns
-                    offs_i, lo_i = jnp.stack([offs, lo], axis=1)[i].T
-                    bi = jnp.clip(lo_i + t - offs_i, 0, cap_b - 1)
-                else:
-                    j = t - offs[i]
-                    bi = jnp.clip(lo[i] + j, 0, cap_b - 1)
-                out = collectives.take_rows(A[k0:], i)
+            if trace._PLANE is not None:
+                trace.event("compile", "exec", program="join_expand",
+                            cap_a=cap_a, cap_b=cap_b, cap_out=cap_out,
+                            match="merge", ranges="handed")
+
+            def expand_dev(lo, per, *records):
+                A = [l[0] for l in records[:nra]]
+                B = [l[0] for l in records[nra:]]
+                total = jnp.sum(per[0])
+                i, bi = collectives.join_slots(lo[0], per[0], cap_out,
+                                               cap_b)
+                out = collectives.take_rows(A, i)
                 if not hashed:
                     out += collectives.take_rows(B[nk:], bi)
                     return (jnp.expand_dims(total, 0),) + tuple(
                         jnp.expand_dims(o, 0) for o in out)
-                picked = collectives.take_rows(B[k0:], bi)
-                same = t < total
+                picked = collectives.take_rows(B, bi)
+                same = jnp.arange(cap_out) < total
                 for x, y in zip(out[:nk], picked[:nk]):
                     same = same & (x == y)
                 out = out + picked[nk:]
@@ -3425,15 +3392,14 @@ class JAXExecutor:
                     lambda: list(out))
                 return tuple(jnp.expand_dims(o, 0)
                              for o in [kept, total - kept] + out)
-            n_in = 2 + len(ranges) + na + nb
             # count [, dropped], a's record, b's values
-            n_out = 1 + int(hashed) + (na - k0) + (nb - k0 - nk)
+            n_out = 1 + int(hashed) + nra + len(rec_b) - nk
             fn = _shard_map(expand_dev, self.mesh,
-                            in_specs=(P(AXIS),) * n_in,
+                            in_specs=(P(AXIS),) * (2 + nra + len(rec_b)),
                             out_specs=(P(AXIS),) * n_out)
             self._compiled[exp_key] = jax.jit(fn)
         outs = self._launch("join_expand", self._compiled[exp_key],
-                            cnt_a, cnt_b, *ranges, *lv_a, *lv_b)
+                            lo, per, *rec_a, *rec_b)
         counts, leaves = outs[0], list(outs[1:])
         if hashed:
             self._join_dropped, leaves = leaves[0], leaves[1:]
@@ -3443,8 +3409,8 @@ class JAXExecutor:
         import jax.tree_util as jtu
         ta = store_a["out_treedef"]
         tb = store_b["out_treedef"]
-        sample_a = jtu.tree_unflatten(ta, list(range(na - k0)))
-        sample_b = jtu.tree_unflatten(tb, list(range(nb - k0)))
+        sample_a = jtu.tree_unflatten(ta, list(range(nra)))
+        sample_b = jtu.tree_unflatten(tb, list(range(len(rec_b))))
         joined_sample = (sample_a[0], (sample_a[1], sample_b[1]))
         out_treedef = jtu.tree_structure(joined_sample)
         return layout.Batch(out_treedef, leaves, counts), {

@@ -283,9 +283,14 @@ def test_wide_rows_move_as_rows_and_the_hash_is_a_key():
     finally:
         tctx.stop()
         trace.configure("off")
-    assert {(e["args"]["program"], e["args"]["sort"]) for e in events} \
+    assert {(e["args"]["program"], e["args"]["sort"]) for e in events
+            if "sort" in e["args"]} \
         == {("narrow", "keys+rows"), ("reduce", "keys+rows"),
             ("narrow", "none")}     # the joined batch's own program
+    # the join's two programs order nothing: they say how they match
+    assert {(e["args"]["program"], e["args"]["match"]) for e in events
+            if "sort" not in e["args"]} \
+        == {("join_count", "merge"), ("join_expand", "merge")}
     words = [np.array([5, 5, 7, 2 ** 62], np.int64),
              np.array([1, 1, 1, -3], np.int64)]
     valid = np.array([True, True, True, False])

@@ -214,11 +214,20 @@ def test_the_cells_rows_stay_carried():
     assert text.count("stablehlo.sort") == 1 and "gather" not in text
 
 
-@pytest.mark.parametrize("wide", (False, True), ids=("few", "many"))
-def test_take_rows_by_an_index_that_repeats(wide):
-    rng = np.random.default_rng(5 + wide)
-    cols = _wide_row(rng, False) if wide else \
-        [rng.integers(-9, 9, N), rng.random(N).astype(np.float32)]
+@pytest.mark.parametrize("kind", ("few", "many", "not_words"))
+def test_take_rows_by_an_index_that_repeats(kind):
+    """Whole rows, collectives._ROW_WORDS words a gather (33: three); a
+    gather a column for a few columns that are not whole words."""
+    rng = np.random.default_rng(5 + len(kind))
+    cols = {"few": [rng.integers(-9, 9, N),
+                    rng.random(N).astype(np.float32)],
+            "many": _wide_row(rng, False),
+            "not_words": [rng.integers(-9, 9, N), rng.random(N) < 0.5,
+                          rng.integers(-9, 9, N).astype(np.int16),
+                          rng.integers(0, 9, (N, 2))]}[kind]
     idx = rng.integers(0, N, 2 * N).astype(np.int32)
-    got = jax.jit(collectives.take_rows)(cols, idx)
-    _assert_same(got, [c[idx] for c in cols])
+    fn = jax.jit(collectives.take_rows)
+    _assert_same(fn(cols, idx), [c[idx] for c in cols])
+    gathers = [line for line in fn.lower(cols, idx).as_text().splitlines()
+               if "stablehlo.gather" in line]
+    assert len(gathers) == {"few": 1, "many": 3, "not_words": 4}[kind]
