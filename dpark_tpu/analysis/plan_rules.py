@@ -31,6 +31,9 @@ shuffle anti-patterns that dominate cost at production scale:
                          padding-sensitive per-group function) — the
                          pre-flight twin of the runtime fallback_reason
                          the tpu scheduler records per stage.
+  host-fallback-splits   a shuffle into more partitions than the tpu
+                         master has devices: object path, unless its
+                         large columnar source streams.
   monoid-multileaf       reduceByKey/combineByKey with a classified
                          min/max merge over values whose pytree has >1
                          leaf or a non-scalar leaf — the exact round-5
@@ -480,10 +483,7 @@ def _key_fallback_reason(key, hash_keys=True, fixed_width=None):
             return ("byte-string column of %d bytes is over the device "
                     "limit of layout.BYTES_WIDTH_MAX = %d"
                     % (fixed_width, BYTES_WIDTH_MAX))
-        if not hash_keys:
-            return ("range shuffle (sortByKey) over string or "
-                    "byte-string keys has no device form")
-        return None
+        return None         # hash and range (sortByKey) shuffles alike
     if isinstance(key, (str, bytes)):
         return ("string key: only text-source chains ride the device "
                 "(dictionary-encoded) and fixed-width byte strings (a "
@@ -512,6 +512,53 @@ def _key_fallback_reason(key, hash_keys=True, fixed_width=None):
     return r
 
 
+def _bytes_bounds_reason(part, width):
+    """Why a range shuffle over an S<width> key column keeps the host
+    path for its BOUNDS, or None: the twin of fuse._range_bounds_array
+    (`bytes` bounds that the key's column can hold)."""
+    bounds = getattr(part, "bounds", None)
+    if not bounds:
+        return None
+    if not all(isinstance(b, bytes) for b in bounds):
+        return RANGE_STRING_REASON
+    if any(len(b) > width for b in bounds):
+        return ("range bounds of another width than the S%d key "
+                "column" % width)
+    return None
+
+
+# backend/tpu/fuse.RANGE_STRING_REASON and MORE_SPLITS_REASON, repeated
+# as BYTES_WIDTH_MAX is: this module never imports jax
+RANGE_STRING_REASON = ("range shuffle (sortByKey) over string or "
+                       "byte-string keys has no device form")
+MORE_SPLITS_REASON = (
+    "shuffle into %d partitions on %d device(s): more splits than "
+    "devices ride the array path only as the streamed (out-of-core) "
+    "shuffle of a large columnar source")
+
+
+def _rule_host_fallback_splits(r, report):
+    """A shuffle into more partitions than the `tpu` master has
+    devices takes the object path (unless its source streams): the
+    pre-flight twin of fuse.analyze_stage's reason.  Quiet on every
+    master that has no device executor."""
+    from dpark_tpu import rdd as _rdd
+    if not isinstance(r, _rdd.ShuffledRDD):
+        return
+    executor = getattr(getattr(r.ctx, "scheduler", None), "executor",
+                       None)
+    ndev = getattr(executor, "ndev", None)
+    n = r.partitioner.num_partitions
+    if not ndev or n <= ndev:
+        return
+    report.add(
+        "host-fallback-splits", "info", r.scope_name,
+        "this shuffle leaves the array path: "
+        + MORE_SPLITS_REASON % (n, ndev),
+        "ask for at most as many splits as the master has devices "
+        "(numSplits <= %d)" % ndev)
+
+
 def _rule_host_fallback_key(r, report):
     """Shuffles whose KEY SHAPE evicts the plan from the array path:
     the pre-flight twin of fuse.analyze_stage's key checks, reporting
@@ -532,6 +579,8 @@ def _rule_host_fallback_key(r, report):
             continue
         reason = _key_fallback_reason(row[0], hash_keys=hash_keys,
                                       fixed_width=fixed_width)
+        if reason is None and isinstance(row[0], bytes):
+            reason = _bytes_bounds_reason(r.partitioner, fixed_width)
         if reason is None:
             continue
         severity = "info" if isinstance(row[0], (str, bytes)) \
@@ -918,6 +967,7 @@ def lint_plan(rdd, master="local", report=None, lineage=None):
         _rule_join_repartition(r, report)
         _rule_monoid_multileaf(r, report)
         _rule_host_fallback_key(r, report)
+        _rule_host_fallback_splits(r, report)
         _rule_host_fallback_group(r, report)
         _rule_adapt_stale_hint(r, report)
         _rule_trace_overhead_hint(r, report)

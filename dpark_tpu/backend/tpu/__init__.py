@@ -617,6 +617,16 @@ class TPUScheduler(DAGScheduler):
             tf = tasks[0].func
             plan.top_candidate = (tf.n, tf.key, tf.smallest)
         plan.topk_used = False
+        # sortByKey's bounds sample: the first n keys of a partition
+        # are sliced on the device and only they reach the host
+        from dpark_tpu.rdd import _TakeSampleKeys
+        plan.sample_keys = None
+        if (not stage.is_shuffle_map and tasks
+                and all(isinstance(t, ResultTask)
+                        and isinstance(t.func, _TakeSampleKeys)
+                        for t in tasks)
+                and len({t.func.n for t in tasks}) == 1):
+            plan.sample_keys = tasks[0].func.n
         # reduce(f) with a PROVABLE monoid over scalar records likewise
         # answers from one per-device reduction (ndev scalars on the
         # wire); unprovable reduces keep the egest + host fold
@@ -674,8 +684,9 @@ class TPUScheduler(DAGScheduler):
             uri = "hbm://%d" % result
             for task in tasks:
                 report(task, "success", (uri, {}, {}))
-        elif kind == "counts":
-            note["kind"] = "array+counts"    # observable: no egest ran
+        elif kind in ("counts", "sampled"):
+            if kind == "counts":
+                note["kind"] = "array+counts"    # observable: no egest ran
             for task in tasks:
                 report(task, "success", (result[task.partition], {}, {}))
         elif kind == "reduced":

@@ -124,18 +124,86 @@ def lex_searchsorted(sorted_cols, query_cols, side="left"):
     return lo
 
 
+# flipping a word's top bit turns its unsigned order into the signed one
+_TOP_BIT = np.int64(-2 ** 63)
+
+
+def bytes_order(words):
+    """The ordering view of a byte-string key's words (layout.ByteStr:
+    big-endian int64 words, word 0 already the sentinel on padding
+    rows): int64 columns whose SIGNED lexicographic order is memcmp's
+    over the bytes, unsigned, and in which the sentinel is still the
+    largest word 0, so padding sorts last.  A word's unsigned order is
+    the signed order of the word with its top bit flipped; word 0
+    steps over the sentinel's place (bytes 7f ff ff ff ff ff ff ff,
+    which ingest keeps out of every valid key): the words above it
+    move down one."""
+    w0 = words[0]
+    v0 = jnp.where(w0 == _sentinel(w0.dtype), w0,
+                   (w0 ^ _TOP_BIT) - (w0 < 0).astype(w0.dtype))
+    return [v0] + [w ^ _TOP_BIT for w in words[1:]]
+
+
+def sort_by_key(cols, nk, unsigned=False):
+    """_lex_sort of rows by their first nk columns (column 0 carries
+    the sentinel on padding rows, which sort last); `unsigned` when
+    those columns are the words of ONE byte-string key, which orders
+    as its bytes do.
+
+    The byte-string form sorts a word at a time, least significant
+    first: a stable sort of (the word's ordering view, the running
+    permutation) a word, the next word gathered through the
+    permutation, and the rows behind the last one as whole rows.  It
+    buys compile time with run time: at 2,359,296 rows of a two-word
+    key the permutation alone takes 24.6 ms and compiles in 51 s on
+    the chip's host, ONE sort with both 64-bit words as keys 11.5 ms
+    and 103 s (PR 33's chip run; 337 s against 120 s for the whole
+    reduce program on this PR's sandbox, six compiles at a time); a
+    word more costs this form a 3-word sort and a column gather.  Its
+    callers are the two places that need the ORDER (SortOp, and the
+    no-combine reduce when SortOp takes its order); where equal keys
+    only have to be adjacent the signed words do."""
+    cols = list(cols)
+    if not unsigned:
+        return list(_lex_sort(tuple(cols), nk))
+    order = lax.iota(jnp.int32, cols[0].shape[0])
+    for i, v in enumerate(reversed(bytes_order(cols[:nk]))):
+        if i:
+            (v,) = take_rows([v], order)
+        _, order = lax.sort((v, order), num_keys=1, is_stable=True)
+    return gather_rows(cols, order)
+
+
 def range_dst_cols(key_cols, bounds_cols, ascending, n_dst, valid,
-                   r=None):
+                   r=None, unsigned=False):
     """range_dst over a COMPOSITE key: bisect_left of each (k1, ..., kn)
     row into the sampled tuple bounds, compared lexicographically —
-    exactly host RangePartitioner.get_partition on tuple keys."""
+    exactly host RangePartitioner.get_partition on tuple keys.  With
+    `unsigned` the columns are the words of ONE byte-string key and of
+    `bytes` bounds, compared as bytes compare.
+
+    The bounds are sorted, so a row's place among them (bisect_left) is
+    the number of bounds below it: a compare and a sum a bound while
+    the loop is short (_bucket_counts' other form), the binary search
+    of gathers past that."""
     key_cols = list(key_cols)
-    if len(key_cols) == 1 and bounds_cols[0].ndim <= 1:
+    if len(key_cols) == 1 and bounds_cols[0].ndim <= 1 and not unsigned:
         return range_dst(key_cols[0], bounds_cols[0], ascending, n_dst,
                          valid, r=r)
     r = n_dst if r is None else r
-    idx = lex_searchsorted(list(bounds_cols), key_cols,
-                           side="left").astype(jnp.int32)
+    bounds_cols = list(bounds_cols)
+    if unsigned:
+        key_cols = [c ^ _TOP_BIT for c in key_cols]
+        bounds_cols = [c ^ _TOP_BIT for c in bounds_cols]
+    nb = int(bounds_cols[0].shape[0])
+    if nb <= _DST_LOOP_MAX:
+        idx = jnp.zeros(key_cols[0].shape, jnp.int32)
+        for b in range(nb):
+            idx = idx + _lex_less_cols(
+                [c[b] for c in bounds_cols], key_cols).astype(jnp.int32)
+    else:
+        idx = lex_searchsorted(bounds_cols, key_cols,
+                               side="left").astype(jnp.int32)
     dst = idx if ascending else (r - 1 - idx)
     return jnp.where(valid, dst, n_dst)
 
@@ -237,6 +305,13 @@ def take_rows(cols, idx):
             c.ndim == 1 and c.dtype.itemsize >= 4 for c in cols):
         return [c[idx] for c in cols]
     return _take_whole_rows(cols, idx)
+
+
+def gather_rows(cols, idx):
+    """take_rows for records that may hold leaves of rank > 1: those
+    are gathered a leaf at a time, the rank-1 columns as whole rows."""
+    moved = iter(take_rows([c for c in cols if c.ndim == 1], idx))
+    return [next(moved) if c.ndim == 1 else c[idx] for c in cols]
 
 
 def _take_whole_rows(cols, idx):

@@ -72,6 +72,28 @@ def _sort_arg(plan, sorts):
     return "carried+gathered" if wide else "carried"
 
 
+def _order_args(plan):
+    """The `dst=`, `key=` and `order=` of a `compile` event, for a
+    program that partitions by range or sorts by key (else nothing):
+    where a row goes (`hash` | `range`), what the key is (`int`, a
+    float among them | `tuple` | `bytes`) and how its columns compare
+    (`signed`, or `unsigned` for the words of a byte string: memcmp)."""
+    ranged = plan.epilogue is not None and plan.epi_spec is not None \
+        and plan.epi_spec[0] == "range"
+    sorts = [op for op in plan.ops if isinstance(op, fuse.SortOp)]
+    if not (ranged or sorts):
+        return {}
+    if ranged:
+        nk, unsigned = plan.epi_nk, fuse.epi_unsigned(plan.epi_spec)
+    else:
+        nk, unsigned = sorts[0].nk, sorts[0].unsigned
+    args = {"key": "bytes" if unsigned else "tuple" if nk > 1 else "int",
+            "order": "unsigned" if unsigned else "signed"}
+    if plan.epilogue is not None:
+        args["dst"] = "range" if ranged else "hash"
+    return args
+
+
 AXIS = conf.MESH_AXIS
 
 
@@ -703,6 +725,9 @@ class JAXExecutor:
         # threads reach host_read too, and a read-modify-write that
         # races there can lose a count.
         self.program_launches = 0
+        # rows the bounds samples of sortByKey brought to the host
+        # (_sample_keys): sampleSize a sort, never the table
+        self.sort_sample_rows = 0
         # stores dropped because their ShuffleDependency died (the
         # scheduler's drain): the release engaging, once a store
         self.stores_released = 0
@@ -937,13 +962,14 @@ class JAXExecutor:
         k = lv[0]
         valid = jnp.arange(k.shape[0]) < n
         if epi_spec is not None and epi_spec[0] == "range":
-            if nk == 1:
+            if nk == 1 and not fuse.epi_unsigned(epi_spec):
                 dst = collectives.range_dst(k, bounds, epi_spec[1],
                                             n_dst, valid, r=r)
             else:
                 bcols = [bounds[:, i] for i in range(nk)]
                 dst = collectives.range_dst_cols(
-                    lv[:nk], bcols, epi_spec[1], n_dst, valid, r=r)
+                    lv[:nk], bcols, epi_spec[1], n_dst, valid, r=r,
+                    unsigned=fuse.epi_unsigned(epi_spec))
         else:
             dst = collectives.hash_dst_cols(
                 lv[:nk], n_dst, valid, r=r,
@@ -996,7 +1022,7 @@ class JAXExecutor:
                         combine=_combine_arg(merge_fn, monoid),
                         sort=_sort_arg(plan, epilogue is not None or any(
                             isinstance(op, (fuse.SortOp, fuse.FilterOp))
-                            for op in ops)))
+                            for op in ops)), **_order_args(plan))
         in_specs = plan.in_specs
 
         def per_device(counts, *rest):
@@ -1147,11 +1173,17 @@ class JAXExecutor:
 
         src_nk = getattr(plan, "src_nk", 1) or 1
         src_hash = getattr(plan, "src_hash", False)
+        # equal keys only have to be adjacent, unless sortByKey's SortOp
+        # takes this order for its own: then a byte string orders as
+        # its bytes do
+        ordered = bool(plan.ops) and isinstance(
+            plan.ops[0], fuse.SortOp) and plan.ops[0].presorted \
+            and plan.ops[0].unsigned
         if trace._PLANE is not None:    # combine= is the source reduce's
             trace.event("compile", "exec", program="reduce", slot=slot,
                         sig=_plan_sig(plan),
                         combine=_combine_arg(merge_fn, monoid),
-                        sort=_sort_arg(plan, True))
+                        sort=_sort_arg(plan, True), **_order_args(plan))
 
         def per_device(*args):
             bounds = args[0][0] if has_bounds else None
@@ -1178,8 +1210,7 @@ class JAXExecutor:
                     flat = [collectives.key_hash64(flat[:src_nk], mask)] \
                         + flat
                     nk = 1
-                packed = collectives._lex_sort(tuple(flat), nk)
-                lv = list(packed)
+                lv = collectives.sort_by_key(flat, nk, unsigned=ordered)
                 n = jnp.sum(mask).astype(jnp.int32)
             for op in ops:
                 lv, n = op.apply(lv, n)
@@ -1825,6 +1856,14 @@ class JAXExecutor:
                     py = float if not intk else int
                     return ("reduced", [(py(v), int(n))
                                         for v, n in zip(vals, counts)])
+            head = getattr(plan, "sample_keys", None)
+            if head is not None and not plan.group_output \
+                    and not encoded:
+                # sortByKey's bounds sample: the first `head` keys of
+                # each partition and nothing else cross to the host
+                keys = self._sample_keys(plan, batch, head)
+                if keys is not None:
+                    return ("sampled", keys)
             top = getattr(plan, "top_candidate", None)
             if top is not None and not plan.group_output:
                 # top(k): select each device's k best rows ON DEVICE
@@ -1901,6 +1940,45 @@ class JAXExecutor:
             else:
                 ranges.append(None)
         return ranges
+
+    def _sample_keys(self, plan, batch, n):
+        """The first n KEYS of every partition of a result batch, as
+        the rows rdd._TakeSampleKeys would keep: one slice program
+        over the key columns, then an egest of ndev * n keys (no value
+        column, no row past the prefix).  None where the key is not
+        the record's leading scalar columns."""
+        nk = layout.key_width(batch.treedef, plan.out_specs, kinds="if")
+        if nk is None:
+            return None
+        cap = batch.cap
+        dtypes = tuple(str(c.dtype) for c in batch.cols[:nk])
+        key = ("sample", cap, dtypes, n)
+        if key not in self._compiled:
+            def per_device(counts, *cols):
+                return (jnp.minimum(counts, n).astype(jnp.int32),) \
+                    + tuple(c[:, :n] for c in cols)
+
+            fn = _shard_map(per_device, self.mesh,
+                            in_specs=(P(AXIS),) * (1 + nk),
+                            out_specs=(P(AXIS),) * (1 + nk))
+            self._compiled[key] = jax.jit(fn)
+        outs = self._launch("sample", self._compiled[key], batch.counts,
+                            *batch.cols[:nk])
+        record = jax.tree_util.tree_unflatten(
+            batch.treedef, list(range(len(batch.cols))))
+        head = layout.Batch(jax.tree_util.tree_structure(record[0]),
+                            list(outs[1:]), outs[0])
+        sp = trace._NOOP
+        if trace._PLANE is not None:
+            sp = trace.span("sort.sample", "exec", splits=batch.ndev,
+                            bytes=sum(int(c.nbytes) for c in head.cols))
+        with sp:
+            keys = layout.egest(head)
+            rows = sum(len(part) for part in keys)
+            if sp is not trace._NOOP:
+                sp.args["rows"] = rows
+        self.sort_sample_rows += rows
+        return keys
 
     def _device_topk(self, plan, batch, kspec, n, smallest):
         """Per-device top-n of a result batch by the classified key:

@@ -55,6 +55,16 @@ def is_list_agg(agg):
             and agg.merge_combiners is _extend)
 
 
+# analysis/plan_rules.py says both pre-flight and repeats them: it
+# never imports jax (tests/test_bytes_sort.py holds the twins together)
+RANGE_STRING_REASON = ("range shuffle (sortByKey) over string or "
+                       "byte-string keys has no device form")
+MORE_SPLITS_REASON = (
+    "shuffle into %d partitions on %d device(s): more splits than "
+    "devices ride the array path only as the streamed (out-of-core) "
+    "shuffle of a large columnar source")
+
+
 def partitioner_spec(part):
     """Device destination function spec for a partitioner, or None."""
     if isinstance(part, SaltedHashPartitioner):
@@ -72,11 +82,21 @@ def partitioner_spec(part):
             bounds = np.asarray(part.bounds)
         except Exception:
             return None
+        if part.bounds and all(isinstance(b, bytes)
+                               for b in part.bounds):
+            # `bytes` bounds: the key has to be a fixed-width byte
+            # string at least as wide (analyze_stage knows the key)
+            return ("range", bool(part.ascending), "bytes")
         if bounds.dtype == object or bounds.dtype.kind in "USO":
-            return _fallback("range shuffle (sortByKey) over string or "
-                             "byte-string keys has no device form")
+            return _fallback(RANGE_STRING_REASON)
         return ("range", bool(part.ascending))
     return None
+
+
+def epi_unsigned(epi_spec):
+    """Does this range epilogue compare the words of ONE byte-string
+    key with `bytes` bounds (epi_spec ("range", ascending, "bytes"))?"""
+    return epi_spec is not None and epi_spec[2:3] == ("bytes",)
 
 
 def epi_bytes_width(epi_spec):
@@ -349,38 +369,48 @@ class SortOp:
     def __init__(self, ascending):
         self.ascending = ascending
         self.nk = 1
+        self.unsigned = False
+        self.presorted = False      # set by analyze_stage alone
         self.key = ("sort", ascending)
 
     def probe(self, treedef, specs):
         nk = layout.key_width(treedef, specs, kinds="if")
-        if nk is not None and layout.bytes_key_width(
-                treedef, len(specs)) is not None:
-            # a byte string's words order as its bytes only for ASCII
-            _fallback("sort over a byte-string key has no device form")
-            nk = None
         if nk is None:
             raise TypeError("sort needs a numeric scalar (or flat "
-                            "numeric tuple) key")
+                            "numeric tuple) or fixed-width byte-string "
+                            "key")
         self.nk = nk
-        self.key = ("sort", self.ascending, nk)
+        # a byte string's words order as its bytes only for ASCII: the
+        # sort takes their ordering view (collectives.bytes_order)
+        self.unsigned = layout.bytes_key_width(
+            treedef, len(specs)) is not None
+        self.key = ("sort", self.ascending, nk) + (
+            ("bytes",) if self.unsigned else ())
         return treedef, specs
 
     def apply(self, leaves, n):
         from dpark_tpu.backend.tpu import collectives
+        if self.presorted:
+            return list(leaves), n
         cap = leaves[0].shape[0]
         valid = jnp.arange(cap) < n
         # only key column 0 needs the sentinel: padding sorts last on
         # it alone, and no valid row can carry it (ingest guard)
         k = jnp.where(valid, leaves[0],
                       collectives._sentinel(leaves[0].dtype))
-        packed = collectives._lex_sort((k,) + tuple(leaves[1:]),
-                                       self.nk)
-        out = [packed[0]] + list(packed[1:])
+        rows = [k] + list(leaves[1:])
         if not self.ascending:
-            # reverse the valid prefix, keep padding in place
+            # descending = the valid prefix reversed, sorted ascending
+            # and reversed again (padding stays in place): rows of
+            # equal keys keep their order, as the host's stable
+            # sorted(reverse=True) leaves them
             idx = jnp.arange(cap)
-            ridx = jnp.where(idx < n, n - 1 - idx, idx)
-            out = [l[ridx] for l in out]
+            ridx = jnp.where(valid, n - 1 - idx, idx)
+            rows = collectives.gather_rows(rows, ridx)
+        out = collectives.sort_by_key(rows, self.nk,
+                                      unsigned=self.unsigned)
+        if not self.ascending:
+            out = collectives.gather_rows(out, ridx)
         return out, n
 
 
@@ -1249,7 +1279,7 @@ def analyze_text_stage(stage, ndev, executor_or_store):
     key_is_str = isinstance(k, (str, bytes))
     if not key_is_str and not isinstance(k, (int, np.integer)):
         return None
-    if key_is_str and epi_spec[0] != "hash":
+    if (key_is_str and epi_spec[0] != "hash") or epi_unsigned(epi_spec):
         return None                      # str keys have no range bounds
     try:
         treedef, specs = layout.record_spec((0, v))
@@ -1392,13 +1422,22 @@ def _big_text(stage):
             > conf.STREAM_TEXT_BYTES)
 
 
-def _range_bounds_array(bounds, specs, nk):
+def _range_bounds_array(bounds, specs, nk, bytes_width=None):
     """The RangePartitioner bounds as the device array the range
     epilogue compares against: 1D cast to the key spec dtype for a
     scalar key, (len(bounds), nk) for a flat tuple key — requiring one
     SHARED spec dtype across the key columns (mixed int/float tuple
     bounds have host bisect semantics no single-dtype device compare
-    reproduces).  None = host fallback."""
+    reproduces) — and for a byte-string key of `bytes_width` bytes
+    the (len(bounds), nk) words of its `bytes` bounds, as the key's
+    own column packs them.  None = host fallback."""
+    if bytes_width is not None:
+        if any(len(b) > bytes_width for b in bounds):
+            # a bound the key's column cannot hold (a shorter one, or
+            # one that ends in NUL, is below the same keys NUL-padded)
+            return _fallback("range bounds of another width than the "
+                             "S%d key column" % bytes_width)
+        return layout.pack_bytes(np.array(bounds, "S%d" % bytes_width))
     dt = np.dtype(specs[0][0])
     if nk == 1:
         return np.asarray(bounds, dtype=dt)
@@ -1820,6 +1859,15 @@ def analyze_stage(stage, ndev, executor_or_store):
         logger.debug("stage %s not traceable (%s); host fallback",
                      stage, e)
         return None
+    if source[0] == "hbm" and not src_combine and ops \
+            and isinstance(ops[0], SortOp) and ops[0].ascending \
+            and ops[0].nk == src_nk:
+        # sortByKey's own sort behind its range shuffle: the no-combine
+        # reduce has just left each device's rows in this very order
+        # (stable, by the whole key, padding last; SegAggOp relies on
+        # the same), so the op orders nothing again
+        ops[0].presorted = True
+        ops[0].key += ("presorted",)
 
     # -- epilogue --------------------------------------------------------
     epilogue = None
@@ -1849,9 +1897,22 @@ def analyze_stage(stage, ndev, executor_or_store):
             if epi_nk is None:
                 return _fallback(
                     "range shuffle needs a numeric scalar (or flat "
-                    "numeric-tuple) key")
+                    "numeric-tuple) or fixed-width byte-string key")
+            width = layout.bytes_key_width(cur_treedef, len(cur_specs))
+            if width is not None and not dep.partitioner.bounds:
+                epi_spec = epi_spec[:2] + ("bytes",)    # one range
+            if (width is not None) != epi_unsigned(epi_spec):
+                # bytes bounds over a numeric key, or the other way
+                return _fallback(RANGE_STRING_REASON)
+            if width is not None and source[0] == "ingest" \
+                    and _big_columnar(source[1]):
+                # the spilled runs are merged on the host in the
+                # device's order of the words: signed
+                return _fallback("a streamed (out-of-core) range "
+                                 "shuffle over byte-string keys has no "
+                                 "device form")
             epi_bounds = _range_bounds_array(
-                dep.partitioner.bounds, cur_specs, epi_nk)
+                dep.partitioner.bounds, cur_specs, epi_nk, width)
             if epi_bounds is None:
                 return None
         if is_list_agg(dep.aggregator):
@@ -1882,7 +1943,8 @@ def analyze_stage(stage, ndev, executor_or_store):
             # the object path HERE, not via an executor error.
             if not (source[0] == "ingest"
                     and _big_columnar(source[1])):
-                return None
+                return _fallback(MORE_SPLITS_REASON % (
+                    dep.partitioner.num_partitions, ndev))
             logical_spill = True
         epilogue = ("shuffle_write", dep)
 

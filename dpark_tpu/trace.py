@@ -50,9 +50,10 @@ Span taxonomy (name / cat):
                                        reduce, exchange, minmax,
                                        join_count, join_expand,
                                        wave_sort, wave_prereduce,
-                                       distinct): jit cache lookup,
-                                       argument handling, a compile if
-                                       one happens; NOT device time.
+                                       distinct, sample): jit cache
+                                       lookup, argument handling, a
+                                       compile if one happens; NOT
+                                       device time.
                                        One span is one count of the
                                        executor's program_launches
     eager                    "exec"    the host's dispatch of eager jnp
@@ -82,13 +83,23 @@ Span taxonomy (name / cat):
                                        bytes, layout.host_columns
                                        (args: rows, width): inside
                                        `egest`, or at the export bridge
+    sort.sample              "exec"    the read of sortByKey's bounds
+                                       sample, JAXExecutor._sample_keys
+                                       (args: splits, rows, bytes: the
+                                       first keys of every split, which
+                                       is all that reaches the host);
+                                       an `egest` nests inside
     hbm.spill                "exec"    one dead HBM shuffle store to
                                        disk buckets, JAXExecutor.
                                        _spill_shuffle_to_disk (args:
                                        sid, bytes); its readbacks nest
                                        inside
     compile, dispatch        "exec"    program cache misses / program
-                                       dispatches (instant events)
+                                       dispatches (instant events); a
+                                       program that partitions by range
+                                       or sorts by key says dst= hash |
+                                       range, key= int | tuple | bytes,
+                                       order= signed | unsigned
     phase.ingest_tokenize,   "phase"   per-stage phase totals emitted
     phase.narrow,                      from the SAME _StreamStats
     phase.exchange,                    snapshot scheduler.phase_table()

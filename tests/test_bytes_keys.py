@@ -416,14 +416,6 @@ def _sentinel_rows():
 
 def _declined(case):
     vals = VALS[:51]
-    if case == "sortByKey":
-        return (lambda c: c.parallelize(Columns(_column(16)[:51], vals), 1)
-                .map(_resident).sortByKey().collect(),
-                "sort over a byte-string key")
-    if case == "sortByKey_range":
-        return (lambda c: c.parallelize(Columns(_column(16)[:51], vals), 2)
-                .map(_resident).sortByKey().collect(),
-                "range shuffle (sortByKey) over string")
     if case == "too_wide":
         wide = layout.BYTES_WIDTH_MAX + 8
         col = np.array([r + b"/" + b"x" * (wide - 16) for r in _ips(40)],
@@ -453,8 +445,7 @@ def _declined(case):
     raise AssertionError(case)
 
 
-@pytest.mark.parametrize("case", ["sortByKey", "sortByKey_range",
-                                  "too_wide", "unicode",
+@pytest.mark.parametrize("case", ["too_wide", "unicode",
                                   "object", "sentinel_word"])
 def test_what_is_not_covered_keeps_the_host_path(masters, case):
     job, reason = _declined(case)
@@ -525,8 +516,8 @@ def test_the_lint_rule_agrees_with_admission():
     from dpark_tpu.analysis.plan_rules import _key_fallback_reason
     assert _key_fallback_reason(np.bytes_(b"1.2.3.4"), fixed_width=16) \
         is None
-    assert "range" in _key_fallback_reason(
-        np.bytes_(b"1.2.3.4"), hash_keys=False, fixed_width=16)
+    assert _key_fallback_reason(       # sortByKey: a device path too
+        np.bytes_(b"1.2.3.4"), hash_keys=False, fixed_width=16) is None
     assert "limit" in _key_fallback_reason(
         np.bytes_(b"1.2.3.4"), fixed_width=layout.BYTES_WIDTH_MAX + 8)
     assert "string key" in _key_fallback_reason(b"1.2.3.4")
