@@ -99,7 +99,12 @@ Span taxonomy (name / cat):
                                        program that partitions by range
                                        or sorts by key says dst= hash |
                                        range, key= int | tuple | bytes,
-                                       order= signed | unsigned
+                                       order= signed | unsigned; the
+                                       exchange program says cap=,
+                                       slot=, nleaves= and send= slices
+                                       (collectives.exchange_round cuts
+                                       a destination's block out of the
+                                       sorted leaves; no other form)
     phase.ingest_tokenize,   "phase"   per-stage phase totals emitted
     phase.narrow,                      from the SAME _StreamStats
     phase.exchange,                    snapshot scheduler.phase_table()

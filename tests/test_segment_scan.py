@@ -21,6 +21,10 @@ The contracts under test:
   permutation: their orderings carry the rows (`sort == "carried"`);
   a record with a rank-2 value gathers that leaf alone
   (`carried+gathered`).
+* SLICES (ISSUE 34) — the `exchange` program between them gathers no
+  slot row either: a destination's block is a `dynamic_slice` of the
+  sorted leaf, of a rank-2 leaf too, and its `compile` event says
+  `send == "slices"`.
 """
 
 import functools
@@ -260,7 +264,7 @@ def launches(monkeypatch):
     launch = JAXExecutor._launch
 
     def spy(self, program, fn, *args):
-        if program in ("narrow", "reduce"):
+        if program in ("narrow", "exchange", "reduce"):
             seen.append((program, fn.lower(*args).as_text()))
         return launch(self, program, fn, *args)
     monkeypatch.setattr(JAXExecutor, "_launch", spy)
@@ -299,13 +303,19 @@ def test_stage_programs_hold_no_scatter_and_name_their_combine(
         ref[k] = merge(ref[k], v) if k in ref else v
     assert set(got) == set(ref)
     assert all(abs(got[k] - ref[k]) <= 1e-5 * abs(ref[k]) for k in ref)
-    assert {p for p, _ in launches} == {"narrow", "reduce"}
+    assert {p for p, _ in launches} == {"narrow", "exchange", "reduce"}
     for program, text in launches:
-        assert "sort" in text, program      # the text is the program's
+        assert _HOLDS[program] in text, program     # the text is its own
         assert "scatter" not in text, program
         assert not _column_gathers(text), program
-    assert {(a["program"], a["combine"], a["sort"]) for a in compiles} == {
+    assert {(a["program"], a["combine"], a["sort"]) for a in compiles
+            if "sort" in a} == {
         ("narrow", form, "carried"), ("reduce", form, "carried")}
+    assert _exchange_sends(compiles) == {"slices"}
+
+
+# what moves the rows in each program
+_HOLDS = {"narrow": "sort", "exchange": "dynamic_slice", "reduce": "sort"}
 
 
 def _column_gathers(text):
@@ -319,6 +329,13 @@ def _column_gathers(text):
             if int(np.prod([int(d) for d in dims] or [1])) >= 64:
                 out.append(line.strip())
     return out
+
+
+def _exchange_sends(compiles):
+    """How the job's exchange programs say they build a send block."""
+    sends = [a["send"] for a in compiles if a["program"] == "exchange"]
+    assert sends, compiles
+    return set(sends)
 
 
 def _vector_pair(r):
@@ -336,14 +353,18 @@ def test_a_rank2_value_is_gathered_behind_the_carried_sort(
         ref[k.item()] = ref.get(k.item(), 0) + np.array([v, 2 * v])
     assert {k: list(v) for k, v in rows} == {
         k: v.tolist() for k, v in ref.items()}
-    assert {p for p, _ in launches} == {"narrow", "reduce"}
+    assert {p for p, _ in launches} == {"narrow", "exchange", "reduce"}
     for program, text in launches:
         gathers = _column_gathers(text)
+        if program == "exchange":       # a rank-2 leaf is sliced too
+            assert not gathers and "dynamic_slice" in text, gathers
+            continue
         assert gathers, program
         assert all("x2xi64>" in g.rsplit("->", 1)[1] for g in gathers), (
             program, gathers)
-    assert {(a["program"], a["sort"]) for a in compiles} == {
+    assert {(a["program"], a["sort"]) for a in compiles if "sort" in a} == {
         ("narrow", "carried+gathered"), ("reduce", "carried+gathered")}
+    assert _exchange_sends(compiles) == {"slices"}
 
 
 def test_a_program_that_combines_nothing_says_none(compiles_of):
