@@ -418,6 +418,19 @@ PLANE_SEAMS = (
      "trace._PLANE"),
     ("backend/tpu/layout.py", "host_read", "trace._PLANE"),
     ("backend/tpu/layout.py", "egest", "trace._PLANE"),
+    # the driver's way in and out of a job (preflight, job.begin,
+    # stage.run, job.finish, store.release)
+    ("context.py", "DparkContext.runJob", "trace._PLANE"),
+    ("schedule.py", "DAGScheduler._begin_job", "trace._PLANE"),
+    ("schedule.py", "DAGScheduler._run_tasks", "trace._PLANE"),
+    ("schedule.py", "DAGScheduler._finish_job", "trace._PLANE"),
+    ("backend/tpu/__init__.py", "TPUScheduler._drain_unreachable",
+     "trace._PLANE"),
+    # the two children of stage.run (adapt.path, result.rows)
+    ("backend/tpu/__init__.py", "TPUScheduler._adapt_span",
+     "trace._PLANE"),
+    ("backend/tpu/__init__.py", "TPUScheduler._run_array_stage",
+     "trace._PLANE"),
 )
 
 

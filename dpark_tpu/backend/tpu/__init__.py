@@ -141,9 +141,31 @@ class TPUScheduler(DAGScheduler):
         lock = ex._mesh_lock
         if lock.try_enter():
             try:
-                super()._release_unreachable()
+                self._drain_unreachable(ex)
             finally:
                 lock.__exit__(None, None, None)
+
+    def _drain_unreachable(self, ex):
+        """The base's drain; with the trace plane on, one
+        `store.release` span a drain that dropped a store (args:
+        stores, bytes), stamped after the fact under the job this
+        thread is running: the drain only learns what it frees by
+        freeing it."""
+        plane = trace._PLANE
+        if plane is not None:
+            import time as _time
+            stores, nbytes = ex.stores_released, ex._store_bytes
+            t0 = _time.time()
+            super()._release_unreachable()
+            stores = ex.stores_released - stores
+            if stores:
+                record = self._current_record
+                trace.emit("store.release", "exec", t0,
+                           _time.time() - t0, stores=stores,
+                           bytes=nbytes - ex._store_bytes,
+                           job=record["id"] if record else None)
+        else:
+            super()._release_unreachable()
 
     def _shuffle_unreachable(self, sid):
         ex = self.executor
@@ -235,16 +257,18 @@ class TPUScheduler(DAGScheduler):
                 # off mode pays nothing past this flag check — the
                 # signature (sha1 over the stable program-key repr)
                 # is only worth computing when observations record
-                try:
-                    adapt_sig = fuse.plan_adapt_signature(plan)
-                except Exception:
-                    adapt_sig = None
-                # cost model (ISSUE 7 decision point 2): with recorded
-                # ms for BOTH paths of this program class, the cheaper
-                # one wins — predicted, not assumed, admission.  The
-                # choice is per stage and recorded as `adapt_reason`
-                # (the cost-model sibling of fallback/degrade_reason).
-                choice = adapt.choose_path(adapt_sig)
+                with self._adapt_span("choose"):
+                    try:
+                        adapt_sig = fuse.plan_adapt_signature(plan)
+                    except Exception:
+                        adapt_sig = None
+                    # cost model (ISSUE 7 decision point 2): with
+                    # recorded ms for BOTH paths of this program class,
+                    # the cheaper one wins — predicted, not assumed,
+                    # admission.  The choice is per stage and recorded
+                    # as `adapt_reason` (the cost-model sibling of
+                    # fallback/degrade_reason).
+                    choice = adapt.choose_path(adapt_sig)
                 if choice is not None and choice["choice"] == "object":
                     self.note_stage(stage.id,
                                     adapt_reason=choice["reason"])
@@ -305,6 +329,16 @@ class TPUScheduler(DAGScheduler):
         if adapt_sig is not None and all_ok:
             adapt.observe_path(adapt_sig, "host",
                                (_time.time() - t0) * 1e3)
+
+    def _adapt_span(self, step):
+        """With the trace plane on, the `adapt.path` span around the
+        cost model's part of a stage's submission (`step`: choose, the
+        plan's signature and choose_path before the stage runs;
+        observe, observe_path's append to the adapt store after it)."""
+        plane = trace._PLANE
+        if plane is not None:
+            return trace.span("adapt.path", "adapt", step=step)
+        return trace._NOOP
 
     def _analyze(self, stage):
         """fuse.analyze_stage; with the trace plane on, one `plan` span
@@ -700,18 +734,26 @@ class TPUScheduler(DAGScheduler):
             if getattr(plan, "topk_used", False):
                 note["kind"] = "array+top"   # observable: pre-top ran
             rows_per_part = result
-            for task in tasks:
-                assert isinstance(task, ResultTask)
-                value = task.func(iter(rows_per_part[task.partition]))
-                report(task, "success", (value, {}, {}))
+            sp = trace._NOOP
+            plane = trace._PLANE
+            if plane is not None:
+                sp = trace.span("result.rows", "sched", tasks=len(tasks),
+                                rows=sum(len(r) for r in rows_per_part))
+            with sp:
+                for task in tasks:
+                    assert isinstance(task, ResultTask)
+                    value = task.func(iter(rows_per_part[task.partition]))
+                    report(task, "success", (value, {}, {}))
         self.note_stage(stage.id, **note)
         # feed the cost model (ISSUE 7): observed device ms for this
         # program class — the other half of the device-vs-object price
         try:
             from dpark_tpu import adapt
             if adapt.enabled():
-                adapt.observe_path(fuse.plan_adapt_signature(plan),
-                                   "device", note["run_seconds"] * 1e3)
+                with self._adapt_span("observe"):
+                    adapt.observe_path(fuse.plan_adapt_signature(plan),
+                                       "device",
+                                       note["run_seconds"] * 1e3)
         except Exception:
             pass
         logger.debug("array path ran %s (%d tasks)", stage, len(tasks))

@@ -17,6 +17,7 @@ stages.  Reference parity anchor: SURVEY.md section 7.0 "array partitions".
 """
 
 import math
+import time
 
 import numpy as np
 
@@ -233,9 +234,8 @@ def column_groups(treedef, nleaves):
 def host_columns(treedef, cols, rows=None):
     """(treedef, numpy columns) as a host exit sees them: the word
     columns of every ByteStr node become one S<w> column.  Unchanged
-    when the record holds no byte string.  One `bytes.unpack` span
-    with the trace plane on; BYTES_ROWS_UNPACKED counts `rows` (the
-    valid rows, where the columns carry padding)."""
+    when the record holds no byte string.  BYTES_ROWS_UNPACKED counts
+    `rows` (the valid rows, where the columns carry padding)."""
     global BYTES_ROWS_UNPACKED
     found = column_groups(treedef, len(cols))
     if found is None:
@@ -243,21 +243,16 @@ def host_columns(treedef, cols, rows=None):
     outer, groups = found
     if rows is None:
         rows = len(cols[0])
-    width = max(g.width for g in groups if _is_bytestr(g))
     BYTES_ROWS_UNPACKED += rows
-    sp = trace._NOOP
-    if trace._PLANE is not None:
-        sp = trace.span("bytes.unpack", "exec", rows=rows, width=width)
-    with sp:
-        out = [unpack_bytes([cols[i] for i in g.words], g.width)
-               if _is_bytestr(g) else cols[g] for g in groups]
+    out = [unpack_bytes([cols[i] for i in g.words], g.width)
+           if _is_bytestr(g) else cols[g] for g in groups]
     return outer, out
 
 
 def _pack_parts(partitions, treedef, specs):
     """Columnar partitions whose S<w> columns become the word columns
     of their ByteStr nodes (so that every part carries one column a
-    leaf).  One `bytes.pack` span; BYTES_ROWS_PACKED counts the rows.
+    leaf).  BYTES_ROWS_PACKED counts the rows.
     Byte strings reach the device from Columns only: the width is the
     column's, which rows of `bytes` objects do not have."""
     global BYTES_ROWS_PACKED
@@ -266,42 +261,34 @@ def _pack_parts(partitions, treedef, specs):
         return partitions
     from dpark_tpu.rdd import _ColumnarSlice
     _, groups = found
-    rows = words = width = 0
+    rows = 0
     out = []
-    sp = trace._NOOP
-    if trace._PLANE is not None:
-        sp = trace.span("bytes.pack", "exec")
-    with sp:
-        for part in partitions:
-            cols = getattr(part, "columns", None)
-            if not len(part) or (cols is not None
-                                 and len(cols) == len(specs)
-                                 and not any(np.asarray(c).dtype.kind
-                                             == "S" for c in cols)):
-                out.append(part)        # empty, or already word columns
+    for part in partitions:
+        cols = getattr(part, "columns", None)
+        if not len(part) or (cols is not None
+                             and len(cols) == len(specs)
+                             and not any(np.asarray(c).dtype.kind
+                                         == "S" for c in cols)):
+            out.append(part)        # empty, or already word columns
+            continue
+        if cols is None or len(cols) != len(groups):
+            raise ValueError("byte-string records reach the device "
+                             "as Columns; taking the host path")
+        leaf_cols = [None] * len(specs)
+        for g, c in zip(groups, cols):
+            if not _is_bytestr(g):
+                leaf_cols[g] = c
                 continue
-            if cols is None or len(cols) != len(groups):
-                raise ValueError("byte-string records reach the device "
-                                 "as Columns; taking the host path")
-            leaf_cols = [None] * len(specs)
-            for g, c in zip(groups, cols):
-                if not _is_bytestr(g):
-                    leaf_cols[g] = c
-                    continue
-                c = np.asarray(c)
-                if c.dtype.kind != "S" or c.dtype.itemsize != g.width:
-                    raise ValueError("column dtype %s where S%d was "
-                                     "planned; taking the host path"
-                                     % (c.dtype, g.width))
-                packed = pack_bytes(c)
-                for j, li in enumerate(g.words):
-                    leaf_cols[li] = packed[:, j]
-                rows += len(c)
-                words += len(g.words)
-                width = max(width, g.width)
-            out.append(_ColumnarSlice(leaf_cols))
-        if sp is not trace._NOOP:
-            sp.args.update(rows=rows, width=width, words=words)
+            c = np.asarray(c)
+            if c.dtype.kind != "S" or c.dtype.itemsize != g.width:
+                raise ValueError("column dtype %s where S%d was "
+                                 "planned; taking the host path"
+                                 % (c.dtype, g.width))
+            packed = pack_bytes(c)
+            for j, li in enumerate(g.words):
+                leaf_cols[li] = packed[:, j]
+            rows += len(c)
+        out.append(_ColumnarSlice(leaf_cols))
     BYTES_ROWS_PACKED += rows
     return out
 
@@ -312,14 +299,18 @@ def host_read(x, site=""):
     fetched in one transfer (a list of numpy arrays comes back).
     `site` is the caller's short literal: it names the `readback` span
     that times the wait for the device plus the copy when the trace
-    plane is on."""
+    plane is on.  The span says how long it waited (`wait_s`: until
+    the device had produced the value); the rest of it is the copy."""
     global HOST_READS
     HOST_READS += 1
     plane = trace._PLANE
     if plane is not None:
         leaves = x if isinstance(x, list) else (x,)
         with trace.span("readback", "exec", site=site, bytes=sum(
-                int(getattr(a, "nbytes", 0)) for a in leaves)):
+                int(getattr(a, "nbytes", 0)) for a in leaves)) as sp:
+            t0 = time.time()
+            jax.block_until_ready(x)
+            sp.args["wait_s"] = round(time.time() - t0, 6)
             return _to_host(x)
     return _to_host(x)
 

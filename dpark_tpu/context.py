@@ -16,12 +16,14 @@ import atexit
 import os
 import sys
 import threading
+import time
 
 import importlib
 import itertools
 
 _accumulator = importlib.import_module("dpark_tpu.accumulator")
 import dpark_tpu.rdd as _rdd
+from dpark_tpu import trace
 from dpark_tpu.broadcast import Broadcast
 from dpark_tpu.env import env
 from dpark_tpu.utils.log import get_logger
@@ -268,7 +270,16 @@ class DparkContext:
         # lazy generator), so DPARK_LINT=error refuses a bad plan at
         # submit time, not at first iteration.
         from dpark_tpu.analysis import preflight
-        preflight(rdd, master=self.master, func=func)
+        plane = trace._PLANE
+        if plane is not None:
+            # the `preflight` span: no job id exists yet, so the reading
+            # rides on the scheduler's thread-local to the job's way in
+            # (DAGScheduler._begin_job), which emits it under the id
+            t0 = time.time()
+            preflight(rdd, master=self.master, func=func)
+            self.scheduler._tls.preflight = (t0, time.time() - t0)
+        else:
+            preflight(rdd, master=self.master, func=func)
         return self.scheduler.run_job(rdd, func, partitions, allow_local)
 
     def clear(self):

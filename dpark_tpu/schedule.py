@@ -107,6 +107,18 @@ class Stage:
         return "<Stage %d on %r>" % (self.id, self.rdd)
 
 
+def _graph_size(stage):
+    """Stages in the graph under `stage`, itself included."""
+    seen = set()
+    todo = [stage]
+    while todo:
+        s = todo.pop()
+        if s.id not in seen:
+            seen.add(s.id)
+            todo.extend(s.parents)
+    return len(seen)
+
+
 class DAGScheduler:
     """Walks the RDD dependency graph bottom-up, running stages whose
     parents are available; master-specific subclasses implement
@@ -272,12 +284,12 @@ class DAGScheduler:
         if not partitions:
             return
         import time as _time
+        final_stage, record, local = self._begin_job(
+            final_rdd, partitions, allow_local)
+        job_t0 = _time.time()
         # allowLocal fast path (reference: runJob allowLocal) — single
         # partition, no shuffle parents: compute inline, no tasks.
-        final_stage = self.new_stage(final_rdd, None)
-        if (allow_local and len(partitions) == 1 and not final_stage.parents):
-            record = self._new_job_record(final_rdd, 1, stages=0)
-            t0 = _time.time()
+        if local:
             try:
                 yield func(final_rdd.iterator(
                     final_rdd.splits[partitions[0]]))
@@ -290,12 +302,7 @@ class DAGScheduler:
                 record["state"] = "aborted"
                 raise
             finally:
-                record["seconds"] = round(_time.time() - t0, 3)
-                record.pop("_t_submit", None)
-                self._finalize_decodes(record)
-                self._trace_job_span(record, t0)
-                self._finalize_health(record)
-                self._job_finished(record)
+                self._finish_job(record, job_t0, local=True)
             return
 
         output_parts = list(partitions)
@@ -321,9 +328,6 @@ class DAGScheduler:
         #   conf.MAX_STAGE_FAILURES so a persistently failing shuffle
         #   source aborts with a chained error instead of looping
         progress = Progress(final_rdd.scope_name, len(output_parts))
-
-        record = self._new_job_record(final_rdd, len(output_parts))
-        job_t0 = _time.time()
 
         stage_of = {}
 
@@ -381,8 +385,7 @@ class DAGScheduler:
                 # spans parent correctly after the serialize trip
                 for t in tasks:
                     t._trace_job = record["id"]
-            with trace.ctx(job=record["id"], stage=stage.id):
-                self._dispatch(stage, tasks, report, record)
+            self._run_tasks(stage, tasks, report, record)
 
         def spawn_duplicate(stage, p):
             """Speculative copy of a straggling task (first result wins)."""
@@ -396,8 +399,7 @@ class DAGScheduler:
             logger.info("speculatively re-launching %r", t)
             if trace._PLANE is not None:
                 t._trace_job = record["id"]
-            with trace.ctx(job=record["id"], stage=stage.id):
-                self._dispatch(stage, [t], report, record)
+            self._run_tasks(stage, [t], report, record)
 
         # crash-consistent journal (ISSUE 20): write-ahead the job,
         # then seed any journaled stage completions whose outputs
@@ -426,22 +428,89 @@ class DAGScheduler:
         finally:
             if record["state"] == "running":
                 record["state"] = "done" if all(finished) else "aborted"
-            record["seconds"] = round(_time.time() - job_t0, 3)
-            record.pop("_t_submit", None)
-            jfp = record.pop("_jfp", None)
-            if jfp is not None and record["state"] == "done":
-                journal.append_job_done(jfp)
-            self._finalize_decodes(record)
-            self._finalize_exchanges(record)
-            self._finalize_adapt(record)
-            self._trace_job_span(record, job_t0)
-            self._finalize_health(record)
-            self._job_finished(record)
+            self._finish_job(record, job_t0)
             # submit_stage calls itself through this frame's cell, a
             # cycle that holds final_rdd (submit_missing_tasks' cell):
             # break it, so a chain the caller drops dies by reference
             # count and not when the cyclic collector next runs
             submit_stage = None
+
+    def _begin_job(self, final_rdd, partitions, allow_local):
+        """The way into a job: the stage graph (new_stage walks the
+        lineage) and the job's record (_new_job_record: the counter
+        baselines, the history, _job_started).  Returns (final stage,
+        record, whether the job runs inline: allowLocal, one partition,
+        no shuffle parents).  With the trace plane on this is the
+        `job.begin` span, which ends where `job` starts; the id is
+        stamped on it, and on the `preflight` reading that
+        DparkContext.runJob left on this thread, once it is minted."""
+        plane = trace._PLANE
+        if plane is not None:
+            with trace.span("job.begin", "sched") as sp:
+                out = self._open_job(final_rdd, partitions, allow_local)
+                job = out[1]["id"]
+                sp.args.update(job=job, stages=_graph_size(out[0]))
+                pre = getattr(self._tls, "preflight", None)
+                if pre is not None:
+                    self._tls.preflight = None
+                    from dpark_tpu.analysis import lint_mode
+                    trace.emit("preflight", "sched", pre[0], pre[1],
+                               job=job, mode=lint_mode())
+                return out
+        return self._open_job(final_rdd, partitions, allow_local)
+
+    def _open_job(self, final_rdd, partitions, allow_local):
+        final_stage = self.new_stage(final_rdd, None)
+        local = bool(allow_local and len(partitions) == 1
+                     and not final_stage.parents)
+        record = self._new_job_record(final_rdd, len(partitions),
+                                      stages=0 if local else 1)
+        return final_stage, record, local
+
+    def _run_tasks(self, stage, tasks, report, record):
+        """_dispatch under the job's span context; with the trace plane
+        on, one `stage.run` span a submission: `plan`, `join` and
+        `stage.exec` lie inside it, and its self time is the
+        scheduler's own work for the stage."""
+        plane = trace._PLANE
+        if plane is not None:
+            with trace.ctx(job=record["id"], stage=stage.id), \
+                    trace.span("stage.run", "sched", tasks=len(tasks),
+                               shuffle=stage.is_shuffle_map):
+                self._dispatch(stage, tasks, report, record)
+        else:
+            self._dispatch(stage, tasks, report, record)
+
+    def _finish_job(self, record, t0, local=False):
+        """The way out of a job: its seconds, then the finalizers.
+        With the trace plane on, the `job` span (emitted with the
+        unrounded duration; record["seconds"] keeps three decimals for
+        the web UI) and, from where it ends to after _job_finished,
+        the `job.finish` span."""
+        import time as _time
+        plane = trace._PLANE
+        if plane is not None:
+            with trace.span("job.finish", "sched", job=record["id"]) as sp:
+                seconds = sp.t0 - t0
+                self._finalize_job(record, t0, seconds, local)
+        else:
+            self._finalize_job(record, t0, _time.time() - t0, local)
+
+    def _finalize_job(self, record, t0, seconds, local):
+        record["seconds"] = round(seconds, 3)
+        record.pop("_t_submit", None)
+        if not local:
+            jfp = record.pop("_jfp", None)
+            if jfp is not None and record["state"] == "done":
+                from dpark_tpu import journal
+                journal.append_job_done(jfp)
+        self._finalize_decodes(record)
+        if not local:
+            self._finalize_exchanges(record)
+            self._finalize_adapt(record)
+        self._trace_job_span(record, t0, seconds)
+        self._finalize_health(record)
+        self._job_finished(record)
 
     def _new_job_record(self, final_rdd, parts, stages=1):
         import time as _time
@@ -542,12 +611,12 @@ class DAGScheduler:
         from dpark_tpu import health
         health.job_finished(self, record)
 
-    def _trace_job_span(self, record, t0):
+    def _trace_job_span(self, record, t0, seconds):
         """Emit the job's span (trace plane, ISSUE 8) — the root of
         the per-job timeline tools/dtrace analyzes."""
         if trace._PLANE is None:
             return
-        trace.emit("job", "sched", t0, record.get("seconds", 0.0),
+        trace.emit("job", "sched", t0, seconds,
                    job=record["id"], scope=record.get("scope"),
                    state=record.get("state"),
                    stages=record.get("stages"),
@@ -1440,9 +1509,7 @@ class DAGScheduler:
                     submitted_at[tkey] = _time.time()
                     if trace._PLANE is not None:
                         retry._trace_job = record["id"]
-                    with trace.ctx(job=record["id"],
-                                   stage=task.stage_id):
-                        self._dispatch(stage, [retry], report, record)
+                    self._run_tasks(stage, [retry], report, record)
             else:       # failure
                 # credit the EXECUTOR that ran the task (fleet
                 # placement): blacklist ranking must see failures
@@ -1480,9 +1547,7 @@ class DAGScheduler:
                 submitted_at[tkey] = _time.time()
                 if trace._PLANE is not None:
                     retry._trace_job = record["id"]
-                with trace.ctx(job=record["id"],
-                               stage=task.stage_id):
-                    self._dispatch(stage, [retry], report, record)
+                self._run_tasks(stage, [retry], report, record)
 
     # -- master-specific -------------------------------------------------
     def _dispatch(self, stage, tasks, report, record):

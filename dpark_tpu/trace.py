@@ -35,7 +35,53 @@ Span taxonomy (name / cat):
 
     job, stage, task         "sched"   driver-side lifecycle (job ->
                                        stage -> task parented by the
-                                       job/stage/task fields)
+                                       job/stage/task fields); `job`
+                                       runs from the end of job.begin
+                                       to the start of job.finish, its
+                                       dur unrounded
+    preflight                "sched"   analysis.preflight of one
+                                       action, DparkContext.runJob
+                                       (args: mode): the lint's lineage
+                                       walk and AST pass; measured
+                                       before the job's id exists and
+                                       emitted under it by _begin_job
+    job.begin                "sched"   the way into a job, DAGScheduler
+                                       ._begin_job (args: stages, the
+                                       graph's): new_stage's DAG walk,
+                                       _new_job_record, _job_started
+    stage.run                "sched"   one submission of a stage's
+                                       tasks, DAGScheduler._run_tasks
+                                       (args: tasks, shuffle): plan,
+                                       join and stage.exec lie inside;
+                                       its self time is the scheduler's
+                                       own work for the stage
+    adapt.path               "adapt"   the cost model's part of a stage's
+                                       submission, TPUScheduler.
+                                       submit_tasks / _run_array_stage
+                                       (args: step — choose: the plan's
+                                       sha1 signature and choose_path;
+                                       observe: observe_path's append
+                                       to the adapt store); two a stage
+                                       inside stage.run
+    result.rows              "sched"   the action's function over a
+                                       result stage's Python rows,
+                                       task.func(iter(rows)) and its
+                                       report, TPUScheduler.
+                                       _run_array_stage (args: tasks,
+                                       rows); inside stage.run, after
+                                       stage.exec
+    job.finish               "sched"   the way out of a job, DAGScheduler
+                                       ._finish_job: the finalizers
+                                       (decodes, exchanges, adapt,
+                                       health) and _job_finished
+    store.release            "exec"    one drain of dead shuffles that
+                                       freed at least one HBM store,
+                                       TPUScheduler._drain_unreachable
+                                       (args: stores, bytes); stamped
+                                       after the drain, inside
+                                       job.begin or job.finish (or a
+                                       stage.exec whose eviction
+                                       drains first)
     task.run                 "worker"  a task executing in whichever
                                        process ran it (the worker
                                        timeline of a multiproc run)
@@ -66,23 +112,16 @@ Span taxonomy (name / cat):
     readback                 "exec"    one blocking device-to-host
                                        read, layout.host_read (args:
                                        site — the caller's literal,
-                                       e.g. exchange.counts; bytes):
-                                       the host waited for the device
-                                       to produce the value, then the
-                                       copy
+                                       e.g. exchange.counts; bytes;
+                                       wait_s): the host waited wait_s
+                                       for the device to produce the
+                                       value (block_until_ready), then
+                                       the copy
     egest                    "exec"    layout.egest: a result batch to
                                        Python rows (args: rows, bytes);
                                        its readbacks nest inside
     ingest                   "exec"    layout.ingest of a stage's host
                                        numpy source (args: rows)
-    bytes.pack               "exec"    the S<w> columns of an ingest
-                                       become int64 word columns,
-                                       layout._pack_parts (args: rows,
-                                       width, words); inside `ingest`
-    bytes.unpack             "exec"    word columns rebuilt as host
-                                       bytes, layout.host_columns
-                                       (args: rows, width): inside
-                                       `egest`, or at the export bridge
     sort.sample              "exec"    the read of sortByKey's bounds
                                        sample, JAXExecutor._sample_keys
                                        (args: splits, rows, bytes: the

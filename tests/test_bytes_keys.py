@@ -304,21 +304,36 @@ def test_byte_keys_ride_the_wave_stream(masters, ndev):
     assert kinds.count("array+spill") == 2 and len(kinds) == 8, kinds
 
 
-def test_pack_and_unpack_spans_ride_the_ring(masters):
+def test_pack_and_unpack_show_on_counters_and_enclosing_spans(masters):
+    """What an ingest packs and an egest unpacks reads on the counters'
+    deltas and on the `rows` of the enclosing `ingest` / `egest` spans
+    (no span of their own: they sit in those loops); the width and the
+    word count are the ingested batch's own."""
     from dpark_tpu import trace
+    from dpark_tpu.backend.tpu import layout
     tctx = masters["tpu:1"]
+    ex = tctx.scheduler.executor
+    col = _column(12)
+    packed, unpacked = ex.bytes_rows_packed, ex.bytes_rows_unpacked
     trace.configure("ring")
     try:
-        col = _column(12)
-        rows = _chains(tctx, col, _whole, 1)["reduce_collect"]()
-        spans = {s["name"]: s for s in trace.snapshot()
-                 if s["name"].startswith("bytes.")}
+        table = tctx.parallelize(Columns(col, VALS), 1) \
+            .map(_resident).cache()
+        assert table.count() == len(col)
+        rows = table.map(_whole).reduceByKey(operator.add, 1).collect()
+        snap = trace.snapshot()
     finally:
         trace.configure("off")
-    assert spans["bytes.pack"]["args"] == {
-        "rows": len(col), "width": 12, "words": 2}
-    assert spans["bytes.unpack"]["args"] == {"rows": len(rows),
-                                             "width": 12}
+    spans = {s["name"]: s for s in snap if s["name"] in ("ingest", "egest")}
+    assert not [s for s in snap if s["name"].startswith("bytes.")]
+    assert ex.bytes_rows_packed - packed == len(col) \
+        == spans["ingest"]["args"]["rows"]
+    assert ex.bytes_rows_unpacked - unpacked == len(rows) \
+        == spans["egest"]["args"]["rows"]
+    meta = ex.result_cache[table.id]
+    _, groups = layout.column_groups(meta["treedef"], len(meta["leaves"]))
+    (key,) = [g for g in groups if isinstance(g, layout.ByteStr)]
+    assert (key.width, len(key.words)) == (12, 2)
 
 
 # -- float32 sums --------------------------------------------------------
