@@ -610,11 +610,14 @@ def bucketize_combine_keys(key_cols, val_leaves, n, n_dst, merge_leaves,
         dst = hash_dst_cols(key_cols, n_dst, valid, r)
     ks = [jnp.where(valid, key_cols[0], _sentinel(key_cols[0].dtype))]
     ks += key_cols[1:]
-    # composite keys: one hash column to order by instead of n key columns
-    # (the reduce side re-sorts by the true key columns; see
-    # _bucketize_combine_cols on why adjacency is sufficient here)
-    order_col = (phash_device_cols(key_cols) if len(key_cols) > 1
-                 else None)
+    # composite keys across devices: one hash column to order by instead
+    # of n key columns (the reduce side re-sorts by the true key columns;
+    # see _bucketize_combine_cols on why adjacency is sufficient there).
+    # With ONE destination there is no reduce side to mend a group that
+    # a hash collision split: the true key columns order the rows, the
+    # combine is exact, and the executor registers the store pre_reduced
+    order_col = (phash_device_cols(key_cols)
+                 if len(key_cols) > 1 and n_dst > 1 else None)
     return _bucketize_combine_cols(dst, ks, val_leaves, n_dst,
                                    merge_leaves, monoid,
                                    order_col=order_col)
@@ -677,7 +680,11 @@ def _bucketize_combine_cols(dst, key_cols, val_leaves, n_dst,
     real key column, so a hash collision merely splits one group into
     two partial combiners — the reduce side merges them anyway).  Do
     NOT use it where callers require true key-sorted output (the
-    spilled-run stream's export relies on lexicographic run order)."""
+    spilled-run stream's export relies on lexicographic run order) or
+    an EXACT combine: bucketize_combine_keys passes it only when
+    n_dst > 1; on one device the store it writes is the reduce's
+    answer (executor._finish_stage marks it pre_reduced), every key
+    once and in key order as _segment_reduce_cols would leave it."""
     nk = len(key_cols)
     if order_col is not None:
         sorted_ops = _lex_sort(

@@ -731,6 +731,13 @@ class JAXExecutor:
         # stores dropped because their ShuffleDependency died (the
         # scheduler's drain): the release engaging, once a store
         self.stores_released = 0
+        # in-core shuffle writes registered `pre_reduced` (_finish_stage:
+        # one device, an exact combine): the reduce stage's exchange and
+        # reduce program not run, once a store
+        self.stores_pre_reduced = 0
+        # program_key -> does that plan's shuffle write combine on the
+        # device (_write_combines' memo, bounded like the program cache)
+        self._combines_memo = {}
         # the device join: pairs it emitted (the totals `join.totals`
         # reads), and pairs of a byte-string join whose one-word hash
         # matched and whose bytes did not (dropped on the device; the
@@ -1014,7 +1021,7 @@ class JAXExecutor:
         has_bounds = plan.epi_bounds is not None
         merge_fn = monoid = epi = None
         if epilogue is not None:
-            merge_fn, monoid = self._epilogue_merge(plan)
+            merge_fn, monoid = self._merge_probe(plan)
             epi = self._epilogue_params(plan)
         if trace._PLANE is not None:
             trace.event("compile", "exec", program="narrow", cap=cap,
@@ -1026,7 +1033,9 @@ class JAXExecutor:
         in_specs = plan.in_specs
 
         def per_device(counts, *rest):
-            n = counts[0]
+            # (ndev,) counts of a batch; a pre_reduced in-core store
+            # hands its (ndev, R=1) bucket counts as they were written
+            n = counts[0].reshape(()) if counts.ndim > 1 else counts[0]
             bounds = rest[0][0] if has_bounds else None
             leaves = rest[1:] if has_bounds else rest
             lv = self._widen_entry(in_specs, [l[0] for l in leaves])
@@ -1179,7 +1188,7 @@ class JAXExecutor:
         has_bounds = plan.epi_bounds is not None
         out_merge_fn = out_monoid = epi = None
         if epilogue is not None:
-            out_merge_fn, out_monoid = self._epilogue_merge(plan)
+            out_merge_fn, out_monoid = self._merge_probe(plan)
             epi = self._epilogue_params(plan)
 
         src_nk = getattr(plan, "src_nk", 1) or 1
@@ -1365,9 +1374,18 @@ class JAXExecutor:
             # histogram, compile with the bucket layout
             return self._run_seg_map(plan)
         if store.get("pre_reduced"):
-            # streamed shuffle already exchanged+combined: device d
-            # holds reduce partition d — just run the narrow tail
+            # device d already holds reduce partition d, every key
+            # once: a streamed shuffle exchanged and combined wave by
+            # wave; an in-core write on ONE device combined exactly
+            # (_finish_stage), and its exchange would be the identity.
+            # No exchange and no reduce program: the plan's narrow tail
+            # over the store's batch, never donated (the store outlives
+            # the result, and a cache()d result the store)
             store["seq"] = self._next_seq()
+            if "offsets" in store:      # in-core: the identity
+                # exchange's row accounting, as _exchange_all keeps it
+                self._note_identity_exchange(store["counts"],
+                                             store["leaves"][0].shape[1])
             batch = layout.Batch(store["out_treedef"], store["leaves"],
                                  store["counts"])
             return self._run_narrow(plan, batch)
@@ -1919,10 +1937,25 @@ class JAXExecutor:
         dep = plan.epilogue[1]
         cnts, offs = outs[0], outs[1]
         leaves = list(outs[2:])
+        # ONE device, and this stage's one program combined its whole
+        # input for the shuffle (bucketize_combine_keys: by the true
+        # key columns when n_dst == 1): the store holds every key once,
+        # in key order, packed.  That is what the reduce stage's
+        # identity exchange and reduce program would hand on, so the
+        # store is what a streamed combine's is: _source_outs runs the
+        # narrow tail alone.  Raw combiners (the (None, None) merge),
+        # list aggregators and range writes combine nothing and keep
+        # the exchange + reduce; so does every write past one device
+        pre_reduced = (self.ndev == 1
+                       and dep.partitioner.num_partitions <= self.ndev
+                       and self._write_combines(plan))
+        if pre_reduced:
+            self.stores_pre_reduced += 1
         return self._register_shuffle(dep, plan, {
             "leaves": leaves,            # (ndev, cap, ...) dst-sorted
             "counts": cnts,              # (ndev, R)
             "offsets": offs,             # (ndev, R)
+            "pre_reduced": pre_reduced,
             "no_combine": fuse.is_list_agg(dep.aggregator),
             "encoded_keys": getattr(plan, "encoded_keys", False),
             # text ingest, union concat, and resliced ingest all
@@ -2129,7 +2162,8 @@ class JAXExecutor:
             # hbm.release (drop or spill-to-disk eviction)
             trace.event("hbm.store", "exec", sid=sid,
                         bytes=store["nbytes"],
-                        job=store["job"])
+                        job=store["job"],
+                        pre_reduced=bool(store.get("pre_reduced")))
         self._evict_hbm(keep_sid=sid)
         self._observe_combine_ratio(dep, plan, store)
         return ("shuffle", sid)
@@ -2508,11 +2542,30 @@ class JAXExecutor:
                 % (chunk_rows, limit))
 
     def _merge_probe(self, plan):
-        """Memoized (merge_fn, monoid) for the plan's shuffle write —
-        the same probe _epilogue_merge runs at compile time."""
+        """_epilogue_merge's (merge_fn, monoid) for the plan's shuffle
+        write, memoized on the plan: the stream mode, the program
+        builders and _write_combines share one probe."""
         if not hasattr(plan, "_merge_probe_result"):
             plan._merge_probe_result = self._epilogue_merge(plan)
         return plan._merge_probe_result
+
+    def _write_combines(self, plan):
+        """Does the plan's shuffle write combine on the device
+        (_epilogue_merge gave a merge_fn or a monoid, so its program
+        ran bucketize_combine_keys)?  Remembered by program key, which
+        decides it: every job builds a fresh plan, and the probe traces
+        the user's merge (too dear to repeat a job).  The memo is
+        bounded like the program cache, oldest entry out."""
+        memo = self._combines_memo
+        combines = memo.get(plan.program_key)
+        if combines is None:
+            merge_fn, monoid = self._merge_probe(plan)
+            combines = merge_fn is not None or monoid is not None
+            if conf.PROGRAM_CACHE_MAX \
+                    and len(memo) >= conf.PROGRAM_CACHE_MAX:
+                del memo[next(iter(memo))]
+            memo[plan.program_key] = combines
+        return combines
 
     def _wave_iter_columnar(self, plan, chunk=None):
         from dpark_tpu.rdd import _ColumnarSlice
@@ -3145,12 +3198,12 @@ class JAXExecutor:
             # bucketized valid prefix IS the received data.  Skip the
             # narrowing probe (there is no wire), the collective
             # program, and every blocking readback (this runs per
-            # wave, and a sync stalls the dispatch queue); the row
-            # metric readback is deferred to the next metric read.
-            self._pending_real_counts.append(counts)
-            if len(self._pending_real_counts) > self._PENDING_COUNTS_MAX:
-                self.exchange_real_rows  # property read drains the list
-            self.ingest_slot_rows += cap
+            # wave, and a sync stalls the dispatch queue).  Who still
+            # comes here: streamed waves, no-combine stores (groupByKey,
+            # the join's sides, sortByKey's range write) and raw
+            # combiners; an in-core COMBINED store is pre_reduced and
+            # its reader skips this call too (_source_outs)
+            self._note_identity_exchange(counts, cap)
             # consumers expect per-device (R=1, slot, ...) receive
             # buffers and (R=1,) counts — counts is already the (1, 1)
             # per-bucket array, leaves gain the source-device axis
@@ -3206,6 +3259,17 @@ class JAXExecutor:
                 self.ndev * self.ndev * slot * wire_itemsize)
             self.exchange_slot_rows += self.ndev * self.ndev * slot
         return recv_rounds, cnt_rounds, slot
+
+    def _note_identity_exchange(self, counts, cap):
+        """Row accounting of a one-device exchange that moves nothing:
+        the valid rows offered (`exchange_real_rows`; the host sum is
+        deferred to the next metric read, never a readback here) and
+        the slots they sat in (`ingest_slot_rows`; the scheduler's
+        `ingest_pad_efficiency` stage note is their ratio)."""
+        self._pending_real_counts.append(counts)
+        if len(self._pending_real_counts) > self._PENDING_COUNTS_MAX:
+            self.exchange_real_rows  # property read drains the list
+        self.ingest_slot_rows += cap
 
     def _merge_into_state(self, plan, state, recv, monoid,
                           merge_fn=None, donate=False):
@@ -3582,7 +3646,8 @@ class JAXExecutor:
             if map_id != 0:
                 return {"no_combine": False}, []
             with self._export_lock:
-                counts = _export_read(store["counts"])
+                # (ndev,), or an in-core store's (ndev=1, R=1)
+                counts = _export_read(store["counts"]).reshape(-1)
                 cnt = int(counts[reduce_id])
                 if not cnt:
                     return {"no_combine": False}, []
@@ -3684,7 +3749,8 @@ class JAXExecutor:
             if map_id != 0:
                 return []
             with self._export_lock:
-                counts = _export_read(store["counts"])
+                # (ndev,), or an in-core store's (ndev=1, R=1)
+                counts = _export_read(store["counts"]).reshape(-1)
                 cnt = int(counts[reduce_id])
                 if not cnt:
                     return []
