@@ -61,6 +61,15 @@ def _device_error(e):
     return False
 
 
+class _PregelRun:
+    """What _new_job_record reads of a job's final RDD, for a job that
+    has none: a Pregel run, named for the user's call."""
+
+    def __init__(self):
+        from dpark_tpu.utils import user_call_site
+        self.scope_name = "Pregel@%s" % user_call_site()
+
+
 class TPUScheduler(DAGScheduler):
     # plan analysis mutates module state (fuse.last_fallback_reason)
     # and probes with shared tracers: with a resident job server's
@@ -329,6 +338,77 @@ class TPUScheduler(DAGScheduler):
         if adapt_sig is not None and all_ok:
             adapt.observe_path(adapt_sig, "host",
                                (_time.time() - t0) * 1e3)
+
+    def run_pregel(self, on_device, on_host, load_error=None):
+        """One Pregel run (bagel.PregelGraph.run) as a job of this
+        scheduler: a record in `history` as runJob leaves one (id,
+        scope, state, seconds, stage_info) and, with the trace plane
+        on, `job.begin`, then `job` > `stage.run` > `stage.exec` around
+        the supersteps with the job's id on every span, then
+        `job.finish`; no lineage is walked and nothing is linted, so no
+        `preflight`.  `on_device()` runs the supersteps under the mesh
+        lock, as run_stage runs a stage, and the job's one stage is
+        then of kind `array+pregel`, with its superstep and message
+        counts.  If it raises anything but a fault of the input, or the
+        device holds no graph (`load_error` says why), the stage stays
+        of kind `object`, carries the cause as its `fallback_reason`,
+        and `on_host()`, the numpy loop, answers inside the same job."""
+        import time as _time
+        from dpark_tpu.schedule import Stage
+        self.start()
+        ex = self.executor
+        with trace.span("job.begin", "sched") as sp:
+            record = self._new_job_record(_PregelRun(), ex.ndev)
+            if trace._PLANE is not None:
+                sp.args.update(job=record["id"], stages=1)
+        t0 = _time.time()
+        stage_id = next(Stage._next_id)
+        ex._job_tls.job = record["id"]
+        try:
+            with trace.ctx(job=record["id"], stage=stage_id), \
+                    trace.span("stage.run", "sched", tasks=ex.ndev,
+                               shuffle=False):
+                out = self._run_pregel_stage(stage_id, on_device,
+                                             on_host, load_error)
+            record["finished"] = record["parts"]
+            record["state"] = "done"
+            return out
+        except BaseException:
+            record["state"] = "aborted"
+            raise
+        finally:
+            self._finish_job(record, t0)
+
+    def _run_pregel_stage(self, stage_id, on_device, on_host, reason):
+        import time as _time
+        from dpark_tpu.bagel import (PregelInputError, _first_line,
+                                     _NotColumnarizable)
+        ex = self.executor
+        if reason is None:
+            t0 = _time.time()
+            steps0, msgs0 = ex.pregel_supersteps, ex.pregel_messages
+            rows0 = self._exchange_rows()
+            try:
+                with ex._mesh_lock, \
+                        trace.span("stage.exec", "exec", source="pregel"):
+                    out = on_device()
+            except (PregelInputError, _NotColumnarizable):
+                raise      # wrong on the host path too: surface it
+            except Exception as e:
+                reason = "device Pregel failed: " + _first_line(e)
+                logger.warning("%s; the host loop answers", reason)
+            else:
+                self.note_stage(
+                    stage_id, kind="array+pregel",
+                    run_seconds=round(_time.time() - t0, 3),
+                    supersteps=ex.pregel_supersteps - steps0,
+                    messages=ex.pregel_messages - msgs0,
+                    **self._exchange_note(rows0))
+                self._pregel_device_used = True
+                return out
+        self.note_stage(stage_id, fallback_reason=reason)
+        self._pregel_device_used = False
+        return on_host()
 
     def _adapt_span(self, step):
         """With the trace plane on, the `adapt.path` span around the
@@ -624,6 +704,35 @@ class TPUScheduler(DAGScheduler):
                      cg.id, nsrc)
         return cg, nparts, was_cached
 
+    def _exchange_rows(self):
+        """The executor's exchange accounting, to take a stage's part of
+        it from (_exchange_note)."""
+        ex = self.executor
+        return (ex.exchange_wire_bytes, ex.exchange_real_rows,
+                ex.exchange_slot_rows, ex.ingest_slot_rows)
+
+    def _exchange_note(self, rows0):
+        """What a stage's exchanges moved since `rows0`, as entries of
+        its stage note (one read of the deferred row counts where a
+        one-device exchange left some)."""
+        ex = self.executor
+        wire0, real0, slot0, islot0 = rows0
+        wire = ex.exchange_wire_bytes - wire0
+        slot_rows = ex.exchange_slot_rows - slot0
+        ingest_rows = ex.ingest_slot_rows - islot0
+        if wire or slot_rows:
+            # per-stage exchange accounting (the slot-sizing tuning
+            # signals, visible in the web UI)
+            return {"wire_bytes": wire, "pad_efficiency": round(
+                (ex.exchange_real_rows - real0) / max(1, slot_rows), 4)}
+        if ingest_rows:
+            # single-chip identity exchange: no wire moved; report the
+            # ingest slot fill under its own name so the UI never
+            # presents ingest padding as wire padding
+            return {"ingest_pad_efficiency": round(
+                (ex.exchange_real_rows - real0) / max(1, ingest_rows), 4)}
+        return {}
+
     def _run_array_stage(self, stage, tasks, plan, report):
         import time as _time
         from dpark_tpu.backend.tpu import fuse
@@ -675,10 +784,7 @@ class TPUScheduler(DAGScheduler):
                     tasks[0].func.f)
             except Exception:
                 plan.reduce_monoid = None
-        wire0 = self.executor.exchange_wire_bytes
-        real0 = self.executor.exchange_real_rows
-        slot0 = self.executor.exchange_slot_rows
-        islot0 = self.executor.ingest_slot_rows
+        rows0 = self._exchange_rows()
         # live per-wave pipeline updates: a long streamed stage reports
         # its ingest/compute/exchange/spill ms and device-idle fraction
         # into stage_info WHILE it runs (web UI), not just at the end
@@ -692,23 +798,7 @@ class TPUScheduler(DAGScheduler):
                 "run_seconds": round(_time.time() - t0, 3)}
         if self.executor.last_stream_stats is not None:
             note["pipeline"] = self.executor.last_stream_stats
-        wire = self.executor.exchange_wire_bytes - wire0
-        slot_rows = self.executor.exchange_slot_rows - slot0
-        ingest_rows = self.executor.ingest_slot_rows - islot0
-        if wire or slot_rows:
-            # per-stage exchange accounting (the slot-sizing tuning
-            # signals, visible in the web UI)
-            note["wire_bytes"] = wire
-            note["pad_efficiency"] = round(
-                (self.executor.exchange_real_rows - real0)
-                / max(1, slot_rows), 4)
-        elif ingest_rows:
-            # single-chip identity exchange: no wire moved; report the
-            # ingest slot fill under its own name so the UI never
-            # presents ingest padding as wire padding
-            note["ingest_pad_efficiency"] = round(
-                (self.executor.exchange_real_rows - real0)
-                / max(1, ingest_rows), 4)
+        note.update(self._exchange_note(rows0))
         if kind == "shuffle":
             store = self.executor.shuffle_store.get(result)
             if store is not None:

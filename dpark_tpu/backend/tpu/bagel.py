@@ -14,6 +14,13 @@ messages are pre-combined per destination (the Combiner optimization)
 before the exchange.  The Python superstep loop stays on the host,
 exactly like the reference; everything between two host iterations is
 three jitted shard_map programs plus the count-exchange rounds.
+
+Two objects: a DeviceGraph is what a graph's load leaves on the devices
+(the vertex-id table, the arcs stored with their source) and lives until
+it is dropped; a DevicePregel is one run over it, with fresh vertex
+state and the user's functions.  The programs are the executor's
+(`JAXExecutor._compiled`), keyed by all they close over, so a later run
+with the same functions over the same size classes builds none.
 """
 
 import numpy as np
@@ -23,10 +30,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from dpark_tpu import conf
+from dpark_tpu import conf, trace
 from dpark_tpu.bagel import (
-    PREGEL_MONOIDS, PregelInputError, as_leaves, monoid_identity,
-    rewrap)
+    PregelInputError, as_leaves, monoid_identity, rewrap)
 from dpark_tpu.backend.tpu import collectives, layout
 from dpark_tpu.backend.tpu.executor import _shard_map
 from dpark_tpu.utils.log import get_logger
@@ -56,18 +62,213 @@ def _axis_reduce(kind, x):
     return jnp.prod(lax.all_gather(x, AXIS))
 
 
-class DevicePregel:
-    """One Pregel run over the executor's mesh.  See bagel.run_pregel for
-    the user-facing contract."""
+class _Held:
+    """A captured value that a program key can only tell apart by
+    identity; the key keeps it alive, so the identity is never reused
+    while the program is cached."""
+    __slots__ = ("obj",)
 
-    def __init__(self, executor, ids, values, edges, compute, send,
-                 combine="add", edge_values=None, active=None,
-                 initial_messages=None, aggregator=None,
+    def __init__(self, obj):
+        self.obj = obj
+
+    def __hash__(self):
+        return id(self.obj)
+
+    def __eq__(self, other):
+        return isinstance(other, _Held) and other.obj is self.obj
+
+    def __repr__(self):
+        return "<held %s>" % type(self.obj).__name__
+
+
+def _value_key(v, depth):
+    if v is None or isinstance(v, (bool, int, float, complex, str,
+                                   bytes)):
+        return (type(v).__name__, v)       # 1, 1.0 and True differ
+    if isinstance(v, np.generic):
+        return (v.dtype.str, v.item())
+    if isinstance(v, tuple):
+        return tuple(_value_key(x, depth) for x in v)
+    if callable(v) and depth < 4:
+        return fn_identity(v, depth + 1)
+    return _Held(v)
+
+
+def fn_identity(fn, depth=0):
+    """What a traced program takes from a user function, as a hashable
+    key: the code object, the closure cells' contents, `__defaults__`
+    and `__kwdefaults__` (two functions that differ only in a default
+    are two programs: fuse.fn_key, ROADMAP M1, leaves them out).  Plain
+    values compare by value, captured functions by this same rule, and
+    anything else (an array, an object, a callable without a code
+    object) by identity.  Module globals are not in it, as they are not
+    in jax.jit's own cache key."""
+    inner = getattr(fn, "__func__", None)
+    if inner is not None:                       # a bound method
+        return ("method", fn_identity(inner, depth + 1),
+                _Held(fn.__self__))
+    code = getattr(fn, "__code__", None)
+    if code is None:
+        return _Held(fn)
+    cells = []
+    for cell in fn.__closure__ or ():
+        try:
+            cells.append(_value_key(cell.cell_contents, depth))
+        except ValueError:                      # an empty cell
+            cells.append(("empty",))
+    return (code, tuple(cells),
+            tuple(_value_key(v, depth) for v in fn.__defaults__ or ()),
+            tuple(sorted((k, _value_key(v, depth)) for k, v in
+                         (fn.__kwdefaults__ or {}).items())))
+
+
+def _specs(leaves):
+    """(dtype, trailing shape) of each leaf: what a program sees of a
+    column beside its capacity."""
+    return tuple((np.dtype(l.dtype).str, tuple(l.shape[1:]))
+                 for l in leaves)
+
+
+class DeviceGraph:
+    """A graph on the executor's mesh: vertices partitioned by
+    hash(id), each device's ids sorted (`vid`, padded with the sentinel;
+    `vcnt`), every arc with its source's device (`e_dst`, `e_slot` the
+    source's place in that device's table, `e_deg` its out-degree, the
+    edge values; `ecnt`).  Built once from the host index
+    (bagel._HostGraph) and read by every run; a run writes none of
+    it."""
+
+    def __init__(self, executor, host):
+        self.ex = executor
+        self.ndev = ndev = executor.ndev
+        self.mesh = executor.mesh
+        self.n = n = host.n
+        ids = host.ids                              # sorted
+        if n and int(ids[-1]) == _SENT:
+            raise PregelInputError(
+                "vertex id equals the padding sentinel")
+        self.e_tuple = host.e_tuple
+        # message leaf specs by (send, vertex leaf specs): a run with
+        # functions this graph has seen traces nothing to learn them
+        self._msg_specs = {}
+
+        # vertices: ids are sorted, so a stable order by device gives
+        # each device its ids in order (the step program's
+        # searchsorted needs that)
+        vdev = (phash_np(ids) % np.uint32(ndev)).astype(np.int64)
+        vorder = np.argsort(vdev, kind="stable")
+        vbounds = np.searchsorted(vdev[vorder], np.arange(ndev + 1))
+        vcnt = np.diff(vbounds).astype(np.int32)
+        self.cap_v = cap_v = layout.round_capacity(
+            int(vcnt.max()) if n else 1)
+        local_slot = np.empty(n, np.int64)
+        local_slot[vorder] = np.arange(n) - vbounds[vdev[vorder]]
+        # where sorted vertex i sits in a flattened (ndev, cap_v)
+        # column, and the same for the vertices in the caller's order
+        slot = vdev * cap_v + local_slot
+        self._slot_of_input = np.empty(n, np.int64)
+        self._slot_of_input[host.order] = slot
+        vid = np.full(ndev * cap_v, _SENT, np.int64)
+        vid[slot] = ids
+
+        # arcs, living with their source vertex
+        src_idx = host.src_idx
+        ne = src_idx.size
+        edev = vdev[src_idx] if ne else np.zeros(0, np.int64)
+        eorder = np.argsort(edev, kind="stable")
+        ebounds = np.searchsorted(edev[eorder], np.arange(ndev + 1))
+        ecnt = np.diff(ebounds).astype(np.int32)
+        self.cap_e = cap_e = layout.round_capacity(
+            int(ecnt.max()) if ne else 1)
+        eslot = np.empty(ne, np.int64)
+        eslot[eorder] = edev[eorder] * cap_e + (
+            np.arange(ne) - ebounds[edev[eorder]])
+        e_dst = np.full(ndev * cap_e, _SENT, np.int64)
+        e_slot = np.zeros(ndev * cap_e, np.int32)
+        e_deg = np.ones(ndev * cap_e, np.int64)
+        e_dst[eslot] = host.dst
+        e_slot[eslot] = local_slot[src_idx]
+        e_deg[eslot] = host.deg[src_idx]
+        h_evals = []
+        for l in host.eleaves:
+            hl = np.zeros((ndev * cap_e,) + l.shape[1:], l.dtype)
+            hl[eslot] = l
+            h_evals.append(hl.reshape((ndev, cap_e) + l.shape[1:]))
+        self.e_specs = _specs(host.eleaves)
+
+        tables = [vid.reshape(ndev, cap_v), vcnt,
+                  e_dst.reshape(ndev, cap_e), e_slot.reshape(ndev, cap_e),
+                  e_deg.reshape(ndev, cap_e), ecnt] + h_evals
+        with executor._mesh_lock, \
+                trace.span("ingest", "exec", rows=n + ne,
+                           bytes=sum(int(t.nbytes) for t in tables),
+                           site="pregel.graph"):
+            (self.vid, self.vcnt, self.e_dst, self.e_slot, self.e_deg,
+             self.ecnt, *self.e_vals) = [self.put(t) for t in tables]
+        executor.pregel_graph_loads += 1
+
+    def put(self, arr):
+        return layout.put_sharded(arr, NamedSharding(self.mesh, P(AXIS)))
+
+    def place(self, leaf):
+        """A column over the vertices, in the order of the ids the graph
+        was given, as its (ndev, cap_v, ...) device column."""
+        leaf = np.asarray(leaf)
+        if leaf.shape[:1] != (self.n,):
+            raise PregelInputError(
+                "a vertex column has %s rows for %d vertices"
+                % (leaf.shape[:1], self.n))
+        h = np.zeros((self.ndev * self.cap_v,) + leaf.shape[1:],
+                     leaf.dtype)
+        h[self._slot_of_input] = leaf
+        return self.put(h.reshape((self.ndev, self.cap_v)
+                                  + leaf.shape[1:]))
+
+    def message_specs(self, send, vleaves, v_tuple):
+        """(dtypes, trailing shapes, was_tuple) of what `send` emits,
+        discovered by tracing it once (the per-edge/per-vertex structs
+        keep their trailing dims — a vector vertex state must probe as
+        a vector, or the discovered message shape collapses to a
+        scalar)."""
+        v_specs = _specs(vleaves)
+        key = (fn_identity(send), v_specs, v_tuple)
+        if key not in self._msg_specs:
+            struct = lambda specs: tuple(            # noqa: E731
+                jax.ShapeDtypeStruct(shp, np.dtype(dt))
+                for dt, shp in specs)
+            has_e = bool(self.e_specs)
+            out = jax.eval_shape(
+                lambda sv, ev, dg: send(
+                    rewrap(list(sv), v_tuple),
+                    rewrap(list(ev), self.e_tuple) if has_e else None,
+                    dg),
+                struct(v_specs), struct(self.e_specs),
+                jax.ShapeDtypeStruct((), np.int64))
+            m_leaves, m_tuple = as_leaves(out)
+            for m in m_leaves:
+                if len(m.shape) > 1:
+                    raise PregelInputError(
+                        "message leaves must be scalars or 1-D vectors")
+            # trailing per-message shape of each leaf: () scalars, or
+            # (k,) sum-vector leaves riding as one rank-2 exchange
+            # column
+            self._msg_specs[key] = (
+                [np.dtype(m.dtype) for m in m_leaves],
+                [tuple(m.shape) for m in m_leaves], m_tuple)
+        return self._msg_specs[key]
+
+
+class DevicePregel:
+    """One Pregel run over a DeviceGraph.  See bagel.run_pregel for the
+    user-facing contract."""
+
+    def __init__(self, graph, values, compute, send, combine="add",
+                 active=None, initial_messages=None, aggregator=None,
                  max_superstep=80, static_superstep=False,
                  send_gate_leaf=None):
-        if combine not in PREGEL_MONOIDS:
-            raise ValueError(
-                "combine must be one of %s" % (PREGEL_MONOIDS,))
+        self.g = graph
+        self.ex = graph.ex
+        self.ndev = graph.ndev
         # static_superstep: compile one step program PER superstep with
         # `s` as a Python int (user compute branches on it — e.g. the
         # columnarized object-Bagel adapter); default traces s as data
@@ -79,373 +280,309 @@ class DevicePregel:
         # then halted, and nothing from an active vertex that emitted
         # none — neither is expressible with the active gate alone)
         self.send_gate = send_gate_leaf
-        self.ex = executor
-        self.ndev = executor.ndev
-        self.mesh = executor.mesh
         self.compute = compute
         self.send = send
         self.combine = combine
         self.aggregator = aggregator
         self.max_superstep = max_superstep
-        self._compiled = {}
-        self._setup(ids, values, edges, edge_values, active,
-                    initial_messages)
 
-    # ------------------------------------------------------------------
-    # host-side setup: partition vertices by hash(id), edges by source
-    # ------------------------------------------------------------------
-    def _setup(self, ids, values, edges, edge_values, active, init_msgs):
-        ndev = self.ndev
-        ids = np.ascontiguousarray(np.asarray(ids, np.int64))
-        n = ids.shape[0]
-        if np.unique(ids).shape[0] != n:
-            raise PregelInputError("vertex ids must be unique")
-        if n and int(ids.max()) == _SENT:
-            raise PregelInputError(
-                "vertex id equals the padding sentinel")
         vleaves, self.v_tuple = as_leaves(values)
         vleaves = [np.asarray(l) for l in vleaves]
-        act = (np.ones(n, bool) if active is None
-               else np.asarray(active, bool))
+        self.msg_dtypes, self.msg_shapes, self.m_tuple = \
+            graph.message_specs(send, vleaves, self.v_tuple)
+        self.values = [graph.place(l) for l in vleaves]
+        self.active = graph.place(
+            np.ones(graph.n, bool) if active is None
+            else np.asarray(active, bool))
+        # all that the gen and step programs close over beside the
+        # user's functions
+        self._sig = (_specs(vleaves), self.v_tuple, graph.e_specs,
+                     graph.e_tuple,
+                     tuple((dt.str, shp) for dt, shp in
+                           zip(self.msg_dtypes, self.msg_shapes)),
+                     self.m_tuple, graph.cap_v, graph.cap_e, combine)
+        # the user's functions as program keys, once a run
+        self._send_key = fn_identity(send)
+        self._compute_key = (
+            fn_identity(compute),
+            None if aggregator is None
+            else (fn_identity(aggregator[0]), aggregator[1]))
+        self.init = self._place_messages(initial_messages)
 
-        vdev = (phash_np(ids) % np.uint32(ndev)).astype(np.int64)
-        sid = np.argsort(ids)
-        sorted_ids = ids[sid]
-
-        src, dst = np.asarray(edges[0], np.int64), \
-            np.asarray(edges[1], np.int64)
-        eleaves, self.e_tuple = ((None, False) if edge_values is None
-                                 else as_leaves(edge_values))
-        eleaves = [np.asarray(l) for l in eleaves] if eleaves else []
-        pos = np.searchsorted(sorted_ids, src)
-        pos = np.clip(pos, 0, max(0, n - 1))
-        src_idx = sid[pos] if n else pos
-        if src.size and (n == 0
-                         or not np.array_equal(ids[src_idx], src)):
-            raise PregelInputError("edge source not in vertex ids")
-        deg = np.bincount(src_idx, minlength=n) if src.size \
-            else np.zeros(n, np.int64)
-        edev = vdev[src_idx] if src.size else src_idx
-
-        # per-device vertex tables, sorted by id (searchsorted
-        # alignment).  One lexsort by (device, id) gives contiguous
-        # per-device runs — no O(n*ndev) mask scans.
-        vorder = np.lexsort((ids, vdev))
-        vbounds = np.searchsorted(vdev[vorder], np.arange(ndev + 1))
-        vcnt = np.diff(vbounds).astype(np.int32)
-        self.cap_v = layout.round_capacity(int(vcnt.max()) if n else 1)
-        vid = np.full((ndev, self.cap_v), _SENT, np.int64)
-        h_vals = [np.zeros((ndev, self.cap_v) + l.shape[1:], l.dtype)
-                  for l in vleaves]
-        h_act = np.zeros((ndev, self.cap_v), bool)
-        # device-local sorted position of every vertex (for edge gather)
-        local_slot = np.zeros(n, np.int64)
-        local_slot[vorder] = np.arange(n) - vbounds[vdev[vorder]]
+    def _place_messages(self, init_msgs):
+        """The initial messages, routed to their target's device."""
+        if init_msgs is None:
+            return None
+        ndev = self.ndev
+        idst = np.asarray(init_msgs[0], np.int64)
+        ivls, _ = as_leaves(init_msgs[1])
+        ivls = [np.asarray(l) for l in ivls]
+        if not idst.size:
+            return None
+        if len(ivls) != len(self.msg_dtypes):
+            raise PregelInputError(
+                "initial message leaves mismatch: got %d, send "
+                "produces %d" % (len(ivls), len(self.msg_dtypes)))
+        mdev = (phash_np(idst) % np.uint32(ndev)).astype(np.int64)
+        mc = np.bincount(mdev, minlength=ndev)
+        cap_m = layout.round_capacity(int(mc.max() or 1))
+        hm_d = np.full((ndev, cap_m), _SENT, np.int64)
+        hm_v = [np.zeros((ndev, cap_m) + shp, dt)
+                for dt, shp in zip(self.msg_dtypes, self.msg_shapes)]
+        mcnt = np.zeros(ndev, np.int32)
         for d in range(ndev):
-            lo, hi = int(vbounds[d]), int(vbounds[d + 1])
-            c = hi - lo
-            if not c:
-                continue
-            sel = vorder[lo:hi]
-            vid[d, :c] = ids[sel]
-            for hl, l in zip(h_vals, vleaves):
-                hl[d, :c] = l[sel]
-            h_act[d, :c] = act[sel]
-
-        # per-device edge tables, living with their source vertex
-        eorder = np.argsort(edev, kind="stable")
-        ebounds = np.searchsorted(edev[eorder], np.arange(ndev + 1))
-        ecnt = np.diff(ebounds).astype(np.int32)
-        self.cap_e = layout.round_capacity(
-            int(ecnt.max()) if src.size else 1)
-        e_dst = np.full((ndev, self.cap_e), _SENT, np.int64)
-        e_slot = np.zeros((ndev, self.cap_e), np.int32)
-        e_deg = np.ones((ndev, self.cap_e), np.int64)
-        h_evals = [np.zeros((ndev, self.cap_e) + l.shape[1:], l.dtype)
-                   for l in eleaves]
-        for d in range(ndev):
-            lo, hi = int(ebounds[d]), int(ebounds[d + 1])
-            c = hi - lo
-            if not c:
-                continue
-            sel = eorder[lo:hi]
-            e_dst[d, :c] = dst[sel]
-            e_slot[d, :c] = local_slot[src_idx[sel]]
-            e_deg[d, :c] = deg[src_idx[sel]]
-            for hl, l in zip(h_evals, eleaves):
-                hl[d, :c] = l[sel]
-
-        sh = self._sharding()
-        put = lambda a: jax.device_put(a, sh)       # noqa: E731
-        self.vid = put(vid)
-        self.vcnt = put(vcnt)
-        self.values = [put(l) for l in h_vals]
-        self.active = put(h_act)
-        self.e_dst = put(e_dst)
-        self.e_slot = put(e_slot)
-        self.e_deg = put(e_deg)
-        self.e_vals = [put(l) for l in h_evals]
-        self.ecnt = put(ecnt)
-
-        # message leaf specs, discovered by tracing `send` once (the
-        # per-edge/per-vertex structs keep their trailing dims — a
-        # vector vertex state must probe as a vector, or the discovered
-        # message shape collapses to a scalar)
-        e_structs = [jax.ShapeDtypeStruct(l.shape[1:], l.dtype)
-                     for l in eleaves]
-        v_structs = [jax.ShapeDtypeStruct(l.shape[1:], l.dtype)
-                     for l in vleaves]
-        out = jax.eval_shape(
-            lambda sv, ev, dg: self.send(
-                rewrap(list(sv), self.v_tuple),
-                rewrap(list(ev), self.e_tuple) if eleaves else None, dg),
-            tuple(v_structs), tuple(e_structs),
-            jax.ShapeDtypeStruct((), np.int64))
-        m_leaves, self.m_tuple = as_leaves(out)
-        for s in m_leaves:
-            if len(s.shape) > 1:
-                raise PregelInputError("message leaves must be scalars "
-                                       "or 1-D vectors")
-        self.msg_dtypes = [np.dtype(s.dtype) for s in m_leaves]
-        # trailing per-message shape of each leaf: () scalars, or (k,)
-        # sum-vector leaves riding as one rank-2 exchange column
-        self.msg_shapes = [tuple(s.shape) for s in m_leaves]
-
-        # initial messages, routed to their target's device
-        self.init = None
-        if init_msgs is not None:
-            idst = np.asarray(init_msgs[0], np.int64)
-            ivls, _ = as_leaves(init_msgs[1])
-            ivls = [np.asarray(l) for l in ivls]
-            if idst.size:
-                if len(ivls) != len(self.msg_dtypes):
-                    raise PregelInputError(
-                        "initial message leaves mismatch: got %d, send "
-                        "produces %d" % (len(ivls),
-                                         len(self.msg_dtypes)))
-                mdev = (phash_np(idst) % np.uint32(self.ndev)) \
-                    .astype(np.int64)
-                mc = np.bincount(mdev, minlength=ndev)
-                cap_m = layout.round_capacity(int(mc.max() or 1))
-                hm_d = np.full((ndev, cap_m), _SENT, np.int64)
-                hm_v = [np.zeros((ndev, cap_m) + shp, dt)
-                        for dt, shp in zip(self.msg_dtypes,
-                                           self.msg_shapes)]
-                mcnt = np.zeros(ndev, np.int32)
-                for d in range(ndev):
-                    m = mdev == d
-                    c = int(m.sum())
-                    mcnt[d] = c
-                    if c:
-                        hm_d[d, :c] = idst[m]
-                        for hl, l in zip(hm_v, ivls):
-                            hl[d, :c] = l[m].astype(hl.dtype)
-                self.init = (put(mcnt), put(hm_d),
-                             [put(l) for l in hm_v])
-
-    def _sharding(self):
-        return NamedSharding(self.mesh, P(AXIS))
+            m = mdev == d
+            c = int(m.sum())
+            mcnt[d] = c
+            if c:
+                hm_d[d, :c] = idst[m]
+                for hl, l in zip(hm_v, ivls):
+                    hl[d, :c] = l[m].astype(hl.dtype)
+        put = self.g.put
+        return (put(mcnt), put(hm_d), [put(l) for l in hm_v])
 
     # ------------------------------------------------------------------
     # the three programs
     # ------------------------------------------------------------------
-    def _jit(self, key, fn, n_in, n_out):
-        if key not in self._compiled:
-            wrapped = _shard_map(fn, self.mesh,
-                                 in_specs=(P(AXIS),) * n_in,
-                                 out_specs=(P(AXIS),) * n_out)
-            self._compiled[key] = jax.jit(wrapped)
-        return self._compiled[key]
+    def _program(self, key, build, n_in, n_out):
+        """The executor's program under `key`, built on a miss: `build`
+        returns the per-device function, which closes over plain values
+        and the user's functions and never over this run."""
+        cache = self.ex._compiled
+        if key in cache:
+            return cache[key]
+        if trace._PLANE is not None:
+            trace.event("compile", "exec", program=key[0],
+                        cap_v=self.g.cap_v, cap_e=self.g.cap_e)
+        wrapped = _shard_map(build(), self.g.mesh,
+                             in_specs=(P(AXIS),) * n_in,
+                             out_specs=(P(AXIS),) * n_out)
+        cache[key] = jax.jit(wrapped)
+        return cache[key]
 
-    def _p_init(self):
+    def _p_init(self, cap_m):
         """Bucketize the user's initial messages by hash(dst)."""
         ndev = self.ndev
         combine = self.combine
         nm = len(self.msg_dtypes)
 
-        def per_device(mcnt, mdst, *mvals):
-            m, d = mcnt[0], mdst[0]
-            vs = [v[0] for v in mvals]
-            kk, vv, counts, offsets = collectives.bucketize_combine(
-                d, vs, m, ndev, None, monoid=combine)
-            out = (counts, offsets, kk) + tuple(vv)
-            return tuple(jnp.expand_dims(o, 0) for o in out)
+        def build():
+            def per_device(mcnt, mdst, *mvals):
+                m, d = mcnt[0], mdst[0]
+                vs = [v[0] for v in mvals]
+                kk, vv, counts, offsets = collectives.bucketize_combine(
+                    d, vs, m, ndev, None, monoid=combine)
+                out = (counts, offsets, kk) + tuple(vv)
+                return tuple(jnp.expand_dims(o, 0) for o in out)
+            return per_device
 
-        return self._jit(("init",), per_device, 2 + nm, 3 + nm)
+        return self._program(("pregel.init", self._sig[4], combine, cap_m),
+                             build, 2 + nm, 3 + nm)
 
     def _p_gen(self):
         """Generate per-edge messages from the current vertex state,
         pre-combine per destination, bucketize by hash(dst)."""
         ndev = self.ndev
-        cap_e = self.cap_e
+        cap_e = self.g.cap_e
         combine = self.combine
         nv = len(self.values)
-        ne = len(self.e_vals)
+        ne = len(self.g.e_vals)
+        send, send_gate = self.send, self.send_gate
+        v_tuple, e_tuple = self.v_tuple, self.g.e_tuple
+        msg_shapes = self.msg_shapes
 
-        def per_device(vcnt, act, edst, eslot, edeg, ecnt, *rest):
-            a = act[0]
-            slot = eslot[0]
-            vals = [v[0] for v in rest[:nv]]
-            evs = [v[0] for v in rest[nv:]]
-            ev = jnp.arange(cap_e) < ecnt[0]
-            sv = [v[slot] for v in vals]
-            if self.send_gate is not None:
-                sa = vals[self.send_gate][slot].astype(bool) & ev
-            else:
-                sa = a[slot] & ev
-            msg = self.send(
-                rewrap(sv, self.v_tuple),
-                rewrap(evs, self.e_tuple) if ne else None, edeg[0])
-            m_leaves, _ = as_leaves(msg)
-            m_leaves = [jnp.broadcast_to(jnp.asarray(l),
-                                         (cap_e,) + shp)
-                        for l, shp in zip(m_leaves, self.msg_shapes)]
-            dstk = jnp.where(sa, edst[0], collectives._sentinel(jnp.int64))
-            packed, cnt = collectives.compact([dstk] + m_leaves, sa)
-            kk, vv, counts, offsets = collectives.bucketize_combine(
-                packed[0], packed[1:], cnt, ndev, None, monoid=combine)
-            out = (counts, offsets, kk) + tuple(vv) + (
-                jnp.reshape(cnt, (1,)),)
-            return tuple(jnp.expand_dims(o, 0) for o in out)
+        def build():
+            def per_device(vcnt, act, edst, eslot, edeg, ecnt, *rest):
+                a = act[0]
+                slot = eslot[0]
+                vals = [v[0] for v in rest[:nv]]
+                evs = [v[0] for v in rest[nv:]]
+                ev = jnp.arange(cap_e) < ecnt[0]
+                sv = [v[slot] for v in vals]
+                if send_gate is not None:
+                    sa = vals[send_gate][slot].astype(bool) & ev
+                else:
+                    sa = a[slot] & ev
+                msg = send(rewrap(sv, v_tuple),
+                           rewrap(evs, e_tuple) if ne else None, edeg[0])
+                m_leaves, _ = as_leaves(msg)
+                m_leaves = [jnp.broadcast_to(jnp.asarray(l),
+                                             (cap_e,) + shp)
+                            for l, shp in zip(m_leaves, msg_shapes)]
+                dstk = jnp.where(sa, edst[0],
+                                 collectives._sentinel(jnp.int64))
+                packed, cnt = collectives.compact([dstk] + m_leaves, sa)
+                kk, vv, counts, offsets = collectives.bucketize_combine(
+                    packed[0], packed[1:], cnt, ndev, None,
+                    monoid=combine)
+                out = (counts, offsets, kk) + tuple(vv) + (
+                    jnp.reshape(cnt, (1,)),)
+                return tuple(jnp.expand_dims(o, 0) for o in out)
+            return per_device
 
         nm = len(self.msg_dtypes)
-        return self._jit(("gen",), per_device, 6 + nv + ne, 4 + nm)
+        return self._program(
+            ("pregel.gen", self._sig, self._send_key, send_gate),
+            build, 6 + nv + ne, 4 + nm)
 
     def _p_step(self, rounds, slot, s_static=None):
         """Deliver combined messages, run the vertex compute, count the
         still-active vertices.  aggregated (if any) is computed from the
         PRE-compute state and psum'd across the mesh."""
-        cap_v = self.cap_v
+        cap_v = self.g.cap_v
         combine = self.combine
         nv = len(self.values)
         nm = len(self.msg_dtypes)
         nleaves = 1 + nm                        # dst key + msg leaves
         static = self.static_superstep
+        compute, aggregator = self.compute, self.aggregator
+        v_tuple, m_tuple = self.v_tuple, self.m_tuple
+        msg_dtypes, msg_shapes = self.msg_dtypes, self.msg_shapes
 
-        def per_device(*all_args):
-            if static:
-                vcnt, vid, act = all_args[:3]
-                rest = all_args[3:]
-                s = s_static
-            else:
-                sstep, vcnt, vid, act = all_args[:4]
-                rest = all_args[4:]
-                s = sstep[0]
-            cnt = vcnt[0]
-            ids = vid[0]
-            a = act[0]
-            vals = [v[0] for v in rest[:nv]]
-            valid_v = jnp.arange(cap_v) < cnt
+        def build():
+            def per_device(*all_args):
+                if static:
+                    vcnt, vid, act = all_args[:3]
+                    rest = all_args[3:]
+                    s = s_static
+                else:
+                    sstep, vcnt, vid, act = all_args[:4]
+                    rest = all_args[4:]
+                    s = sstep[0]
+                cnt = vcnt[0]
+                ids = vid[0]
+                a = act[0]
+                vals = [v[0] for v in rest[:nv]]
+                valid_v = jnp.arange(cap_v) < cnt
 
-            ag = None
-            if self.aggregator is not None:
-                create, amon = self.aggregator
-                a_leaves, a_tuple = as_leaves(
-                    create(rewrap(vals, self.v_tuple)))
-                glob = []
-                for leaf in a_leaves:
-                    ident = monoid_identity(amon, leaf.dtype)
-                    masked = jnp.where(
-                        collectives._bcast(valid_v, leaf), leaf, ident)
-                    glob.append(_axis_reduce(
-                        amon, _local_reduce(amon, masked)))
-                ag = rewrap(glob, a_tuple)
+                ag = None
+                if aggregator is not None:
+                    create, amon = aggregator
+                    a_leaves, a_tuple = as_leaves(
+                        create(rewrap(vals, v_tuple)))
+                    glob = []
+                    for leaf in a_leaves:
+                        ident = monoid_identity(amon, leaf.dtype)
+                        masked = jnp.where(
+                            collectives._bcast(valid_v, leaf), leaf,
+                            ident)
+                        glob.append(_axis_reduce(
+                            amon, _local_reduce(amon, masked)))
+                    ag = rewrap(glob, a_tuple)
 
-            if rounds:
-                cnts = [c[0] for c in rest[nv:nv + rounds]]
-                bufs = rest[nv + rounds:]
-                recvs = []
-                for r in range(rounds):
-                    recvs.append([bufs[r * nleaves + li][0]
-                                  for li in range(nleaves)])
-                flat, mask = collectives.flatten_received(recvs, cnts)
-                uk, uv, _ = collectives.segment_reduce(
-                    flat[0], flat[1:], mask, None, monoid=combine)
-                pos = jnp.clip(jnp.searchsorted(uk, ids), 0,
-                               uk.shape[0] - 1)
-                has = (uk[pos] == ids) & valid_v \
-                    & (ids != collectives._sentinel(jnp.int64))
-                msg = [jnp.where(collectives._bcast(has, u[pos]),
-                                 u[pos],
-                                 monoid_identity(combine, dt))
-                       for u, dt in zip(uv, self.msg_dtypes)]
-            else:
-                has = jnp.zeros(cap_v, bool)
-                msg = [jnp.full((cap_v,) + shp,
-                                monoid_identity(combine, dt), dt)
-                       for dt, shp in zip(self.msg_dtypes,
-                                          self.msg_shapes)]
+                if rounds:
+                    cnts = [c[0] for c in rest[nv:nv + rounds]]
+                    bufs = rest[nv + rounds:]
+                    recvs = []
+                    for r in range(rounds):
+                        recvs.append([bufs[r * nleaves + li][0]
+                                      for li in range(nleaves)])
+                    flat, mask = collectives.flatten_received(recvs,
+                                                              cnts)
+                    uk, uv, _ = collectives.segment_reduce(
+                        flat[0], flat[1:], mask, None, monoid=combine)
+                    pos = jnp.clip(jnp.searchsorted(uk, ids), 0,
+                                   uk.shape[0] - 1)
+                    has = (uk[pos] == ids) & valid_v \
+                        & (ids != collectives._sentinel(jnp.int64))
+                    msg = [jnp.where(collectives._bcast(has, u[pos]),
+                                     u[pos],
+                                     monoid_identity(combine, dt))
+                           for u, dt in zip(uv, msg_dtypes)]
+                else:
+                    has = jnp.zeros(cap_v, bool)
+                    msg = [jnp.full((cap_v,) + shp,
+                                    monoid_identity(combine, dt), dt)
+                           for dt, shp in zip(msg_dtypes, msg_shapes)]
 
-            nv_, na_ = self.compute(
-                rewrap(vals, self.v_tuple),
-                rewrap(msg, self.m_tuple), has, a & valid_v, ag, s)
-            new_leaves, _ = as_leaves(nv_)
-            new_act = jnp.broadcast_to(
-                jnp.asarray(na_, bool), (cap_v,)) & valid_v
-            new_leaves = [
-                jnp.where(collectives._bcast(valid_v, l), l,
-                          jnp.zeros((), l.dtype))
-                for l in [jnp.broadcast_to(l, (cap_v,) + l.shape[1:])
-                          for l in new_leaves]]
-            n_active = jnp.sum(new_act).astype(jnp.int32)
-            out = tuple(new_leaves) + (new_act,
-                                       jnp.reshape(n_active, (1,)))
-            return tuple(jnp.expand_dims(o, 0) for o in out)
+                nv_, na_ = compute(
+                    rewrap(vals, v_tuple), rewrap(msg, m_tuple), has,
+                    a & valid_v, ag, s)
+                new_leaves, _ = as_leaves(nv_)
+                new_act = jnp.broadcast_to(
+                    jnp.asarray(na_, bool), (cap_v,)) & valid_v
+                new_leaves = [
+                    jnp.where(collectives._bcast(valid_v, l), l,
+                              jnp.zeros((), l.dtype))
+                    for l in [jnp.broadcast_to(l,
+                                               (cap_v,) + l.shape[1:])
+                              for l in new_leaves]]
+                n_active = jnp.sum(new_act).astype(jnp.int32)
+                out = tuple(new_leaves) + (new_act,
+                                           jnp.reshape(n_active, (1,)))
+                return tuple(jnp.expand_dims(o, 0) for o in out)
+            return per_device
 
         n_in = (3 if static else 4) + nv + rounds + rounds * nleaves
-        return self._jit(("step", rounds, slot,
-                          s_static if static else None), per_device,
-                         n_in, nv + 2)
+        return self._program(
+            ("pregel.step", self._sig, self._compute_key, rounds, slot,
+             static, s_static if static else None),
+            build, n_in, nv + 2)
 
     # ------------------------------------------------------------------
     def run(self):
+        """The superstep loop.  Every program goes through the
+        executor's _launch and every blocking read through
+        layout.host_read, so a job's launches and reads are counted and
+        under spans: a superstep is its exchange (none on one device),
+        the step program, the read of the active count, the gen program
+        and the read of the message count."""
+        g, ex = self.g, self.ex
         nv = len(self.values)
         nm = len(self.msg_dtypes)
-        sh = self._sharding()
+        tracing = trace._PLANE is not None
         pending = None            # (counts, offsets, kk, vv) bucketized
         total_msgs = 0
         if self.init is not None:
             mcnt, mdst, mvals = self.init
-            outs = self._p_init()(mcnt, mdst, *mvals)
+            outs = ex._launch("pregel.init", self._p_init(mdst.shape[1]),
+                              mcnt, mdst, *mvals)
             pending = (outs[0], outs[1], outs[2], list(outs[3:]))
-            total_msgs = int(np.asarray(
-                jax.device_get(outs[0])).sum())
+            total_msgs = int(layout.host_read(
+                outs[0], site="pregel.init").sum())
 
         s = 0
-        n_active = None
         while s < self.max_superstep:
-            if self.static_superstep:
-                head = [self.vcnt, self.vid, self.active]
-            else:
-                head = [jax.device_put(
-                    np.full((self.ndev,), s, np.int32), sh),
-                    self.vcnt, self.vid, self.active]
-            if pending is not None and total_msgs > 0:
-                counts, offsets, kk, vv = pending
-                recv_rounds, cnt_rounds, slot = self.ex._exchange_all(
-                    [kk] + vv, counts, offsets)
-                rounds = len(recv_rounds)
-                step = self._p_step(rounds, slot, s_static=s)
-                args = head + self.values + list(cnt_rounds)
-                for r in range(rounds):
-                    args.extend(recv_rounds[r])
-            else:
-                step = self._p_step(0, 0, s_static=s)
-                args = head + self.values
-            outs = step(*args)
-            self.values = list(outs[:nv])
-            self.active = outs[nv]
-            n_active = int(np.asarray(
-                jax.device_get(outs[nv + 1])).sum())
+            sp = trace.span("pregel.superstep", "exec", s=s)
+            with sp:
+                if self.static_superstep:
+                    head = [g.vcnt, g.vid, self.active]
+                else:
+                    head = [g.put(np.full((self.ndev,), s, np.int32)),
+                            g.vcnt, g.vid, self.active]
+                rounds = 0
+                if pending is not None and total_msgs > 0:
+                    counts, offsets, kk, vv = pending
+                    recv_rounds, cnt_rounds, slot = ex._exchange_all(
+                        [kk] + vv, counts, offsets)
+                    rounds = len(recv_rounds)
+                    step = self._p_step(rounds, slot, s_static=s)
+                    args = head + self.values + list(cnt_rounds)
+                    for r in range(rounds):
+                        args.extend(recv_rounds[r])
+                else:
+                    step = self._p_step(0, 0, s_static=s)
+                    args = head + self.values
+                outs = ex._launch("pregel.step", step, *args)
+                self.values = list(outs[:nv])
+                self.active = outs[nv]
+                n_active = int(layout.host_read(
+                    outs[nv + 1], site="pregel.active").sum())
 
-            gouts = self._p_gen()(
-                self.vcnt, self.active, self.e_dst, self.e_slot,
-                self.e_deg, self.ecnt, *(self.values + self.e_vals))
-            pending = (gouts[0], gouts[1], gouts[2],
-                       list(gouts[3:3 + nm]))
-            total_msgs = int(np.asarray(
-                jax.device_get(gouts[3 + nm])).sum())
+                gouts = ex._launch(
+                    "pregel.gen", self._p_gen(), g.vcnt, self.active,
+                    g.e_dst, g.e_slot, g.e_deg, g.ecnt,
+                    *(self.values + g.e_vals))
+                pending = (gouts[0], gouts[1], gouts[2],
+                           list(gouts[3:3 + nm]))
+                total_msgs = int(layout.host_read(
+                    gouts[3 + nm], site="pregel.msgs").sum())
+                if tracing:
+                    sp.args.update(active=n_active, msgs=total_msgs,
+                                   rounds=rounds)
             s += 1
+            ex.pregel_supersteps += 1
+            ex.pregel_messages += total_msgs
             logger.debug("superstep %d: active=%d msgs=%d",
                          s, n_active, total_msgs)
             if n_active == 0 and total_msgs == 0:
@@ -453,22 +590,22 @@ class DevicePregel:
         return self._collect()
 
     def _collect(self):
-        """Pull the final state to host, unpad, sort by id."""
-        vid = np.asarray(jax.device_get(self.vid))
-        vcnt = np.asarray(jax.device_get(self.vcnt))
-        vals = [np.asarray(jax.device_get(l)) for l in self.values]
-        act = np.asarray(jax.device_get(self.active))
-        ids, leaves, actv = [], [[] for _ in vals], []
-        for d in range(self.ndev):
-            c = int(vcnt[d])
-            ids.append(vid[d, :c])
-            for i, l in enumerate(vals):
-                leaves[i].append(l[d, :c])
-            actv.append(act[d, :c])
-        ids = np.concatenate(ids) if ids else np.zeros(0, np.int64)
-        order = np.argsort(ids)
-        leaves = [np.concatenate(ls)[order] for ls in leaves]
-        return (ids[order],
-                rewrap(leaves, self.v_tuple),
-                np.concatenate(actv)[order] if actv
-                else np.zeros(0, bool))
+        """The final state to the host, unpadded, in the order of the
+        sorted ids: one `egest` span around its reads."""
+        g = self.g
+        sp = trace.span("egest", "exec", site="pregel.collect")
+        with sp:
+            vid = layout.host_read(g.vid, site="pregel.collect")
+            vcnt = layout.host_read(g.vcnt, site="pregel.collect")
+            vals = [layout.host_read(l, site="pregel.collect")
+                    for l in self.values]
+            act = layout.host_read(self.active, site="pregel.collect")
+            if trace._PLANE is not None:
+                sp.args.update(rows=int(vcnt.sum()), bytes=sum(
+                    int(a.nbytes) for a in [vid, vcnt, act] + vals))
+            keep = (np.arange(g.cap_v) < vcnt[:, None]).reshape(-1)
+            order = np.argsort(vid.reshape(-1)[keep])
+            flat = lambda a: a.reshape(     # noqa: E731
+                (-1,) + a.shape[2:])[keep][order]
+            return (flat(vid), rewrap([flat(l) for l in vals],
+                                      self.v_tuple), flat(act))

@@ -735,6 +735,14 @@ class JAXExecutor:
         # one device, an exact combine): the reduce stage's exchange and
         # reduce program not run, once a store
         self.stores_pre_reduced = 0
+        # device Pregel (backend/tpu/bagel.py; bare `+=`, as
+        # program_launches): supersteps run, the messages their gen
+        # programs reported (edges that sent, before the combine; read
+        # every superstep anyway), and graphs partitioned and put on the
+        # devices (DeviceGraph: once a resident graph, never a run)
+        self.pregel_supersteps = 0
+        self.pregel_messages = 0
+        self.pregel_graph_loads = 0
         # program_key -> does that plan's shuffle write combine on the
         # device (_write_combines' memo, bounded like the program cache)
         self._combines_memo = {}

@@ -21,7 +21,10 @@ Two execution models:
   shard_map programs (hash-dst all_to_all for messages, segment reduce
   for the combine, psum for the aggregator and halting counters); on
   local/process masters an equivalent vectorized numpy loop is the
-  golden model.
+  golden model.  `PregelGraph` is the same contract over a graph that
+  stays loaded: built once, run many times (each run one job of the
+  tpu master's scheduler); `run_pregel` builds one, runs it once and
+  drops it.
 """
 
 import numpy as np
@@ -652,16 +655,110 @@ _NP_REDUCE = {"add": np.sum, "min": np.min,
               "max": np.max, "mul": np.prod}
 
 
+class PregelGraph:
+    """A graph that stays loaded: Pregel runs over it take fresh vertex
+    state and the user's functions, and leave it as they found it.
+
+    ids:     (n,) int array of unique vertex ids
+    edges:   (src_ids, dst_ids) int arrays; each edge lives with its
+             source, messages flow along it to dst
+    edge_values: None, or an array / tuple of arrays over the edges
+
+    On the tpu master the graph is partitioned over the mesh and put on
+    the devices once, here (backend/tpu/bagel.py: DeviceGraph); every
+    `run` is then one job of the scheduler whose programs outlive it, so
+    a second run with the same functions builds and traces nothing.  On
+    the other masters the same object runs the vectorized numpy loop
+    over its host index.  `drop()` frees the device's copy.
+    """
+
+    def __init__(self, ctx, ids, edges, edge_values=None):
+        ctx.start()
+        self.ctx = ctx
+        self._host = _HostGraph(ids, edges, edge_values)
+        self._device = None
+        # why the device holds no copy of a graph that a tpu master was
+        # given: every run's job then records it as its fallback_reason
+        self._load_error = None
+        self._empty = not (self._host.n + self._host.src_idx.size)
+        ex = getattr(ctx.scheduler, "executor", None)
+        if ex is not None and not self._empty:
+            from dpark_tpu.backend.tpu.bagel import DeviceGraph
+            try:
+                self._device = DeviceGraph(ex, self._host)
+            except PregelInputError:
+                raise              # wrong on both paths: surface it
+            except Exception as e:
+                self._load_error = _first_line(e)
+                logger.warning("device Pregel graph not loaded (%s); "
+                               "runs take the host path",
+                               self._load_error)
+
+    def run(self, values, compute, send, combine="add", active=None,
+            initial_messages=None, aggregator=None, max_superstep=80,
+            static_superstep=False, send_gate_leaf=None):
+        """One Pregel run over the loaded graph: run_pregel's arguments
+        of the same names, `values` and `active` in the order of the
+        `ids` the graph was built from.  Returns (ids, values, active)
+        sorted by id."""
+        if combine not in PREGEL_MONOIDS:
+            raise ValueError("combine must be one of %s"
+                             % (PREGEL_MONOIDS,))
+        host = self._host
+        if self._empty:
+            vleaves, v_tuple = as_leaves(values)
+            return (np.zeros(0, np.int64),
+                    rewrap([np.asarray(l)[:0] for l in vleaves], v_tuple),
+                    np.zeros(0, bool))
+
+        def on_host():
+            return host.run(values, compute, send, combine, active,
+                            initial_messages, aggregator, max_superstep,
+                            send_gate_leaf)
+
+        scheduler = self.ctx.scheduler
+        if getattr(scheduler, "executor", None) is None:
+            return on_host()
+        device = self._device
+
+        def on_device():
+            from dpark_tpu.backend.tpu.bagel import DevicePregel
+            return DevicePregel(
+                device, values, compute, send, combine=combine,
+                active=active, initial_messages=initial_messages,
+                aggregator=aggregator, max_superstep=max_superstep,
+                static_superstep=static_superstep,
+                send_gate_leaf=send_gate_leaf).run()
+
+        return scheduler.run_pregel(on_device, on_host,
+                                    self._load_error)
+
+    def drop(self):
+        """Free the device's copy; later runs take the host path and
+        say so."""
+        if self._device is not None:
+            self._device = None
+            self._load_error = "the graph was dropped"
+
+
+def _first_line(e):
+    """An exception as `Type: first line of its message`."""
+    text = str(e).strip().splitlines()
+    return "%s: %s" % (type(e).__name__, text[0][:200] if text else "")
+
+
 def run_pregel(ctx, ids, values, edges, compute, send, combine="add",
                edge_values=None, active=None, initial_messages=None,
                aggregator=None, max_superstep=80,
                static_superstep=False, send_gate_leaf=None):
-    """Vectorized Pregel — the device-native Bagel.
+    """Vectorized Pregel — the device-native Bagel: a PregelGraph
+    built, run once and dropped.
 
     ids:     (n,) int array of unique vertex ids
-    values:  (n,) array or tuple of (n, ...) arrays — vertex state
     edges:   (src_ids, dst_ids) int arrays; each edge lives with its
              source, messages flow along it to dst
+    values:  (n,) array or tuple of (n, ...) arrays — vertex state, in
+             the order of the graph's `ids`
     compute(values, msg, has_msg, active, aggregated, superstep)
              -> (new_values, new_active): applied BLOCKWISE — every
              argument is an array over a whole block of vertices (all of
@@ -693,168 +790,165 @@ def run_pregel(ctx, ids, values, edges, compute, send, combine="add",
     over the device mesh (backend/tpu/bagel.py); other masters use the
     equivalent vectorized numpy loop below (the golden model).
     """
-    if combine not in PREGEL_MONOIDS:
-        raise ValueError("combine must be one of %s" % (PREGEL_MONOIDS,))
-    if np.asarray(ids).shape[0] == 0 \
-            and np.asarray(edges[0]).shape[0] == 0:
+    graph = PregelGraph(ctx, ids, edges, edge_values)
+    try:
+        return graph.run(values, compute, send, combine=combine,
+                         active=active,
+                         initial_messages=initial_messages,
+                         aggregator=aggregator,
+                         max_superstep=max_superstep,
+                         static_superstep=static_superstep,
+                         send_gate_leaf=send_gate_leaf)
+    finally:
+        graph.drop()
+
+
+class _HostGraph:
+    """A graph's host index: the ids sorted, where each given vertex
+    went (`order`), every edge's source as a position among the sorted
+    ids (`src_idx`) and the out-degrees.  The numpy loop runs over it,
+    and the device graph is partitioned from it."""
+
+    def __init__(self, ids, edges, edge_values=None):
+        ids = np.asarray(ids, np.int64)
+        self.n = n = ids.shape[0]
+        self.order = np.argsort(ids)
+        self.ids = ids[self.order]
+        if n > 1 and bool((self.ids[1:] == self.ids[:-1]).any()):
+            raise PregelInputError("vertex ids must be unique")
+        src = np.asarray(edges[0], np.int64)
+        self.dst = np.asarray(edges[1], np.int64)
+        eleaves, self.e_tuple = ((None, False) if edge_values is None
+                                 else as_leaves(edge_values))
+        self.eleaves = [np.asarray(l) for l in eleaves] if eleaves else []
+        src_idx = np.searchsorted(self.ids, src)
+        self.src_idx = np.clip(src_idx, 0, max(0, n - 1))
+        if src.size and (n == 0 or not np.array_equal(
+                self.ids[self.src_idx], src)):
+            raise PregelInputError("edge source not in vertex ids")
+        self.deg = np.bincount(self.src_idx, minlength=n) if src.size \
+            else np.zeros(n, np.int64)
+
+    def run(self, values, compute, send, combine, active,
+            initial_messages, aggregator, max_superstep,
+            send_gate_leaf=None):
+        """Single-host vectorized Pregel: the golden model for the
+        device implementation.  The framework side is pure numpy, but
+        user compute/send may use jnp — whose first call initializes the
+        default jax backend, so honor DPARK_TPU_PLATFORM here too."""
+        from dpark_tpu.utils import apply_platform_override
+        apply_platform_override()
+        ids, n, order = self.ids, self.n, self.order
+        src_idx, dst, deg = self.src_idx, self.dst, self.deg
+        eleaves, e_tuple = self.eleaves, self.e_tuple
+        n_edges = src_idx.size
         vleaves, v_tuple = as_leaves(values)
-        return (np.zeros(0, np.int64),
-                rewrap([np.asarray(l)[:0] for l in vleaves], v_tuple),
-                np.zeros(0, bool))
-    ctx.start()
-    ex = getattr(ctx.scheduler, "executor", None)
-    if ex is not None:
+        vleaves = [np.asarray(l)[order] for l in vleaves]
+        act = np.ones(n, bool) if active is None \
+            else np.asarray(active, bool)[order]
+        # message dtypes AND trailing shapes (leaves may be scalars or
+        # small fixed-size vectors — the sum-vector exchange), discovered
+        # by probing `send` on empty slices (the host twin of the device
+        # path's eval_shape)
         try:
-            from dpark_tpu.backend.tpu.bagel import DevicePregel
-            out = DevicePregel(
-                ex, ids, values, edges, compute, send, combine=combine,
-                edge_values=edge_values, active=active,
-                initial_messages=initial_messages, aggregator=aggregator,
-                max_superstep=max_superstep,
-                static_superstep=static_superstep,
-                send_gate_leaf=send_gate_leaf).run()
-            ctx.scheduler._pregel_device_used = True
-            return out
-        except PregelInputError:
-            raise                  # wrong on both paths: surface it
-        except _NotColumnarizable:
-            raise                  # the host twin would raise it too:
-            #                        let the object fallback run instead
-        except Exception as e:
-            logger.warning("device Pregel unavailable (%s); host path", e)
-            ctx.scheduler._pregel_device_used = False
-    return _pregel_host(ids, values, edges, compute, send, combine,
-                        edge_values, active, initial_messages,
-                        aggregator, max_superstep, send_gate_leaf)
+            probe = send(rewrap([l[:0] for l in vleaves], v_tuple),
+                         rewrap([l[:0] for l in eleaves], e_tuple)
+                         if eleaves else None, deg[:0])
+            m_probe, m_tuple = as_leaves(probe)
+            msg_dtypes = [np.asarray(l).dtype for l in m_probe]
+            msg_shapes = [np.asarray(l).shape[1:] for l in m_probe]
+        except Exception:
+            m_tuple = False
+            msg_dtypes = [np.dtype(np.float64)]
+            msg_shapes = [()]
+
+        def deliver(pdst, pvals):
+            """Combine pending messages per target; unknown targets drop
+            (parity with the object path).  Vector leaves combine
+            elementwise — the per-leaf monoid."""
+            pos = np.searchsorted(ids, pdst)
+            pos = np.clip(pos, 0, max(0, n - 1))
+            known = ids[pos] == pdst
+            pos = pos[known]
+            bufs = []
+            for l in pvals:
+                buf = np.full((n,) + l.shape[1:],
+                              monoid_identity(combine, l.dtype), l.dtype)
+                _NP_COMBINE[combine].at(buf, pos, l[known])
+                bufs.append(buf)
+            has = np.bincount(pos, minlength=n) > 0
+            return bufs, has
+
+        pending = None
+        if initial_messages is not None:
+            idst = np.asarray(initial_messages[0], np.int64)
+            ivls, _ = as_leaves(initial_messages[1])
+            if idst.size and len(ivls) != len(msg_dtypes):
+                raise PregelInputError(
+                    "initial message leaves mismatch: got %d, send "
+                    "produces %d" % (len(ivls), len(msg_dtypes)))
+            pending = (idst, [np.asarray(l, dt)
+                              for l, dt in zip(ivls, msg_dtypes)])
+
+        s = 0
+        while s < max_superstep:
+            aggregated = None
+            if aggregator is not None:
+                create, amon = aggregator
+                a_leaves, a_tuple = as_leaves(
+                    create(rewrap(vleaves, v_tuple)))
+                aggregated = rewrap(
+                    [_NP_REDUCE[amon](np.asarray(l)) for l in a_leaves],
+                    a_tuple)
+
+            if pending is not None and pending[0].size:
+                msg_leaves, has = deliver(*pending)
+            else:
+                msg_leaves = [np.full((n,) + shp,
+                                      monoid_identity(combine, dt), dt)
+                              for dt, shp in zip(msg_dtypes, msg_shapes)]
+                has = np.zeros(n, bool)
+            nv_, na_ = compute(rewrap(vleaves, v_tuple),
+                               rewrap(msg_leaves, m_tuple), has, act,
+                               aggregated, s)
+            new_leaves, _ = as_leaves(nv_)
+            vleaves = [np.broadcast_to(np.asarray(l), (n,) +
+                                       np.asarray(l).shape[1:]).copy()
+                       if np.asarray(l).shape[:1] != (n,)
+                       else np.asarray(l) for l in new_leaves]
+            act = np.broadcast_to(np.asarray(na_, bool), (n,)).copy()
+
+            gate = (np.asarray(vleaves[send_gate_leaf], bool)
+                    if send_gate_leaf is not None else act)
+            src_mask = gate[src_idx] if n_edges else np.zeros(0, bool)
+            if n_edges:
+                msg = send(rewrap([l[src_idx] for l in vleaves], v_tuple),
+                           rewrap([l for l in eleaves], e_tuple)
+                           if eleaves else None,
+                           deg[src_idx])
+                m_leaves, m_tuple = as_leaves(msg)
+                m_leaves = [np.broadcast_to(
+                    np.asarray(l),
+                    (n_edges,) + np.asarray(l).shape[1:]).copy()
+                    for l in m_leaves]
+                pending = (dst[src_mask],
+                           [l[src_mask] for l in m_leaves])
+            else:
+                pending = (np.zeros(0, np.int64), [])
+            n_active = int(act.sum())
+            n_msgs = int(src_mask.sum())
+            s += 1
+            logger.debug("host superstep %d: active=%d msgs=%d",
+                         s, n_active, n_msgs)
+            if n_active == 0 and n_msgs == 0:
+                break
+        return ids, rewrap(vleaves, v_tuple), act
 
 
 def _pregel_host(ids, values, edges, compute, send, combine,
                  edge_values, active, initial_messages, aggregator,
                  max_superstep, send_gate_leaf=None):
-    """Single-host vectorized Pregel: the golden model for the device
-    implementation.  The framework side is pure numpy, but user
-    compute/send may use jnp — whose first call initializes the default
-    jax backend, so honor DPARK_TPU_PLATFORM here too."""
-    from dpark_tpu.utils import apply_platform_override
-    apply_platform_override()
-    ids = np.asarray(ids, np.int64)
-    n = ids.shape[0]
-    if np.unique(ids).shape[0] != n:
-        raise PregelInputError("vertex ids must be unique")
-    order = np.argsort(ids)
-    ids = ids[order]
-    vleaves, v_tuple = as_leaves(values)
-    vleaves = [np.asarray(l)[order] for l in vleaves]
-    act = np.ones(n, bool) if active is None \
-        else np.asarray(active, bool)[order]
-
-    src = np.asarray(edges[0], np.int64)
-    dst = np.asarray(edges[1], np.int64)
-    eleaves, e_tuple = ((None, False) if edge_values is None
-                        else as_leaves(edge_values))
-    eleaves = [np.asarray(l) for l in eleaves] if eleaves else []
-    src_idx = np.searchsorted(ids, src)
-    src_idx = np.clip(src_idx, 0, max(0, n - 1))
-    if src.size and (n == 0
-                     or not np.array_equal(ids[src_idx], src)):
-        raise PregelInputError("edge source not in vertex ids")
-    deg = np.bincount(src_idx, minlength=n) if src.size \
-        else np.zeros(n, np.int64)
-
-    # message dtypes AND trailing shapes (leaves may be scalars or
-    # small fixed-size vectors — the sum-vector exchange), discovered
-    # by probing `send` on empty slices (the host twin of the device
-    # path's eval_shape)
-    try:
-        probe = send(rewrap([l[:0] for l in vleaves], v_tuple),
-                     rewrap([l[:0] for l in eleaves], e_tuple)
-                     if eleaves else None, deg[:0])
-        m_probe, m_tuple = as_leaves(probe)
-        msg_dtypes = [np.asarray(l).dtype for l in m_probe]
-        msg_shapes = [np.asarray(l).shape[1:] for l in m_probe]
-    except Exception:
-        m_tuple = False
-        msg_dtypes = [np.dtype(np.float64)]
-        msg_shapes = [()]
-
-    def deliver(pdst, pvals):
-        """Combine pending messages per target; unknown targets drop
-        (parity with the object path).  Vector leaves combine
-        elementwise — the per-leaf monoid."""
-        pos = np.searchsorted(ids, pdst)
-        pos = np.clip(pos, 0, max(0, n - 1))
-        known = ids[pos] == pdst
-        pos = pos[known]
-        bufs = []
-        for l in pvals:
-            buf = np.full((n,) + l.shape[1:],
-                          monoid_identity(combine, l.dtype), l.dtype)
-            _NP_COMBINE[combine].at(buf, pos, l[known])
-            bufs.append(buf)
-        has = np.bincount(pos, minlength=n) > 0
-        return bufs, has
-
-    pending = None
-    if initial_messages is not None:
-        idst = np.asarray(initial_messages[0], np.int64)
-        ivls, _ = as_leaves(initial_messages[1])
-        if idst.size and len(ivls) != len(msg_dtypes):
-            raise PregelInputError(
-                "initial message leaves mismatch: got %d, send "
-                "produces %d" % (len(ivls), len(msg_dtypes)))
-        pending = (idst, [np.asarray(l, dt)
-                          for l, dt in zip(ivls, msg_dtypes)])
-
-    s = 0
-    while s < max_superstep:
-        aggregated = None
-        if aggregator is not None:
-            create, amon = aggregator
-            a_leaves, a_tuple = as_leaves(
-                create(rewrap(vleaves, v_tuple)))
-            aggregated = rewrap(
-                [_NP_REDUCE[amon](np.asarray(l)) for l in a_leaves],
-                a_tuple)
-
-        if pending is not None and pending[0].size:
-            msg_leaves, has = deliver(*pending)
-        else:
-            msg_leaves = [np.full((n,) + shp,
-                                  monoid_identity(combine, dt), dt)
-                          for dt, shp in zip(msg_dtypes, msg_shapes)]
-            has = np.zeros(n, bool)
-        nv_, na_ = compute(rewrap(vleaves, v_tuple),
-                           rewrap(msg_leaves, m_tuple), has, act,
-                           aggregated, s)
-        new_leaves, _ = as_leaves(nv_)
-        vleaves = [np.broadcast_to(np.asarray(l), (n,) +
-                                   np.asarray(l).shape[1:]).copy()
-                   if np.asarray(l).shape[:1] != (n,)
-                   else np.asarray(l) for l in new_leaves]
-        act = np.broadcast_to(np.asarray(na_, bool), (n,)).copy()
-
-        gate = (np.asarray(vleaves[send_gate_leaf], bool)
-                if send_gate_leaf is not None else act)
-        src_mask = gate[src_idx] if src.size else np.zeros(0, bool)
-        if src.size:
-            msg = send(rewrap([l[src_idx] for l in vleaves], v_tuple),
-                       rewrap([l for l in eleaves], e_tuple)
-                       if eleaves else None,
-                       deg[src_idx])
-            m_leaves, m_tuple = as_leaves(msg)
-            m_leaves = [np.broadcast_to(
-                np.asarray(l),
-                (src.size,) + np.asarray(l).shape[1:]).copy()
-                for l in m_leaves]
-            pending = (dst[src_mask],
-                       [l[src_mask] for l in m_leaves])
-        else:
-            pending = (np.zeros(0, np.int64), [])
-        n_active = int(act.sum())
-        n_msgs = int(src_mask.sum())
-        s += 1
-        logger.debug("host superstep %d: active=%d msgs=%d",
-                     s, n_active, n_msgs)
-        if n_active == 0 and n_msgs == 0:
-            break
-    return ids, rewrap(vleaves, v_tuple), act
+    """The numpy loop over a graph indexed for this one run."""
+    return _HostGraph(ids, edges, edge_values).run(
+        values, compute, send, combine, active, initial_messages,
+        aggregator, max_superstep, send_gate_leaf)

@@ -96,7 +96,8 @@ Span taxonomy (name / cat):
                                        reduce, exchange, minmax,
                                        join_count, join_expand,
                                        wave_sort, wave_prereduce,
-                                       distinct, sample): jit cache
+                                       distinct, sample, pregel.init,
+                                       pregel.step, pregel.gen): jit cache
                                        lookup, argument handling, a
                                        compile if one happens; NOT
                                        device time.
@@ -112,16 +113,45 @@ Span taxonomy (name / cat):
     readback                 "exec"    one blocking device-to-host
                                        read, layout.host_read (args:
                                        site — the caller's literal,
-                                       e.g. exchange.counts; bytes;
+                                       e.g. exchange.counts, or device
+                                       Pregel's pregel.init,
+                                       pregel.active, pregel.msgs and
+                                       pregel.collect; bytes;
                                        wait_s): the host waited wait_s
                                        for the device to produce the
                                        value (block_until_ready), then
                                        the copy
     egest                    "exec"    layout.egest: a result batch to
                                        Python rows (args: rows, bytes);
-                                       its readbacks nest inside
+                                       its readbacks nest inside.  Also
+                                       a Pregel run's final state to
+                                       numpy columns, DevicePregel.
+                                       _collect (args: site —
+                                       pregel.collect; rows, bytes)
     ingest                   "exec"    layout.ingest of a stage's host
-                                       numpy source (args: rows)
+                                       numpy source (args: rows).  Also
+                                       the load of a resident Pregel
+                                       graph, DeviceGraph (args: site —
+                                       pregel.graph; rows: vertices and
+                                       arcs; bytes): under no job
+    pregel.superstep         "exec"    one superstep of device Pregel,
+                                       backend/tpu/bagel.py:
+                                       DevicePregel.run (args: s,
+                                       active, msgs, rounds — the
+                                       exchange's), inside the run's
+                                       stage.exec (source pregel): the
+                                       exchange's launches and reads,
+                                       the step program's launch, the
+                                       read of the active count, the gen
+                                       program's launch and the read of
+                                       the message count.  A run is one
+                                       job: TPUScheduler.run_pregel
+                                       emits job.begin, job, stage.run,
+                                       stage.exec and job.finish as
+                                       runJob does.  The executor counts
+                                       pregel_supersteps,
+                                       pregel_messages (the msgs above,
+                                       summed) and pregel_graph_loads
     sort.sample              "exec"    the read of sortByKey's bounds
                                        sample, JAXExecutor._sample_keys
                                        (args: splits, rows, bytes: the
