@@ -9,16 +9,26 @@ come back to the host loop each superstep.
 Vertex state is columnar — int64 ids, numeric value leaves, bool active
 flags — sharded over the mesh by hash(id), so hash-routed messages land
 on the device that owns their target.  Edges are stored with their
-SOURCE vertex, making message generation a local gather; the per-edge
-messages are pre-combined per destination (the Combiner optimization)
-before the exchange.  The Python superstep loop stays on the host,
-exactly like the reference; everything between two host iterations is
-three jitted shard_map programs plus the count-exchange rounds.
+SOURCE vertex's device, making message generation a local gather; the
+per-edge messages are pre-combined per destination (the Combiner
+optimization) before the exchange.  The Python superstep loop stays on
+the host, exactly like the reference; everything between two host
+iterations is three jitted shard_map programs plus the count-exchange
+rounds.
+
+On ONE device the owner of every destination is the device itself, so
+where a message lands never changes: the load puts the arcs in their
+destination's order once, and a superstep is one gather of the senders'
+rows, `send`, a segmented scan over the load's segments and a read at a
+fixed slot per vertex (`_p_gen_static`, the step program's `delivered`
+form): no sort, no search, no exchange.  Across devices what a device
+receives is a merge of what the others sent, of sizes the frontier
+decides, and the programs are the bucketizing ones.
 
 Two objects: a DeviceGraph is what a graph's load leaves on the devices
-(the vertex-id table, the arcs stored with their source) and lives until
-it is dropped; a DevicePregel is one run over it, with fresh vertex
-state and the user's functions.  The programs are the executor's
+(the vertex-id table, the arcs) and lives until it is dropped; a
+DevicePregel is one run over it, with fresh vertex state and the user's
+functions.  The programs are the executor's
 (`JAXExecutor._compiled`), keyed by all they close over, so a later run
 with the same functions over the same size classes builds none.
 """
@@ -129,14 +139,40 @@ def _specs(leaves):
                  for l in leaves)
 
 
+def _rides_rows(col):
+    kind = np.dtype(col.dtype).kind
+    return col.ndim == 1 and kind in "biuf" and (
+        kind != "f" or col.dtype.itemsize >= 4)
+
+
+def _take_columns(cols, idx):
+    """Rows `idx` of parallel columns, every rank-1 column of flags,
+    integers or whole-word floats in ONE whole-row gather (a gather
+    costs a row of up to 16 words what it costs a word, and
+    collectives.take_rows gathers a column at a time as soon as one is
+    narrower than a word, which a flag is); any other leaf by
+    itself."""
+    rides = [_rides_rows(c) for c in cols]
+    moved = iter(collectives._take_whole_rows(
+        [c for c, r in zip(cols, rides) if r], idx))
+    return [next(moved) if r else c[idx] for c, r in zip(cols, rides)]
+
+
 class DeviceGraph:
     """A graph on the executor's mesh: vertices partitioned by
     hash(id), each device's ids sorted (`vid`, padded with the sentinel;
     `vcnt`), every arc with its source's device (`e_dst`, `e_slot` the
     source's place in that device's table, `e_deg` its out-degree, the
-    edge values; `ecnt`).  Built once from the host index
-    (bagel._HostGraph) and read by every run; a run writes none of
-    it."""
+    edge values; `ecnt`, the padding behind it), in the order given.
+    On one device (`dst_ordered`) the arcs lie in the order of their
+    destination id instead (stable), beside two columns that say where
+    a destination's arcs end: `e_start`, bool[cap_e], set at slot 0,
+    wherever an arc's destination differs from the one before it and at
+    the first padding slot; `v_last`, int32[cap_v], for every vertex
+    slot the slot of the last arc that points at it, or -1 (an arc to
+    no vertex forms a segment that no `v_last` points at).  Built once
+    from the host index (bagel._HostGraph) and read by every run; a run
+    writes none of it."""
 
     def __init__(self, executor, host):
         self.ex = executor
@@ -153,8 +189,8 @@ class DeviceGraph:
         self._msg_specs = {}
 
         # vertices: ids are sorted, so a stable order by device gives
-        # each device its ids in order (the step program's
-        # searchsorted needs that)
+        # each device its ids in order (the `rounds` form of the step
+        # program searches them, and v_last below is found the same way)
         vdev = (phash_np(ids) % np.uint32(ndev)).astype(np.int64)
         vorder = np.argsort(vdev, kind="stable")
         vbounds = np.searchsorted(vdev[vorder], np.arange(ndev + 1))
@@ -171,11 +207,15 @@ class DeviceGraph:
         vid = np.full(ndev * cap_v, _SENT, np.int64)
         vid[slot] = ids
 
-        # arcs, living with their source vertex
+        # arcs: on every device those of its own vertices, as given;
+        # on ONE device in the order of their destination, which a
+        # superstep then neither sorts nor searches for
         src_idx = host.src_idx
         ne = src_idx.size
         edev = vdev[src_idx] if ne else np.zeros(0, np.int64)
-        eorder = np.argsort(edev, kind="stable")
+        self.dst_ordered = ndev == 1
+        eorder = np.argsort(host.dst if self.dst_ordered else edev,
+                            kind="stable")
         ebounds = np.searchsorted(edev[eorder], np.arange(ndev + 1))
         ecnt = np.diff(ebounds).astype(np.int32)
         self.cap_e = cap_e = layout.round_capacity(
@@ -196,15 +236,31 @@ class DeviceGraph:
             h_evals.append(hl.reshape((ndev, cap_e) + l.shape[1:]))
         self.e_specs = _specs(host.eleaves)
 
+        statics = []
+        if self.dst_ordered:
+            d = e_dst[:ne]                          # non-decreasing
+            e_start = np.zeros(cap_e, bool)
+            e_start[0] = True
+            e_start[1:ne] = d[1:] != d[:-1]
+            e_start[ne:ne + 1] = True    # padding joins no real segment
+            v_last = np.full(cap_v, -1, np.int32)
+            if ne:
+                last = np.searchsorted(d, vid, "right") - 1
+                hit = (last >= 0) & (d[np.maximum(last, 0)] == vid)
+                v_last[hit] = last[hit]
+            statics = [e_start.reshape(1, cap_e), v_last.reshape(1, cap_v)]
+
         tables = [vid.reshape(ndev, cap_v), vcnt,
                   e_dst.reshape(ndev, cap_e), e_slot.reshape(ndev, cap_e),
-                  e_deg.reshape(ndev, cap_e), ecnt] + h_evals
+                  e_deg.reshape(ndev, cap_e), ecnt] + statics + h_evals
         with executor._mesh_lock, \
                 trace.span("ingest", "exec", rows=n + ne,
                            bytes=sum(int(t.nbytes) for t in tables),
                            site="pregel.graph"):
             (self.vid, self.vcnt, self.e_dst, self.e_slot, self.e_deg,
-             self.ecnt, *self.e_vals) = [self.put(t) for t in tables]
+             self.ecnt, *rest) = [self.put(t) for t in tables]
+        self.e_start, self.v_last = rest[:2] if statics else (None, None)
+        self.e_vals = rest[len(statics):]
         executor.pregel_graph_loads += 1
 
     def put(self, arr):
@@ -342,7 +398,7 @@ class DevicePregel:
         return (put(mcnt), put(hm_d), [put(l) for l in hm_v])
 
     # ------------------------------------------------------------------
-    # the three programs
+    # the programs: init, gen (bucketizing, or static on one device), step
     # ------------------------------------------------------------------
     def _program(self, key, build, n_in, n_out):
         """The executor's program under `key`, built on a miss: `build`
@@ -379,6 +435,84 @@ class DevicePregel:
         return self._program(("pregel.init", self._sig[4], combine, cap_m),
                              build, 2 + nm, 3 + nm)
 
+    def _edge_messages(self):
+        """`send` over every arc slot: (the senders' state leaves, the
+        edge value leaves, the degrees) -> the message leaves, each
+        (cap_e, ...)."""
+        cap_e = self.g.cap_e
+        has_e = bool(self.g.e_vals)
+        send = self.send
+        v_tuple, e_tuple = self.v_tuple, self.g.e_tuple
+        msg_shapes = self.msg_shapes
+
+        def messages(sv, evs, deg):
+            msg = send(rewrap(sv, v_tuple),
+                       rewrap(evs, e_tuple) if has_e else None, deg)
+            m_leaves, _ = as_leaves(msg)
+            return [jnp.broadcast_to(jnp.asarray(l), (cap_e,) + shp)
+                    for l, shp in zip(m_leaves, msg_shapes)]
+        return messages
+
+    def _p_gen_static(self):
+        """The gen program over arcs in their destination's order (one
+        device): the senders' state by ONE whole-row gather, `send`, a
+        segmented scan over the load's segments (`e_start`) that leaves
+        every destination's combined message and whether any of its
+        senders sent at the segment's last slot, and a read of those at
+        `v_last`: per-vertex message columns and `has`, as the step
+        program's `delivered` form takes them.  No sort, no search."""
+        cap_v, cap_e = self.g.cap_v, self.g.cap_e
+        op = collectives._MONOID_OPS[self.combine]
+        idents = [monoid_identity(self.combine, dt)
+                  for dt in self.msg_dtypes]
+        nv = len(self.values)
+        nm = len(self.msg_dtypes)
+        send_gate = self.send_gate
+        messages = self._edge_messages()
+
+        def merge(first, later):
+            return [op(x, y) for x, y in zip(first[:-1], later[:-1])] \
+                + [first[-1] | later[-1]]
+
+        def build():
+            def per_device(vcnt, act, vlast, estart, eslot, edeg, ecnt,
+                           *rest):
+                vals = [v[0] for v in rest[:nv]]
+                evs = [v[0] for v in rest[nv:]]
+                ev = jnp.arange(cap_e) < ecnt[0]
+                if send_gate is not None:
+                    sv = _take_columns(vals, eslot[0])
+                    sa = sv[send_gate].astype(bool) & ev
+                else:
+                    gate, *sv = _take_columns([act[0]] + vals, eslot[0])
+                    sa = gate & ev
+                m_leaves = [
+                    jnp.where(collectives._bcast(sa, l), l, ident)
+                    for l, ident in zip(messages(sv, evs, edeg[0]),
+                                        idents)]
+                # a leaf at a time: cap_v reads of cap_e-slot columns,
+                # which stacked into rows first would be written out
+                # whole (and the TPU compiler then carries the scan in
+                # the rows' padded [cap_e, 1] layout: 128 times the
+                # bytes, more than the chip has)
+                last = jnp.maximum(vlast[0], 0)
+                *combined, sent = [
+                    leaf[last] for leaf in collectives.segmented_combine(
+                        estart[0], m_leaves + [sa], merge)]
+                has = (vlast[0] >= 0) & sent \
+                    & (jnp.arange(cap_v) < vcnt[0])
+                msg = [jnp.where(collectives._bcast(has, l), l, ident)
+                       for l, ident in zip(combined, idents)]
+                cnt = jnp.sum(sa).astype(jnp.int32)
+                out = tuple(msg) + (has, jnp.reshape(cnt, (1,)))
+                return tuple(jnp.expand_dims(o, 0) for o in out)
+            return per_device
+
+        return self._program(
+            ("pregel.gen", self._sig, self._send_key, send_gate,
+             "static"),
+            build, 7 + nv + len(self.g.e_vals), nm + 2)
+
     def _p_gen(self):
         """Generate per-edge messages from the current vertex state,
         pre-combine per destination, bucketize by hash(dst)."""
@@ -387,9 +521,8 @@ class DevicePregel:
         combine = self.combine
         nv = len(self.values)
         ne = len(self.g.e_vals)
-        send, send_gate = self.send, self.send_gate
-        v_tuple, e_tuple = self.v_tuple, self.g.e_tuple
-        msg_shapes = self.msg_shapes
+        send_gate = self.send_gate
+        messages = self._edge_messages()
 
         def build():
             def per_device(vcnt, act, edst, eslot, edeg, ecnt, *rest):
@@ -403,12 +536,7 @@ class DevicePregel:
                     sa = vals[send_gate][slot].astype(bool) & ev
                 else:
                     sa = a[slot] & ev
-                msg = send(rewrap(sv, v_tuple),
-                           rewrap(evs, e_tuple) if ne else None, edeg[0])
-                m_leaves, _ = as_leaves(msg)
-                m_leaves = [jnp.broadcast_to(jnp.asarray(l),
-                                             (cap_e,) + shp)
-                            for l, shp in zip(m_leaves, msg_shapes)]
+                m_leaves = messages(sv, evs, edeg[0])
                 dstk = jnp.where(sa, edst[0],
                                  collectives._sentinel(jnp.int64))
                 packed, cnt = collectives.compact([dstk] + m_leaves, sa)
@@ -425,10 +553,14 @@ class DevicePregel:
             ("pregel.gen", self._sig, self._send_key, send_gate),
             build, 6 + nv + ne, 4 + nm)
 
-    def _p_step(self, rounds, slot, s_static=None):
+    def _p_step(self, rounds, slot, s_static=None, delivered=False):
         """Deliver combined messages, run the vertex compute, count the
         still-active vertices.  aggregated (if any) is computed from the
-        PRE-compute state and psum'd across the mesh."""
+        PRE-compute state and psum'd across the mesh.  Three forms: no
+        message pending (`rounds` 0), `rounds` exchange rounds of
+        bucketized messages to reduce and look the ids up in, and
+        `delivered`: the static gen program's per-vertex message columns
+        and `has`, taken as they are."""
         cap_v = self.g.cap_v
         combine = self.combine
         nv = len(self.values)
@@ -470,7 +602,10 @@ class DevicePregel:
                             amon, _local_reduce(amon, masked)))
                     ag = rewrap(glob, a_tuple)
 
-                if rounds:
+                if delivered:
+                    msg = [m[0] for m in rest[nv:nv + nm]]
+                    has = rest[nv + nm][0]
+                elif rounds:
                     cnts = [c[0] for c in rest[nv:nv + rounds]]
                     bufs = rest[nv + rounds:]
                     recvs = []
@@ -513,9 +648,11 @@ class DevicePregel:
                 return tuple(jnp.expand_dims(o, 0) for o in out)
             return per_device
 
-        n_in = (3 if static else 4) + nv + rounds + rounds * nleaves
+        n_in = (3 if static else 4) + nv + (
+            nm + 1 if delivered else rounds + rounds * nleaves)
         return self._program(
-            ("pregel.step", self._sig, self._compute_key, rounds, slot,
+            ("pregel.step", self._sig, self._compute_key,
+             "delivered" if delivered else rounds, slot,
              static, s_static if static else None),
             build, n_in, nv + 2)
 
@@ -526,12 +663,17 @@ class DevicePregel:
         layout.host_read, so a job's launches and reads are counted and
         under spans: a superstep is its exchange (none on one device),
         the step program, the read of the active count, the gen program
-        and the read of the message count."""
+        and the read of the message count.  Over a graph whose arcs are
+        in their destination's order (one device) the gen program hands
+        per-vertex messages straight to the next step program: nothing
+        is exchanged, and `pregel_static_supersteps` counts it."""
         g, ex = self.g, self.ex
         nv = len(self.values)
         nm = len(self.msg_dtypes)
         tracing = trace._PLANE is not None
         pending = None            # (counts, offsets, kk, vv) bucketized
+        delivered = None          # [message columns..., has] a vertex
+        delivery = "static" if g.dst_ordered else "exchange"
         total_msgs = 0
         if self.init is not None:
             mcnt, mdst, mvals = self.init
@@ -551,7 +693,10 @@ class DevicePregel:
                     head = [g.put(np.full((self.ndev,), s, np.int32)),
                             g.vcnt, g.vid, self.active]
                 rounds = 0
-                if pending is not None and total_msgs > 0:
+                if delivered is not None:
+                    step = self._p_step(0, 0, s_static=s, delivered=True)
+                    args = head + self.values + delivered
+                elif pending is not None and total_msgs > 0:
                     counts, offsets, kk, vv = pending
                     recv_rounds, cnt_rounds, slot = ex._exchange_all(
                         [kk] + vv, counts, offsets)
@@ -569,17 +714,25 @@ class DevicePregel:
                 n_active = int(layout.host_read(
                     outs[nv + 1], site="pregel.active").sum())
 
-                gouts = ex._launch(
-                    "pregel.gen", self._p_gen(), g.vcnt, self.active,
-                    g.e_dst, g.e_slot, g.e_deg, g.ecnt,
-                    *(self.values + g.e_vals))
-                pending = (gouts[0], gouts[1], gouts[2],
-                           list(gouts[3:3 + nm]))
+                if g.dst_ordered:
+                    gouts = ex._launch(
+                        "pregel.gen", self._p_gen_static(), g.vcnt,
+                        self.active, g.v_last, g.e_start, g.e_slot,
+                        g.e_deg, g.ecnt, *(self.values + g.e_vals))
+                    delivered = list(gouts[:nm + 1])
+                    ex.pregel_static_supersteps += 1
+                else:
+                    gouts = ex._launch(
+                        "pregel.gen", self._p_gen(), g.vcnt, self.active,
+                        g.e_dst, g.e_slot, g.e_deg, g.ecnt,
+                        *(self.values + g.e_vals))
+                    pending = (gouts[0], gouts[1], gouts[2],
+                               list(gouts[3:3 + nm]))
                 total_msgs = int(layout.host_read(
-                    gouts[3 + nm], site="pregel.msgs").sum())
+                    gouts[-1], site="pregel.msgs").sum())
                 if tracing:
                     sp.args.update(active=n_active, msgs=total_msgs,
-                                   rounds=rounds)
+                                   rounds=rounds, delivery=delivery)
             s += 1
             ex.pregel_supersteps += 1
             ex.pregel_messages += total_msgs

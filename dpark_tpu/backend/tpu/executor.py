@@ -738,11 +738,14 @@ class JAXExecutor:
         # device Pregel (backend/tpu/bagel.py; bare `+=`, as
         # program_launches): supersteps run, the messages their gen
         # programs reported (edges that sent, before the combine; read
-        # every superstep anyway), and graphs partitioned and put on the
-        # devices (DeviceGraph: once a resident graph, never a run)
+        # every superstep anyway), graphs partitioned and put on the
+        # devices (DeviceGraph: once a resident graph, never a run), and
+        # supersteps whose messages were combined and delivered over the
+        # load's destination order (one device: no sort, no exchange)
         self.pregel_supersteps = 0
         self.pregel_messages = 0
         self.pregel_graph_loads = 0
+        self.pregel_static_supersteps = 0
         # program_key -> does that plan's shuffle write combine on the
         # device (_write_combines' memo, bounded like the program cache)
         self._combines_memo = {}

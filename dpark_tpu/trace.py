@@ -138,7 +138,15 @@ Span taxonomy (name / cat):
                                        backend/tpu/bagel.py:
                                        DevicePregel.run (args: s,
                                        active, msgs, rounds — the
-                                       exchange's), inside the run's
+                                       exchange's, 0 where the
+                                       messages came delivered — and
+                                       delivery: static where this
+                                       superstep's messages are
+                                       combined over the load's
+                                       destination order and read at
+                                       a fixed slot, one device;
+                                       exchange where they are
+                                       bucketized), inside the run's
                                        stage.exec (source pregel): the
                                        exchange's launches and reads,
                                        the step program's launch, the
@@ -151,7 +159,9 @@ Span taxonomy (name / cat):
                                        runJob does.  The executor counts
                                        pregel_supersteps,
                                        pregel_messages (the msgs above,
-                                       summed) and pregel_graph_loads
+                                       summed), pregel_graph_loads and
+                                       pregel_static_supersteps (the
+                                       supersteps of delivery static)
     sort.sample              "exec"    the read of sortByKey's bounds
                                        sample, JAXExecutor._sample_keys
                                        (args: splits, rows, bytes: the
