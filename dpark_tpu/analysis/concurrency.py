@@ -431,6 +431,10 @@ PLANE_SEAMS = (
      "trace._PLANE"),
     ("backend/tpu/__init__.py", "TPUScheduler._run_array_stage",
      "trace._PLANE"),
+    # the query plane's two spans (query.plan, measured here and
+    # emitted by PlannedQuery._job under the job's id; query.finish)
+    ("query/planner.py", "plan_query", "trace._PLANE"),
+    ("query/planner.py", "PlannedQuery._run", "trace._PLANE"),
 )
 
 

@@ -735,6 +735,13 @@ class JAXExecutor:
         # one device, an exact combine): the reduce stage's exchange and
         # reduce program not run, once a store
         self.stores_pre_reduced = 0
+        # the query plane's scans (bare `+=`): rows of a table resident
+        # on the device that a stage program was launched over
+        # (_source_outs: the count read when the table was made,
+        # resident_table), and rows a planned query's Scan evaluated on
+        # the driver in numpy and reported here (note_host_scan)
+        self.scan_rows_device = 0
+        self.scan_rows_host = 0
         # device Pregel (backend/tpu/bagel.py; bare `+=`, as
         # program_launches): supersteps run, the messages their gen
         # programs reported (edges that sent, before the combine; read
@@ -1371,7 +1378,13 @@ class JAXExecutor:
                                  meta["counts"])
             if keyed:
                 self._check_cached_keys(batch)
-            return self._run_narrow(plan, batch)
+            outs = self._run_narrow(plan, batch)
+            table = meta.get("table")
+            if table:
+                # a table was made over this batch (resident_table): its
+                # rows went to a stage program and not to the driver
+                self.scan_rows_device += table["rows"]
+            return outs
         if plan.source[0] == "join":
             dep_a, dep_b = plan.source[1]
             batch = self.device_join_batch(dep_a, dep_b)
@@ -1685,6 +1698,58 @@ class JAXExecutor:
         self._result_bytes += nbytes
         self._evict_hbm(keep_rdd=rdd_id)
 
+    def resident_table(self, rdd_id):
+        """What the query plane needs of a cached RDD to plan over it
+        as a table resident on the device, or None when the RDD is not
+        in the result cache or its records are not flat rows of scalar
+        and byte-string columns.  {"rows", "columns"}: a column is
+        (numpy dtype, (lo, hi)) with the range of an int column's
+        valid rows (None for floats and bools), or for an S<w> column
+        (S dtype, [(lo, hi) a word]).  The ranges are read ONCE, the
+        first time a table is made over the RDD (one program a column
+        and one blocking read, site `table.stats`), and kept with the
+        cached batch: a query plans from them and reads nothing."""
+        meta = self.result_cache.get(rdd_id)
+        if meta is None:
+            return None
+        if "table" not in meta:
+            meta["table"] = self._table_stats(meta)
+        return meta["table"]
+
+    def _table_stats(self, meta):
+        specs = meta["specs"]
+        found = layout.column_groups(meta["treedef"], len(specs))
+        if found is None:
+            outer, groups = meta["treedef"], list(range(len(specs)))
+        else:
+            outer, groups = found
+        flat = jax.tree_util.tree_unflatten(outer, list(range(len(groups))))
+        if flat != tuple(range(len(groups))) or any(
+                shape != () for _, shape in specs):
+            return None
+        ints = [i for i, (dt, _) in enumerate(specs) if dt.kind == "i"]
+        read = layout.host_read(
+            [meta["counts"]] + [layout._masked_minmax(
+                meta["leaves"][i], meta["counts"]) for i in ints],
+            site="table.stats")
+        rows = int(read[0].sum())
+        # an empty table's masked min/max are the type's extremes
+        ranges = {i: (int(r[0]), int(r[1])) if rows else (0, 0)
+                  for i, r in zip(ints, read[1:])}
+        columns = []
+        for g in groups:
+            if layout._is_bytestr(g):
+                columns.append((np.dtype("S%d" % g.width),
+                                [ranges[i] for i in g.words]))
+            else:
+                columns.append((specs[g][0], ranges.get(g)))
+        return {"rows": rows, "columns": columns}
+
+    def note_host_scan(self, rows):
+        """The query plane's driver-side scan (planner._ScanSeg.run)
+        reports the rows it evaluated in numpy."""
+        self.scan_rows_host += rows
+
     def drop_result(self, rdd_id):
         meta = self.result_cache.pop(rdd_id, None)
         if meta:
@@ -1962,6 +2027,7 @@ class JAXExecutor:
                        and self._write_combines(plan))
         if pre_reduced:
             self.stores_pre_reduced += 1
+            leaves = self._trim_combined(leaves, cnts)
         return self._register_shuffle(dep, plan, {
             "leaves": leaves,            # (ndev, cap, ...) dst-sorted
             "counts": cnts,              # (ndev, R)
@@ -1977,6 +2043,31 @@ class JAXExecutor:
             "single_map": (plan.source[0] in ("text", "union")
                            or getattr(plan, "reslice", False)),
         })
+
+    def _trim_combined(self, leaves, counts):
+        """A combined store's leaves keep their INPUT's capacity however
+        few keys the combine left: four groups of 8M rows are 448 MiB
+        of padding.  Where registering them whole would pass
+        conf.SHUFFLE_HBM_BUDGET (and _evict_hbm would spill a store or
+        drop a cached table for that padding), the count is read (one
+        blocking read, site `store.counts`: the host waits for the map
+        program here and not at the egest) and the leaves are cut to the
+        capacity class of the count: every key is packed at the front.
+        Under the budget nothing is read and nothing is cut."""
+        budget = conf.SHUFFLE_HBM_BUDGET * self.ndev - sum(
+            int(l.nbytes) for l in leaves)
+        if self._store_bytes + self._result_bytes <= budget:
+            return leaves
+        release = self._release_unreachable
+        if release is not None:
+            release()       # as _evict_hbm: what nobody can read goes first
+            if self._store_bytes + self._result_bytes <= budget:
+                return leaves
+        rows = int(layout.host_read(counts, site="store.counts").max())
+        cap = layout.round_capacity(rows)
+        if cap >= leaves[0].shape[1]:
+            return leaves
+        return [layout._head(l, cap) for l in leaves]
 
     def _int_col_ranges(self, batch):
         """Exact (lo, hi) Python ints per int64 scalar column of a

@@ -233,7 +233,14 @@ class DparkContext:
     def sql(self, query, /, **tables):
         """Minimal SELECT front over TableRDDs:
         ctx.sql("select region, sum(qty) as q from t group by region",
-                t=my_table)."""
+                t=my_table).
+        Where the query scans is what its table is made of
+        (query/planner.py): part files and driver-resident slices scan
+        on the driver in numpy; a table over a cached RDD that is
+        resident on the device (`ctx.table(rdd.cache(), fields)` after
+        an action has filled the cache) scans, filters and projects
+        inside the stage program that aggregates it; any other RDD is
+        served by the host row chain."""
         from dpark_tpu.table import execute
         return execute(query, tables)
 

@@ -58,9 +58,10 @@ def iter_plan(root):
 
 
 class Scan(Node):
-    """Leaf: a columnar source.  `source` is a TabularRDD (file scan)
-    or a driver-resident RDD with columnarizable slices
-    (ParallelCollection).  The planner's pushdown rules fill `wanted`
+    """Leaf: a columnar source.  `source` is a TabularRDD (file scan),
+    a driver-resident RDD with columnarizable slices
+    (ParallelCollection), or a cached RDD resident on the device
+    (`device` holds its stats).  The planner's pushdown rules fill `wanted`
     (column pruning), `pushed` (vectorized predicates evaluated over
     column batches before any row exists), and `ranges` (chunk-skip
     {col: (lo, hi)} intervals for the footer-stats pruning)."""
@@ -73,6 +74,9 @@ class Scan(Node):
         self.pushed = []            # planner: [(ColumnExpr, vec_fn)]
         self.ranges = None          # planner: {col: (lo, hi)}
         self.derived = []           # planner: [(name, ColumnExpr)]
+        # a cached RDD whose partitions are columns resident on the
+        # device: its load-time stats (TableRDD._resident), else None
+        self.device = None
 
     def describe(self):
         cols = sorted(self.wanted) if self.wanted is not None \

@@ -5,23 +5,43 @@ fixed sequence of rewrite rules — shape normalization, column pruning,
 predicate pushdown (vectorized filters + chunk-skip ranges), string
 dictionary encoding, group-agg and join lowering, and adaptive path
 pricing — and compiles the admitted pipeline onto the shipped device
-machinery:
+machinery.  Where a SCAN runs depends on what its source is, and on
+nothing else (no conf entry, no argument):
 
-  * the SCAN runs as a driver-side columnar pipeline: only `wanted`
-    columns are read from the tabular part files (or columnarized from
-    parallelize slices), whole chunks skip via the v2 footer's min/max
-    stats, and filter predicates / derived columns evaluate as
-    vectorized array programs over column batches — no row tuple ever
-    materializes before the device ingest;
+  * tabular part files and driver-resident parallelize slices scan on
+    the DRIVER, as a columnar pipeline in numpy: only `wanted` columns
+    are read (or columnarized from the slices), whole chunks skip via
+    the v2 footer's min/max stats, and filter predicates / derived
+    columns evaluate as vectorized array programs over column batches
+    (`_ScanSeg.run`); the result is handed to the device by
+    `parallelize` for the group-by or the join;
+  * a cached RDD whose partitions are columns RESIDENT ON THE DEVICE
+    (`TableRDD._resident`: it is in the executor's result cache and its
+    records are flat rows) scans THERE: the same admitted programs
+    become the `filter` and `map` of one narrow stage over the cached
+    RDD (`PlannedQuery._device_chain`), traced by fuse.py like any user
+    lambda, and feed the group-by's shuffle write directly: no numpy
+    pass on the driver, no parallelize, no ingest.  What the rules
+    need of such a table (dtypes, [min, max] a column, rows) was read
+    once, when the table was made (`JAXExecutor.resident_table`).
+    Group-by and plain scans lower; a join over such a table declines.
+
+After the scan:
+
   * GROUP-AGG lowers onto the device exchange: multi-aggregate queries
     ride a reduceByKey whose accumulator merge traces (the PR 3
-    tuple-key combine path), single provable aggregates ride
+    tuple-key combine path) and whose accumulator holds each distinct
+    sum / count / min / max ONCE (`_share_leaves`: avg(x) is sum(x)
+    over the count); single provable aggregates ride
     groupByKey().mapValues(sum/min/max/len) (SegAggOp / the combiner
     rewrite — adapt decision point 4 prices which), and traceable UDAs
     ride the SegMapOp segmented apply (PR 4);
   * equi-JOINs lower onto the PR 3 device join source;
   * string group/join keys (and string passthrough columns crossing
-    the device) ride TokenDict-encoded int64 ids, decoded at egest;
+    the device) ride TokenDict-encoded int64 ids, decoded at egest; a
+    fixed-width byte-string (S<w>) key column of a resident table rides
+    as its int64 words, alone or beside other key columns, and is
+    rebuilt as bytes at egest (`_key_of`, `_key_columns`);
   * result finishing (HAVING, post-aggregate projections, ORDER BY,
     LIMIT) runs at EGEST on the driver with exact host eval semantics
     — result rows are one-per-group / driver-resident by then.
@@ -31,6 +51,8 @@ Every rule records its choice with a reason; host choices surface as
 pre-flight and the runtime records per stage.  Admission is exact:
 anything the rules cannot PROVE equivalent to the host row path
 declines with a reason, and the host object path serves the query.
+With the trace plane on, `plan_query` is the `query.plan` span and the
+egest-side finishing the `query.finish` span (dpark_tpu/trace.py).
 """
 
 import time
@@ -63,54 +85,126 @@ _CLASSIFIED = {"sum": sum, "min": min, "max": max, "count": len}
 # key and the executor's program cache serves warm runs across plan
 # rebuilds.
 
-def _make_pair(nk):
-    """Flat (k1..knk, v) row -> (key, v) with the tuple key repacked."""
-    if nk == 1:
-        def f(rec):
-            return (rec[0], rec[1])
-    else:
-        def f(rec):
-            return (tuple(rec[:nk]), rec[nk])
-    return f
+def _word_ints(value, width):
+    """A host `bytes` value of an S<width> column as the signed 64-bit
+    big-endian words the device holds it in (layout.pack_bytes)."""
+    raw = value.ljust(8 * -(-width // 8), b"\0")
+    return tuple(int.from_bytes(raw[i:i + 8], "big", signed=True)
+                 for i in range(0, len(raw), 8))
 
 
-def _make_create(nk, kinds):
-    """Flat (k..., a...) row -> (key, acc tree): one accumulator leaf
-    per aggregate (sum/min/max: the arg; count: int64 1; avg: the
-    (sum, count) pair)."""
+def _key_nwords(widths):
+    """Key words of a group's key columns: a number is one, an S<w>
+    byte string one a started 8 bytes."""
+    return sum(-(-w // 8) if w else 1 for w in widths)
+
+
+def _key_of(rec, widths):
+    """The group key of a flat (k..., a...) row.  `widths` holds, a key
+    column, 0 for a number or w for an S<w> byte string, whose bytes
+    ride as int64 key words, big-endian as layout.pack_bytes lays them
+    (a layout.ByteStr inside a stage program, `bytes` in a host row):
+    a key is ONE int or a flat tuple of ints, which is what the
+    exchange hashes, orders and compares."""
+    parts = []
+    for k, w in zip(rec, widths):
+        if not w:
+            parts.append(k)
+        elif isinstance(k, bytes):
+            parts.extend(_word_ints(k, w))
+        else:
+            parts.extend(k.words)
+    return parts[0] if len(parts) == 1 else tuple(parts)
+
+
+def _key_columns(key, widths, decode):
+    """The inverse at egest: a collected key back to one value a key
+    column, `bytes` for a byte string (numpy strips an S column's
+    trailing NULs at tolist()) and decode(column, value) for the rest."""
+    words = list(key) if isinstance(key, tuple) else [key]
+    out = []
+    for i, w in enumerate(widths):
+        take = -(-w // 8) if w else 1
+        part, words = words[:take], words[take:]
+        if not w:
+            out.append(decode(i, part[0]))
+            continue
+        raw = b"".join(int(x).to_bytes(8, "big", signed=True)
+                       for x in part)
+        out.append(raw[:w].rstrip(b"\0"))
+    return tuple(out)
+
+
+def _make_pair(widths):
+    """Flat (k1..kn, v) row -> (key, v)."""
+    nk = len(widths)
+
     def f(rec):
-        key = rec[0] if nk == 1 else tuple(rec[:nk])
-        vals = rec[nk:]
-        accs = []
-        vi = 0
-        for kind in kinds:
-            if kind == "count":
-                accs.append(np.int64(1))
-            elif kind == "avg":
-                accs.append((vals[vi], np.int64(1)))
-                vi += 1
-            else:
-                accs.append(vals[vi])
-                vi += 1
-        return (key, tuple(accs))
+        return (_key_of(rec, widths), rec[nk])
     return f
 
 
-def _make_merge(kinds):
-    """Accumulator merge, branchless so the device exchange traces it
-    (min/max via the table layer's jnp.where forms)."""
+def _make_create(widths, leaves):
+    """Flat (k..., a...) row -> (key, accumulator): one leaf for each
+    of `leaves`, (op, index of its argument column or None): the
+    argument for sum / min / max, int64 1 for count.  Aggregates share
+    leaves (_admit_aggs): avg(x) is sum(x) over THE count."""
+    nk = len(widths)
+
+    def f(rec):
+        vals = rec[nk:]
+        return (_key_of(rec, widths),
+                tuple(np.int64(1) if arg is None else vals[arg]
+                      for _op, arg in leaves))
+    return f
+
+
+def _make_merge(ops):
+    """Accumulator merge, a leaf an op of add / min / max, branchless
+    so the device exchange traces it (min/max via the table layer's
+    jnp.where forms)."""
     def f(a, b):
         from dpark_tpu.table import _branchless_max, _branchless_min
         out = []
-        for kind, x, y in zip(kinds, a, b):
-            if kind in ("sum", "count"):
+        for op, x, y in zip(ops, a, b):
+            if op == "add":
                 out.append(x + y)
-            elif kind == "avg":
-                out.append((x[0] + y[0], x[1] + y[1]))
-            elif kind == "min":
+            elif op == "min":
                 out.append(_branchless_min(x, y))
             else:
                 out.append(_branchless_max(x, y))
+        return tuple(out)
+    return f
+
+
+def _make_dev_filter(names, progs):
+    """Record predicate of a table resident on the device: the admitted
+    programs of a Filter over the record's columns by name."""
+    def f(rec):
+        env = dict(zip(names, rec))
+        out = E.evaluate(progs[0], env)
+        for p in progs[1:]:
+            out = out & E.evaluate(p, env)
+        return out
+    return f
+
+
+def _make_dev_project(names, items):
+    """Record projection of a table resident on the device: an output
+    column is the name of an input column or (program, kind)."""
+    def f(rec):
+        env = dict(zip(names, rec))
+        out = []
+        for item in items:
+            if isinstance(item, str):
+                out.append(env[item])
+                continue
+            v = E.evaluate(item[0], env)
+            if item[1] == "f":
+                # what a float column is on the device
+                v = np.float32(v) if isinstance(v, (int, float)) \
+                    else v.astype(np.float32)
+            out.append(v)
         return tuple(out)
     return f
 
@@ -130,25 +224,16 @@ def _make_join_flat(nl, nr):
     return f
 
 
-def _make_group_over(key_idxs, arg_idxs, kinds):
-    """Flat joined row -> (key, acc tree), keys/args picked by index."""
+def _make_group_over(key_idxs, leaves):
+    """Flat joined row -> (key, accumulator), keys picked by index and
+    every leaf's argument by its index in the row."""
     def f(rec):
         if len(key_idxs) == 1:
             key = rec[key_idxs[0]]
         else:
             key = tuple(rec[i] for i in key_idxs)
-        accs = []
-        vi = 0
-        for kind in kinds:
-            if kind == "count":
-                accs.append(np.int64(1))
-            elif kind == "avg":
-                accs.append((rec[arg_idxs[vi]], np.int64(1)))
-                vi += 1
-            else:
-                accs.append(rec[arg_idxs[vi]])
-                vi += 1
-        return (key, tuple(accs))
+        return (key, tuple(np.int64(1) if arg is None else rec[arg]
+                           for _op, arg in leaves))
     return f
 
 
@@ -162,6 +247,34 @@ def _make_pick(idxs):
 # ---------------------------------------------------------------------------
 # plan-time helpers
 # ---------------------------------------------------------------------------
+
+def _share_leaves(kinds, args):
+    """The accumulator of a group's aggregates as shared leaves:
+    (leaves, refs).  A leaf is (sum | count | min | max, its argument
+    or None), each distinct one once; an aggregate refers to its leaf,
+    avg to (its sum, the count): eight aggregates of TPC-H Q1 are six
+    leaves, and what the exchange carries is leaves."""
+    leaves, refs = [], []
+
+    def leaf(op, arg):
+        if (op, arg) not in leaves:
+            leaves.append((op, arg))
+        return leaves.index((op, arg))
+
+    for kind, arg in zip(kinds, args):
+        if kind == "count":
+            refs.append((leaf("count", None),))
+        elif kind == "avg":
+            refs.append((leaf("sum", arg), leaf("count", None)))
+        else:
+            refs.append((leaf(kind, arg),))
+    return tuple(leaves), tuple(refs)
+
+
+def _merge_ops(leaves):
+    return tuple("add" if op in ("sum", "count") else op
+                 for op, _ in leaves)
+
 
 def _std_dtype(dt):
     """The scan's standardized dtype: the host row path materializes
@@ -291,6 +404,11 @@ class _ScanSeg:
         self.dtypes = {}            # final env dtypes
         self.bounds = {}            # final env int bounds
         self._env = None            # run-time cache
+        # the source is a table resident on the device: its load-time
+        # stats (JAXExecutor.resident_table), else None.  The steps
+        # then become the filter and map of a stage program
+        # (PlannedQuery._device_chain); run() is for the other sources
+        self.device = scan.device
 
     # -- plan-time -------------------------------------------------------
     def source_meta(self):
@@ -299,6 +417,15 @@ class _ScanSeg:
         the columnarized slices for in-memory sources."""
         from dpark_tpu.tabular import TabularRDD, read_header
         src = self.scan.source
+        if self.device is not None:
+            # read once, when the table was made: nothing is read here
+            dtypes, ranges = {}, {}
+            for name, (dt, rng) in zip(self.scan.fields,
+                                       self.device["columns"]):
+                dtypes[name] = dt if dt.kind == "S" else _std_dtype(dt)
+                if dt.kind == "i":
+                    ranges[name] = rng
+            return dtypes, ranges, self.device["rows"]
         if isinstance(src, TabularRDD):
             ranges, rows = {}, 0
             seen_stats = {}
@@ -392,6 +519,14 @@ class _ScanSeg:
         return self._raw_cols
 
     # -- run-time --------------------------------------------------------
+    def _note_host_rows(self, n):
+        """Rows this scan evaluated on the driver, reported to the array
+        executor's counter `scan_rows_host` where the master has one."""
+        note = getattr(getattr(self.scan.source.ctx.scheduler, "executor",
+                               None), "note_host_scan", None)
+        if note is not None:
+            note(n)
+
     def run(self, stats=None):
         """Execute the pipeline -> {field: array} (cached: repeated
         actions on one planned query re-use the scanned columns)."""
@@ -405,6 +540,7 @@ class _ScanSeg:
                 for nrows, cols in read_chunks(
                         path, self.wanted, self.skip_ranges,
                         stats=stats):
+                    self._note_host_rows(nrows)
                     env = {}
                     for nm, c in cols.items():
                         a = _std_col(c)
@@ -432,6 +568,7 @@ class _ScanSeg:
         else:
             raw = self._columnarize()
             n = len(next(iter(raw.values()))) if raw else 0
+            self._note_host_rows(n)
             env = {k: raw[k] for k in (self.wanted or raw)}
             if stats is not None:
                 stats.setdefault("columns_read", set()).update(env)
@@ -445,8 +582,8 @@ class _ScanSeg:
         for kind, items in self.steps:
             if kind == "filter":
                 mask = None
-                for fn in items:
-                    m = fn(env)
+                for ve in items:
+                    m = ve.fn(env)
                     mask = m if mask is None else mask & m
                 env = {k: v[mask] for k, v in env.items()}
                 n = int(mask.sum())
@@ -456,7 +593,7 @@ class _ScanSeg:
                     if spec[0] == "pass":
                         out[name] = env[spec[1]]
                     else:
-                        r = spec[1](env)
+                        r = spec[1].fn(env)
                         if np.ndim(r) == 0:
                             r = np.full(n, r)
                         out[name] = r
@@ -491,6 +628,9 @@ class PlannedQuery:
         self._out_fields = None
         self._partial = None        # result-cache partial-merge recipe
         self._cache_offer = None    # result-cache store-back ticket
+        # (ts, dur, args) of plan_query with the trace plane on, until
+        # _job emits it under the job's id
+        self._plan_reading = None
 
     # -- bookkeeping -----------------------------------------------------
     def decide(self, rule, op, choice, reason):
@@ -537,15 +677,49 @@ class PlannedQuery:
         if self._rows_cache is not None or has_filter \
                 or self._partial is not None:
             return len(self.rows())
-        if self.mode == "scan":
+        if self.mode == "scan" and self.segs[0].device is None:
             env = self.segs[0].run(self.scan_stats)
             return len(next(iter(env.values()))) if env else 0
-        return self._build_rdd().count()
+        return self._job(lambda rdd: rdd.count())[0]
 
     # -- execution -------------------------------------------------------
-    def _run(self):
-        t0 = time.time()
+    def _job(self, action):
+        """One action over the lowered RDD: the query's job.  Returns
+        (what the action returned, the id of the job this thread opened
+        for it, or None).  With the trace plane on, the reading
+        plan_query left on this query is emitted here, as the
+        `query.plan` span of that job: a plan that leads to no job
+        leaves no span."""
+        rdd = self._build_rdd()
+        # the calling thread's own record (DAGScheduler._tls), not the
+        # history's last, which under concurrent clients is anybody's
+        tls = getattr(self.ctx.scheduler, "_tls", None)
+        before = getattr(tls, "record", None)
+        out = action(rdd)
+        record = getattr(tls, "record", None)
+        job = record["id"] if record is not None \
+            and record is not before else None
+        reading, self._plan_reading = self._plan_reading, None
+        if reading is not None and job is not None:
+            from dpark_tpu import trace
+            trace.emit("query.plan", "query", reading[0], reading[1],
+                       job=job, **reading[2])
+        return out, job
+
+    def _finish(self, raw):
+        """The egest side of a query: the collected rows decoded and
+        finalized (_shape_rows), then HAVING / projections / ORDER BY
+        (_egest).  _run puts it under the `query.finish` span."""
         if self.mode == "scan":
+            rows, fields = [tuple(r) for r in raw], self.segs[0].out
+        else:
+            rows, fields = self._shape_rows(raw)
+        return self._egest(rows, fields)
+
+    def _run(self):
+        from dpark_tpu import trace
+        t0 = time.time()
+        if self.mode == "scan" and self.segs[0].device is None:
             env = self.segs[0].run(self.scan_stats)
             names = self.segs[0].out
             rows = list(zip(*(env[n].tolist()
@@ -553,11 +727,16 @@ class PlannedQuery:
                               and env[n].dtype != object
                               else list(env[n]) for n in names))) \
                 if names else []
-            fields = names
+            rows = self._egest(rows, names)
         else:
-            raw = self._build_rdd().collect()
-            rows, fields = self._shape_rows(raw)
-        rows = self._egest(rows, fields)
+            raw, job = self._job(lambda rdd: rdd.collect())
+            plane = trace._PLANE
+            if plane is not None:
+                with trace.span("query.finish", "query", job=job) as sp:
+                    rows = self._finish(raw)
+                    sp.args["rows"] = len(rows)
+            else:
+                rows = self._finish(raw)
         self._observe("device", (time.time() - t0) * 1e3)
         return rows
 
@@ -606,33 +785,40 @@ class PlannedQuery:
         from dpark_tpu.rdd import Columns
         ctx = self.ctx
         npart = max(1, ctx.default_parallelism)
-        if self.mode == "group":
+        if self.mode == "scan":
+            r = self._device_chain(self.segs[0])
+        elif self.mode == "group":
             seg = self.segs[0]
-            env = seg.run(self.scan_stats)
             g = self._group
-            # decoders key by the OUTPUT field name (what _shape_rows
-            # decodes), not the internal __k*/__a* pipeline names
-            dec_names = list(g["key_names"]) + [None] * (
-                len(g["cols"]) - g["nk"])
-            cols = [self._encoded(env[c], dn or c)
-                    for c, dn in zip(g["cols"], dec_names)]
-            if len(cols) == g["nk"]:
-                # count-only query: no aggregate argument columns —
-                # records still need a value leaf (the count ignores
-                # its content)
-                cols.append(np.ones(len(cols[0]) if cols else 0,
-                                    np.int64))
-            base = ctx.parallelize(Columns(*cols), npart)
-            nk = g["nk"]
+            if seg.device is not None:
+                base = self._device_chain(seg)
+            else:
+                env = seg.run(self.scan_stats)
+                # decoders key by the OUTPUT field name (what
+                # _shape_rows decodes), not the internal __k*/__a*
+                # pipeline names
+                dec_names = list(g["key_names"]) + [None] * (
+                    len(g["cols"]) - g["nk"])
+                cols = [self._encoded(env[c], dn or c)
+                        for c, dn in zip(g["cols"], dec_names)]
+                if len(cols) == g["nk"]:
+                    # count-only query: no aggregate argument columns
+                    # — records still need a value leaf (the count
+                    # ignores its content)
+                    cols.append(np.ones(len(cols[0]) if cols else 0,
+                                        np.int64))
+                base = ctx.parallelize(Columns(*cols), npart)
             if g["lower"] == "classified":
-                r = base.map(_make_pair(nk)).groupByKey(npart) \
+                r = base.map(_make_pair(g["widths"])) \
+                    .groupByKey(npart) \
                     .mapValues(_CLASSIFIED[g["kinds"][0]])
             elif g["lower"] == "uda":
-                r = base.map(_make_pair(nk)).groupByKey(npart) \
-                    .mapValues(g["uda"])
+                r = base.map(_make_pair(g["widths"])) \
+                    .groupByKey(npart).mapValues(g["uda"])
             else:
-                r = base.map(_make_create(nk, g["kinds"])) \
-                    .reduceByKey(_make_merge(g["kinds"]), npart)
+                r = base.map(_make_create(g["widths"], g["leaves"])) \
+                    .reduceByKey(_make_merge(_merge_ops(g["leaves"])),
+                                 npart)
         else:                       # join / join_group
             j = self._join
             sides = []
@@ -657,13 +843,40 @@ class PlannedQuery:
             if self.mode == "join_group":
                 g = self._group
                 flat = flat.map(_make_group_over(
-                    tuple(g["key_idxs"]), tuple(g["arg_idxs"]),
-                    g["kinds"]))
-                r = flat.reduceByKey(_make_merge(g["kinds"]), npart)
+                    tuple(g["key_idxs"]), g["leaves"]))
+                r = flat.reduceByKey(
+                    _make_merge(_merge_ops(g["leaves"])), npart)
             else:
                 r = flat.map(_make_pick(tuple(j["out_idxs"])))
         self._rdd = r
         return r
+
+    def _device_chain(self, seg):
+        """The scan pipeline of a table resident on the device as the
+        narrow chain of ONE stage over the cached RDD: a pick of the
+        columns the query reads, then every admitted Filter as a
+        `filter` and every Project as a `map` of the record, which
+        fuse.py traces like any user lambda.  The functions close over
+        names and programs alone (E.evaluate), so a plan made anew of
+        the same text is the same stage program."""
+        rdd = seg.scan.source
+        names = tuple(seg.scan.fields)
+        wanted = tuple(seg.wanted or names)
+        if wanted != names:
+            rdd = rdd.map(_make_pick(tuple(names.index(c)
+                                           for c in wanted)))
+            names = wanted
+        for kind, items in seg.steps:
+            if kind == "filter":
+                rdd = rdd.filter(_make_dev_filter(
+                    names, tuple(ve.prog for ve in items)))
+                continue
+            rdd = rdd.map(_make_dev_project(names, tuple(
+                spec[1] if spec[0] == "pass"
+                else (spec[1].prog, spec[1].kind)
+                for _, spec in items)))
+            names = tuple(n for n, _ in items)
+        return rdd
 
     def _encoded(self, col, name, dict_=None):
         """Dictionary-encode an object column for the device path (or
@@ -707,24 +920,23 @@ class PlannedQuery:
         out = []
         if self.mode in ("group", "join_group"):
             g = self._group
-            nk = g["nk"]
             key_names = g["key_names"]
+            widths = g["widths"]
             for k, acc in raw:
-                keys = (k,) if nk == 1 else tuple(k)
-                keys = tuple(
-                    self._decode(key_names[i], _normalize(v))
-                    for i, v in enumerate(keys))
+                keys = _key_columns(
+                    k, widths, lambda i, v: self._decode(
+                        key_names[i], _normalize(v)))
                 if g["lower"] in ("classified", "uda"):
                     out.append(keys + (_normalize(acc),))
                     continue
+                acc = _normalize(tuple(acc))
                 vals = []
-                for kind, a in zip(g["kinds"], acc):
-                    a = _normalize(a)
+                for kind, ref in zip(g["kinds"], g["refs"]):
                     if kind == "avg":
-                        s, c = a
+                        s, c = acc[ref[0]], acc[ref[1]]
                         vals.append(s / c if c else None)
                     else:
-                        vals.append(a)
+                        vals.append(acc[ref[0]])
                 out.append(keys + tuple(vals))
             return out, list(g["key_names"]) + list(g["agg_names"])
         # join (no group): rows are already flat in out_idx order
@@ -783,8 +995,27 @@ def plan_query(root, ctx, reuse=True):
     the query (with `.fallbacks` carrying the reasons).  `reuse=False`
     skips the result-cache probe (residual plans must not re-probe)."""
     pq = PlannedQuery(root, ctx)
+    from dpark_tpu import trace
+    plane = trace._PLANE
+    if plane is not None:
+        # no job exists yet: the reading stays with the query until the
+        # job it leads to is open (PlannedQuery._job)
+        t0 = time.time()
+        _plan(pq, reuse)
+        sources = {"device" if seg.device is not None else "files"
+                   if hasattr(seg.scan.source, "files") else "driver"
+                   for seg in pq.segs}
+        pq._plan_reading = (t0, time.time() - t0, {
+            "mode": pq.mode, "source": "+".join(sorted(sources)),
+            "rules": len(pq.decisions)})
+        return pq
+    return _plan(pq, reuse)
+
+
+def _plan(pq, reuse):
     try:
         _rule_shape(pq)
+        _rule_resident(pq)
         _rule_prune(pq)
         _rule_scan_pipelines(pq)
         if pq.mode in ("join", "join_group"):
@@ -805,6 +1036,24 @@ def plan_query(root, ctx, reuse=True):
                   "planner error: %s" % str(e)[:160])
         pq.ok = False
     return pq
+
+
+def _rule_resident(pq):
+    """A Scan over a table resident on the device plans from what was
+    read when the table was made; the batch itself has to be there
+    still (the executor drops cached results under HBM pressure)."""
+    for seg in pq.segs:
+        if seg.device is None:
+            continue
+        executor = getattr(pq.ctx.scheduler, "executor", None)
+        if executor is None \
+                or seg.scan.source.id not in executor.result_cache_ids():
+            raise _Decline("scan", "table %r is no longer resident on "
+                           "the device" % seg.scan.table_name)
+        pq.decide("scan-resident", "scan", "device",
+                  "table %r is resident on the device (%d rows): its "
+                  "filters and projections are the narrow stage's"
+                  % (seg.scan.table_name, seg.device["rows"]))
 
 
 def _linearize(node):
@@ -868,6 +1117,10 @@ def _shape_join(pq, join):
             raise _Decline("join", "join input is not a scan chain")
         if any(isinstance(o, Sort) for o in ops):
             raise _Decline("sort", "sort below a join stays on host")
+        if leaf.device is not None:
+            raise _Decline("join", "a join over a table resident on "
+                           "the device is not lowered yet (table %r)"
+                           % leaf.table_name)
         sides.append((ops, leaf))
     pq.segs = [_ScanSeg(leaf) for _, leaf in sides]
     pq._shape["side_ops"] = [ops for ops, _ in sides]
@@ -999,6 +1252,7 @@ def _build_pipeline(pq, seg, ops, label):
     dtypes, ranges, nrows = seg.source_meta()
     seg.nrows = nrows
     seg.src_dtypes = dict(dtypes)   # run() casts chunks up to these
+    device = seg.device is not None
     env = {}                        # name -> (dtype, bounds, src | None)
     for c in (seg.wanted or seg.scan.fields):
         env[c] = (dtypes.get(c, np.dtype(object)), ranges.get(c), c)
@@ -1011,13 +1265,13 @@ def _build_pipeline(pq, seg, ops, label):
                 ve, reason = E.vectorize(
                     p, {k: v[0] for k, v in env.items()},
                     {k: v[1] for k, v in env.items() if v[1]},
-                    boolean=True)
+                    boolean=True, device=device)
                 if ve is None:
                     raise _Decline(
                         "filter", "predicate %r stays on the host: %s"
                         % (p.expr, reason))
-                fns.append(ve.fn)
-                if first_filters:
+                fns.append(ve)
+                if first_filters and not device:
                     for col, rng in _skip_bounds(
                             p, set(seg.wanted or ()),
                             {k: v[0] for k, v in env.items()}).items():
@@ -1038,6 +1292,11 @@ def _build_pipeline(pq, seg, ops, label):
             items = []
             nxt = {}
             for name, ce in op.exprs:
+                if ce.columns and not ce.columns <= set(env):
+                    # an output nothing above reads, over a column the
+                    # pruning therefore left out (_refs_of): dropped
+                    # here too; a later reader of it still declines
+                    continue
                 if _is_bare_name(ce):
                     src = ce.tree.body.id
                     if src not in env:
@@ -1048,18 +1307,22 @@ def _build_pipeline(pq, seg, ops, label):
                     continue
                 ve, reason = E.vectorize(
                     ce, {k: v[0] for k, v in env.items()},
-                    {k: v[1] for k, v in env.items() if v[1]})
+                    {k: v[1] for k, v in env.items() if v[1]},
+                    device=device)
+                if ve is not None and device and ve.kind == "f" \
+                        and pq.mode == "scan":
+                    ve, reason = None, (
+                        "float arithmetic over a table resident on the "
+                        "device is float32 and the rows are the answer")
                 if ve is None:
                     raise _Decline(
                         "project", "expression %r stays on the host: "
                         "%s" % (ce.expr, reason))
-                items.append((name, ("vec", ve.fn)))
+                items.append((name, ("vec", ve)))
                 nxt[name] = (np.dtype(np.int64) if ve.kind == "i"
                              else np.dtype(np.float64), ve.bounds,
                              None)
-            seg.steps.append(("project", [
-                (n, s if s[0] == "pass" else ("vec", s[1]))
-                for n, s in items]))
+            seg.steps.append(("project", items))
             env = nxt
         else:
             raise _Decline("sort", "sort inside a scan pipeline")
@@ -1089,12 +1352,37 @@ def _rule_scan_pipelines(pq):
             _build_pipeline(pq, pq.segs[si], ops, "scan[%d]" % si)
 
 
-def _key_decline(name, dt):
+def _key_decline(name, dt, device=False):
     if dt.kind == "f":
         return ("float group/join key %r: device hash routing needs "
                 "int keys (floats ride range/sortByKey only)" % name)
+    if dt.kind == "S" and device:
+        return None     # its words are int key columns (_key_of)
     if dt.kind not in "i" and dt != np.dtype(object):
         return "unsupported key dtype %s for %r" % (dt, name)
+    return None
+
+
+def _key_words_ok(seg, bounds, widths, key_srcs):
+    """A resident table's key columns as int key words (_key_of): how
+    many there are, and that no valid row's word is the exchange's
+    padding sentinel, proved from the load-time ranges as ingest proves
+    it of a host batch.  Returns a decline reason or None."""
+    from dpark_tpu import conf
+    if _key_nwords(widths) > int(getattr(conf, "MAX_KEY_LEAVES", 4)):
+        return ("the group key is %d key words (a byte string is one "
+                "a started 8 bytes), over conf.MAX_KEY_LEAVES=%d"
+                % (_key_nwords(widths), conf.MAX_KEY_LEAVES))
+    stats = dict(zip(seg.scan.fields, seg.device["columns"]))
+    for ranges, src in zip(bounds, key_srcs):
+        # a derived int key has its expression's bounds; a bare column
+        # (a number's range, a byte string's a word) its load-time ones
+        if ranges is None and src in stats:
+            ranges = stats[src][1]
+        for rng in ranges if isinstance(ranges, list) else [ranges]:
+            if rng is None or rng[1] >= _I64_MAX:
+                return ("a group key column may hold the device's "
+                        "padding sentinel (no range below 2**63 - 1)")
     return None
 
 
@@ -1113,14 +1401,16 @@ def _rule_lower_group(pq):
                            len(g.keys), conf.MAX_KEY_LEAVES))
     if pq.mode == "group":
         env = seg.env_meta
+        device = seg.device is not None
         extra = []                  # derived key/arg project items
+        widths, key_srcs = [], []
         for name, ce in g.keys:
             cname = "__k%d" % len(key_cols)
             dt, reason = _group_col(pq, seg, env, ce, cname, extra)
             if reason is not None:
                 raise _Decline("group-agg", "group key %r: %s"
                                % (ce.expr, reason))
-            bad = _key_decline(ce.expr, dt)
+            bad = _key_decline(ce.expr, dt, device)
             if bad:
                 if dt == np.dtype(object):
                     encode.append(cname)
@@ -1130,11 +1420,24 @@ def _rule_lower_group(pq):
                 encode.append(cname)
             key_cols.append(cname)
             key_names.append(name)
+            widths.append(dt.itemsize if dt.kind == "S" else 0)
+            # the SOURCE column behind a bare name, through any
+            # passing projections (the load-time stats are by it)
+            key_srcs.append(env[ce.tree.body.id][2]
+                            if _is_bare_name(ce) else None)
+        widths = tuple(widths)
+        if device:
+            bad = _key_words_ok(
+                seg, [env.get(c, (None, None))[1] for c in key_cols],
+                widths, key_srcs)
+            if bad:
+                raise _Decline("group-agg", bad)
+
         def _extra_pop(cname):
             extra[:] = [(n, s) for n, s in extra if n != cname]
             env.pop(cname, None)
 
-        kinds, arg_cols, agg_names, uda = _admit_aggs(
+        kinds, agg_args, arg_cols, agg_names, uda = _admit_aggs(
             pq, g, nrows, lambda ce, nm:
             _group_col(pq, seg, env, ce, nm, extra), _extra_pop)
         if extra:
@@ -1143,24 +1446,36 @@ def _rule_lower_group(pq):
             # __k*/__a* columns
             seg.steps.append(("project", list(extra)))
             seg.out = [n for n, _ in extra]
+        leaves, refs = _share_leaves(kinds, agg_args)
         pq._group = {
             "cols": key_cols + arg_cols, "nk": len(key_cols),
             "kinds": tuple(kinds), "key_names": key_names,
             "agg_names": agg_names, "encode": encode,
+            "widths": widths, "leaves": leaves, "refs": refs,
+            # a resident table always combines on the map side: the
+            # narrow stage's shuffle write is the aggregation
             "lower": ("uda" if uda is not None else
-                      "classified" if _classified_ok(kinds) else
-                      "reduce"),
+                      "classified" if _classified_ok(kinds)
+                      and not device else "reduce"),
             "uda": uda}
         if encode:
             pq.decide("encode-strings", "group-agg", "device",
                       "string group key(s) %s ride dictionary-encoded "
                       "(TokenDict int64 ids, decoded at egest)"
                       % [key_names[key_cols.index(c)] for c in encode])
+        if any(widths):
+            pq.decide("key-words", "group-agg", "device",
+                      "byte-string group key(s) %s ride as int64 key "
+                      "words (%d in all), rebuilt as bytes at egest"
+                      % ([n for n, w in zip(key_names, widths) if w],
+                         _key_nwords(widths)))
         pq.decide("lower-group-agg", "group-agg", "device",
-                  "lowered as %s over the %s-key exchange (aggs: %s)"
+                  "lowered as %s over the %s-key exchange (aggs: %s; "
+                  "%d accumulator leaves)"
                   % (pq._group["lower"],
-                     "tuple" if len(key_cols) > 1 else "scalar",
-                     ",".join(kinds) if kinds else "uda"))
+                     "tuple" if _key_nwords(widths) > 1 else "scalar",
+                     ",".join(kinds) if kinds else "uda",
+                     len(leaves)))
         return
     # -- join_group: keys/args picked from the flat joined row ----------
     j = pq._join
@@ -1180,7 +1495,7 @@ def _rule_lower_group(pq):
             raise _Decline("group-agg", bad)
         key_idxs.append(idx_of[src])
         key_names.append(name)
-    kinds, arg_idxs, agg_names = [], [], []
+    kinds, agg_args, agg_names = [], [], []
     for (name, fn, arg, uda) in g.aggs:
         if uda is not None:
             raise _Decline("group-agg",
@@ -1205,11 +1520,13 @@ def _rule_lower_group(pq):
             if dtypes[src] == np.dtype(object):
                 raise _Decline("group-agg",
                                "string aggregate column %r" % src)
-            arg_idxs.append(idx_of[src])
         kinds.append(fn)
+        agg_args.append(None if fn == "count" else idx_of[src])
         agg_names.append(name)
+    leaves, refs = _share_leaves(kinds, agg_args)
     pq._group = {"nk": len(key_idxs), "kinds": tuple(kinds),
-                 "key_idxs": key_idxs, "arg_idxs": arg_idxs,
+                 "key_idxs": key_idxs, "leaves": leaves, "refs": refs,
+                 "widths": (0,) * len(key_idxs),
                  "key_names": key_names, "agg_names": agg_names,
                  "lower": "reduce", "uda": None}
     pq.decide("lower-group-agg", "group-agg", "device",
@@ -1231,10 +1548,11 @@ def _group_col(pq, seg, env, ce, cname, extra):
         return env[src][0], None
     ve, reason = E.vectorize(
         ce, {k: v[0] for k, v in env.items()},
-        {k: v[1] for k, v in env.items() if v[1]})
+        {k: v[1] for k, v in env.items() if v[1]},
+        device=seg.device is not None)
     if ve is None:
         return None, reason
-    extra.append((cname, ("vec", ve.fn)))
+    extra.append((cname, ("vec", ve)))
     dt = np.dtype(np.int64) if ve.kind == "i" else np.dtype(np.float64)
     env[cname] = (dt, ve.bounds, None)
     return dt, None
@@ -1242,8 +1560,12 @@ def _group_col(pq, seg, env, ce, cname, extra):
 
 def _admit_aggs(pq, g, nrows, admit_col, extra_pop):
     """Aggregate admission for the single-input group: device kinds,
-    derived arg columns, overflow proofs, UDA traceability."""
-    kinds, arg_cols, agg_names = [], [], []
+    derived arg columns (one a distinct argument text: sum(x) and
+    avg(x) read the same column), overflow proofs, UDA traceability.
+    Returns (kinds, the index of each aggregate's argument column or
+    None, the argument columns, names, uda)."""
+    kinds, agg_args, arg_cols, agg_names = [], [], [], []
+    by_text = {}
     uda = None
     for (name, fn, arg, uda_fn) in g.aggs:
         if uda_fn is not None:
@@ -1254,7 +1576,7 @@ def _admit_aggs(pq, g, nrows, admit_col, extra_pop):
             dt, reason = admit_col(arg, cname)
             if reason is not None:
                 raise _Decline("group-agg", "UDA argument: %s" % reason)
-            if dt == np.dtype(object):
+            if dt == np.dtype(object) or dt.kind == "S":
                 raise _Decline("group-agg", "string UDA argument")
             _check_uda(uda_fn, dt)
             arg_cols.append(cname)
@@ -1283,16 +1605,22 @@ def _admit_aggs(pq, g, nrows, admit_col, extra_pop):
                                    "%s" % (arg.expr, reason))
                 extra_pop(cname)
             kinds.append("count")
+            agg_args.append(None)
             agg_names.append(name)
             continue
-        cname = "__a%d" % len(arg_cols)
-        dt, reason = admit_col(arg, cname)
-        if reason is not None:
-            raise _Decline("group-agg", "aggregate %s(%s): %s"
-                           % (fn, arg.expr, reason))
-        if dt == np.dtype(object):
-            raise _Decline("group-agg",
-                           "string aggregate column %r" % arg.expr)
+        shared = by_text.get(arg.expr)
+        cname = "__a%d" % (len(arg_cols) if shared is None else shared[0])
+        if shared is None:
+            dt, reason = admit_col(arg, cname)
+            if reason is not None:
+                raise _Decline("group-agg", "aggregate %s(%s): %s"
+                               % (fn, arg.expr, reason))
+            if dt == np.dtype(object) or dt.kind == "S":
+                raise _Decline("group-agg",
+                               "string aggregate column %r" % arg.expr)
+            shared = by_text[arg.expr] = (len(arg_cols), dt)
+            arg_cols.append(cname)
+        dt = shared[1]
         if fn in ("sum", "avg") and dt.kind == "i":
             # the host folds exact Python ints; the device wraps at
             # int64 — prove the total cannot leave int64
@@ -1309,9 +1637,9 @@ def _admit_aggs(pq, g, nrows, admit_col, extra_pop):
                     % (fn, arg.expr, max(abs(bounds[0]),
                                          abs(bounds[1])), nrows))
         kinds.append(fn)
-        arg_cols.append(cname)
+        agg_args.append(shared[0])
         agg_names.append(name)
-    return kinds, arg_cols, agg_names, uda
+    return kinds, agg_args, arg_cols, agg_names, uda
 
 
 def _arg_bounds(pq, arg, cname):
@@ -1450,7 +1778,7 @@ def _rule_lower_join(pq):
                         raise _Decline(
                             "filter", "post-join predicate %r: %s"
                             % (p.expr, reason))
-                    seg.steps.append(("filter", [ve.fn]))
+                    seg.steps.append(("filter", [ve]))
                     pq.decide("pushdown-predicate", "join", "device",
                               "post-join predicate %r pushed below "
                               "the join into scan[%d]" % (p.expr, si))

@@ -11,7 +11,8 @@ the reference's.
 
 Columnar query plane (ISSUE 13): every DSL call ALSO lowers into a
 logical plan (dpark_tpu/query/) when its source is a columnar scan
-(tabular part files, parallelize slices) and its expressions parse.
+(tabular part files, parallelize slices, or a cached RDD whose columns
+are resident on the device) and its expressions parse.
 Actions (collect/take/count/top) then ask the rule-driven physical
 planner to compile the plan onto the device path — pruned vectorized
 scans, device exchanges for group-by/join, egest-side result finishing
@@ -233,12 +234,18 @@ class TableRDD:
     # -- query-plane lowering -------------------------------------------
     def _scan_plan(self):
         """A Scan node when this table's source is columnar (tabular
-        part files / driver-resident parallelize slices), else None —
-        the host chain then serves every action."""
+        part files / driver-resident parallelize slices / a cached RDD
+        whose partitions are columns resident on the device), else
+        None — the host chain then serves every action."""
         try:
             from dpark_tpu.query.logical import Scan
             from dpark_tpu.rdd import ParallelCollection
             from dpark_tpu.tabular import TabularRDD
+            resident = self._resident()
+            if resident is not None:
+                scan = Scan(self.rdd, self.fields, self.name)
+                scan.device = resident
+                return scan
             if isinstance(self.rdd, TabularRDD):
                 if list(self.fields) == list(self.rdd.wanted):
                     return Scan(self.rdd, self.fields, self.name)
@@ -250,6 +257,21 @@ class TableRDD:
         except Exception as e:
             logger.debug("no scan plan: %s", e)
         return None
+
+    def _resident(self):
+        """What the executor knows of this table's RDD as columns
+        resident on the device (JAXExecutor.resident_table: row count,
+        a dtype and a value range a column, read once and kept with
+        the cached batch), or None: no array executor, the RDD not in
+        its result cache, or records that are not flat rows of as many
+        columns as the table has fields."""
+        executor = getattr(getattr(self.rdd.ctx, "scheduler", None),
+                           "executor", None)
+        describe = getattr(executor, "resident_table", None)
+        found = describe(self.rdd.id) if describe is not None else None
+        if found is None or len(found["columns"]) != len(self.fields):
+            return None
+        return found
 
     def _note_fallback(self, op, reason):
         self._plan_fallbacks.append({"op": op, "reason": reason})
@@ -295,7 +317,13 @@ class TableRDD:
             pq = plan_query(self.plan, self.rdd.ctx,
                             reuse=self._reuse)
         except Exception as e:
-            logger.debug("query planning unavailable: %s", e)
+            # no silent host path: the reason rides the lineage like
+            # any decline (plan_query catches its own rules' errors;
+            # what arrives here is an import or a planner bug)
+            self._note_fallback(
+                "plan", "query planning failed: %s: %s"
+                % (type(e).__name__, (str(e).splitlines() or [""])[0]))
+            self.rdd._query_fallbacks = list(self._plan_fallbacks)
             return None
         if pq.ok:
             self._planned_q = pq

@@ -162,6 +162,38 @@ Span taxonomy (name / cat):
                                        summed), pregel_graph_loads and
                                        pregel_static_supersteps (the
                                        supersteps of delivery static)
+    query.plan               "query"   query/planner.py: plan_query of
+                                       one table action (args: mode —
+                                       scan, group, join or join_group;
+                                       source — device where every Scan
+                                       reads a table resident on the
+                                       device, files for tabular part
+                                       files, driver for driver-resident
+                                       slices; rules — decisions
+                                       recorded); measured before the
+                                       job's id exists, kept on the
+                                       PlannedQuery and emitted by its
+                                       _job under the id of the job the
+                                       action opened; a plan that leads
+                                       to no job of its own (declined,
+                                       answered from a cache, explain())
+                                       leaves no span
+    query.finish             "query"   PlannedQuery._run after its job
+                                       returned: the collected rows
+                                       decoded (key words to bytes,
+                                       dictionary ids to strings),
+                                       averages divided, HAVING /
+                                       projections / ORDER BY evaluated
+                                       (args: rows); stamped with the
+                                       id of the job it finishes.  The
+                                       executor counts scan_rows_device
+                                       (rows of a resident table that
+                                       a stage program was launched
+                                       over, JAXExecutor._source_outs)
+                                       and scan_rows_host (rows
+                                       _ScanSeg.run evaluated on the
+                                       driver in numpy and reported to
+                                       note_host_scan)
     sort.sample              "exec"    the read of sortByKey's bounds
                                        sample, JAXExecutor._sample_keys
                                        (args: splits, rows, bytes: the

@@ -542,7 +542,9 @@ def test_every_host_span_site_is_a_checked_seam():
         ("schedule.py", "DAGScheduler._finish_job"),
         ("backend/tpu/__init__.py", "TPUScheduler._drain_unreachable"),
         ("backend/tpu/__init__.py", "TPUScheduler._adapt_span"),
-        ("backend/tpu/__init__.py", "TPUScheduler._run_array_stage")}
+        ("backend/tpu/__init__.py", "TPUScheduler._run_array_stage"),
+        ("query/planner.py", "plan_query"),
+        ("query/planner.py", "PlannedQuery._run")}
 
 
 @pytest.mark.parametrize("relfile,qualname,dotted", SEAMS)

@@ -2,8 +2,10 @@
 group-by / join trees over seeded int/float/string columns must be
 BIT-IDENTICAL between the device query plan (DPARK_QUERY on) and the
 host object path (DPARK_QUERY off, the pre-plan row path) — on the
-local master and on a 2-device tpu mesh, over both in-memory and
-tabular-file sources.  Float columns are seeded integer-valued so
+local master and on a 2-device tpu mesh, over in-memory and
+tabular-file sources and over a table RESIDENT ON THE DEVICE (ISSUE 39:
+a cached columnar RDD, its string column a fixed-width byte string; the
+scan, filter and projection then run inside the stage program).  Float columns are seeded integer-valued so
 device f64 folds are exact (the documented GROUP_AGG_REWRITE-style
 float caveat is about reassociation, not correctness).
 
@@ -38,12 +40,16 @@ AGG_POOL = ["sum(a) as sa", "count(*) as c", "avg(f) as af",
             "sum(a * 2 + f) as sx", "max(a) as ma"]
 
 
-def build_query(rng):
+def build_query(rng, resident=False):
     """A random DSL program as a list of (op, params), applied
-    identically on both sides."""
+    identically on both sides.  `resident`: only what the planner
+    lowers over a table resident on the device (no join, no float
+    comparison: float32 there), so that every such cell is a planned
+    query and never the host chain."""
     prog = []
     if rng.random() < 0.7:
-        w = rng.choice(WHERES).format(
+        w = rng.choice([w for w in WHERES
+                        if not (resident and w.startswith("f "))]).format(
             c=rng.randint(-20, 20), m=rng.randint(2, 5),
             r=rng.randint(0, 1), j=rng.randint(0, 6))
         prog.append(("where", w))
@@ -51,8 +57,8 @@ def build_query(rng):
         prog.append(("select",
                      ["k", "a * %d + 1 as a" % rng.randint(1, 3),
                       "f", "s"]))
-    shape = rng.choice(["group", "group", "join", "join_group",
-                        "scan"])
+    shape = rng.choice(["group", "group", "scan"] if resident else
+                       ["group", "group", "join", "join_group", "scan"])
     if shape in ("join", "join_group"):
         on = rng.choice(["k", "s"])
         prog.append(("join", on, rng.randint(0, 2 ** 30)))
@@ -72,12 +78,15 @@ def build_query(rng):
     return prog
 
 
-def apply_query(ctx, table, prog):
+def apply_query(ctx, table, prog, as_bytes=False):
+    """`as_bytes`: the string column holds `bytes` (an S<w> column of a
+    resident table), so its literals and the join's dimension do too."""
     t = table
     for step in prog:
         op = step[0]
         if op == "where":
-            t = t.where(step[1])
+            t = t.where(step[1].replace("'w", "b'w") if as_bytes
+                        else step[1])
         elif op == "select":
             t = t.select(*step[1])
         elif op == "join":
@@ -86,8 +95,8 @@ def apply_query(ctx, table, prog):
             if on == "k":
                 dim = [(i, r2.randint(0, 99)) for i in range(13)]
             else:
-                dim = [("w%d" % i, r2.randint(0, 99))
-                       for i in range(7)]
+                dim = [(b"w%d" % i if as_bytes else "w%d" % i,
+                        r2.randint(0, 99)) for i in range(7)]
             dt = ctx.parallelize(dim, 2).asTable([on, "dv"], "dim")
             t = t.join(dt, on=on)
         elif op == "group":
@@ -105,7 +114,10 @@ def _run_cell(master, seed, source):
     from dpark_tpu import DparkContext, conf
     rng = random.Random(seed)
     rows = make_rows(rng, rng.choice([200, 1500]))
-    prog = build_query(rng)
+    as_bytes = source == "resident"
+    prog = build_query(rng, resident=as_bytes)
+    if as_bytes:
+        rows = [r[:3] + (r[3].encode(),) for r in rows]
     ctx = DparkContext(master)
     lctx = DparkContext("local")
     tmpdir = None
@@ -116,6 +128,8 @@ def _run_cell(master, seed, source):
         def table_for(c):
             if source == "tabular":
                 return c.tabular(tmpdir).asTable("t")
+            if source == "resident" and c is ctx:
+                return _resident_table(c, rows)
             return c.parallelize(rows, 4).asTable(FIELDS, "t")
 
         if source == "tabular":
@@ -125,12 +139,14 @@ def _run_cell(master, seed, source):
             write_tabular(os.path.join(tmpdir, "part-00000.tab"),
                           FIELDS.split(), rows, chunk_rows=256)
         conf.QUERY_PLAN = True
-        dev = apply_query(ctx, table_for(ctx), prog)
+        dev = apply_query(ctx, table_for(ctx), prog, as_bytes)
+        if as_bytes:
+            assert dev._planned() is not None, dev.explain()
         got = canonical(dev.collect())
         got_n = dev.count()
         conf.QUERY_PLAN = False
         try:
-            host = apply_query(lctx, table_for(lctx), prog)
+            host = apply_query(lctx, table_for(lctx), prog, as_bytes)
             expect = canonical(host.collect())
             expect_n = host.count()
         finally:
@@ -142,6 +158,31 @@ def _run_cell(master, seed, source):
     finally:
         ctx.stop()
         lctx.stop()
+
+
+def _identity(r):
+    return r
+
+
+def _resident_table(ctx, rows):
+    """The rows as a cached columnar RDD resident on the device: int64,
+    int64, float32 (integer-valued: exact) and S2 columns."""
+    import numpy as np
+    from dpark_tpu import Columns
+    k, a, f, s = zip(*rows)
+    rdd = ctx.parallelize(
+        Columns(np.array(k, np.int64), np.array(a, np.int64),
+                np.array(f, np.float32), np.array(s, "S2")),
+        ctx.default_parallelism).map(_identity).cache()
+    assert rdd.count() == len(rows)
+    t = ctx.table(rdd, FIELDS)
+    assert t.plan is not None and t.plan.device is not None
+    return t
+
+
+@pytest.mark.parametrize("seed", range(10))
+def test_query_parity_resident(seed):
+    _run_cell("tpu:1" if seed % 2 else "tpu:2", 200 + seed, "resident")
 
 
 @pytest.mark.parametrize("seed", range(12))
