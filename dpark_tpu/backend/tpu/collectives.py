@@ -424,15 +424,14 @@ def _bucket_counts(d, nb):
     return jnp.diff(edges).astype(jnp.int32)
 
 
-def bucketize(key, leaves, n, n_dst, dst=None, r=None):
+def bucketize(key, leaves, valid, n_dst, dst=None, r=None):
     """Sort one device's rows by destination partition: the stable
     _lex_sort by `dst` alone, the leaves riding it.
 
     Returns (sorted_leaves, counts[n_dst], offsets[n_dst]).  Invalid rows
-    sort into a sentinel bucket past the end.
+    (`valid` False: past the count, or failed a filter folded into the
+    write) sort into a sentinel bucket past the end.
     """
-    cap = key.shape[0]
-    valid = jnp.arange(cap) < n
     if dst is None:
         dst = hash_dst(key, n_dst, valid, r)
     sorted_ops = _lex_sort((dst,) + tuple(leaves), 1)
@@ -590,22 +589,20 @@ def bucketize_combine(key, val_leaves, n, n_dst, merge_leaves,
     are destination-sorted and combined.
     """
     ks, vv, counts, offsets = bucketize_combine_keys(
-        [key], val_leaves, n, n_dst, merge_leaves, monoid=monoid,
-        dst=dst, r=r)
+        [key], val_leaves, jnp.arange(key.shape[0]) < n, n_dst,
+        merge_leaves, monoid=monoid, dst=dst, r=r)
     return ks[0], vv, counts, offsets
 
 
-def bucketize_combine_keys(key_cols, val_leaves, n, n_dst, merge_leaves,
-                           monoid=None, dst=None, r=None):
+def bucketize_combine_keys(key_cols, val_leaves, valid, n_dst,
+                           merge_leaves, monoid=None, dst=None, r=None):
     """bucketize_combine over a COMPOSITE key: sort one device's rows by
     (destination, k1, ..., kn), merge rows equal in EVERY key column,
     compact.  Returns (key_cols', vals', counts, offsets).  Only key
-    column 0 carries the sentinel on invalid rows — invalid rows sort
-    into the sentinel bucket and are dropped by the keep mask, so the
-    other columns never need guarding."""
+    column 0 carries the sentinel on invalid rows (`valid` False) —
+    invalid rows sort into the sentinel bucket and are dropped by the
+    keep mask, so the other columns never need guarding."""
     key_cols = list(key_cols)
-    cap = key_cols[0].shape[0]
-    valid = jnp.arange(cap) < n
     if dst is None:
         dst = hash_dst_cols(key_cols, n_dst, valid, r)
     ks = [jnp.where(valid, key_cols[0], _sentinel(key_cols[0].dtype))]
@@ -710,7 +707,7 @@ def _bucketize_combine_cols(dst, key_cols, val_leaves, n_dst,
             jnp.cumsum(counts) - counts)
 
 
-def bucketize_combine_rid(rid, key_cols, val_leaves, n, n_dst,
+def bucketize_combine_rid(rid, key_cols, val_leaves, valid, n_dst,
                           merge_leaves, monoid=None):
     """Map-side pre-combine for the spilled-run stream (r > mesh): sort
     one device's rows by (device, rid, k1, ..., kn) — device =
@@ -722,8 +719,6 @@ def bucketize_combine_rid(rid, key_cols, val_leaves, n, n_dst,
     Returns (sorted_leaves=[rid', key cols'...] + vals', counts[n_dst],
     offsets[n_dst]) with rows device-sorted and combined."""
     key_cols = list(key_cols)
-    cap = key_cols[0].shape[0]
-    valid = jnp.arange(cap) < n
     dev = jnp.where(valid, (rid % n_dst).astype(jnp.int32), n_dst)
     rd = jnp.where(valid, rid, _sentinel(rid.dtype))
     ks = [jnp.where(valid, key_cols[0], _sentinel(key_cols[0].dtype))]

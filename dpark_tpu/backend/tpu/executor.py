@@ -72,6 +72,16 @@ def _sort_arg(plan, sorts):
     return "carried+gathered" if wide else "carried"
 
 
+def _filter_arg(ops, writes):
+    """The `filter=` of a `compile` event: `mask` where every filter of
+    the plan folds into its shuffle write's validity mask
+    (fuse.run_ops), `pack` where one compacts, `none` without one."""
+    nf = sum(isinstance(op, fuse.FilterOp) for op in ops)
+    if not nf:
+        return "none"
+    return "mask" if fuse.masked_filters(ops, writes) == nf else "pack"
+
+
 def _order_args(plan):
     """The `dst=`, `key=` and `order=` of a `compile` event, for a
     program that partitions by range or sorts by key (else nothing):
@@ -742,6 +752,10 @@ class JAXExecutor:
         # the driver in numpy and reported here (note_host_scan)
         self.scan_rows_device = 0
         self.scan_rows_host = 0
+        # filters folded into a shuffle write's validity mask
+        # (fuse.run_ops; bare `+=`): a plan's count, at each launch of
+        # its narrow, reduce or spilled-run map program
+        self.filters_masked = 0
         # device Pregel (backend/tpu/bagel.py; bare `+=`, as
         # program_launches): supersteps run, the messages their gen
         # programs reported (edges that sent, before the combine; read
@@ -975,17 +989,17 @@ class JAXExecutor:
                 plan.epi_spec)
 
     @staticmethod
-    def _epilogue_block(epi, lv, n, n_dst, merge_fn, monoid, bounds):
+    def _epilogue_block(epi, lv, valid, n_dst, merge_fn, monoid, bounds):
         """Shared shuffle-write tail: destination assignment (hash or
         range bounds over the LOGICAL partition count r <= mesh size) +
-        bucketize[-combine].  `epi` is _epilogue_params(plan).
-        Composite (tuple) keys occupy the first nk columns:
-        destinations hash over all of them with the pair-extended
-        phash, and the combine merges rows equal in every key
-        column."""
+        bucketize[-combine] of the rows `valid` marks (fuse.run_ops:
+        the count's prefix, and the filters folded into it).  `epi` is
+        _epilogue_params(plan).  Composite (tuple) keys occupy the
+        first nk columns: destinations hash over all of them with the
+        pair-extended phash, and the combine merges rows equal in every
+        key column."""
         nk, r, epi_spec = epi
         k = lv[0]
-        valid = jnp.arange(k.shape[0]) < n
         if epi_spec is not None and epi_spec[0] == "range":
             if nk == 1 and not fuse.epi_unsigned(epi_spec):
                 dst = collectives.range_dst(k, bounds, epi_spec[1],
@@ -1001,11 +1015,11 @@ class JAXExecutor:
                 bytes_width=fuse.epi_bytes_width(epi_spec))
         if merge_fn is not None or monoid is not None:
             k2s, v2, cnts, offs = collectives.bucketize_combine_keys(
-                lv[:nk], lv[nk:], n, n_dst, merge_fn, monoid=monoid,
+                lv[:nk], lv[nk:], valid, n_dst, merge_fn, monoid=monoid,
                 dst=dst, r=r)
         else:
             sorted_lv, cnts, offs = collectives.bucketize(
-                k, lv, n, n_dst, dst=dst, r=r)
+                k, lv, valid, n_dst, dst=dst, r=r)
             k2s, v2 = sorted_lv[:nk], sorted_lv[nk:]
         return (cnts, offs) + tuple(k2s) + tuple(v2)
 
@@ -1042,12 +1056,16 @@ class JAXExecutor:
             merge_fn, monoid = self._merge_probe(plan)
             epi = self._epilogue_params(plan)
         if trace._PLANE is not None:
+            # a filter orders rows only where it packs, and it folds
+            # only ahead of a write, whose own sort is counted anyway
             trace.event("compile", "exec", program="narrow", cap=cap,
                         sig=_plan_sig(plan),
                         combine=_combine_arg(merge_fn, monoid),
                         sort=_sort_arg(plan, epilogue is not None or any(
                             isinstance(op, (fuse.SortOp, fuse.FilterOp))
-                            for op in ops)), **_order_args(plan))
+                            for op in ops)),
+                        filter=_filter_arg(ops, epilogue is not None),
+                        **_order_args(plan))
         in_specs = plan.in_specs
 
         def per_device(counts, *rest):
@@ -1057,12 +1075,11 @@ class JAXExecutor:
             bounds = rest[0][0] if has_bounds else None
             leaves = rest[1:] if has_bounds else rest
             lv = self._widen_entry(in_specs, [l[0] for l in leaves])
-            for op in ops:
-                lv, n = op.apply(lv, n)
+            lv, n, valid = fuse.run_ops(ops, lv, n, epi is not None)
             if epi is None:
                 return (jnp.expand_dims(n, 0),) + tuple(
                     jnp.expand_dims(l, 0) for l in lv)
-            out = self._epilogue_block(epi, lv, n, n_dst, merge_fn,
+            out = self._epilogue_block(epi, lv, valid, n_dst, merge_fn,
                                        monoid, bounds)
             return tuple(jnp.expand_dims(o, 0) for o in out)
 
@@ -1221,7 +1238,9 @@ class JAXExecutor:
             trace.event("compile", "exec", program="reduce", slot=slot,
                         sig=_plan_sig(plan),
                         combine=_combine_arg(merge_fn, monoid),
-                        sort=_sort_arg(plan, True), **_order_args(plan))
+                        sort=_sort_arg(plan, True),
+                        filter=_filter_arg(ops, epilogue is not None),
+                        **_order_args(plan))
 
         def per_device(*args):
             bounds = args[0][0] if has_bounds else None
@@ -1250,13 +1269,12 @@ class JAXExecutor:
                     nk = 1
                 lv = collectives.sort_by_key(flat, nk, unsigned=ordered)
                 n = jnp.sum(mask).astype(jnp.int32)
-            for op in ops:
-                lv, n = op.apply(lv, n)
+            lv, n, valid = fuse.run_ops(ops, lv, n, epi is not None)
             if epi is None:
                 return (jnp.expand_dims(n, 0),) + tuple(
                     jnp.expand_dims(l, 0) for l in lv)
-            out = self._epilogue_block(epi, lv, n, n_dst, out_merge_fn,
-                                       out_monoid, bounds)
+            out = self._epilogue_block(epi, lv, valid, n_dst,
+                                       out_merge_fn, out_monoid, bounds)
             return tuple(jnp.expand_dims(o, 0) for o in out)
 
         n_in = rounds + rounds * nleaves + (1 if has_bounds else 0)
@@ -1438,6 +1456,8 @@ class JAXExecutor:
         args = (batch.counts,) + ((bounds,) if bounds is not None
                                   else ()) + tuple(batch.cols)
         self._capture_cost(plan, jitted, args)
+        self.filters_masked += fuse.masked_filters(
+            plan.ops, plan.epilogue is not None)
         return self._launch("narrow", jitted, *args)
 
     def _capture_cost(self, plan, jitted, args):
@@ -2314,6 +2334,8 @@ class JAXExecutor:
         args = ([bounds] if bounds is not None else []) + list(cnt_rounds)
         for r in range(rounds):
             args.extend(recv_rounds[r])
+        self.filters_masked += fuse.masked_filters(
+            plan.ops, plan.epilogue is not None)
         return self._launch("reduce", reduce_fn, *args)
 
     # ------------------------------------------------------------------
@@ -2866,11 +2888,8 @@ class JAXExecutor:
             bounds = rest[0][0] if has_bounds else None
             leaves = rest[1:] if has_bounds else rest
             lv = self._widen_entry(in_specs, [l[0] for l in leaves])
-            for op in ops:
-                lv, n = op.apply(lv, n)
+            lv, n, valid = fuse.run_ops(ops, lv, n, True)
             k = lv[0]
-            capn = k.shape[0]
-            valid = jnp.arange(capn) < n
             if has_bounds:
                 if nk == 1:
                     rid = collectives.range_dst(k, bounds, ascending,
@@ -2885,17 +2904,17 @@ class JAXExecutor:
             if carry_rid and (merge_fn is not None
                               or monoid is not None):
                 cols, cnts, offs = collectives.bucketize_combine_rid(
-                    rid, lv[:nk], lv[nk:], n, ndev, merge_fn,
+                    rid, lv[:nk], lv[nk:], valid, ndev, merge_fn,
                     monoid=monoid)
             elif carry_rid:
                 dev = jnp.where(valid, rid % ndev,
                                 ndev).astype(jnp.int32)
                 cols, cnts, offs = collectives.bucketize(
-                    k, [rid.astype(jnp.int64)] + lv, n, ndev, dst=dev)
+                    k, [rid.astype(jnp.int64)] + lv, valid, ndev, dst=dev)
             else:
                 dev = jnp.where(valid, rid, ndev).astype(jnp.int32)
                 cols, cnts, offs = collectives.bucketize(
-                    k, lv, n, ndev, dst=dev)
+                    k, lv, valid, ndev, dst=dev)
             out = (cnts, offs) + tuple(cols)
             return tuple(jnp.expand_dims(o, 0) for o in out)
 
@@ -3024,6 +3043,7 @@ class JAXExecutor:
                                           if bounds is not None
                                           else ()) + tuple(batch.cols)
                 self._capture_cost(plan, jitted, args)
+                self.filters_masked += fuse.masked_filters(plan.ops, True)
                 outs = jitted(*args)
                 cnts, offs = outs[0], outs[1]
                 leaves = list(outs[2:])      # [rid +] row leaves

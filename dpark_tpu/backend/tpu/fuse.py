@@ -434,6 +434,60 @@ class FilterOp:
         mask = pred.astype(bool) & (jnp.arange(cap) < n)
         return collectives.compact(leaves, mask)
 
+    def mask(self, leaves, valid):
+        """The filter folded into a shuffle write (run_ops): its
+        predicate ANDed into the write's validity mask, every row left
+        in its slot for the write's own sort to drop."""
+        (pred,) = self._vfn(*leaves)
+        return valid & pred.astype(bool)
+
+
+def _fold_from(ops, writes):
+    """Index of the first filter that folds into the plan's shuffle
+    write: the first FilterOp of the trailing run of FilterOps and
+    MapOps, where the plan `writes` one (len(ops) where none folds).
+    Every other filter packs: a SortOp, SegAggOp or SegMapOp after it,
+    or an egest that reads a prefix, needs the passing rows first."""
+    if not writes:
+        return len(ops)
+    at = len(ops)
+    while at and isinstance(ops[at - 1], (FilterOp, MapOp)):
+        at -= 1
+    while at < len(ops) and not isinstance(ops[at], FilterOp):
+        at += 1
+    return at
+
+
+def masked_filters(ops, writes):
+    """Filters of a plan that run_ops folds into its write's mask."""
+    return sum(isinstance(op, FilterOp)
+               for op in ops[_fold_from(ops, writes):])
+
+
+def run_ops(ops, lv, n, writes):
+    """A stage program's ops over one device's leaves, `n` rows valid;
+    returns (lv, n, valid), `valid` the mask the shuffle write takes
+    (None where the plan `writes` none).  A filter in the trailing run
+    of filters and maps ahead of the write does not compact: the
+    write's sort moves the rows its mask drops behind the kept ones
+    anyway, at the same capacity, so the pack's sort and whole-row
+    gather bought nothing.  Maps after it map every slot, as they map
+    the padding past `n`; the write drops what they made of the
+    failed rows.  Where no filter folds, `valid` is arange(cap) < n
+    after the last op, as the write built it before."""
+    at = _fold_from(ops, writes)
+    for op in ops[:at]:
+        lv, n = op.apply(lv, n)
+    if not writes:
+        return lv, n, None
+    valid = jnp.arange(lv[0].shape[0]) < n
+    for op in ops[at:]:
+        if isinstance(op, FilterOp):
+            valid = op.mask(lv, valid)
+        else:
+            lv, _ = op.apply(lv, n)
+    return lv, n, valid
+
 
 class SegAggOp:
     """groupByKey().mapValues(provable aggregate) consumed ON DEVICE:

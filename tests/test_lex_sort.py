@@ -122,8 +122,8 @@ def test_bucketize_matches_a_stable_argsort(n_dst, wide):
     if wide:
         leaves.append(rng.integers(0, 9, (N, 2)))
     got, counts, offsets = jax.jit(
-        lambda d, *lv: collectives.bucketize(lv[0], list(lv), n, n_dst,
-                                             dst=d))(dst, *leaves)
+        lambda d, *lv: collectives.bucketize(
+            lv[0], list(lv), jnp.arange(N) < n, n_dst, dst=d))(dst, *leaves)
     order = np.argsort(dst, kind="stable")
     _assert_same(got, [leaf[order] for leaf in leaves])
     want = np.bincount(dst, minlength=n_dst + 1)[:n_dst]

@@ -67,9 +67,9 @@ def _programs(kind, nk, n_dst):
     row count and the rows are arguments)."""
     monoid = None if kind == "unclassified" else kind
 
-    def combine(n, dst, *cols):
+    def combine(valid, dst, *cols):
         return collectives.bucketize_combine_keys(
-            cols[:nk], cols[nk:], n, n_dst, _user_add, monoid=monoid,
+            cols[:nk], cols[nk:], valid, n_dst, _user_add, monoid=monoid,
             dst=dst)
 
     def reduce(mask, *cols):
@@ -153,7 +153,7 @@ def test_combine_and_reduce_match_numpy(kind, dtype, nk, rows, n_dst):
     pvals = vals[order]
     dst = np.where(np.arange(CAP) < n, (pcols[0] + 8) % n_dst, n_dst)
     ks, (vs,), counts, offsets = combine(
-        np.int32(n), dst.astype(np.int32), *pcols, pvals)
+        np.arange(CAP) < n, dst.astype(np.int32), *pcols, pvals)
     ks, vs = [np.asarray(k) for k in ks], np.asarray(vs)
     ref = _reference(kind, lambda i: (dst[i],) + tuple(c[i] for c in pcols),
                      pcols, pvals, np.arange(CAP) < n)
@@ -188,7 +188,7 @@ def test_an_unclassified_merge_sees_a_segments_rows_in_input_order():
     keys = np.array([3, 1, 3, 2, 1, 3, 9, 9], np.int64)
     vals = np.array([1, 2, 3, 4, 5, 6, 7, 8], np.int64)
     ks, (vs,), counts, _ = collectives.bucketize_combine_keys(
-        [keys], [vals], 6, 1, spell)
+        [keys], [vals], np.arange(8) < 6, 1, spell)
     assert counts.tolist() == [3]
     assert dict(zip(ks[0][:3].tolist(), vs[:3].tolist())) == {
         1: 25, 2: 4, 3: 136}
@@ -214,8 +214,8 @@ def test_bucketize_groups_rows_by_destination(n_dst):
     key = rng.integers(0, 1000, 64)
     dst = np.where(np.arange(64) < 50, key % n_dst, n_dst).astype(np.int32)
     (k2, i2), counts, offsets = collectives.bucketize(
-        jnp.asarray(key), [jnp.asarray(key), jnp.arange(64)], 50, n_dst,
-        dst=jnp.asarray(dst))
+        jnp.asarray(key), [jnp.asarray(key), jnp.arange(64)],
+        np.arange(64) < 50, n_dst, dst=jnp.asarray(dst))
     order = np.argsort(dst, kind="stable")
     assert np.asarray(i2).tolist() == order.tolist()
     assert np.asarray(k2).tolist() == key[order].tolist()
