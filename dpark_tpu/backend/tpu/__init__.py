@@ -463,6 +463,7 @@ class TPUScheduler(DAGScheduler):
         from the device fold — same contract as GROUP_AGG_REWRITE.
         Integer workloads (the chaos parity suite) are exact."""
         from dpark_tpu import conf
+        from dpark_tpu.backend.tpu.executor import CombineOverflow
         from dpark_tpu.shuffle import SpillWriteError
         try:
             self._run_array_stage(stage, tasks, plan, report)
@@ -471,6 +472,8 @@ class TPUScheduler(DAGScheduler):
         except SpillWriteError as e:
             self._spill_write_failed(stage, tasks, report, e)
             return True
+        except CombineOverflow:
+            raise       # the store itself is short: no path answers
         except Exception as e:
             if _multiproc_cpu_gap(e):
                 # a CAPABILITY gap, not a runtime fault: the CPU

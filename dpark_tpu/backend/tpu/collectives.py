@@ -595,13 +595,16 @@ def bucketize_combine(key, val_leaves, n, n_dst, merge_leaves,
 
 
 def bucketize_combine_keys(key_cols, val_leaves, valid, n_dst,
-                           merge_leaves, monoid=None, dst=None, r=None):
+                           merge_leaves, monoid=None, dst=None, r=None,
+                           out_cap=None):
     """bucketize_combine over a COMPOSITE key: sort one device's rows by
     (destination, k1, ..., kn), merge rows equal in EVERY key column,
     compact.  Returns (key_cols', vals', counts, offsets).  Only key
     column 0 carries the sentinel on invalid rows (`valid` False) —
     invalid rows sort into the sentinel bucket and are dropped by the
-    keep mask, so the other columns never need guarding."""
+    keep mask, so the other columns never need guarding.  `out_cap`:
+    _bucketize_combine_cols' proven bound on the rows the combine
+    keeps."""
     key_cols = list(key_cols)
     if dst is None:
         dst = hash_dst_cols(key_cols, n_dst, valid, r)
@@ -612,12 +615,15 @@ def bucketize_combine_keys(key_cols, val_leaves, valid, n_dst,
     # see _bucketize_combine_cols on why adjacency is sufficient there).
     # With ONE destination there is no reduce side to mend a group that
     # a hash collision split: the true key columns order the rows, the
-    # combine is exact, and the executor registers the store pre_reduced
+    # combine is exact, and the executor registers the store pre_reduced.
+    # A write packed to a bound orders by the true columns too: a split
+    # group would be a kept row the bound does not count
     order_col = (phash_device_cols(key_cols)
-                 if len(key_cols) > 1 and n_dst > 1 else None)
+                 if len(key_cols) > 1 and n_dst > 1 and out_cap is None
+                 else None)
     return _bucketize_combine_cols(dst, ks, val_leaves, n_dst,
                                    merge_leaves, monoid,
-                                   order_col=order_col)
+                                   order_col=order_col, out_cap=out_cap)
 
 
 def _changed_adjacent(cols):
@@ -661,12 +667,37 @@ def _segment_merge(key_cols, val_leaves, keep_valid, merge_leaves,
     return keep_valid(is_last), reduced
 
 
+def _kept_counts(d, keep, n_dst):
+    """Kept rows of each destination 0..n_dst-1 over a `d` sorted by
+    destination, in the rows' own order: _bucket_counts with no pack
+    sort ahead of it."""
+    if n_dst <= _DST_LOOP_MAX:
+        return _bucket_counts(jnp.where(keep, d, n_dst), n_dst + 1)[:n_dst]
+    edges = jnp.searchsorted(d, jnp.arange(n_dst + 1, dtype=d.dtype))
+    kept = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                            jnp.cumsum(keep, dtype=jnp.int32)])
+    return jnp.diff(kept[edges])
+
+
 def _bucketize_combine_cols(dst, key_cols, val_leaves, n_dst,
-                            merge_leaves, monoid, order_col=None):
+                            merge_leaves, monoid, order_col=None,
+                            out_cap=None):
     """Sort by (dst, *key_cols) carrying values, merge rows equal in
     every key column, compact; dst and key_cols must already carry the
     sentinel / sentinel-bucket on invalid rows.  Returns
     (key_cols', vals', counts[n_dst], offsets[n_dst]).
+
+    `out_cap` (optional): a PROVEN bound on the rows the combine keeps,
+    below the input's capacity (the query planner's key bound over a
+    resident table's ranges, as executor._pack_cap classes it).  The
+    pack then sorts only the keep flags with an iota riding and gathers
+    the first out_cap rows of that order a column at a time: the same
+    rows the un-cut pack leaves first, where it gathers every row of the
+    record to keep a few (Q1: 8,388,608 rows of 17 words for four).
+    counts and offsets count EVERY kept row, whatever the cut, so a bound
+    that was wrong shows as a count past the store's capacity, which
+    the executor's first read of the counts refuses: loud, never a group
+    dropped.  Without it the function and its HLO are as they were.
 
     `order_col` (optional, composite keys): a single synthetic
     ordering column (e.g. the 32-bit composite key hash) used INSTEAD
@@ -700,6 +731,12 @@ def _bucketize_combine_cols(dst, key_cols, val_leaves, n_dst,
         lambda flags: flags & (d < n_dst), merge_leaves, monoid)
     dd_full = jnp.where(keep, d, n_dst)
     k_fulls = [jnp.where(keep, k, _sentinel(k.dtype)) for k in ks]
+    if out_cap is not None:
+        order = _lex_sort((~keep, lax.iota(jnp.int32, keep.shape[0])),
+                          1)[1][:out_cap]
+        counts = _kept_counts(d, keep, n_dst)
+        return ([k[order] for k in k_fulls], [v[order] for v in reduced],
+                counts, jnp.cumsum(counts) - counts)
     packed = _lex_sort((~keep, dd_full) + tuple(k_fulls)
                        + tuple(reduced), 1)
     counts = _bucket_counts(packed[1], n_dst + 1)[:n_dst]

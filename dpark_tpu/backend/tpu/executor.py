@@ -448,6 +448,27 @@ class _StoreInFlight(Exception):
     its outputs yet — the eviction scan must skip it, not drop it."""
 
 
+class CombineOverflow(RuntimeError):
+    """A shuffle store counts more rows on a device than its capacity
+    holds.  Only a combining write packed to a key bound that was wrong
+    (a bug of the proof: collectives._bucketize_combine_cols keeps the
+    un-cut counts) can leave one; the job fails rather than answer
+    without the groups past the capacity, and no other path is tried."""
+
+
+def _check_fits(counts, cap, site):
+    """Refuse host-read counts of one store or batch whose rows on some
+    device ((ndev, ...) per-bucket counts summed) pass its capacity."""
+    counts = np.asarray(counts)
+    rows = counts.reshape(counts.shape[0], -1).sum(axis=1) \
+        if counts.ndim else counts.reshape(1)
+    if rows.size and int(rows.max()) > cap:
+        raise CombineOverflow(
+            "%d rows counted on a device of a store of capacity %d "
+            "(read at %s): a combine was packed to a key bound its data "
+            "passes" % (int(rows.max()), cap, site))
+
+
 class _ProgramCache:
     """Bounded LRU over compiled stage programs (ISSUE 9 satellite).
 
@@ -745,6 +766,10 @@ class JAXExecutor:
         # one device, an exact combine): the reduce stage's exchange and
         # reduce program not run, once a store
         self.stores_pre_reduced = 0
+        # launches of a program whose combining write packs to a proven
+        # key bound (_pack_cap; bare `+= 1`): its store leaves the
+        # program at the bound's capacity class, not the input's
+        self.combines_bounded = 0
         # the query plane's scans (bare `+=`): rows of a table resident
         # on the device that a stage program was launched over
         # (_source_outs: the count read when the table was made,
@@ -909,7 +934,10 @@ class JAXExecutor:
         if self._pending_real_counts:
             pending, self._pending_real_counts = \
                 self._pending_real_counts, []
-            for c in layout.host_read(pending, site="exchange.real_rows"):
+            read = layout.host_read([c for c, _ in pending],
+                                    site="exchange.real_rows")
+            for c, (_, cap) in zip(read, pending):
+                _check_fits(c, cap, "exchange.real_rows")
                 self._exchange_real_rows += int(c.sum())
         return self._exchange_real_rows
 
@@ -989,7 +1017,8 @@ class JAXExecutor:
                 plan.epi_spec)
 
     @staticmethod
-    def _epilogue_block(epi, lv, valid, n_dst, merge_fn, monoid, bounds):
+    def _epilogue_block(epi, lv, valid, n_dst, merge_fn, monoid, bounds,
+                        out_cap=None):
         """Shared shuffle-write tail: destination assignment (hash or
         range bounds over the LOGICAL partition count r <= mesh size) +
         bucketize[-combine] of the rows `valid` marks (fuse.run_ops:
@@ -997,7 +1026,8 @@ class JAXExecutor:
         _epilogue_params(plan).  Composite (tuple) keys occupy the
         first nk columns: destinations hash over all of them with the
         pair-extended phash, and the combine merges rows equal in every
-        key column."""
+        key column.  `out_cap` (_pack_cap; the narrow program's alone)
+        packs the combine's output to that many slots."""
         nk, r, epi_spec = epi
         k = lv[0]
         if epi_spec is not None and epi_spec[0] == "range":
@@ -1016,7 +1046,7 @@ class JAXExecutor:
         if merge_fn is not None or monoid is not None:
             k2s, v2, cnts, offs = collectives.bucketize_combine_keys(
                 lv[:nk], lv[nk:], valid, n_dst, merge_fn, monoid=monoid,
-                dst=dst, r=r)
+                dst=dst, r=r, out_cap=out_cap)
         else:
             sorted_lv, cnts, offs = collectives.bucketize(
                 k, lv, valid, n_dst, dst=dst, r=r)
@@ -1051,13 +1081,15 @@ class JAXExecutor:
         epilogue = plan.epilogue
         n_dst = self.ndev
         has_bounds = plan.epi_bounds is not None
-        merge_fn = monoid = epi = None
+        merge_fn = monoid = epi = out_cap = None
         if epilogue is not None:
             merge_fn, monoid = self._merge_probe(plan)
             epi = self._epilogue_params(plan)
+            out_cap = self._pack_cap(plan, cap)
         if trace._PLANE is not None:
             # a filter orders rows only where it packs, and it folds
             # only ahead of a write, whose own sort is counted anyway
+            pack = {} if epilogue is None else {"pack": out_cap or "full"}
             trace.event("compile", "exec", program="narrow", cap=cap,
                         sig=_plan_sig(plan),
                         combine=_combine_arg(merge_fn, monoid),
@@ -1065,7 +1097,7 @@ class JAXExecutor:
                             isinstance(op, (fuse.SortOp, fuse.FilterOp))
                             for op in ops)),
                         filter=_filter_arg(ops, epilogue is not None),
-                        **_order_args(plan))
+                        **pack, **_order_args(plan))
         in_specs = plan.in_specs
 
         def per_device(counts, *rest):
@@ -1080,7 +1112,7 @@ class JAXExecutor:
                 return (jnp.expand_dims(n, 0),) + tuple(
                     jnp.expand_dims(l, 0) for l in lv)
             out = self._epilogue_block(epi, lv, valid, n_dst, merge_fn,
-                                       monoid, bounds)
+                                       monoid, bounds, out_cap)
             return tuple(jnp.expand_dims(o, 0) for o in out)
 
         n_in = 1 + nleaves_in + (1 if has_bounds else 0)
@@ -1458,6 +1490,8 @@ class JAXExecutor:
         self._capture_cost(plan, jitted, args)
         self.filters_masked += fuse.masked_filters(
             plan.ops, plan.epilogue is not None)
+        if self._pack_cap(plan, batch.cap) is not None:
+            self.combines_bounded += 1
         return self._launch("narrow", jitted, *args)
 
     def _capture_cost(self, plan, jitted, args):
@@ -2065,15 +2099,19 @@ class JAXExecutor:
         })
 
     def _trim_combined(self, leaves, counts):
-        """A combined store's leaves keep their INPUT's capacity however
-        few keys the combine left: four groups of 8M rows are 448 MiB
-        of padding.  Where registering them whole would pass
-        conf.SHUFFLE_HBM_BUDGET (and _evict_hbm would spill a store or
-        drop a cached table for that padding), the count is read (one
-        blocking read, site `store.counts`: the host waits for the map
-        program here and not at the egest) and the leaves are cut to the
-        capacity class of the count: every key is packed at the front.
-        Under the budget nothing is read and nothing is cut."""
+        """An UNBOUNDED combined store's leaves keep their INPUT's
+        capacity however few keys the combine left: four groups of 8M
+        rows are 448 MiB of padding.  (A write the query planner proved
+        a key bound for packs to that bound's class inside its program,
+        _pack_cap: its store arrives small and under the budget, and
+        nothing is read for it here.)  Where registering the leaves
+        whole would pass conf.SHUFFLE_HBM_BUDGET (and _evict_hbm would
+        spill a store or drop a cached table for that padding), the
+        count is read (one blocking read, site `store.counts`: the host
+        waits for the map program here and not at the egest) and the
+        leaves are cut to the capacity class of the count: every key is
+        packed at the front.  Under the budget nothing is read and
+        nothing is cut."""
         budget = conf.SHUFFLE_HBM_BUDGET * self.ndev - sum(
             int(l.nbytes) for l in leaves)
         if self._store_bytes + self._result_bytes <= budget:
@@ -2083,8 +2121,9 @@ class JAXExecutor:
             release()       # as _evict_hbm: what nobody can read goes first
             if self._store_bytes + self._result_bytes <= budget:
                 return leaves
-        rows = int(layout.host_read(counts, site="store.counts").max())
-        cap = layout.round_capacity(rows)
+        read = layout.host_read(counts, site="store.counts")
+        _check_fits(read, leaves[0].shape[1], "store.counts")
+        cap = layout.round_capacity(int(read.max()))
         if cap >= leaves[0].shape[1]:
             return leaves
         return [layout._head(l, cap) for l in leaves]
@@ -2690,6 +2729,19 @@ class JAXExecutor:
                 del memo[next(iter(memo))]
             memo[plan.program_key] = combines
         return combines
+
+    def _pack_cap(self, plan, cap):
+        """The slots a narrow program's combining write packs to: the
+        capacity class of the plan's key bound (fuse: the most distinct
+        keys a partition can hold, which only the query planner proves,
+        from a resident table's load-time ranges) where that class is
+        below the program's input capacity `cap`; else None, and the
+        write packs every row as it always did."""
+        bound = getattr(plan, "key_bound", None)
+        if bound is None or not self._write_combines(plan):
+            return None
+        out_cap = layout.round_capacity(bound)
+        return out_cap if out_cap < cap else None
 
     def _wave_iter_columnar(self, plan, chunk=None):
         from dpark_tpu.rdd import _ColumnarSlice
@@ -3332,6 +3384,7 @@ class JAXExecutor:
             recv = [l.reshape((1, 1) + l.shape[1:]) for l in leaves]
             return [recv], [counts], cap
         host_counts = layout.host_read(counts, site="exchange.counts")
+        _check_fits(host_counts, cap, "exchange.counts")
         max_run = int(host_counts.max()) if host_counts.size else 1
         mean = int(host_counts.sum()) // max(1, host_counts.size)
         # slot sizing: fine (1/16-octave) classes — power-of-two slots
@@ -3388,7 +3441,7 @@ class JAXExecutor:
         deferred to the next metric read, never a readback here) and
         the slots they sat in (`ingest_slot_rows`; the scheduler's
         `ingest_pad_efficiency` stage note is their ratio)."""
-        self._pending_real_counts.append(counts)
+        self._pending_real_counts.append((counts, cap))
         if len(self._pending_real_counts) > self._PENDING_COUNTS_MAX:
             self.exchange_real_rows  # property read drains the list
         self.ingest_slot_rows += cap
@@ -3770,6 +3823,8 @@ class JAXExecutor:
             with self._export_lock:
                 # (ndev,), or an in-core store's (ndev=1, R=1)
                 counts = _export_read(store["counts"]).reshape(-1)
+                _check_fits(counts, store["leaves"][0].shape[1],
+                            "bridge.export")
                 cnt = int(counts[reduce_id])
                 if not cnt:
                     return {"no_combine": False}, []
@@ -3873,6 +3928,8 @@ class JAXExecutor:
             with self._export_lock:
                 # (ndev,), or an in-core store's (ndev=1, R=1)
                 counts = _export_read(store["counts"]).reshape(-1)
+                _check_fits(counts, store["leaves"][0].shape[1],
+                            "bridge.export")
                 cnt = int(counts[reduce_id])
                 if not cnt:
                     return []

@@ -2017,4 +2017,13 @@ def analyze_stage(stage, ndev, executor_or_store):
     plan.reslice = reslice
     plan.program_key = plan.program_key + (
         src_combine, group_output, epi_spec, epi_nk, plan.src_nk)
+    # the most distinct keys a partition of the write can hold, where
+    # the query planner proved it (planner._key_bound; no user code sets
+    # it): the narrow program packs its combine to the bound's capacity
+    # class (executor._pack_cap), so the class is part of the program
+    plan.key_bound = None if epilogue is None else getattr(
+        epilogue[1], "key_bound", None)
+    if plan.key_bound is not None:
+        plan.program_key += (("key_bound",
+                              layout.round_capacity(plan.key_bound)),)
     return plan

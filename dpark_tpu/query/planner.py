@@ -55,6 +55,7 @@ With the trace plane on, `plan_query` is the `query.plan` span and the
 egest-side finishing the `query.finish` span (dpark_tpu/trace.py).
 """
 
+import math
 import time
 
 import numpy as np
@@ -819,6 +820,9 @@ class PlannedQuery:
                 r = base.map(_make_create(g["widths"], g["leaves"])) \
                     .reduceByKey(_make_merge(_merge_ops(g["leaves"])),
                                  npart)
+                if g["key_bound"] is not None:
+                    # proved by _rule_lower_group; read by fuse's plan
+                    r.dep.key_bound = g["key_bound"]
         else:                       # join / join_group
             j = self._join
             sides = []
@@ -1373,17 +1377,43 @@ def _key_words_ok(seg, bounds, widths, key_srcs):
         return ("the group key is %d key words (a byte string is one "
                 "a started 8 bytes), over conf.MAX_KEY_LEAVES=%d"
                 % (_key_nwords(widths), conf.MAX_KEY_LEAVES))
-    stats = dict(zip(seg.scan.fields, seg.device["columns"]))
-    for ranges, src in zip(bounds, key_srcs):
-        # a derived int key has its expression's bounds; a bare column
-        # (a number's range, a byte string's a word) its load-time ones
-        if ranges is None and src in stats:
-            ranges = stats[src][1]
-        for rng in ranges if isinstance(ranges, list) else [ranges]:
+    for words in _key_word_ranges(seg, bounds, key_srcs):
+        for rng in words:
             if rng is None or rng[1] >= _I64_MAX:
                 return ("a group key column may hold the device's "
                         "padding sentinel (no range below 2**63 - 1)")
     return None
+
+
+def _key_word_ranges(seg, bounds, key_srcs):
+    """A resident table's key columns' (lo, hi) a key word, or None
+    where a word has none: a derived int key has its expression's
+    bounds; a bare column (a number's range, a byte string's a word) its
+    load-time ones."""
+    stats = dict(zip(seg.scan.fields, seg.device["columns"]))
+    out = []
+    for ranges, src in zip(bounds, key_srcs):
+        if ranges is None and src in stats:
+            ranges = stats[src][1]
+        out.append(ranges if isinstance(ranges, list) else [ranges])
+    return out
+
+
+def _key_bound(seg, bounds, widths, key_srcs):
+    """The most distinct group keys any partition of a resident table
+    can hold, from the ranges alone (nothing is read): the product over
+    key words of the values a word's range allows, hi - lo + 1 for a
+    number; word i of an S<w> column holds b = min(8, w - 8i) bytes
+    high, its low 8 (8 - b) bits always zero.  Returns (bound, the
+    factors) or None where a word has no range."""
+    factors = []
+    for words, w in zip(_key_word_ranges(seg, bounds, key_srcs), widths):
+        for i, rng in enumerate(words):
+            if rng is None:
+                return None
+            zero = 8 * (8 - min(8, w - 8 * i)) if w else 0
+            factors.append(((rng[1] - rng[0]) >> zero) + 1)
+    return math.prod(factors), factors
 
 
 def _rule_lower_group(pq):
@@ -1426,12 +1456,13 @@ def _rule_lower_group(pq):
             key_srcs.append(env[ce.tree.body.id][2]
                             if _is_bare_name(ce) else None)
         widths = tuple(widths)
+        key_bound = None
         if device:
-            bad = _key_words_ok(
-                seg, [env.get(c, (None, None))[1] for c in key_cols],
-                widths, key_srcs)
+            key_ranges = [env.get(c, (None, None))[1] for c in key_cols]
+            bad = _key_words_ok(seg, key_ranges, widths, key_srcs)
             if bad:
                 raise _Decline("group-agg", bad)
+            key_bound = _key_bound(seg, key_ranges, widths, key_srcs)
 
         def _extra_pop(cname):
             extra[:] = [(n, s) for n, s in extra if n != cname]
@@ -1457,7 +1488,15 @@ def _rule_lower_group(pq):
             "lower": ("uda" if uda is not None else
                       "classified" if _classified_ok(kinds)
                       and not device else "reduce"),
-            "uda": uda}
+            "uda": uda, "key_bound": None}
+        if key_bound is not None and pq._group["lower"] == "reduce":
+            pq._group["key_bound"] = key_bound[0]
+            pq.decide("key-bound", "group-agg", "device",
+                      "at most %d distinct group keys a partition (%s "
+                      "values by the key words' load-time ranges): the "
+                      "combine packs to that bound's capacity class "
+                      "where it is below the input's"
+                      % (key_bound[0], " x ".join(map(str, key_bound[1]))))
         if encode:
             pq.decide("encode-strings", "group-agg", "device",
                       "string group key(s) %s ride dictionary-encoded "

@@ -511,10 +511,12 @@ def test_a_plan_that_opens_no_job_leaves_no_span(masters):
 
 def test_a_combined_store_is_cut_to_its_count_under_hbm_pressure(
         masters, monkeypatch):
-    """Four groups of a table's rows: the store's leaves keep the
-    input's capacity unless registering them whole would pass the HBM
-    budget; then the count is read, the leaves are cut to its capacity
-    class and the table stays resident."""
+    """Four groups of a table's rows: the map program packs the store
+    to the capacity class of the key bound the planner proved (18 x 10
+    values of the two S1 keys' ranges: 256 slots), and keeps it so
+    unless registering it would pass the HBM budget; then the count is
+    read, the leaves are cut to its capacity class and the table stays
+    resident."""
     from dpark_tpu import conf, trace
     dev, _ = masters
     ex = dev.scheduler.executor
@@ -532,7 +534,7 @@ def test_a_combined_store_is_cut_to_its_count_under_hbm_pressure(
         return rows, store["leaves"][0].shape[1], store["nbytes"], t
 
     rows, cap, nbytes, keep = run()
-    assert rows == q1_reference(cols, CUTOFF) and cap == 4096
+    assert rows == q1_reference(cols, CUTOFF) and cap == 256
     # a budget the table and the whole store do not fit in together
     monkeypatch.setattr(conf, "SHUFFLE_HBM_BUDGET",
                         ex._result_bytes + ex._store_bytes + nbytes // 2)
@@ -543,7 +545,7 @@ def test_a_combined_store_is_cut_to_its_count_under_hbm_pressure(
         spans = trace.snapshot()
     finally:
         trace.configure("off")
-    assert rows2 == rows and cap2 == 8 and nbytes2 == nbytes * 8 // 4096
+    assert rows2 == rows and cap2 == 8 and nbytes2 == nbytes * 8 // 256
     assert table.rdd.id in ex.result_cache_ids()
     assert [s for s in spans if s["name"] == "readback"
             and s["args"].get("site") == "store.counts"]
