@@ -73,7 +73,7 @@ _mode = None                  # resolved mode, or None = read conf lazily
 _dir = None                   # resolved store dir, or None = read conf
 _loaded = False
 _agg = {"wave_budget": {}, "stage": {}, "skew": {}, "combine": {},
-        "pane": {}, "site": {}, "prog": {}, "reuse": {}, "xch": {},
+        "pane": {}, "site": {}, "reuse": {}, "xch": {},
         "replan": {}}
 _counters = {"store_hits": 0, "store_misses": 0, "steered": 0,
              "recorded": 0, "skipped_lines": 0}
@@ -255,8 +255,6 @@ def _compact_locked(path):
                              "w": int(ent.get("w", 0))})
     for key, ent in _agg["site"].items():
         recs.append({"k": "site", "key": key, "digest": dict(ent)})
-    for key, ent in _agg["prog"].items():
-        recs.append({"k": "prog", "key": key, "profile": dict(ent)})
     for key, ent in _agg["reuse"].items():
         recs.append(dict(ent, k="reuse", key=key))
     for key, ent in _agg["xch"].items():
@@ -350,38 +348,11 @@ def _apply(rec):
         from dpark_tpu import health
         _agg["site"][key] = health.merge_digests(
             _agg["site"].get(key), rec.get("digest"))
-    elif kind == "prog":
-        # program cost profile (ledger plane, ISSUE 15; AOT plane,
-        # ISSUE 17): static flops / bytes / peak-HBM captured at
-        # compile time PLUS the observed compile ms and resolution
-        # hit count the AOT cache's boot warming ranks by, keyed by
-        # the cross-process-stable plan signature.  Field-wise merge:
-        # "hits" accumulates across records (compaction folds the
-        # running total into one line, so reload stays honest),
-        # "compile_ms" smooths by EMA (a noisy box must not own the
-        # ranking), every other field is latest-wins (static profiles
-        # are a pure function of the program + shape class; a newer
-        # jax may refine the numbers).
-        prof = rec.get("profile")
-        if isinstance(prof, dict):
-            ent = _agg["prog"].setdefault(key, {})
-            for k, v in prof.items():
-                if not isinstance(v, (int, float)):
-                    continue
-                v = float(v) if isinstance(v, float) else int(v)
-                if k == "hits":
-                    ent[k] = int(ent.get(k, 0)) + int(v)
-                elif k == "compile_ms" and ent.get(k):
-                    ent[k] = round(float(ent[k]) * (1 - _EMA)
-                                   + float(v) * _EMA, 3)
-                else:
-                    ent[k] = v
     elif kind == "reuse":
         # result-cache hit-rate profile (ISSUE 18), keyed by the
         # cache entry key: pure running counts (compaction folds them
         # into one line, so reload stays honest).  The disk tier's
-        # boot preload ranks entries by these hits — the same
-        # observed-demand ranking the AOT warming uses.
+        # boot preload ranks entries by these hits.
         ent = _agg["reuse"].setdefault(
             key, {"hits": 0, "misses": 0, "partials": 0})
         for k in ("hits", "misses", "partials"):
@@ -535,10 +506,6 @@ def summary():
                 # persisted (ISSUE 14): the item-5 handoff's proof a
                 # fresh process sees what earlier ones observed
                 "sites": sorted(_agg["site"]),
-                # persisted static program cost profiles (ledger
-                # plane, ISSUE 15): the acceptance proof a fresh
-                # process can price a program before running it
-                "programs": sorted(_agg["prog"]),
                 "decisions": [dict(d) for d in _decisions[-32:]]}
 
 
@@ -952,48 +919,6 @@ def record_site_tail(site, digest):
                  "digest": dict(digest)})
     except Exception as e:
         logger.debug("record_site_tail failed: %s", e)
-
-
-def record_program_cost(key, profile):
-    """Persist one static program cost profile (ledger plane, ISSUE
-    15): flops / bytes-accessed / arg-bytes (and, when captured via
-    the compiled path, measured peak-HBM bytes) keyed by the
-    cross-process-stable plan signature "progid|shapeclass" — the
-    pricing prior ROADMAP items 2/3 read before a program's first
-    observed run."""
-    try:
-        if not enabled() or not key or not profile:
-            return
-        _append({"k": "prog", "key": str(key),
-                 "profile": dict(profile)})
-    except Exception as e:
-        logger.debug("record_program_cost failed: %s", e)
-
-
-def program_cost(key):
-    """The persisted cost profile for one plan signature, or None."""
-    try:
-        if not enabled():
-            return None
-        _ensure_loaded()
-        with _lock:
-            ent = _agg["prog"].get(str(key))
-            return dict(ent) if ent is not None else None
-    except Exception:
-        return None
-
-
-def program_costs():
-    """{signature: profile} — every persisted program cost profile.
-    A fresh process calling this prices programs it never ran."""
-    try:
-        if not enabled():
-            return {}
-        _ensure_loaded()
-        with _lock:
-            return {k: dict(v) for k, v in _agg["prog"].items()}
-    except Exception:
-        return {}
 
 
 def record_reuse(key, hits=0, misses=0, partials=0):

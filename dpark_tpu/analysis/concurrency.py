@@ -398,13 +398,9 @@ PLANE_SEAMS = (
     ("locks.py", "_NamedLock.__exit__", "_SANITIZER"),
     ("locks.py", "note_acquire", "_SANITIZER"),
     ("locks.py", "note_release", "_SANITIZER"),
-    ("aotcache.py", "set_current_sig", "_PLANE"),
-    ("aotcache.py", "stats", "_PLANE"),
     ("resultcache.py", "probe", "_PLANE"),
     ("resultcache.py", "offer", "_PLANE"),
     ("resultcache.py", "stats", "_PLANE"),
-    ("backend/tpu/executor.py", "_ProgramCache.__setitem__",
-     "aotcache._PLANE"),
     # the host-side spans of the array path (plan, launch, eager,
     # readback, egest, ingest, hbm.spill): off, each site is this one
     # check

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from dpark_tpu import aotcache, conf, faults, locks, trace
+from dpark_tpu import conf, faults, locks, trace
 from dpark_tpu.backend.tpu import collectives, fuse, layout
 from dpark_tpu.utils.log import get_logger
 
@@ -561,27 +561,13 @@ class _ProgramCache:
             return self._d[key]     # probe already counted + touched
 
     def __setitem__(self, key, fn):
-        # the AOT plane seam (ISSUE 17): with a plane installed every
-        # inserted program wraps in the lazy two-tier proxy whose
-        # first call consults disk before compiling; off costs this
-        # one `is None` check (plane-contract rule)
-        plane = aotcache._PLANE
-        if plane is not None:
-            fn = plane.wrap(key, fn)
-        evicted = []
         with self._lock:
             self._d[key] = fn
             self._d.move_to_end(key)
             if self.cap:
                 while len(self._d) > max(1, self.cap):
-                    evicted.append(self._d.popitem(last=False)[1])
+                    self._d.popitem(last=False)
                     self.evictions += 1
-        # write-back OUTSIDE the cache lock: serializing an evicted
-        # executable is disk work no concurrent probe should wait on
-        for old in evicted:
-            wb = getattr(old, "writeback", None)
-            if wb is not None:
-                wb()
 
     def __len__(self):
         return len(self._d)
@@ -708,7 +694,7 @@ def _place_compile_cache(platform):
     given from outside (JAX_COMPILATION_CACHE_DIR) is left alone;
     otherwise the cache sits at a FIXED path beside the package (the
     path is part of the cache key, so a directory that moves never
-    hits).  Device backends only: XLA:CPU AOT entries are
+    hits).  Device backends only: XLA:CPU cache entries are
     machine-feature-sensitive (observed "could lead to SIGILL" loads),
     and CPU compiles are cheap."""
     if platform != "cpu" \
@@ -1125,10 +1111,7 @@ class JAXExecutor:
         jitted = jax.jit(fn, donate_argnums=tuple(
             range(leaf0, leaf0 + nleaves_in)) if donate else ())
         self._compiled[key] = jitted
-        # read back through the cache: with the AOT plane on, the
-        # stored value is the two-tier proxy, and EVERY call path must
-        # route through it or the first call double-compiles
-        return self._compiled[key]
+        return jitted
 
     def _compile_exchange(self, dtypes, nleaves, slot, cap,
                           narrow=None, donate=False):
@@ -1166,7 +1149,7 @@ class JAXExecutor:
         jitted = jax.jit(fn, donate_argnums=tuple(
             range(3, 3 + nleaves)) if donate else ())
         self._compiled[key] = jitted
-        return self._compiled[key]
+        return jitted
 
     def _compile_minmax(self, nleaves, cap):
         """(counts, int64 leaves) -> per-device (lo, hi) over each
@@ -1193,8 +1176,8 @@ class JAXExecutor:
         fn = _shard_map(per_device, self.mesh,
                         in_specs=(P(AXIS),) * (1 + nleaves),
                         out_specs=(P(AXIS),) * nleaves)
-        self._compiled[key] = jax.jit(fn)
-        return self._compiled[key]
+        jitted = self._compiled[key] = jax.jit(fn)
+        return jitted
 
     def _narrow_plan(self, leaves, counts):
         """Per-leaf wire dtype for the exchange (None = keep).
@@ -1319,7 +1302,7 @@ class JAXExecutor:
         jitted = jax.jit(fn, donate_argnums=tuple(
             range(buf0, buf0 + rounds * nleaves)) if donate else ())
         self._compiled[key] = jitted
-        return self._compiled[key]
+        return jitted
 
     def _bounds_arg(self, plan):
         """plan.epi_bounds tiled per device and sharded, or None.
@@ -1365,10 +1348,6 @@ class JAXExecutor:
             # every backend compile inside this stage (narrow,
             # exchange, egest, ...) attributes to the stage's program
             trace.set_compile_sig(sig)
-        if aotcache._PLANE is not None:
-            # programs inserted under this stage carry its adapt
-            # signature into the disk index / warm ranking
-            aotcache.set_current_sig(fuse.plan_adapt_signature(plan))
         with self._mesh_lock, \
                 trace.span("stage.exec", "exec", source=plan.source[0],
                            **extra):
@@ -1477,8 +1456,6 @@ class JAXExecutor:
             # backend compiles fired by the jitted call below
             # attribute to this program (ledger plane, ISSUE 15)
             trace.set_compile_sig(_plan_sig(plan))
-        if aotcache._PLANE is not None:
-            aotcache.set_current_sig(fuse.plan_adapt_signature(plan))
         jitted = self._compile_narrow(
             plan, batch.cap, len(batch.cols),
             tuple(str(c.dtype) for c in batch.cols), donate=donate,
@@ -1487,29 +1464,11 @@ class JAXExecutor:
             bounds = self._bounds_arg(plan)
         args = (batch.counts,) + ((bounds,) if bounds is not None
                                   else ()) + tuple(batch.cols)
-        self._capture_cost(plan, jitted, args)
         self.filters_masked += fuse.masked_filters(
             plan.ops, plan.epilogue is not None)
         if self._pack_cap(plan, batch.cap) is not None:
             self.combines_bounded += 1
         return self._launch("narrow", jitted, *args)
-
-    def _capture_cost(self, plan, jitted, args):
-        """Static program cost profile at first dispatch (ISSUE 15):
-        once per plan signature, BEFORE the call (donated buffers are
-        dead after it; lower() reads only avals).  Gated on BOTH the
-        ledger sink and an installed trace plane — the documented
-        contract is that the whole attribution plane is inert with
-        DPARK_TRACE=off, and the capture's re-trace must never ride
-        an untraced production dispatch under the mesh lock."""
-        from dpark_tpu import ledger
-        if ledger._SINK is None or trace._PLANE is None:
-            return
-        try:
-            ledger.capture_program_cost(
-                fuse.plan_adapt_signature(plan), jitted, args)
-        except Exception:
-            pass
 
     # ------------------------------------------------------------------
     # text-source ingest (SURVEY.md 3.1 hot loop #1): the narrow chain
@@ -2623,7 +2582,7 @@ class JAXExecutor:
         jitted = jax.jit(fn, donate_argnums=tuple(
             range(k, k + k * nleaves)) if donate else ())
         self._compiled[key] = jitted
-        return self._compiled[key]
+        return jitted
 
     # ------------------------------------------------------------------
     # out-of-core streaming shuffle (SURVEY.md 7.2 item 4): input bigger
@@ -2976,9 +2935,9 @@ class JAXExecutor:
                         in_specs=(P(AXIS),) * n_in,
                         out_specs=(P(AXIS),) * n_out)
         leaf0 = 1 + (1 if has_bounds else 0)
-        self._compiled[key] = jax.jit(fn, donate_argnums=tuple(
+        jitted = self._compiled[key] = jax.jit(fn, donate_argnums=tuple(
             range(leaf0, leaf0 + nleaves_in)) if donate else ())
-        return self._compiled[key]
+        return jitted
 
     def _spill_wave(self, spool, runs, carry_rid, wave,
                     sorted_batch, writer, stats):
@@ -3084,9 +3043,6 @@ class JAXExecutor:
                 faults.hit("executor.dispatch")   # chaos site: per wave
                 if trace._PLANE is not None:
                     trace.set_compile_sig(_plan_sig(plan))
-                if aotcache._PLANE is not None:
-                    aotcache.set_current_sig(
-                        fuse.plan_adapt_signature(plan))
                 jitted = self._compile_stream_nocombine(
                     plan, batch.cap, len(batch.cols), r,
                     tuple(str(c.dtype) for c in batch.cols),
@@ -3094,7 +3050,6 @@ class JAXExecutor:
                 args = (batch.counts,) + ((bounds,)
                                           if bounds is not None
                                           else ()) + tuple(batch.cols)
-                self._capture_cost(plan, jitted, args)
                 self.filters_masked += fuse.masked_filters(plan.ops, True)
                 outs = jitted(*args)
                 cnts, offs = outs[0], outs[1]
@@ -3905,14 +3860,8 @@ class JAXExecutor:
     def program_cache_stats(self):
         """Hit/miss/evict counters of the bounded compiled-program
         cache (ISSUE 9): /metrics, the web UI per-job cache column,
-        and the warm-submit bench read these.  With the AOT plane
-        installed (ISSUE 17) the disk tier's load/store/warm counters
-        ride along under "aot"."""
-        out = self._compiled.stats()
-        aot = aotcache.stats()
-        if aot is not None:
-            out["aot"] = aot
-        return out
+        and the warm-submit bench read these."""
+        return self._compiled.stats()
 
     def _export_bucket(self, sid, map_id, reduce_id):
         store = self.shuffle_store.get(sid)

@@ -201,35 +201,12 @@ SERVICE_WEIGHT = int(os.environ.get("DPARK_SERVICE_WEIGHT", "1") or 1)
 PROGRAM_CACHE_MAX = int(os.environ.get("DPARK_PROGRAM_CACHE_MAX",
                                        "512") or 0)
 
-# persistent AOT executable cache (ISSUE 17): off | read | on.
-# "off" (the default) costs one `is None` check at the program-cache
-# seam and is bit-identical to any cached run; "read" loads serialized
-# executables from DPARK_AOT_CACHE_DIR but never writes (a replica
-# trusting a cache it does not own); "on" additionally stores newly
-# compiled programs — tmp+rename entries plus an O_APPEND index, so
-# one directory is safely shared across replicas and concurrent
-# writers.  Corrupt / truncated / version-mismatched entries skip
-# silently and fall back to compile (the adapt-store contract).
-AOT_CACHE = os.environ.get("DPARK_AOT_CACHE", "off")
-
-# where serialized executables live (delete the directory to reset)
-AOT_CACHE_DIR = os.environ.get(
-    "DPARK_AOT_CACHE_DIR", os.path.join(DPARK_WORK_DIR, "aotcache"))
-
-# boot-warming deadline: a starting JobServer spends at most this many
-# milliseconds deserializing the hottest programs (ranked by observed
-# compile ms x hit count from the adapt store) before serving.  0
-# disables warming without disabling the cache.
-AOT_WARM_BUDGET_MS = float(os.environ.get(
-    "DPARK_AOT_WARM_BUDGET_MS", "2000") or 0)
-
 # shared-computation plane (ISSUE 18): off | mem | disk.  "off" (the
 # default) costs one `is None` check at the planner's probe seam and
 # is bit-identical to any cached run; "mem" serves repeated sub-plans
 # (and mergeable partial aggregates) from a host-memory LRU tier;
-# "disk" adds a crc-framed on-disk tier that survives restarts
-# alongside the AOT cache — same corruption contract (any defect
-# means recompute, never an error).  Entries invalidate by source
+# "disk" adds a crc-framed on-disk tier that survives restarts (any
+# defect means recompute, never an error).  Entries invalidate by source
 # fingerprint: v2 tabular footer stats, (path, mtime, size) for v1.
 RESULT_CACHE = os.environ.get("DPARK_RESULT_CACHE", "off")
 
@@ -648,19 +625,6 @@ DPARK_LEDGER = os.environ.get("DPARK_LEDGER", "on")
 # distinct programs a resident server serves.  0 = unbounded.
 LEDGER_MAX_KEYS = int(os.environ.get("DPARK_LEDGER_MAX_KEYS",
                                      "512") or 0)
-
-# static program cost profiles (the items-2/3 pricing prior): at first
-# dispatch of a compiled stage program, capture jax cost analysis
-# keyed by fuse.plan_adapt_signature and persist it to the adapt store
-# (adapt.record_program_cost).
-#   lower    (default) jitted.lower(args).cost_analysis() only — a
-#            host-side re-trace, no extra XLA compile (safe on real
-#            chips where a compile runs 30-150s)
-#   compile  additionally .compile().memory_analysis() for measured
-#            peak-HBM fields — ONE extra XLA compile per program
-#            signature (cheap on XLA:CPU; tests/CI use this)
-#   off      capture nothing
-LEDGER_COST = os.environ.get("DPARK_LEDGER_COST", "lower")
 
 # concurrency sanitizer plane (dpark_tpu/locks.py — ISSUE 16): the
 # named-lock registry records per-thread lock acquisition order and

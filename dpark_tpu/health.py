@@ -807,14 +807,6 @@ def api_health(scheduler=None):
     except Exception:
         pass
     try:
-        # AOT executable-cache counters (ISSUE 17) for the UI topline
-        from dpark_tpu import aotcache
-        aot = aotcache.stats()
-        if aot is not None:
-            out["aot"] = aot
-    except Exception:
-        pass
-    try:
         # shared-computation result-cache counters (ISSUE 18)
         from dpark_tpu import resultcache
         rc = resultcache.stats()

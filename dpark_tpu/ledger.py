@@ -40,16 +40,6 @@ from a spool alone.  Everything is bounded: past
 ``conf.LEDGER_MAX_KEYS`` account keys, new keys fold into their job's
 coarse account (stage/sig dropped) so totals stay honest.
 
-Static **program cost profiles** ride alongside (the pricing prior
-ROADMAP items 2/3 need before a program's first observed run): at
-first dispatch of a freshly compiled stage program,
-:func:`capture_program_cost` captures ``jitted.lower(args)``'s
-``cost_analysis()`` (flops, bytes accessed — a host-side re-trace, no
-extra XLA compile) and, under ``DPARK_LEDGER_COST=compile``, the
-compiled ``memory_analysis()`` (measured arg/out/temp = peak-HBM
-bytes), keyed by ``fuse.plan_adapt_signature`` and persisted to the
-adapt store via ``adapt.record_program_cost``.
-
 The **conservation check**: per-tenant attributed device-seconds must
 reconcile with the measured mesh busy time (the mesh lock's depth-0
 hold total) — :func:`conservation` computes the ratio,
@@ -852,96 +842,6 @@ def summary():
             "mesh": snap["mesh"],
             "conservation": conservation(snap=snap),
             "folded": snap["folded"]}
-
-
-# ---------------------------------------------------------------------------
-# static program cost profiles (the items-2/3 pricing prior)
-# ---------------------------------------------------------------------------
-
-_cost_seen = set()
-_cost_lock = locks.named_lock("ledger.cost")
-
-
-def _cost_key(sig):
-    return "%s|%s" % (sig[0], sig[1])
-
-
-def capture_program_cost(sig, jitted, args):
-    """Capture one program's static cost profile at FIRST dispatch:
-    ``jitted.lower(*args)`` (a host-side re-trace — no extra XLA
-    compile) -> ``cost_analysis()`` flops / bytes accessed, plus under
-    DPARK_LEDGER_COST=compile the compiled ``memory_analysis()``
-    (measured arg/out/temp bytes = the peak-HBM prior).  Persisted to
-    the adapt store keyed by the cross-process-stable plan signature,
-    so a FRESH process prices a program before ever running it.
-    Must be called BEFORE the jitted call when buffers are donated
-    (lower only reads avals, never the buffers).  Never raises."""
-    try:
-        if _SINK is None or sig is None:
-            return None
-        # the streaming dispatch loop calls this per wave: the
-        # already-captured fast path must be one set probe (racy read
-        # is fine — the add below re-checks under the lock)
-        key = _cost_key(sig)
-        if key in _cost_seen:
-            return None
-        cost_mode = str(getattr(conf, "LEDGER_COST", "lower") or
-                        "lower").lower()
-        if cost_mode == "off":
-            return None
-        from dpark_tpu import adapt
-        if not adapt.enabled():
-            return None
-        with _cost_lock:
-            if key in _cost_seen:
-                return None
-            _cost_seen.add(key)
-        if adapt.program_cost(key) is not None:
-            return None              # an earlier process already paid
-        lowered = jitted.lower(*args)
-        ca = lowered.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        profile = {
-            "flops": float(ca.get("flops", 0.0) or 0.0),
-            "bytes_accessed": float(ca.get("bytes accessed", 0.0)
-                                    or 0.0),
-            "arg_bytes": int(sum(int(getattr(a, "nbytes", 0) or 0)
-                                 for a in args)),
-        }
-        if cost_mode == "compile":
-            # the extra compile is PLANE overhead: suppress its
-            # compile.backend span so the program's compile_ms
-            # account never double-bills the tenant for it
-            from dpark_tpu import trace
-            trace.suppress_compile_spans(True)
-            try:
-                m = lowered.compile().memory_analysis()
-            finally:
-                trace.suppress_compile_spans(False)
-            if m is not None:
-                profile["out_bytes"] = int(
-                    getattr(m, "output_size_in_bytes", 0) or 0)
-                profile["temp_bytes"] = int(
-                    getattr(m, "temp_size_in_bytes", 0) or 0)
-                profile["peak_hbm_bytes"] = (
-                    int(getattr(m, "argument_size_in_bytes", 0) or 0)
-                    + profile["out_bytes"] + profile["temp_bytes"])
-        adapt.record_program_cost(key, profile)
-        from dpark_tpu import trace
-        trace.event("ledger.cost", "ledger", sig=sig[0],
-                    flops=profile["flops"])
-        return profile
-    except Exception as e:
-        logger.debug("capture_program_cost failed: %s", e)
-        return None
-
-
-def reset_cost_capture():
-    """Forget which signatures this process already profiled
-    (tests)."""
-    with _cost_lock:
-        _cost_seen.clear()
 
 
 def _init_from_conf():

@@ -63,11 +63,6 @@ DOCUMENTED_ORDER = (
     "executor.mesh",         # THE mesh lock: every device dispatch
     "executor.shard_build",  # PR 9 rule: mesh -> shard_build only
     "executor.program_cache",
-    "aot.store",             # AOT disk-tier counters/preload map: a
-    #                          program-cache eviction write-back and
-    #                          a proxy resolving under a device
-    #                          dispatch both reach it, never the
-    #                          reverse
     "shuffle.shard_pool",
     "dcn.serves",
     "resultcache.store",     # shared result cache LRU/counters: the
@@ -79,7 +74,6 @@ DOCUMENTED_ORDER = (
     "trace.plane",           # span ring/spool (spans emit under mesh)
     "health.sink",
     "ledger.sink",
-    "ledger.cost",
 )
 _ORDER_INDEX = {n: i for i, n in enumerate(DOCUMENTED_ORDER)}
 

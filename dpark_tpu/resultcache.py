@@ -41,8 +41,8 @@ Serving.  The planner probes at plan time (``planner._rule_reuse``):
 
 Storage.  Host-memory tier with size-budgeted LRU eviction
 (``DPARK_RESULT_CACHE_BUDGET`` bytes); ``disk`` mode adds a
-crc-framed on-disk tier that survives restarts alongside the AOT
-cache — entry files are tmp+rename with a crc-framed header and a
+crc-framed on-disk tier that survives restarts — entry files are
+tmp+rename with a crc-framed header and a
 crc/length-checked pickled payload, the index is O_APPEND whole-line
 jsonl (the adapt-store idioms), and ANY defect means "miss and
 recompute", never an error.
@@ -59,7 +59,7 @@ Modes (``DPARK_RESULT_CACHE`` / conf.RESULT_CACHE):
 
   off   no plane installed.  The seams cost exactly one module-global
         load + ``is None`` check — the same off-mode contract as the
-        faults/trace/health/ledger/lockcheck/aot planes,
+        faults/trace/health/ledger/lockcheck planes,
         machine-checked by the ``plane-contract`` dlint rule.
   mem   host-memory LRU only.
   disk  mem + write-through to the on-disk tier; a restarting server

@@ -48,7 +48,6 @@ import time
 import traceback
 from collections import deque
 
-from dpark_tpu import aotcache
 from dpark_tpu import conf
 from dpark_tpu import locks
 from dpark_tpu import resultcache
@@ -183,8 +182,6 @@ class JobServer:
         self._bulk_bytes = {}
         # per-tenant SLO accounting (ISSUE 14; see note_job_done)
         self._tenants = {}
-        # boot-warming summary (ISSUE 17; see _boot_warm)
-        self._aot_warm = None
         # result-cache boot summary (ISSUE 18; see _boot_resultcache)
         self._rc_boot = None
 
@@ -198,12 +195,6 @@ class JobServer:
             self.scheduler = _make_scheduler(self.master)
             self.scheduler.start()
             self.scheduler._service = self
-            # instant-on serving (ISSUE 17): with the AOT plane
-            # installed, pre-deserialize the hottest previously-seen
-            # programs before the first submission arrives
-            plane = aotcache._PLANE
-            if plane is not None:
-                self._boot_warm(plane)
             # shared computation (ISSUE 18): a disk-tier result cache
             # preloads its hottest entries so the first repeated
             # query after a restart serves with zero scan chunks
@@ -225,40 +216,14 @@ class JobServer:
                         self.max_jobs)
         return self
 
-    def _boot_warm(self, plane):
-        """Boot-warming pass (ISSUE 17): deserialize the hottest
-        previously-seen programs — ranked by the adapt store's
-        observed compile ms x hit count — into the AOT plane's
-        preload map under the DPARK_AOT_WARM_BUDGET_MS deadline, so a
-        restarted server's first submission starts from loaded
-        executables.  The pass runs under the ``__boot__``
-        pseudo-tenant span context: any work it triggers folds to the
-        boot account, never a real tenant's, and the ledger's
-        conservation ratio stays 1.0 (no mesh occupancy is drawn).
-        Never raises — a defective cache dir means a cold start, not
-        a dead server."""
-        budget = float(getattr(conf, "AOT_WARM_BUDGET_MS", 0.0) or 0.0)
-        if budget <= 0:
-            return
-        from dpark_tpu import trace
-        try:
-            with trace.ctx(job="__boot__"):
-                summary = plane.warm(budget)
-            self._aot_warm = summary
-            logger.info(
-                "aot boot warm: %d/%d entries in %.0f ms (budget "
-                "%.0f ms)", summary["warmed"], summary["entries"],
-                summary["ms"], summary["budget_ms"])
-        except Exception as e:
-            logger.debug("aot boot warm failed: %s", e)
-
     def _boot_resultcache(self, rc):
         """Result-cache boot pass (ISSUE 18): load the disk tier's
         index and preload the hottest entries (ranked by the adapt
-        store's reuse profiles) into the memory tier.  Same contract
-        as _boot_warm: runs as the ``__boot__`` pseudo-tenant, never
-        raises — a defective cache dir means cold serving, not a dead
-        server."""
+        store's reuse profiles) into the memory tier.  Runs as the
+        ``__boot__`` pseudo-tenant span context, so any work it
+        triggers folds to the boot account, never a real tenant's.
+        Never raises — a defective cache dir means cold serving, not a
+        dead server."""
         from dpark_tpu import trace
         try:
             with trace.ctx(job="__boot__"):
@@ -557,8 +522,6 @@ class JobServer:
         ex = getattr(self.scheduler, "executor", None)
         if ex is not None:
             out["program_cache"] = ex.program_cache_stats()
-        if self._aot_warm is not None:
-            out["aot_warm"] = dict(self._aot_warm)
         if self._rc_boot is not None:
             out["result_cache_boot"] = dict(self._rc_boot)
         rc = resultcache.stats()

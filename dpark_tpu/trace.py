@@ -249,12 +249,6 @@ Span taxonomy (name / cat):
                                        decode counters (the merge
                                        substrate, see
                                        merged_worker_counters)
-    aot.load, aot.store,     "aot"     persistent AOT executable
-    aot.warm                           cache (ISSUE 17): disk-tier
-                                       load/serialize per program and
-                                       the boot-warm deserializations
-                                       (warm passes run under the
-                                       __boot__ pseudo-tenant ctx)
     journal.replay           "sched"   crash-journal replay (ISSUE
                                        20): one instant event per job
                                        whose completed stages were
@@ -775,14 +769,6 @@ def set_compile_sig(sig):
     _tls.compile_sig = sig
 
 
-def suppress_compile_spans(flag):
-    """Gate compile.backend emission on THIS thread: the ledger's
-    cost-capture compile (DPARK_LEDGER_COST=compile) is plane
-    overhead, not tenant consumption — emitting a span for it would
-    double-bill the program's compile_ms."""
-    _tls.no_compile_spans = bool(flag)
-
-
 def install_compile_listener():
     """Register the jax.monitoring duration listener that turns
     backend compiles into measured ``compile.backend`` spans.  Safe to
@@ -797,8 +783,6 @@ def install_compile_listener():
             plane = _PLANE
             if plane is None:
                 return
-            if getattr(_tls, "no_compile_spans", False):
-                return           # ledger cost-capture compile
             if not str(event).endswith("backend_compile_duration"):
                 return
             try:

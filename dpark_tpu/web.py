@@ -71,22 +71,6 @@ def render_metrics(scheduler):
             ("evictions", "compiled-program cache LRU evictions")):
         metric("dpark_program_cache_%s_total" % key, "counter",
                help_text, [({}, pc.get(key, 0))])
-    # persistent AOT executable cache (ISSUE 17): the disk tier's
-    # load/store/warm/evict counters — the restart acceptance ("0
-    # backend compiles on a warm process") is asserted from these
-    aot = pc.get("aot") or {}
-    for key, help_text in (
-            ("loads", "aot executables loaded from the disk cache"),
-            ("load_misses", "aot disk-cache misses (fell back to "
-                            "compile)"),
-            ("stores", "aot executables serialized to the disk cache"),
-            ("warmed", "aot executables preloaded by boot warming"),
-            ("warm_hits", "boot-warm preloads consumed by programs"),
-            ("evict_writebacks", "aot write-backs at LRU eviction"),
-            ("fallbacks", "aot executables dropped back to the jit "
-                          "path")):
-        metric("dpark_aot_%s_total" % key, "counter", help_text,
-               [({}, aot.get(key, 0))])
     # shared-computation result cache (ISSUE 18): the planner-probe
     # counters — the two-tenant reuse acceptance ("zero scan chunks
     # on the repeated query") is asserted from these
@@ -393,7 +377,6 @@ _PAGE = """<!doctype html>
 <div id="util" style="width:480px;height:18px;display:flex;
  border:1px solid #999;margin-bottom:6px"></div>
 <div id="utiltxt" style="margin-bottom:8px"></div>
-<div id="aotline" style="margin-bottom:8px"></div>
 <table id="l"><tr><th>tenant</th><th>device s</th>
 <th>lock wait s</th><th>HBM byte-s</th><th>bulk bytes</th>
 <th>spill bytes</th><th>fetches</th><th>compiles (ms)</th>
@@ -436,17 +419,6 @@ async function tick() {
   let hd = {};
   try { hd = await (await fetch('/api/health')).json(); }
   catch (e) { hd = {}; }
-  // persistent AOT executable cache (ISSUE 17): disk-tier counters —
-  // a warm restart shows loads/warm hits with zero backend compiles
-  const ao = hd.aot || null;
-  document.getElementById('aotline').textContent = ao
-    ? 'aot cache [' + ao.mode + ']: ' + (ao.loads || 0) + ' loaded / '
-      + (ao.load_misses || 0) + ' missed / ' + (ao.stores || 0)
-      + ' stored / ' + (ao.warmed || 0) + ' warmed ('
-      + (ao.warm_hits || 0) + ' consumed) / '
-      + (ao.evict_writebacks || 0) + ' evict write-backs / '
-      + (ao.fallbacks || 0) + ' fallbacks'
-    : '';
   const r = await fetch('/api/jobs'); const jobs = await r.json();
   const t = document.getElementById('t');
   while (t.rows.length > 1) t.deleteRow(1);

@@ -101,20 +101,28 @@ def test_more_devices_than_exist_is_refused():
         c.start()
 
 
-def test_outside_compile_cache_dir_is_left_alone(monkeypatch):
+@pytest.mark.parametrize("platform,outside", [
+    ("tpu", True), ("tpu", False), ("cpu", True), ("cpu", False)])
+def test_outside_compile_cache_dir_is_left_alone(monkeypatch, platform,
+                                                 outside):
+    """A directory given in JAX_COMPILATION_CACHE_DIR is left alone; with
+    none given a device backend's cache goes to the fixed `.jax_cache`
+    beside the package, and a CPU backend's stays off."""
     import jax
     from dpark_tpu.backend.tpu import executor
     before = jax.config.jax_compilation_cache_dir
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
-    executor._place_compile_cache("tpu")
-    assert jax.config.jax_compilation_cache_dir == before
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    if outside:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     try:
-        executor._place_compile_cache("tpu")
-        assert jax.config.jax_compilation_cache_dir == os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            ".jax_cache")
+        executor._place_compile_cache(platform)
+        placed = jax.config.jax_compilation_cache_dir
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
-    executor._place_compile_cache("cpu")
-    assert jax.config.jax_compilation_cache_dir == before
+    if platform == "tpu" and not outside:
+        assert placed == os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache")
+    else:
+        assert placed == before

@@ -1,5 +1,5 @@
 """Resource attribution plane (ISSUE 15): per-tenant mesh ledger,
-program cost profiles, utilization/conservation.
+utilization/conservation.
 
 The contracts under test:
 
@@ -13,9 +13,6 @@ The contracts under test:
 * MESH LOCK — acquisition wait is measured (the new mesh.lock span),
   hold time meters mesh-busy, and the conservation check reconciles
   attributed occupancy with the meter under two concurrent tenants.
-* COST PROFILES — compile-time jax cost analysis persists to the
-  adapt store keyed by the plan signature and reads back in a FRESH
-  process (the items-2/3 pricing prior).
 * PROGRAM CACHE — per-job hit/miss counts are EXACT under concurrency
   (the PR 9 caveat, closed).
 * CROSS-PROCESS — multiproc workers' fetch activity surfaces in the
@@ -28,8 +25,6 @@ The contracts under test:
 
 import json
 import os
-import subprocess
-import sys
 import threading
 import time
 import urllib.request
@@ -42,21 +37,18 @@ from dpark_tpu import conf, faults, health, ledger, trace
 @pytest.fixture(autouse=True)
 def _clean_planes():
     """Every test starts and ends with fresh sinks and no trace/chaos
-    planes; the cost-capture seen-set resets so per-test stores see
-    their own captures."""
+    planes."""
     from dpark_tpu import service
     trace.configure("off")
     faults.configure(None)
     health.configure("on")
     ledger.configure("on")
-    ledger.reset_cost_capture()
     yield
     service.shutdown()
     trace.configure("off")
     faults.configure(None)
     health.configure("on")
     ledger.configure("on")
-    ledger.reset_cost_capture()
 
 
 @pytest.fixture()
@@ -557,79 +549,6 @@ def test_cache_served_query_end_to_end_ledger(tmp_path):
         trace.configure("off")
         ledger.configure("off")
         ctx.stop()
-
-
-# ---------------------------------------------------------------------------
-# program cost profiles (the items-2/3 pricing prior)
-# ---------------------------------------------------------------------------
-
-def test_program_cost_profile_roundtrip_fresh_process(
-        tctx2, tmp_path, monkeypatch):
-    from dpark_tpu import adapt
-    monkeypatch.setattr(conf, "LEDGER_COST", "compile")
-    store = str(tmp_path / "adapt")
-    adapt.configure(mode="observe", store_dir=store)
-    trace.configure("ring")
-    try:
-        got = dict(tctx2.parallelize(_device_data(8000), 2)
-                   .reduceByKey(lambda a, b: a + b, 2).collect())
-        assert len(got) == 37
-        profiles = adapt.program_costs()
-        assert profiles, "no cost profile captured"
-        key, prof = next(iter(profiles.items()))
-        assert prof["flops"] > 0, prof
-        assert prof["bytes_accessed"] > 0, prof
-        # the compile path captured measured memory analysis
-        assert prof.get("peak_hbm_bytes", 0) > 0, prof
-        assert key in adapt.summary()["programs"]
-        # a FRESH process reads the persisted profile back (the
-        # acceptance criterion: pricing before the first observed run)
-        out = subprocess.run(
-            [sys.executable, "-c",
-             "import json\n"
-             "from dpark_tpu import adapt\n"
-             "adapt.configure(mode='observe', store_dir=%r)\n"
-             "print(json.dumps(adapt.program_costs()))" % store],
-            capture_output=True, text=True, timeout=120)
-        assert out.returncode == 0, out.stderr
-        fresh = json.loads(out.stdout.strip().splitlines()[-1])
-        assert fresh.get(key, {}).get("flops") == prof["flops"], fresh
-    finally:
-        trace.configure("off")
-        adapt.configure()
-
-
-def test_cost_capture_once_per_signature(tctx2, tmp_path, monkeypatch):
-    from dpark_tpu import adapt
-    adapt.configure(mode="observe", store_dir=str(tmp_path / "a"))
-    trace.configure("ring")
-    try:
-        data = _device_data(6000)
-        for _ in range(3):
-            dict(tctx2.parallelize(data, 2)
-                 .reduceByKey(lambda a, b: a + b, 2).collect())
-        events = [r for r in trace.snapshot()
-                  if r["name"] == "ledger.cost"]
-        sigs = [r["args"]["sig"] for r in events]
-        assert len(sigs) == len(set(sigs)), sigs
-    finally:
-        trace.configure("off")
-        adapt.configure()
-
-
-def test_cost_capture_off_mode_records_nothing(
-        tctx2, tmp_path, monkeypatch):
-    from dpark_tpu import adapt
-    monkeypatch.setattr(conf, "LEDGER_COST", "off")
-    adapt.configure(mode="observe", store_dir=str(tmp_path / "a"))
-    trace.configure("ring")
-    try:
-        dict(tctx2.parallelize(_device_data(6000), 2)
-             .reduceByKey(lambda a, b: a + b, 2).collect())
-        assert adapt.program_costs() == {}
-    finally:
-        trace.configure("off")
-        adapt.configure()
 
 
 # ---------------------------------------------------------------------------
