@@ -675,6 +675,21 @@ class _MeshLock:
                 "wall_s": round(time.time() - self.t_created, 6)}
 
 
+def _rises(per_device, counts):
+    """Is a column strictly increasing over its valid rows and from each
+    device that holds rows to the next (layout._masked_ascending's
+    (rising, first, last) a device)?  Then no value is there twice."""
+    prev = None
+    for (rising, first, last), n in zip(per_device.tolist(),
+                                        counts.tolist()):
+        if not n:
+            continue
+        if not rising or (prev is not None and first <= prev):
+            return False
+        prev = last
+    return True
+
+
 def _shard_map(fn, mesh, in_specs, out_specs):
     return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
@@ -763,6 +778,9 @@ class JAXExecutor:
         # the driver in numpy and reported here (note_host_scan)
         self.scan_rows_device = 0
         self.scan_rows_host = 0
+        # joins a planned query lowered with every side a table resident
+        # on the device (note_device_joins, a bare `+=` a job)
+        self.query_joins_device = 0
         # filters folded into a shuffle write's validity mask
         # (fuse.run_ops; bare `+=`): a plan's count, at each launch of
         # its narrow, reduce or spilled-run map program
@@ -1721,7 +1739,10 @@ class JAXExecutor:
         (S dtype, [(lo, hi) a word]).  The ranges are read ONCE, the
         first time a table is made over the RDD (one program a column
         and one blocking read, site `table.stats`), and kept with the
-        cached batch: a query plans from them and reads nothing."""
+        cached batch: a query plans from them and reads nothing.  The
+        same read says which int columns are unique ("unique", a flag a
+        column): strictly increasing over the valid rows of each device
+        and from one device to the next, as a generator writes keys."""
         meta = self.result_cache.get(rdd_id)
         if meta is None:
             return None
@@ -1741,27 +1762,39 @@ class JAXExecutor:
                 shape != () for _, shape in specs):
             return None
         ints = [i for i, (dt, _) in enumerate(specs) if dt.kind == "i"]
+        plain = [k for k, g in enumerate(groups)
+                 if not layout._is_bytestr(g) and specs[g][0].kind == "i"]
         read = layout.host_read(
             [meta["counts"]] + [layout._masked_minmax(
-                meta["leaves"][i], meta["counts"]) for i in ints],
+                meta["leaves"][i], meta["counts"]) for i in ints]
+            + [layout._masked_ascending(meta["leaves"][groups[k]],
+                                        meta["counts"]) for k in plain],
             site="table.stats")
-        rows = int(read[0].sum())
+        counts = read[0]
+        rows = int(counts.sum())
         # an empty table's masked min/max are the type's extremes
         ranges = {i: (int(r[0]), int(r[1])) if rows else (0, 0)
-                  for i, r in zip(ints, read[1:])}
-        columns = []
-        for g in groups:
+                  for i, r in zip(ints, read[1:1 + len(ints)])}
+        rising = dict(zip(plain, read[1 + len(ints):]))
+        columns, unique = [], []
+        for k, g in enumerate(groups):
             if layout._is_bytestr(g):
                 columns.append((np.dtype("S%d" % g.width),
                                 [ranges[i] for i in g.words]))
             else:
                 columns.append((specs[g][0], ranges.get(g)))
-        return {"rows": rows, "columns": columns}
+            unique.append(k in rising and _rises(rising[k], counts))
+        return {"rows": rows, "columns": columns, "unique": unique}
 
     def note_host_scan(self, rows):
         """The query plane's driver-side scan (planner._ScanSeg.run)
         reports the rows it evaluated in numpy."""
         self.scan_rows_host += rows
+
+    def note_device_joins(self, joins):
+        """A planned query's job (planner.PlannedQuery._job) reports the
+        joins it lowered with every side resident on the device."""
+        self.query_joins_device += joins
 
     def drop_result(self, rdd_id):
         meta = self.result_cache.pop(rdd_id, None)

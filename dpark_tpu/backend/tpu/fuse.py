@@ -1745,6 +1745,14 @@ def analyze_stage(stage, ndev, executor_or_store):
                          lambda: ())()
     top = stage.rdd
     extracted = extract_chain(top, cached_ids)
+    # an aggregation the query plane could not prove inside int64 runs
+    # in exact Python ints (table._pin_exact): its map and reduce stages
+    pinned = getattr(stage.shuffle_dep, "host_reason", None) \
+        if stage.is_shuffle_map else None
+    if extracted is not None and isinstance(extracted[0], ShuffledRDD):
+        pinned = pinned or getattr(extracted[0].dep, "host_reason", None)
+    if pinned:
+        return _fallback(pinned)
     if extracted is None:
         return analyze_text_stage(stage, ndev, executor_or_store)
     source_rdd, ops, passthrough = extracted

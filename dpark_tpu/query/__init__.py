@@ -14,7 +14,9 @@ shipped device machinery instead of per-row Python lambdas:
   * group-by aggregates (sum/count/min/max/avg + traceable UDAs)
     lower onto the device combine exchange / SegAggOp / SegMapOp over
     the tuple-key shuffle (PRs 3-4);
-  * equi-joins lower onto the device join source (PR 3);
+  * equi-joins, a left-deep chain of them over part files, driver
+    slices or tables resident on the device, lower onto the device join
+    source (PR 3), their sums proved from keys shown unique;
   * string group/join keys ride dictionary-encoded (TokenDict) and
     decode at egest;
   * per-operator device-vs-host choice is priced through the adaptive

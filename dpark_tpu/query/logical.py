@@ -132,32 +132,48 @@ class GroupAgg(Node):
 
 
 class Join(Node):
-    """Equi-join on one column name present in both inputs; output
-    schema mirrors TableRDD.join ([on] + left-rest + right-rest with
-    uniquified names)."""
+    """Equi-join of the left input's column `on` with the right input's
+    `right_on` (the same name where it is None); output schema mirrors
+    TableRDD.join ([on] + [right_on where it differs] + left-rest +
+    right-rest with uniquified names).  `colmap` lists (out_name, side,
+    source name) with side "on" for the key, "l" or "r"."""
 
-    def __init__(self, left, right, on, fields):
+    def __init__(self, left, right, on, fields, right_on=None):
         super().__init__(fields)
         self.children = (left, right)
         self.on = on
+        self.right_on = right_on or on
 
     def describe(self):
+        if self.right_on != self.on:
+            return "Join(on=%s=%s)" % (self.on, self.right_on)
         return "Join(on=%s)" % self.on
 
 
 class Sort(Node):
-    """keys: [ColumnExpr]; applied at egest (result rows are
+    """keys: [ColumnExpr]; reverse: one flag for every key, or a flag a
+    key (kept as a tuple a key); applied at egest (result rows are
     driver-resident by then — the coordinator gather-sort)."""
 
     def __init__(self, child, keys, reverse=False):
         super().__init__(child.fields)
         self.children = (child,)
         self.keys = keys
-        self.reverse = reverse
+        if isinstance(reverse, (list, tuple)):
+            if len(reverse) != len(keys):
+                raise ValueError("one direction a sort key: %d keys, %d "
+                                 "directions" % (len(keys), len(reverse)))
+            self.reverse = tuple(bool(r) for r in reverse)
+        else:
+            self.reverse = (bool(reverse),) * len(keys)
 
     def describe(self):
+        if len(set(self.reverse)) > 1:
+            return "Sort(%s)" % ", ".join(
+                k.expr + (" desc" if r else "")
+                for k, r in zip(self.keys, self.reverse))
         return "Sort(%s%s)" % (", ".join(k.expr for k in self.keys),
-                               " desc" if self.reverse else "")
+                               " desc" if any(self.reverse) else "")
 
 
 class CachedResult(Node):
@@ -208,12 +224,12 @@ def plan_signature(node):
                       for a in node.aggs),
                 plan_signature(node.children[0]))
     if isinstance(node, Join):
-        return ("Join", node.on, tuple(node.fields),
+        return ("Join", node.on, node.right_on, tuple(node.fields),
                 plan_signature(node.children[0]),
                 plan_signature(node.children[1]))
     if isinstance(node, Sort):
         return ("Sort", tuple(k.expr for k in node.keys),
-                bool(node.reverse),
+                node.reverse,
                 plan_signature(node.children[0]))
     if isinstance(node, CachedResult):
         return ("CachedResult", node.key)

@@ -24,7 +24,7 @@ nothing else (no conf entry, no argument):
     pass on the driver, no parallelize, no ingest.  What the rules
     need of such a table (dtypes, [min, max] a column, rows) was read
     once, when the table was made (`JAXExecutor.resident_table`).
-    Group-by and plain scans lower; a join over such a table declines.
+    Group-by, plain scans and joins lower over such tables.
 
 After the scan:
 
@@ -36,7 +36,18 @@ After the scan:
     groupByKey().mapValues(sum/min/max/len) (SegAggOp / the combiner
     rewrite — adapt decision point 4 prices which), and traceable UDAs
     ride the SegMapOp segmented apply (PR 4);
-  * equi-JOINs lower onto the PR 3 device join source;
+  * equi-JOINs lower onto the PR 3 device join source: a left-deep
+    chain of them (`_Chain`), keys named differently on each side, each
+    side a scan (its stage program over a resident table, else the
+    driver scan handed over by parallelize), a join's output re-keyed
+    by a map for the next, every predicate over one table's columns
+    pushed into that table's scan at any depth, a group key or argument
+    over one table's columns computed there.  A bushy tree, a computed
+    column between joins or a predicate over two tables declines.  The
+    int64 proof of a sum over a join takes a bound on the last join's
+    rows (`unique-key`): the other side's rows where a join key is
+    shown unique (the load-time read of a resident table, one np.unique
+    over a driver-resident key), else left x right;
   * string group/join keys (and string passthrough columns crossing
     the device) ride TokenDict-encoded int64 ids, decoded at egest; a
     fixed-width byte-string (S<w>) key column of a resident table rides
@@ -51,8 +62,12 @@ Every rule records its choice with a reason; host choices surface as
 pre-flight and the runtime records per stage.  Admission is exact:
 anything the rules cannot PROVE equivalent to the host row path
 declines with a reason, and the host object path serves the query.
+A declined query whose int64 proof failed pins its row chain's combine
+to the object path (table._pin_exact), which folds exact Python ints.
 With the trace plane on, `plan_query` is the `query.plan` span and the
-egest-side finishing the `query.finish` span (dpark_tpu/trace.py).
+egest-side finishing the `query.finish` span (dpark_tpu/trace.py); a
+job whose joins all had every side on the device adds their number to
+the executor's `query_joins_device` (PlannedQuery._job).
 """
 
 import math
@@ -210,14 +225,16 @@ def _make_dev_project(names, items):
     return f
 
 
-def _make_join_side(nvals):
-    """Flat (k, v1..vn) row -> (k, (v1..vn)) for the join exchange."""
+def _make_join_side(idxs):
+    """Flat row -> (row[idxs[0]], (row[i] for the rest)) for the join
+    exchange: a scan's key and values, or a join's flat output re-keyed
+    for the next join of a chain."""
     def f(rec):
-        return (rec[0], tuple(rec[1:1 + nvals]))
+        return (rec[idxs[0]], tuple(rec[i] for i in idxs[1:]))
     return f
 
 
-def _make_join_flat(nl, nr):
+def _make_join_flat():
     """Joined (k, ((l...), (r...))) -> flat (k, l..., r...)."""
     def f(kv):
         k, (lv, rv) = kv
@@ -410,6 +427,7 @@ class _ScanSeg:
         # then become the filter and map of a stage program
         # (PlannedQuery._device_chain); run() is for the other sources
         self.device = scan.device
+        self.unique = {}            # name -> no value twice (_seg_unique)
 
     # -- plan-time -------------------------------------------------------
     def source_meta(self):
@@ -625,6 +643,7 @@ class PlannedQuery:
         self._rdd = None
         self._rows_cache = None
         self._group = None
+        self._chain = None          # joins: the left-deep chain (_Chain)
         self._join = None
         self._out_fields = None
         self._partial = None        # result-cache partial-merge recipe
@@ -697,6 +716,14 @@ class PlannedQuery:
         tls = getattr(self.ctx.scheduler, "_tls", None)
         before = getattr(tls, "record", None)
         out = action(rdd)
+        if self.mode in ("join", "join_group") \
+                and all(seg.device is not None for seg in self.segs):
+            # every side of every join on the device: the executor's
+            # counter query_joins_device, as _ScanSeg reports host scans
+            note = getattr(getattr(self.ctx.scheduler, "executor", None),
+                           "note_device_joins", None)
+            if note is not None:
+                note(len(self.segs) - 1)
         record = getattr(tls, "record", None)
         job = record["id"] if record is not None \
             and record is not before else None
@@ -823,27 +850,15 @@ class PlannedQuery:
                 if g["key_bound"] is not None:
                     # proved by _rule_lower_group; read by fuse's plan
                     r.dep.key_bound = g["key_bound"]
-        else:                       # join / join_group
+        else:                       # join / join_group: a left-deep chain
             j = self._join
-            sides = []
-            for si, seg in enumerate(self.segs):
-                env = seg.run(self.scan_stats)
-                names = j["side_cols"][si]
-                dec_names = j["side_dec"][si]
-                n = len(next(iter(env.values()))) if env else 0
-                cols = []
-                for c, dn in zip(names, dec_names):
-                    if c is None:       # key-only side: dummy value
-                        cols.append(np.zeros(n, np.int64))
-                        continue
-                    cols.append(self._encoded(
-                        env[c], dn or c, j["enc"].get((si, c))))
-                rdd = ctx.parallelize(Columns(*cols), npart)
-                sides.append(rdd.map(_make_join_side(len(names) - 1)))
-            joined = sides[0].join(sides[1], npart)
-            nl = len(j["side_cols"][0]) - 1
-            nr = len(j["side_cols"][1]) - 1
-            flat = joined.map(_make_join_flat(nl, nr))
+            sides = [self._join_side(si, seg, npart)
+                     for si, seg in enumerate(self.segs)]
+            flat = None
+            for i in range(1, len(sides)):
+                left = sides[0] if i == 1 \
+                    else flat.map(_make_join_side(j["rekey"][i]))
+                flat = left.join(sides[i], npart).map(_make_join_flat())
             if self.mode == "join_group":
                 g = self._group
                 flat = flat.map(_make_group_over(
@@ -854,6 +869,23 @@ class PlannedQuery:
                 r = flat.map(_make_pick(tuple(j["out_idxs"])))
         self._rdd = r
         return r
+
+    def _join_side(self, si, seg, npart):
+        """Scan `si` as one side of the chain's join exchange, (key,
+        (values)) records: over a table resident on the device its
+        stage program's chain (_device_chain), else the driver scan's
+        columns handed over by parallelize."""
+        from dpark_tpu.rdd import Columns
+        j = self._join
+        names = j["sides"][si]
+        if seg.device is not None:
+            return self._device_chain(seg).map(_make_join_side(
+                tuple(seg.out.index(c) for c in names)))
+        env = seg.run(self.scan_stats)
+        cols = [self._encoded(env[c], j["dec"].get((si, c), [c]),
+                              j["enc"].get((si, c))) for c in names]
+        return self.ctx.parallelize(Columns(*cols), npart).map(
+            _make_join_side(tuple(range(len(cols)))))
 
     def _device_chain(self, seg):
         """The scan pipeline of a table resident on the device as the
@@ -884,8 +916,9 @@ class PlannedQuery:
 
     def _encoded(self, col, name, dict_=None):
         """Dictionary-encode an object column for the device path (or
-        pass a numeric column through).  `dict_` shares one TokenDict
-        across the two sides of a join.  Only GENUINE str values
+        pass a numeric column through), decoded at egest by `name` (or
+        by each of a list of names).  `dict_` shares one TokenDict
+        across the sides of a join key.  Only GENUINE str values
         encode — a bool/None/mixed object column raises, which the
         table action catches as a recorded host fallback (encoding
         them would silently turn True into the string 'True' at
@@ -908,7 +941,8 @@ class PlannedQuery:
             out = ids[inv]
         else:
             out = np.array([], np.int64)
-        self.decoders.setdefault(name, td)
+        for nm in [name] if isinstance(name, str) else name:
+            self.decoders.setdefault(nm, td)
         return out
 
     def _decode(self, name, val):
@@ -979,12 +1013,18 @@ class PlannedQuery:
                 fields = names
             else:                   # sort
                 codes, reverse = meta
-                def key(row, codes=codes, fields=fields):
-                    env = dict(zip(fields, row))
-                    ks = [eval(c, {"__builtins__": _SAFE_BUILTINS},
-                               dict(env)) for c in codes]
-                    return ks[0] if len(ks) == 1 else tuple(ks)
-                rows = sorted(rows, key=key, reverse=reverse)
+                # one pass over the key tuple where every key has one
+                # direction; else a stable pass a key, the last first
+                passes = [(codes, reverse[0])] if len(set(reverse)) == 1 \
+                    else [([c], r) for c, r in reversed(list(
+                        zip(codes, reverse)))]
+                for keys, rev in passes:
+                    def key(row, codes=keys, fields=fields):
+                        env = dict(zip(fields, row))
+                        ks = [eval(c, {"__builtins__": _SAFE_BUILTINS},
+                                   dict(env)) for c in codes]
+                        return ks[0] if len(ks) == 1 else tuple(ks)
+                    rows = sorted(rows, key=key, reverse=rev)
         self._out_fields = fields
         return rows
 
@@ -1011,7 +1051,9 @@ def plan_query(root, ctx, reuse=True):
                    for seg in pq.segs}
         pq._plan_reading = (t0, time.time() - t0, {
             "mode": pq.mode, "source": "+".join(sorted(sources)),
-            "rules": len(pq.decisions)})
+            "rules": len(pq.decisions),
+            "joins": len(pq.segs) - 1 if pq.mode in ("join", "join_group")
+            else 0})
         return pq
     return _plan(pq, reuse)
 
@@ -1114,20 +1156,259 @@ def _rule_shape(pq):
 
 
 def _shape_join(pq, join):
-    sides = []
-    for child in join.children:
-        ops, leaf = _linearize(child)
+    """A left-deep chain of equi-joins over scan chains (_Chain): the
+    leftmost scan is segs[0] and the right input of the chain's i-th
+    join, leaf-most first, is segs[i].  Every name a join outputs is
+    resolved to the scans that hold its value, and every predicate
+    between the joins or above the last one that reads ONE scan's
+    columns is pushed into that scan's pipeline, at any depth.  A join
+    whose right input is a join (a bushy tree), a computed column
+    between joins and a predicate over two scans decline."""
+    joins, node = [], join
+    while isinstance(node, Join):
+        joins.append(node)
+        node = _linearize(node.children[0])[1]
+    joins.reverse()
+    if not isinstance(node, Scan):
+        raise _Decline("join", "join input is not a scan chain")
+    ch = _Chain(joins)
+    side_ops = [list(_linearize(joins[0].children[0])[0])]
+    ch.tops.append(joins[0].children[0])
+    segs = [_ScanSeg(node)]
+    for j in joins:
+        ops, leaf = _linearize(j.children[1])
+        if isinstance(leaf, Join):
+            raise _Decline("join", "the right input of a join is itself "
+                           "a join: a bushy join tree stays on the host")
         if not isinstance(leaf, Scan):
             raise _Decline("join", "join input is not a scan chain")
+        segs.append(_ScanSeg(leaf))
+        side_ops.append(list(ops))
+        ch.tops.append(j.children[1])
+    between = [_linearize(j.children[0])[0] for j in joins[1:]]
+    for ops in side_ops + between + [pq._shape["join_ops"]]:
         if any(isinstance(o, Sort) for o in ops):
             raise _Decline("sort", "sort below a join stays on host")
-        if leaf.device is not None:
-            raise _Decline("join", "a join over a table resident on "
-                           "the device is not lowered yet (table %r)"
-                           % leaf.table_name)
-        sides.append((ops, leaf))
-    pq.segs = [_ScanSeg(leaf) for _, leaf in sides]
-    pq._shape["side_ops"] = [ops for ops, _ in sides]
+    pq.segs = segs
+    pq._shape["side_ops"] = side_ops
+    pq._chain = ch
+    names = {n: [(0, n)] for n in ch.tops[0].fields}
+    for i, j in enumerate(joins, 1):
+        if i > 1:
+            names = ch.through(pq, names, between[i - 2], side_ops)
+        right = {n: [(i, n)] for n in j.children[1].fields}
+        if j.on not in names or j.right_on not in right:
+            raise _Decline("join", "join key %r or %r is not a column of "
+                           "its input" % (j.on, j.right_on))
+        ch.lkeys.append(list(names[j.on]))
+        ch.rkeys.append(j.right_on)
+        out = {}
+        for out_name, side, src in j.colmap:
+            if side == "on":
+                out[out_name] = names[j.on] + right[j.right_on]
+            else:
+                out[out_name] = (names if side == "l" else right)[src]
+        names = out
+    ch.names = ch.through(pq, names, pq._shape["join_ops"], side_ops)
+
+
+def _conjuncts(p, fields):
+    """A predicate's top-level `and` terms, each its own ColumnExpr (a
+    WHERE over a join pushes each into the scan it reads)."""
+    import ast
+    body = p.tree.body if p.tree is not None else None
+    if not (isinstance(body, ast.BoolOp) and isinstance(body.op, ast.And)):
+        return [p]
+    return [q for v in body.values for q in _conjuncts(
+        E.compile_expr(ast.get_source_segment(p.expr, v), fields), fields)]
+
+
+class _Chain:
+    """A left-deep chain of equi-joins over scans (_shape_join).  A
+    column is named by its CANON, (scan index, name at that scan's
+    output); `names` maps every name the chain's consumer reads to the
+    canons that hold its value (a join key's to both of its sides).
+
+    A flat row is a list of SLOTS, each a frozenset of canons of equal
+    value (None: a dummy value, for a side that carries none).  Scan s
+    hands the join exchange (key, values) records, `side_columns(s)`;
+    the output of join i is [its key slot] + the slots its left input
+    carries + the values of scan i; for join i + 1 that row is re-keyed
+    by a map (`rekey(i + 1)`).  `lay_out` chooses, for what the
+    consumer reads, the fewest columns to carry."""
+
+    def __init__(self, joins):
+        self.joins = joins      # bottom-up: join i is joins[i - 1]
+        self.tops = []          # a scan: the node its side ends in
+        self.lkeys = []         # join i: canons of its left key
+        self.rkeys = []         # join i: its right key at scan i
+        self.names = {}
+        self.derived = []       # (scan, name, text): computed in a scan
+
+    # -- shape -----------------------------------------------------------
+    def through(self, pq, names, ops, side_ops):
+        """The names above `ops` (top-down Filters and Projects over a
+        join's output): each predicate pushed into the scan it reads, a
+        bare projection a rename, anything else declines."""
+        for op in reversed(ops):
+            if isinstance(op, Filter):
+                for p in op.preds:
+                    for part in _conjuncts(p, list(names)):
+                        self._push(pq, part, names, side_ops)
+                continue
+            out = {}
+            for name, ce in op.exprs:
+                if not _is_bare_name(ce) or ce.tree.body.id not in names:
+                    raise _Decline("join", "computed column %r between "
+                                   "joins stays on the host" % ce.expr)
+                out[name] = names[ce.tree.body.id]
+            names = out
+        return names
+
+    def _at(self, names, columns, s):
+        """{column: its name at scan s} where scan s holds every one of
+        `columns`, else None."""
+        ren = {}
+        for c in columns:
+            hit = [n for si, n in names.get(c, ()) if si == s]
+            if not hit:
+                return None
+            ren[c] = hit[0]
+        return ren
+
+    def _push(self, pq, p, names, side_ops):
+        for s in range(len(self.tops)):
+            ren = self._at(names, p.columns, s)
+            if ren is None:
+                continue
+            top = self.tops[s]
+            ce = E.compile_expr(_rename_expr(p, ren), list(top.fields))
+            side_ops[s].insert(0, Filter(top, [ce]))
+            pq.decide("pushdown-predicate", "join", "device",
+                      "predicate %r pushed below the joins into scan[%d]"
+                      % (p.expr, s))
+            return
+        raise _Decline("filter", "cross-side post-join predicate %r "
+                       "stays on the host" % p.expr)
+
+    def requirement(self, ce):
+        """The slot the consumer reads `ce` from: a name's canons, or a
+        column derived inside the one scan whose columns it reads."""
+        if _is_bare_name(ce):
+            name = ce.tree.body.id
+            if name not in self.names:
+                raise _Decline("join", "column %r is not an output of the "
+                               "join" % name)
+            return frozenset(self.names[name])
+        for s in range(len(self.tops)):
+            ren = self._at(self.names, ce.columns, s)
+            if ren is None:
+                continue
+            text = _rename_expr(ce, ren)
+            for ds, dname, dtext in self.derived:
+                if (ds, dtext) == (s, text):
+                    return frozenset({(s, dname)})
+            dname = "__j%d" % len(self.derived)
+            self.derived.append((s, dname, text))
+            return frozenset({(s, dname)})
+        raise _Decline("group-agg", "%r reads columns of more than one "
+                       "table of the join: it stays on the host" % ce.expr)
+
+    # -- layout ----------------------------------------------------------
+    def lay_out(self, reqs):
+        """Lay out every flat row so that each requirement (a set of
+        canons, any one of which will do) is in the last join's output.
+        Returns the slot each is read from there."""
+        n = len(self.joins)
+        self.vals = [[] for _ in range(n + 1)]      # scan s: its values
+        self.carry = [[] for _ in range(n + 1)]     # join i, i >= 2
+        self.key_slot = [None] * (n + 1)
+        self.lkey_slot = [None] * (n + 1)
+        self.side_key = [self.lkeys[0][0][1]] + list(self.rkeys)
+        for i in range(1, n + 1):
+            self.lkey_slot[i] = self._need(set(self.lkeys[i - 1]), i - 1)
+            self.key_slot[i] = self.lkey_slot[i] | {(i, self.rkeys[i - 1])}
+        slots = [self._need(r, n) for r in reqs]
+        self.layouts = [[frozenset({(0, self.side_key[0])})]
+                        + self._vals(0)]
+        for i in range(1, n + 1):
+            left = self.layouts[0][1:] if i == 1 \
+                else self.carry[i] or [None]
+            self.layouts.append([self.key_slot[i]] + list(left)
+                                + self._vals(i))
+        return slots
+
+    def _vals(self, s):
+        return [frozenset({(s, v)}) for v in self.vals[s]] or [None]
+
+    def _need(self, req, level):
+        if level and req & self.key_slot[level]:
+            return self.key_slot[level]
+        mine = sorted(c for c in req if c[0] == level)
+        if mine:
+            name = mine[0][1]
+            if name != self.side_key[level] and name not in self.vals[level]:
+                self.vals[level].append(name)
+            return frozenset(mine[:1])
+        slot = self._need(req, level - 1)
+        if level > 1 and slot != self.lkey_slot[level] \
+                and slot not in self.carry[level]:
+            self.carry[level].append(slot)
+        return slot
+
+    def side_columns(self, s):
+        """Scan s's (key, values...) names; its key again as the value
+        of a side that carries none."""
+        return [self.side_key[s]] + (self.vals[s] or [self.side_key[s]])
+
+    def rekey(self, i):
+        """Indices of join i - 1's flat output that make join i's left
+        side: its key slot, then what it carries (or the key again)."""
+        row = self.layouts[i - 1]
+        k = row.index(self.lkey_slot[i])
+        return (k,) + (tuple(row.index(c) for c in self.carry[i]) or (k,))
+
+    # -- the int64 proof -------------------------------------------------
+    def row_bound(self, nrows, unique):
+        """An upper bound on the last join's output rows, and the reason
+        a join: a join whose key is unique on one side yields at most
+        the other side's rows (where `unique(s, name)`: no value twice
+        among the rows of scan s), else |left| x |right|.  A column
+        unique on a side stays unique in the output where the OTHER
+        side's key is unique."""
+        n = len(self.joins)
+        ru = [None] + [unique(i, self.rkeys[i - 1]) for i in range(1, n + 1)]
+        lu = [None] * (n + 1)
+
+        def unique_at(c, level):
+            s = c[0]
+            if not unique(*c):
+                return False
+            return all(ru[m] if s < m else lu[m]
+                       for m in range(max(1, s), level + 1))
+
+        rows, why = nrows(0), []
+        for i in range(1, n + 1):
+            lu[i] = any(unique_at(c, i - 1)
+                        for c in sorted(self.lkey_slot[i]))
+            right = nrows(i)
+            j = self.joins[i - 1]
+            on = "%s = %s" % (j.on, j.right_on)
+            if lu[i] and ru[i]:
+                rows = min(rows, right)
+                how = "both keys unique"
+            elif ru[i]:
+                how = "%s unique on the right: at most the left's rows" \
+                    % j.right_on
+            elif lu[i]:
+                rows = right
+                how = "%s unique on the left: at most the right's rows" \
+                    % j.on
+            else:
+                rows = rows * right
+                how = "no key proved unique: left x right rows"
+            why.append("join %d on %s: %s, %d" % (i, on, how, rows))
+        return rows, why
 
 
 def _refs_of(ops, needed):
@@ -1193,52 +1474,42 @@ def _rule_prune(pq):
                   "scan reads %s of %d columns"
                   % (pq.segs[0].wanted, len(scan.fields)))
         return
-    # join modes: need the on-column + every referenced output column,
-    # mapped back through the join's column map to each side
-    join = sh["join"]
-    needed_out = set()
+    _prune_join(pq)
+
+
+def _prune_join(pq):
+    """Column pruning over a chain of joins: what the consumer reads
+    (the group's keys and arguments, or every output column) is laid
+    out through the chain (_Chain.lay_out), and each scan reads what its
+    side carries, its key, and the columns its derived columns read."""
+    sh = pq._shape
+    ch = pq._chain
     if pq.mode == "join_group":
         g = sh["group"]
-        for _, ce in g.keys:
-            needed_out |= ce.columns
-        for (_name, _fn, arg, _uda) in g.aggs:
-            if arg is not None:
-                needed_out |= arg.columns
+        if any(a[3] is not None for a in g.aggs):
+            raise _Decline("group-agg", "UDA over a join stays on host")
+        exprs = [ce for _, ce in g.keys] + [a[2] for a in g.aggs
+                                            if a[2] is not None]
+        reqs = [ch.requirement(ce) for ce in exprs]
+        texts = [ce.expr for ce in exprs]
     else:
-        needed_out = set(join.fields)
-        for op in sh["egest"]:
-            if isinstance(op, Filter):
-                for p in op.preds:
-                    needed_out |= p.columns
-            elif isinstance(op, Sort):
-                for k in op.keys:
-                    needed_out |= k.columns
-            elif isinstance(op, Project):
-                for _, ce in op.exprs:
-                    needed_out |= ce.columns
-    for op in sh["join_ops"]:
-        if isinstance(op, Filter):
-            for p in op.preds:
-                needed_out |= p.columns
-        else:
-            raise _Decline(
-                "join", "non-filter operator between join and "
-                "group-by stays on host")
-    side_needed = [set(), set()]
-    for out_name, side, src in join.colmap:
-        if side == "on":
-            continue
-        if out_name in needed_out:
-            side_needed[0 if side == "l" else 1].add(src)
-    for si, (ops) in enumerate(sh["side_ops"]):
+        join = sh["join"]
+        reqs = [frozenset(ch.names[n]) for n in join.fields]
+        texts = list(join.fields)
+    slots = ch.lay_out(reqs)
+    pq._join_reads = dict(zip(texts, slots))
+    for si, ops in enumerate(sh["side_ops"]):
+        need = set(ch.side_columns(si))
+        for s, _name, text in ch.derived:
+            if s == si:
+                need |= E.compile_expr(text, list(ch.tops[si].fields)
+                                       ).columns
         scan = pq.segs[si].scan
-        wanted = _refs_of(ops, side_needed[si] | {join.on})
-        wanted |= {join.on}
+        wanted = _refs_of(ops, need)
         pq.segs[si].wanted = [c for c in scan.fields if c in wanted]
         pq.decide("prune-columns", "scan[%d]" % si, "device",
                   "scan reads %s of %d columns"
                   % (pq.segs[si].wanted, len(scan.fields)))
-    pq._side_needed = side_needed
 
 
 def _pipe_out_fields(pq):
@@ -1422,7 +1693,7 @@ def _rule_lower_group(pq):
     from dpark_tpu import conf
     g = pq._shape["group"]
     seg = pq.segs[0] if pq.mode == "group" else None
-    nrows = max(1, max(getattr(s, "nrows", 1) or 1 for s in pq.segs))
+    nrows = max(1, getattr(seg, "nrows", 1) or 1)
     # -- keys ------------------------------------------------------------
     key_cols, key_names, encode = [], [], []
     if len(g.keys) > int(getattr(conf, "MAX_KEY_LEAVES", 4)):
@@ -1516,53 +1787,28 @@ def _rule_lower_group(pq):
                      ",".join(kinds) if kinds else "uda",
                      len(leaves)))
         return
-    # -- join_group: keys/args picked from the flat joined row ----------
+    # -- join_group: keys and arguments read from the last join's row --
     j = pq._join
-    idx_of = j["idx_of"]
-    dtypes = j["out_dtypes"]
-    key_idxs = []
-    key_names = []
+    reads = j["reads"]          # text -> (index in the row, dtype, bounds)
+    key_idxs, key_names = [], []
     for name, ce in g.keys:
-        if not _is_bare_name(ce) or ce.tree.body.id not in idx_of:
-            raise _Decline(
-                "group-agg", "group key %r over a join must be a "
-                "plain joined column" % ce.expr)
-        src = ce.tree.body.id
-        dt = dtypes[src]
-        bad = _key_decline(src, dt)
+        idx, dt, _ = reads[ce.expr]
+        bad = _key_decline(ce.expr, dt)
         if bad and dt != np.dtype(object):
             raise _Decline("group-agg", bad)
-        key_idxs.append(idx_of[src])
+        key_idxs.append(idx)
         key_names.append(name)
-    kinds, agg_args, agg_names = [], [], []
-    for (name, fn, arg, uda) in g.aggs:
-        if uda is not None:
-            raise _Decline("group-agg",
-                           "UDA over a join stays on host")
-        if fn not in DEVICE_AGGS:
-            raise _Decline("group-agg", "non-device aggregate %r "
-                           "(device aggregates: %s)"
-                           % (fn, "/".join(DEVICE_AGGS)))
-        if fn == "count" and arg is not None and _is_bare_name(arg) \
-                and dtypes.get(arg.tree.body.id) == np.dtype(object):
-            raise _Decline(
-                "group-agg", "count(%s) over an object column counts "
-                "non-null on the host" % arg.expr)
-        if fn != "count":
-            if arg is None or not _is_bare_name(arg) \
-                    or arg.tree.body.id not in idx_of:
-                raise _Decline(
-                    "group-agg", "aggregate argument %r over a join "
-                    "must be a plain joined column"
-                    % (arg.expr if arg else None))
-            src = arg.tree.body.id
-            if dtypes[src] == np.dtype(object):
-                raise _Decline("group-agg",
-                               "string aggregate column %r" % src)
-        kinds.append(fn)
-        agg_args.append(None if fn == "count" else idx_of[src])
-        agg_names.append(name)
-    leaves, refs = _share_leaves(kinds, agg_args)
+    flat_of = {}
+
+    def admit(ce, cname):
+        flat_of[cname] = reads[ce.expr][0]
+        return reads[ce.expr][1], None
+
+    kinds, agg_args, arg_cols, agg_names, _ = _admit_aggs(
+        pq, g, j["rows"], admit, lambda cname: None,
+        lambda arg, cname: reads[arg.expr][2], j["rows_why"])
+    leaves, refs = _share_leaves(kinds, [
+        None if a is None else flat_of[arg_cols[a]] for a in agg_args])
     pq._group = {"nk": len(key_idxs), "kinds": tuple(kinds),
                  "key_idxs": key_idxs, "leaves": leaves, "refs": refs,
                  "widths": (0,) * len(key_idxs),
@@ -1597,12 +1843,16 @@ def _group_col(pq, seg, env, ce, cname, extra):
     return dt, None
 
 
-def _admit_aggs(pq, g, nrows, admit_col, extra_pop):
-    """Aggregate admission for the single-input group: device kinds,
-    derived arg columns (one a distinct argument text: sum(x) and
-    avg(x) read the same column), overflow proofs, UDA traceability.
-    Returns (kinds, the index of each aggregate's argument column or
-    None, the argument columns, names, uda)."""
+def _admit_aggs(pq, g, nrows, admit_col, extra_pop, arg_bounds=None,
+                rows_why=""):
+    """Aggregate admission: device kinds, derived arg columns (one a
+    distinct argument text: sum(x) and avg(x) read the same column),
+    overflow proofs over `nrows` rows (a group over a join: the chain's
+    row bound, `rows_why` its reason), UDA traceability.  Returns
+    (kinds, the index of each aggregate's argument column or None, the
+    argument columns, names, uda)."""
+    arg_bounds = arg_bounds or (lambda arg, cname:
+                                _arg_bounds(pq, arg, cname))
     kinds, agg_args, arg_cols, agg_names = [], [], [], []
     by_text = {}
     uda = None
@@ -1663,7 +1913,7 @@ def _admit_aggs(pq, g, nrows, admit_col, extra_pop):
         if fn in ("sum", "avg") and dt.kind == "i":
             # the host folds exact Python ints; the device wraps at
             # int64 — prove the total cannot leave int64
-            bounds = _arg_bounds(pq, arg, cname)
+            bounds = arg_bounds(arg, cname)
             if bounds is None:
                 raise _Decline(
                     "group-agg", "int %s(%s) has no value range for "
@@ -1672,9 +1922,9 @@ def _admit_aggs(pq, g, nrows, admit_col, extra_pop):
             if peak > _I64_MAX:
                 raise _Decline(
                     "group-agg", "int %s(%s) may overflow int64 "
-                    "(|value| <= %d over %d rows)"
-                    % (fn, arg.expr, max(abs(bounds[0]),
-                                         abs(bounds[1])), nrows))
+                    "(|value| <= %d over %d rows%s)"
+                    % (fn, arg.expr, max(abs(bounds[0]), abs(bounds[1])),
+                       nrows, "; " + rows_why if rows_why else ""))
         kinds.append(fn)
         agg_args.append(shared[0])
         agg_names.append(name)
@@ -1712,135 +1962,155 @@ def _check_uda(fn, dt):
                        % reason_or_vdef)
 
 
+def _seg_unique(seg, name):
+    """Does column `name` of this scan's output hold no value twice?  A
+    bare source column of a table resident on the device: what the
+    executor read when the table was made (JAXExecutor.resident_table);
+    of driver-resident slices: one np.unique over it; of part files:
+    not known.  A filter keeps a unique column unique."""
+    memo = seg.unique
+    if name in memo:
+        return memo[name]
+    ent = getattr(seg, "env_meta", {}).get(name)
+    src = ent[2] if ent is not None else None
+    found = False
+    if src is not None and seg.device is not None:
+        found = bool(dict(zip(seg.scan.fields,
+                              seg.device.get("unique") or ())).get(src))
+    elif src is not None and not hasattr(seg.scan.source, "files"):
+        try:
+            col = seg._columnarize()[src]
+            found = len(np.unique(col)) == len(col)
+        except (_Decline, TypeError, ValueError):
+            found = False
+    memo[name] = found
+    return found
+
+
 def _rule_lower_join(pq):
-    """Lower the equi-join onto the device join source: shared key
-    dtype (string keys share one TokenDict), side layouts, post-join
-    filters pushed to their side's scan when single-sided."""
-    join = pq._shape["join"]
+    """Lower the chain of equi-joins onto the device join source: each
+    scan a side of the exchange (the stage program over a resident
+    table, else the driver scan's columns), a join's output re-keyed
+    for the next, the keys of a join of one dtype (strings share one
+    TokenDict a key, a resident key's range below the padding
+    sentinel), a group key or argument computed from one scan's
+    columns computed in that scan, and the bound on the last join's
+    rows that the int64 proofs of its sums take (`unique-key`)."""
+    ch = pq._chain
     segs = pq.segs
-    key_dts = []
-    for si in range(2):
-        dt = segs[si].dtypes.get(join.on)
-        if dt is None:
-            raise _Decline("join", "join column %r not produced by "
-                           "side %d's scan" % (join.on, si))
-        key_dts.append(dt)
+    n = len(ch.joins)
+    derived = [[] for _ in segs]
+    for s, dname, text in ch.derived:
+        seg = segs[s]
+        env = seg.env_meta
+        ve, reason = E.vectorize(
+            E.compile_expr(text, list(env)),
+            {k: v[0] for k, v in env.items()},
+            {k: v[1] for k, v in env.items() if v[1]},
+            device=seg.device is not None)
+        if ve is None:
+            raise _Decline("group-agg", "%r over a join stays on the host: "
+                           "%s" % (text, reason))
+        dt = np.dtype(np.int64) if ve.kind == "i" \
+            else np.dtype(np.float64)
+        env[dname] = (dt, ve.bounds, None)
+        seg.dtypes[dname] = dt
+        if ve.bounds:
+            seg.bounds[dname] = ve.bounds
+        derived[s].append((dname, ("vec", ve)))
+    sides = []
+    for s, seg in enumerate(segs):
+        cols = ch.side_columns(s)
+        if derived[s]:
+            made = {d for d, _ in derived[s]}
+            keep = list(dict.fromkeys(c for c in cols if c not in made))
+            seg.steps.append(("project", [(c, ("pass", c)) for c in keep]
+                              + derived[s]))
+            seg.out = keep + [d for d, _ in derived[s]]
+        sides.append(cols)
+    # the keys: one dtype a join, strings through one shared TokenDict
     enc = {}
-    if any(dt == np.dtype(object) for dt in key_dts):
-        if key_dts[0] != key_dts[1]:
-            raise _Decline("join", "join key dtypes disagree "
-                           "(%s vs %s)" % tuple(key_dts))
-        from dpark_tpu.native import TokenDict
-        shared = TokenDict()
-        enc[(0, join.on)] = shared
-        enc[(1, join.on)] = shared
-        pq.decide("encode-strings", "join", "device",
-                  "string join key %r rides dictionary-encoded "
-                  "(one shared TokenDict across both sides)" % join.on)
-    else:
-        bad = _key_decline(join.on, key_dts[0]) \
-            or _key_decline(join.on, key_dts[1])
-        if bad:
-            raise _Decline("join", bad)
-    # side column layouts: on-key first, then each side's needed
-    # passthrough columns (join output order)
-    side_needed = getattr(pq, "_side_needed", [set(), set()])
-    on_out = next(o for (o, s, c) in join.colmap if s == "on")
-    side_cols = [[join.on], [join.on]]
-    side_dec = [[on_out], [on_out]]     # decoder names (output names)
-    out_fields = []
-    out_idxs = []
-    idx_of = {}
-    out_dtypes = {}
-    # flat row layout: (on, l_needed..., r_needed...)
-    lmap = [(o, s, c) for (o, s, c) in join.colmap if s == "l"]
-    rmap = [(o, s, c) for (o, s, c) in join.colmap if s == "r"]
-    side_outs = [[], []]
-    for side_i, cmap in ((0, lmap), (1, rmap)):
-        for out_name, _s, src in cmap:
-            if src not in side_needed[side_i]:
-                continue
-            side_cols[side_i].append(src)
-            side_dec[side_i].append(out_name)
-            side_outs[side_i].append(out_name)
-            out_dtypes[out_name] = segs[side_i].dtypes.get(
-                src, np.dtype(object))
-    # a side with only the key still needs one value column (the
-    # device join carries (k, v) records) — a dummy zero rides along
-    for si in range(2):
-        if len(side_cols[si]) == 1:
-            side_cols[si].append(None)      # dummy marker
-            side_dec[si].append(None)
-            side_outs[si].append(None)
-    idx_of[on_out] = 0
-    out_dtypes[on_out] = key_dts[0]
-    flat_idx = 1
-    for si in range(2):
-        for out_name in side_outs[si]:
-            if out_name is not None:
-                idx_of[out_name] = flat_idx
-            flat_idx += 1
-    # join output order for the no-group mode
-    if pq.mode == "join":
-        for out_name in join.fields:
-            if out_name not in idx_of:
-                raise _Decline("join", "output column %r not mapped "
-                               "through the join" % out_name)
-            out_fields.append(out_name)
-            out_idxs.append(idx_of[out_name])
-    pq._join = {"side_cols": side_cols, "side_dec": side_dec,
-                "enc": enc, "idx_of": idx_of,
-                "out_dtypes": out_dtypes,
-                "out_fields": out_fields, "out_idxs": out_idxs}
-    # post-join filters: push single-side predicates into that side's
-    # scan pipeline; anything cross-side declines (v1 surface)
-    for op in pq._shape.get("join_ops", ()):
-        for p in op.preds:
-            pushed = False
-            for si, cmap in ((0, lmap + [(on_out, "on", join.on)]),
-                             (1, rmap + [(on_out, "on", join.on)])):
-                names = {o: c for (o, _s, c) in cmap}
-                if p.columns <= set(names):
-                    seg = segs[si]
-                    alias_dt = {names[o]: seg.dtypes.get(
-                        names[o], np.dtype(object))
-                        for o in p.columns}
-                    remapped = E.compile_expr(
-                        _rename_expr(p, names), list(alias_dt))
-                    ve, reason = E.vectorize(
-                        remapped, alias_dt,
-                        {names[o]: seg.bounds.get(names[o])
-                         for o in p.columns
-                         if seg.bounds.get(names[o])},
-                        boolean=True)
-                    if ve is None:
-                        raise _Decline(
-                            "filter", "post-join predicate %r: %s"
-                            % (p.expr, reason))
-                    seg.steps.append(("filter", [ve]))
-                    pq.decide("pushdown-predicate", "join", "device",
-                              "post-join predicate %r pushed below "
-                              "the join into scan[%d]" % (p.expr, si))
-                    pushed = True
-                    break
-            if not pushed:
-                raise _Decline(
-                    "filter", "cross-side post-join predicate %r "
-                    "stays on the host" % p.expr)
+    for i in range(1, n + 1):
+        slot = sorted(ch.key_slot[i])
+        dts = [segs[s].dtypes.get(c, np.dtype(object)) for s, c in slot]
+        objs = [dt == np.dtype(object) for dt in dts]
+        if any(objs) and not all(objs):
+            raise _Decline("join", "join key dtypes disagree (%s)"
+                           % ", ".join(sorted({str(d) for d in dts})))
+        for (s, c), dt in zip(slot, dts):
+            bad = _key_decline(c, dt)
+            if bad:
+                raise _Decline("join", bad)
+            rng = segs[s].bounds.get(c)
+            if segs[s].device is not None \
+                    and (rng is None or rng[1] >= _I64_MAX):
+                raise _Decline("join", "join key %r of table %r may hold "
+                               "the device's padding sentinel (no range "
+                               "below 2**63 - 1)"
+                               % (c, segs[s].scan.table_name))
+        if all(objs):
+            from dpark_tpu.native import TokenDict
+            shared = next((enc[c] for c in slot if c in enc), None)
+            if shared is None:
+                shared = TokenDict()
+            for c in slot:
+                enc[c] = shared
+            pq.decide("encode-strings", "join[%d]" % i, "device",
+                      "string join key %r rides dictionary-encoded (one "
+                      "shared TokenDict across its sides)" % ch.rkeys[i - 1])
+    # decoders by every name the consumer may read a column under
+    dec = {}
+    for name, canons in ch.names.items():
+        for c in canons:
+            dec.setdefault(c, []).append(name)
+    if pq.mode == "join_group":
+        for name, ce in pq._shape["group"].keys:
+            for c in pq._join_reads[ce.expr]:
+                dec.setdefault(c, []).append(name)
+    last = ch.layouts[n]
+
+    def slot_meta(slot):
+        s, c = sorted(slot)[0]
+        return segs[s].dtypes.get(c, np.dtype(object)), \
+            segs[s].bounds.get(c)
+
+    reads = {text: (last.index(slot),) + slot_meta(slot)
+             for text, slot in pq._join_reads.items()}
+    rows, why = ch.row_bound(
+        lambda s: max(1, getattr(segs[s], "nrows", 1) or 1),
+        lambda s, c: _seg_unique(segs[s], c))
+    pq.decide("unique-key", "join", "device",
+              "; ".join(why) + ": the sums over the last join are proved "
+              "over %d rows" % rows)
+    out_fields = list(pq._shape["join"].fields) if pq.mode == "join" \
+        else []
+    pq._join = {"sides": sides, "enc": enc, "dec": dec, "reads": reads,
+                "rekey": {i: ch.rekey(i) for i in range(2, n + 1)},
+                "rows": rows, "rows_why": "; ".join(why),
+                "out_fields": out_fields,
+                "out_idxs": [reads[f][0] for f in out_fields]}
     pq.decide("lower-join", "join", "device",
-              "equi-join on %r lowered onto the device join source"
-              % join.on)
+              "%s lowered onto the device join source (sides: %s)"
+              % (", ".join("equi-join on %r" % (j.on if j.on == j.right_on
+                                                else "%s = %s" % (
+                                                    j.on, j.right_on))
+                           for j in ch.joins),
+                 ", ".join("%s %s" % (seg.scan.table_name,
+                                      "on the device" if seg.device
+                                      is not None else "from the driver")
+                           for seg in segs)))
 
 
 def _rename_expr(colexpr, name_map):
-    """Expression text with output names substituted by source names
-    (token-level; names are \\w+ so a regex boundary is exact)."""
+    """Expression text with output names substituted by source names,
+    in one pass (names are \\w+ so a regex boundary is exact)."""
     import re
-    text = colexpr.expr
-    for out, src in sorted(name_map.items(), key=lambda kv: -len(kv[0])):
-        if out != src:
-            text = re.sub(r"\b%s\b" % re.escape(out), src, text)
-    return text
+    moved = {o: s for o, s in name_map.items() if o != s}
+    if not moved:
+        return colexpr.expr
+    pattern = r"\b(%s)\b" % "|".join(
+        re.escape(o) for o in sorted(moved, key=len, reverse=True))
+    return re.sub(pattern, lambda m: moved[m.group(1)], colexpr.expr)
 
 
 def _rule_price(pq):

@@ -24,6 +24,7 @@ action to the host chain (the pre-plan behavior, and the bench A/B's
 baseline side).
 """
 
+import functools
 import re
 import time
 from collections import namedtuple
@@ -196,6 +197,28 @@ def _parse_column(col, fields, index):
     return (name or ("col%d" % index)), _compile_expr(col, fields)
 
 
+def _pin_exact(rdd, reason):
+    """The planner could not prove a product or a sum of this table's
+    aggregation inside int64, where the device wraps: every combine of
+    the row chain (`_AggCombine`) is pinned to the object path, which
+    folds exact Python ints.  The reason is each stage's
+    fallback_reason (fuse.analyze_stage reads `host_reason`)."""
+    seen, todo = set(), [rdd]
+    while todo:
+        r = todo.pop()
+        if r.id in seen:
+            continue
+        seen.add(r.id)
+        for dep in r.dependencies:
+            agg = getattr(dep, "aggregator", None)
+            if isinstance(getattr(agg, "merge_combiners", None),
+                          _AggCombine):
+                dep.host_reason = ("the query plane declined (%s): the "
+                                   "aggregation folds Python ints on the "
+                                   "host" % reason)
+            todo.append(dep.rdd)
+
+
 class _UDA:
     """User-defined aggregate marker for groupBy: a traceable
     per-group function over one argument column's value list.  On the
@@ -334,6 +357,10 @@ class TableRDD:
             self.rdd._query_fallbacks = (list(self._plan_fallbacks)
                                          + list(pq.fallbacks))
             self._host_sig = pq.adapt_sig
+            for f in pq.fallbacks:
+                if "int64" in f["reason"] or "overflow" in f["reason"]:
+                    _pin_exact(self.rdd, f["reason"])
+                    break
         return self._planned_q
 
     def _host_observe(self, t0):
@@ -511,10 +538,18 @@ class TableRDD:
         return TableRDD(out, [n for n, _ in parsed], self.name)
 
     def sort(self, key, reverse=False, numSplits=None):
+        """Sort by one or several key expressions; `reverse` is one
+        flag for every key or a list of one flag a key."""
         texts = _split_cols((key,) if isinstance(key, str) else key)
         fns = [_compile_expr(k, self.fields) for k in texts]
-        out = self.rdd.sort(key=_GroupKeyFn(fns), reverse=reverse,
-                            numSplits=numSplits)
+        flags = list(reverse) if isinstance(reverse, (list, tuple)) \
+            else [reverse] * len(fns)
+        if len(set(flags)) > 1:
+            # mixed directions: the descending keys compare reversed
+            key_fn, flip = _SortKeyFn(fns, flags), False
+        else:
+            key_fn, flip = _GroupKeyFn(fns), bool(flags[0])
+        out = self.rdd.sort(key=key_fn, reverse=flip, numSplits=numSplits)
         plan = None
         err = None
         if self.plan is not None:
@@ -545,25 +580,34 @@ class TableRDD:
         return out
 
     def join(self, other, on, numSplits=None):
-        """Equi-join on a column name present in both tables."""
-        if on not in self.fields or on not in other.fields:
-            raise ValueError("join column %r must be a plain field of "
-                             "both tables" % on)
-        li, ri = self.fields.index(on), other.fields.index(on)
-        lf = _compile_expr(on, self.fields)
-        rf = _compile_expr(on, other.fields)
+        """Inner equi-join: `on` is a column name of both tables, or a
+        pair (column of this table, column of `other`) whose names may
+        differ, as TPC-H's c_custkey = o_custkey.  The output is the
+        key (under both names where they differ), this table's other
+        columns, then `other`'s (a name this table has already gets
+        `other`'s name as a prefix).  Joins chain: the left input of a
+        join may itself be a join."""
+        lon, ron = (on, on) if isinstance(on, str) else tuple(on)
+        if lon not in self.fields or ron not in other.fields:
+            raise ValueError("join columns %r and %r must be plain "
+                             "fields of the left and the right table"
+                             % (lon, ron))
+        li, ri = self.fields.index(lon), other.fields.index(ron)
+        lf = _compile_expr(lon, self.fields)
+        rf = _compile_expr(ron, other.fields)
         left = self.rdd.map(_JoinKeyFn(lf))
         right = other.rdd.map(_JoinKeyFn(rf))
         joined = left.join(right, numSplits)
-        out = joined.map(_JoinMerge(li, ri))
-        fields = ([on] + [f for f in self.fields if f != on]
+        keys = [lon] if lon == ron else [lon, ron]
+        out = joined.map(_JoinMerge(li, ri, len(keys)))
+        fields = (keys + [f for f in self.fields if f != lon]
                   + [f if f not in self.fields else other.name + "_" + f
-                     for f in other.fields if f != on])
+                     for f in other.fields if f != ron])
         # ensure uniqueness, tracking which source column each output
         # name came from (the plan's join column map)
-        srcs = ([("on", on)]
-                + [("l", f) for f in self.fields if f != on]
-                + [("r", f) for f in other.fields if f != on])
+        srcs = ([("on", k) for k in keys]
+                + [("l", f) for f in self.fields if f != lon]
+                + [("r", f) for f in other.fields if f != ron])
         seen, uniq = set(), []
         for f in fields:
             while f in seen:
@@ -575,7 +619,7 @@ class TableRDD:
             from dpark_tpu.query.logical import Join
             colmap = [(out_name, side, src) for out_name, (side, src)
                       in zip(uniq, srcs)]
-            plan = Join(self.plan, other.plan, on, uniq)
+            plan = Join(self.plan, other.plan, lon, uniq, right_on=ron)
             plan.colmap = colmap
             return self._derive(out, uniq, plan)
         reason = None
@@ -650,16 +694,22 @@ class TableRDD:
 
 _SQL_RE = re.compile(
     r"^\s*select\s+(?P<cols>.+?)\s+from\s+(?P<table>\w+)"
-    r"(?:\s+join\s+(?P<jtable>\w+)\s+on\s+(?P<jon>.+?))?"
+    r"(?P<joins>(?:\s+join\s+\w+\s+on\s+.+?)*)"
     r"(?:\s+where\s+(?P<where>.+?))?"
     r"(?:\s+group\s+by\s+(?P<group>.+?))?"
     r"(?:\s+having\s+(?P<having>.+?))?"
-    r"(?:\s+order\s+by\s+(?P<order>.+?)(?P<dir>\s+(?:asc|desc))?)?"
+    r"(?:\s+order\s+by\s+(?P<order>.+?))?"
     r"(?:\s+limit\s+(?P<limit>\d+))?\s*$",
     re.I | re.S)
 
+_JOIN_RE = re.compile(r"\s+join\s+(\w+)\s+on\s+", re.I)
+
+# ON col | ON a.x = b.y (each side optionally qualified by its table)
 _JOIN_ON_RE = re.compile(
-    r"^\s*(?:\w+\s*\.\s*)?(\w+)\s*(?:=\s*(?:\w+\s*\.\s*)?(\w+)\s*)?$")
+    r"^\s*(?:(\w+)\s*\.\s*)?(\w+)\s*"
+    r"(?:=\s*(?:(\w+)\s*\.\s*)?(\w+)\s*)?$")
+
+_ORDER_DIR_RE = re.compile(r"^(.*?)\s+(asc|desc)$", re.I | re.S)
 
 # an aggregate CALL embedded in a larger expression (one paren-nesting
 # level in the argument, e.g. avg(abs(x)))
@@ -754,20 +804,55 @@ def _sql_quote_escapes(text):
     return "".join(out)
 
 
+def _join_keys(on, left, right, rname):
+    """(left column, right column) of a JOIN's ON text: ``col``, or
+    ``a.x = b.y`` in either order, a side qualified by its table's name
+    or known by which table has the column."""
+    jm = _JOIN_ON_RE.match(on)
+    if not jm:
+        raise ValueError("unsupported JOIN ON: %r" % on)
+    qa, a, qb, b = jm.groups()
+    if b is None:
+        return a, a
+    if qa == rname or qb not in (None, rname) or (
+            qa is None and qb is None and a not in left.fields
+            and b in left.fields):
+        a, b = b, a
+    if a not in left.fields or b not in right.fields:
+        raise ValueError("JOIN %s ON %r: %r is not a column of the left "
+                         "input or %r not one of %r" % (rname, on, a, b,
+                                                        rname))
+    return a, b
+
+
+def _order_keys(order):
+    """ORDER BY text -> [(expression, descending?)] a key."""
+    out = []
+    for item in _split_cols((order,)):
+        dm = _ORDER_DIR_RE.match(item)
+        out.append((dm.group(1).strip(), dm.group(2).lower() == "desc")
+                   if dm else (item.strip(), False))
+    return out
+
+
 def execute(sql, tables):
     """Minimal SQL-ish front over TableRDD (reference: dpark table's
     `execute` [SURVEY.md 2.3, low-confidence item]).  Supports
-    SELECT cols FROM t [JOIN t2 ON col] [WHERE expr] [GROUP BY keys]
-    [HAVING expr] [ORDER BY col [DESC]] [LIMIT n]; column expressions
-    and aggregates use the DSL's syntax.  SELECT and HAVING may use
-    aggregate EXPRESSIONS (``sum(v) / count(*)``); JOIN ... ON lowers
-    to TableRDD.join (the device-riding equi-join) and accepts ``col``
-    or ``a.col = b.col`` with the same column name on both sides.
+    SELECT cols FROM t [JOIN t2 ON on2 [JOIN t3 ON on3 ...]]
+    [WHERE expr] [GROUP BY keys] [HAVING expr]
+    [ORDER BY k1 [ASC|DESC], k2 [ASC|DESC] ...] [LIMIT n]; column
+    expressions and aggregates use the DSL's syntax.  SELECT and HAVING
+    may use aggregate EXPRESSIONS (``sum(v) / count(*)``).  Each JOIN
+    ... ON lowers to TableRDD.join (the device-riding equi-join) over
+    the join before it, a left-deep chain, and takes ``col`` or ``a.x
+    = b.y``, whose names may differ (TPC-H's ``c_custkey =
+    o_custkey``).  Each ORDER BY key has its own direction.
 
     `tables`: dict name -> TableRDD.  Returns a TableRDD, or a row list
     when LIMIT is given.
     """
-    m = _SQL_RE.match(_mask_literals(sql))
+    masked = _mask_literals(sql)
+    m = _SQL_RE.match(masked)
     if not m:
         raise ValueError("unsupported SQL: %r" % sql)
 
@@ -783,24 +868,19 @@ def execute(sql, tables):
     t = tables.get(m.group("table"))
     if t is None:
         raise ValueError("unknown table %r" % m.group("table"))
-    if m.group("jtable"):
-        other = tables.get(m.group("jtable"))
+    lo, hi = m.span("joins")
+    found = list(_JOIN_RE.finditer(masked, lo, hi))
+    for k, jm in enumerate(found):
+        other = tables.get(jm.group(1))
         if other is None:
-            raise ValueError("unknown table %r" % m.group("jtable"))
-        jm = _JOIN_ON_RE.match(part("jon"))
-        if not jm:
-            raise ValueError("unsupported JOIN ON: %r" % part("jon"))
-        lcol, rcol = jm.group(1), jm.group(2) or jm.group(1)
-        if lcol != rcol:
-            raise ValueError(
-                "JOIN ON must equate the same column name "
-                "(%r vs %r)" % (lcol, rcol))
-        t = t.join(other, lcol)
+            raise ValueError("unknown table %r" % jm.group(1))
+        end = found[k + 1].start() if k + 1 < len(found) else hi
+        t = t.join(other, _join_keys(sql[jm.end():end].strip(), t, other,
+                                     jm.group(1)))
     if part("where"):
         t = t.where(part("where"))
 
-    order = (part("order") or "").strip()
-    desc = (m.group("dir") or "").strip().lower() == "desc"
+    order = _order_keys(part("order")) if part("order") else []
     cols = part("cols").strip()
 
     if part("having") and not part("group"):
@@ -853,15 +933,15 @@ def execute(sql, tables):
         t = t.groupBy(group_keys, *aggs)
         if hav is not None:
             t = t.where(hav)
-        if order and order not in out_names:
-            # ORDER BY a grouped column that the SELECT list drops or
-            # renames: sort on the aggregated table before projecting
-            t = t.sort(order, reverse=desc)
-            order = ""
+        if order and _sort_first(order, t.fields, out_names):
+            # ORDER BY grouped columns (one that the SELECT list drops
+            # or renames among them): sort on the aggregated table
+            # before projecting
+            t = _sort_by(t, order)
+            order = []
         t = t.select(*out_exprs)
         if order:
-            t = t.sort(order, reverse=desc)
-            order = ""
+            t = _sort_by(t, order)
     else:
         # ORDER BY may reference either the source columns or a projected
         # output name: sort on whichever side actually holds it
@@ -869,16 +949,30 @@ def execute(sql, tables):
             projected = [
                 _parse_column(c, t.fields, i)[0]
                 for i, c in enumerate(_split_cols((cols,)))]
-            if order not in projected:
-                t = t.sort(order, reverse=desc)
-                order = ""
+            if _sort_first(order, t.fields, projected):
+                t = _sort_by(t, order)
+                order = []
         if cols != "*":
             t = t.select(cols)
         if order:
-            t = t.sort(order, reverse=desc)
+            t = _sort_by(t, order)
     if m.group("limit"):
         return t.take(int(m.group("limit")))
     return t
+
+
+def _sort_by(t, order):
+    return t.sort([k for k, _ in order], reverse=[d for _, d in order])
+
+
+def _sort_first(order, before, after):
+    """Does the ORDER BY sort the table before the SELECT's projection?
+    One key: unless it is an output name.  Several: where every key is
+    a column before the projection, or some key is not an output."""
+    if len(order) == 1:
+        return order[0][0] not in after
+    return all(k in before for k, _ in order) \
+        or not all(k in after for k, _ in order)
 
 
 def _split_cols(cols):
@@ -989,15 +1083,57 @@ class _JoinKeyFn(_GroupKeyFn):
 
 
 class _JoinMerge:
-    def __init__(self, li, ri):
+    """(k, (left row, right row)) -> the key `nkeys` times (once a
+    name), then each row without its key column."""
+
+    def __init__(self, li, ri, nkeys=1):
         self.li = li
         self.ri = ri
+        self.nkeys = nkeys
 
     def __call__(self, kv):
         k, (l, r) = kv
         l = tuple(x for i, x in enumerate(l) if i != self.li)
         r = tuple(x for i, x in enumerate(r) if i != self.ri)
-        return (k,) + l + r
+        return (k,) * self.nkeys + l + r
+
+
+@functools.total_ordering
+class _Desc:
+    """A sort key's value compared in reverse: a key of a descending
+    direction in a mixed-direction sort (_SortKeyFn)."""
+
+    __slots__ = ("v",)
+
+    def __init__(self, v):
+        self.v = v
+
+    def __eq__(self, other):
+        return self.v == other.v
+
+    def __lt__(self, other):
+        return other.v < self.v
+
+    def __hash__(self):
+        return hash(self.v)
+
+    def __getstate__(self):
+        return self.v
+
+    def __setstate__(self, v):
+        self.v = v
+
+
+class _SortKeyFn:
+    """A row's sort key over several keys of their own directions."""
+
+    def __init__(self, fns, reverse):
+        self.fns = fns
+        self.reverse = tuple(reverse)
+
+    def __call__(self, row):
+        return tuple(_Desc(fn(row)) if r else fn(row)
+                     for fn, r in zip(self.fns, self.reverse))
 
 
 class _AggCreate:

@@ -170,7 +170,9 @@ Span taxonomy (name / cat):
                                        device, files for tabular part
                                        files, driver for driver-resident
                                        slices; rules — decisions
-                                       recorded); measured before the
+                                       recorded; joins — the joins of
+                                       the chain, 0 without one);
+                                       measured before the
                                        job's id exists, kept on the
                                        PlannedQuery and emitted by its
                                        _job under the id of the job the
@@ -193,7 +195,12 @@ Span taxonomy (name / cat):
                                        and scan_rows_host (rows
                                        _ScanSeg.run evaluated on the
                                        driver in numpy and reported to
-                                       note_host_scan)
+                                       note_host_scan), and
+                                       query_joins_device (the joins
+                                       of a job whose every side was a
+                                       resident table, reported by
+                                       PlannedQuery._job to
+                                       note_device_joins)
     sort.sample              "exec"    the read of sortByKey's bounds
                                        sample, JAXExecutor._sample_keys
                                        (args: splits, rows, bytes: the

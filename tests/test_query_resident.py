@@ -188,7 +188,8 @@ def test_a_product_past_int64_declines_and_the_host_answers(masters):
     """The interval proof is from the columns' ranges: two columns that
     are never large in one row still bound their product past int64,
     so the query declines with the reason and the row chain answers
-    what the local master answers."""
+    what the local master answers, its aggregation on the host in
+    Python ints (the device would wrap where the proof fails)."""
     dev, host = masters
     sql = ("select l_linestatus, sum(l_extendedprice * l_quantity) as v "
            "from lineitem group by l_linestatus order by l_linestatus")
@@ -207,10 +208,14 @@ def test_a_product_past_int64_declines_and_the_host_answers(masters):
         sql, lineitem=resident_table(host, cols)).collect()]
     assert rows == expect
     assert sum(r[1] for r in rows) == 500 * 4_000_000_000
-    # the row chain that answers is itself a stage program over the
-    # resident batch (fuse.py traces it): the executor counts the rows
-    # where a program is launched over them, whoever built the chain
-    assert left["device_rows"] == 500 and left["host_rows"] == 0
+    # the row chain's combine is pinned to the object path, and says
+    # why on both of its stages; no stage program ran over the table
+    reasons = [st.get("fallback_reason") for rec in left["records"]
+               for st in rec["stage_info"]]
+    assert len(reasons) == 2 and all(
+        r and "may leave int64" in r and "Python ints" in r
+        for r in reasons), reasons
+    assert left["device_rows"] == 0 and left["host_rows"] == 0
 
 
 def test_a_sum_past_int64_over_the_rows_declines(masters):

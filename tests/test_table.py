@@ -253,14 +253,14 @@ def test_sql_join_having_agg_exprs(ctx, sales):
     exp = {k: s * 2 + c for k, (s, c) in base.items()}
     assert {r.item: r.score for r in t.collect()} == exp
 
-    # a.col = b.col spelling; mismatched names refuse
+    # a.col = b.col spelling; a column neither table has refuses
     rows = ctx.sql("select item, code from sales join dim on "
                    "sales.item = dim.item limit 3",
                    sales=sales, dim=dim)
     assert rows
     import pytest as _pytest
     with _pytest.raises(ValueError):
-        ctx.sql("select * from sales join dim on sales.item = dim.code",
+        ctx.sql("select * from sales join dim on sales.item = dim.nosuch",
                 sales=sales, dim=dim)
     with _pytest.raises(ValueError):
         ctx.sql("select item from sales having sum(qty) > 1",
