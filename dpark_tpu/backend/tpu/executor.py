@@ -781,6 +781,9 @@ class JAXExecutor:
         # joins a planned query lowered with every side a table resident
         # on the device (note_device_joins, a bare `+=` a job)
         self.query_joins_device = 0
+        # a planned query's grouped finish run over columns in numpy
+        # (note_columnar_finish, a bare `+= 1` a job)
+        self.query_finishes_columnar = 0
         # filters folded into a shuffle write's validity mask
         # (fuse.run_ops; bare `+=`): a plan's count, at each launch of
         # its narrow, reduce or spilled-run map program
@@ -1795,6 +1798,11 @@ class JAXExecutor:
         """A planned query's job (planner.PlannedQuery._job) reports the
         joins it lowered with every side resident on the device."""
         self.query_joins_device += joins
+
+    def note_columnar_finish(self):
+        """A planned query's job (planner.PlannedQuery._run) reports that
+        its grouped finish ran over columns."""
+        self.query_finishes_columnar += 1
 
     def drop_result(self, rdd_id):
         meta = self.result_cache.pop(rdd_id, None)

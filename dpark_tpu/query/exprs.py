@@ -37,6 +37,7 @@ import sys
 import numpy as np
 
 _I64_MAX = 2 ** 63 - 1
+_F64_EXACT = 2 ** 53            # every int of at most this size is a float64
 
 
 class ExprDecline(Exception):
@@ -263,12 +264,19 @@ class _Vectorizer:
     table resident on the device, where a float is float32: an ordering
     or equality of floats there is not provably the host's (float64),
     so it declines; float ARGUMENTS of aggregates are admitted as on
-    the driver path, whose columns round to float32 at ingest."""
+    the driver path, whose columns round to float32 at ingest.
+    `egest`: the program computes a query's result column or predicate
+    at egest, where a value has to be the host's in type as well: a
+    min/max of an int and a float returns one OPERAND on the host (an
+    int stays an int), and an int past 2**53 is not exact as a float —
+    the host compares an int with a float exactly and divides two ints
+    exactly, where the array rounds each to float64 first."""
 
-    def __init__(self, dtypes, ranges, device=False):
+    def __init__(self, dtypes, ranges, device=False, egest=False):
         self.dtypes = dtypes
         self.ranges = ranges or {}
         self.device = device
+        self.egest = egest
 
     def build(self, node, boolean):
         meth = getattr(self, "_v_%s" % type(node).__name__, None)
@@ -362,6 +370,8 @@ class _Vectorizer:
             return _V(("mul", a.prog, b.prog), kind, bounds)
         if isinstance(op, ast.Div):
             self._nonzero(b, "/")
+            if int_sides:
+                self._float_exact(a, b, "/ of two ints")
             return _V(("div", a.prog, b.prog), "f")
         if isinstance(op, (ast.FloorDiv, ast.Mod)):
             self._nonzero(b, "// or %")
@@ -383,6 +393,18 @@ class _Vectorizer:
             return _V(("mod", a.prog, b.prog), "i", bounds)
         raise ExprDecline("unsupported operator %s"
                           % type(op).__name__)
+
+    def _float_exact(self, a, b, what):
+        """At egest, where an int meets a float (or two ints divide), the
+        ints have to be exact as float64."""
+        if not self.egest:
+            return
+        for v in (a, b):
+            if v.kind in "ib" and max(abs(v.bounds[0]),
+                                      abs(v.bounds[1])) > _F64_EXACT:
+                raise ExprDecline(
+                    "%s over an int past 2**53 (the host is exact, the "
+                    "array rounds the int to float64 first)" % what)
 
     def _nonzero(self, v, what):
         if v.kind == "f":
@@ -411,6 +433,9 @@ class _Vectorizer:
                 raise ExprDecline("unsupported comparison %s" % name)
             if self.device:
                 self._device_compare(left, right, name)
+            if "f" in (left.kind, right.kind) \
+                    and "o" not in (left.kind, right.kind):
+                self._float_exact(left, right, "comparison with a float")
             parts.append((name, left.prog, right.prog))
             left = right
         return _V(("cmp", tuple(parts)), "b", (0, 1))
@@ -457,6 +482,11 @@ class _Vectorizer:
         if name in ("min", "max") and len(args) >= 2:
             args = [self._numeric(a, name) for a in args]
             kind = "f" if "f" in {a.kind for a in args} else "i"
+            if self.egest and kind == "f" \
+                    and {a.kind for a in args} != {"f"}:
+                raise ExprDecline(
+                    "%s() of an int and a float returns an operand on the "
+                    "host, int or float, where the array is float" % name)
             if kind == "f" and self.device:
                 self._device_compare(args[0], args[1], "Lt")
             bounds = None
@@ -490,19 +520,22 @@ class VecExpr:
         return evaluate(self.prog, env)
 
 
-def vectorize(colexpr, dtypes, ranges=None, boolean=False, device=False):
+def vectorize(colexpr, dtypes, ranges=None, boolean=False, device=False,
+              egest=False):
     """Admit a ColumnExpr as an array program, or explain why not.
 
     Returns (VecExpr, None) on admission or (None, reason) on decline.
     `ranges` supplies exact (lo, hi) per int column for the
     no-overflow interval proof; `device` says the program will run
-    inside a stage program over a table resident on the device (see
+    inside a stage program over a table resident on the device, and
+    `egest` that it computes a query's result at egest (see
     _Vectorizer)."""
     if colexpr.parse_error:
         return None, colexpr.parse_error
     try:
         dts = {k: np.dtype(v) for k, v in dtypes.items()}
-        v = _Vectorizer(dts, ranges, device).build(colexpr.tree, boolean)
+        v = _Vectorizer(dts, ranges, device, egest).build(colexpr.tree,
+                                                          boolean)
         if boolean:
             return VecExpr(("bool", v.prog), "b"), None
         if v.kind == "o":

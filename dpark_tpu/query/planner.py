@@ -55,7 +55,12 @@ After the scan:
     rebuilt as bytes at egest (`_key_of`, `_key_columns`);
   * result finishing (HAVING, post-aggregate projections, ORDER BY,
     LIMIT) runs at EGEST on the driver with exact host eval semantics
-    — result rows are one-per-group / driver-resident by then.
+    — result rows are one-per-group / driver-resident by then; a
+    grouped query's finish runs over numpy columns where every one of
+    its expressions is a pick or a program `exprs` admits as the host's
+    exact value, and an ORDER BY key is no float (`compile_egest`'s
+    decision `egest` -> columns, `PlannedQuery._finish_columns`), else
+    a row at a time (`_shape_rows`, `_egest`).
 
 Every rule records its choice with a reason; host choices surface as
 `fallbacks` which the `table-host-fallback` lint rule reports
@@ -65,12 +70,17 @@ declines with a reason, and the host object path serves the query.
 A declined query whose int64 proof failed pins its row chain's combine
 to the object path (table._pin_exact), which folds exact Python ints.
 With the trace plane on, `plan_query` is the `query.plan` span and the
-egest-side finishing the `query.finish` span (dpark_tpu/trace.py); a
-job whose joins all had every side on the device adds their number to
-the executor's `query_joins_device` (PlannedQuery._job).
+egest-side finishing the `query.finish` span (dpark_tpu/trace.py; arg
+`path`, columns or rows); a job whose joins all had every side on the
+device adds their number to the executor's `query_joins_device`
+(PlannedQuery._job), and a finish over columns adds one to its
+`query_finishes_columnar` (PlannedQuery._run).
 """
 
+import ast
+import itertools
 import math
+import operator
 import time
 
 import numpy as np
@@ -398,6 +408,38 @@ def _normalize(val):
     return val
 
 
+def _on_columns(col, named, n):
+    """A result column of a grouped finish over columns (_result_column):
+    the column a name picks, or an admitted program over the columns it
+    reads, as n values (a byte-string column reads as its `bytes`
+    objects, which is what the row path compares)."""
+    if isinstance(col, str):
+        return named[col]
+    prog, reads = col
+    env = {c: np.array(named[c].tolist(), object)
+           if named[c].dtype.kind == "S" else named[c] for c in reads}
+    return np.broadcast_to(np.asarray(E.evaluate(prog, env)), (n,))
+
+
+def _sort_keys(col, reverse):
+    """An ORDER BY key as the columns np.lexsort orders ascending, the
+    first the most significant: an int column itself; a byte-string
+    column its NUL-padded bytes as big-endian unsigned words (which
+    order as Python orders the bytes the row path holds, trailing NULs
+    stripped); a string column its rank among the distinct values.
+    DESC is the bitwise NOT of each, which reverses an int's order
+    exactly, where negation overflows at its minimum."""
+    if col.dtype.kind == "S":
+        take = -(-col.dtype.itemsize // 8)
+        keys = list(col.astype("S%d" % (8 * take)).view(">u8").reshape(
+            len(col), take).T)
+    elif col.dtype == object:
+        keys = [np.unique(col, return_inverse=True)[1]]
+    else:
+        keys = [col]
+    return [~k for k in keys] if reverse else keys
+
+
 class _Decline(Exception):
     def __init__(self, op, reason):
         super().__init__(reason)
@@ -639,6 +681,7 @@ class PlannedQuery:
         self.mode = None            # scan | group | join | join_group
         self.segs = []
         self.egest_ops = []         # leaf-to-top (code, kind, meta)
+        self._columnar = None       # a grouped finish over columns
         self.decoders = {}          # out field -> TokenDict
         self._rdd = None
         self._rows_cache = None
@@ -737,7 +780,12 @@ class PlannedQuery:
     def _finish(self, raw):
         """The egest side of a query: the collected rows decoded and
         finalized (_shape_rows), then HAVING / projections / ORDER BY
-        (_egest).  _run puts it under the `query.finish` span."""
+        (_egest); a grouped plan that compile_egest found admissible
+        does all of it over columns instead (_finish_columns).  _run
+        puts it under the `query.finish` span, whose arg `path` says
+        which ran."""
+        if self._columnar is not None:
+            return self._finish_columns(raw)
         if self.mode == "scan":
             rows, fields = [tuple(r) for r in raw], self.segs[0].out
         else:
@@ -758,13 +806,21 @@ class PlannedQuery:
             rows = self._egest(rows, names)
         else:
             raw, job = self._job(lambda rdd: rdd.collect())
+            path = "rows" if self._columnar is None else "columns"
             plane = trace._PLANE
             if plane is not None:
-                with trace.span("query.finish", "query", job=job) as sp:
+                with trace.span("query.finish", "query", job=job,
+                                path=path) as sp:
                     rows = self._finish(raw)
                     sp.args["rows"] = len(rows)
             else:
                 rows = self._finish(raw)
+            if path == "columns":
+                # the executor's counter query_finishes_columnar
+                note = getattr(getattr(self.ctx.scheduler, "executor",
+                                       None), "note_columnar_finish", None)
+                if note is not None:
+                    note()
         self._observe("device", (time.time() - t0) * 1e3)
         return rows
 
@@ -987,10 +1043,90 @@ class PlannedQuery:
             out.append(rec)
         return out, fields
 
+    def _finish_columns(self, raw):
+        """_finish over columns (the plan compile_egest made,
+        _columnar_finish): the collected (key, accumulator) records
+        turned once into one column a field (_group_columns), HAVING as
+        one mask, projections as picks or admitted programs, ORDER BY as
+        one stable np.lexsort, last key first; then each column's
+        tolist() zipped into the tuples the row path gives, value for
+        value, type for type and in its order, ties in collected
+        order."""
+        plan = self._columnar
+        cols = self._group_columns(raw, plan)
+        fields, n = plan["fields"], len(raw)
+        for kind, meta in plan["steps"]:
+            named = dict(zip(fields, cols))
+            if kind == "filter":
+                keep = np.ones(n, bool)
+                for pred in meta:
+                    keep &= _on_columns(pred, named, n)
+                cols = [c[keep] for c in cols]
+                n = int(keep.sum())
+            elif kind == "project":
+                fields, items = meta
+                cols = [_on_columns(item, named, n) for item in items]
+            else:
+                keys = [k for key, rev in meta
+                        for k in _sort_keys(_on_columns(key, named, n), rev)]
+                order = np.lexsort(keys[::-1])
+                cols = [c[order] for c in cols]
+        self._out_fields = fields
+        return list(zip(*(c.tolist() for c in cols)))
+
+    def _group_columns(self, raw, plan):
+        """A group's collected (key, accumulator) records as one numpy
+        column a pre-egest field: a number key int64 (decoded over its
+        TokenDict's distinct ids where it has one), an S<w> key the
+        bytes of its big-endian words, cut to w (tolist() strips the
+        trailing NULs, as _key_columns does); an aggregate its
+        accumulator leaf, int64 or float64, and an average s / c over
+        the two leaves' Python values (correctly rounded as the row
+        path's, and None where c is 0)."""
+        g = self._group
+        n = len(raw)
+        nw = _key_nwords(g["widths"])
+        keys, accs = zip(*raw) if n else ((), ())
+        words = np.fromiter(itertools.chain.from_iterable(keys)
+                            if nw > 1 else keys, np.int64,
+                            n * nw).reshape(n, nw)
+        cols, at = [], 0
+        for name, w in zip(g["key_names"], g["widths"]):
+            take = -(-w // 8) if w else 1
+            part, at = words[:, at:at + take], at + take
+            if w:
+                cols.append(np.ascontiguousarray(part.astype(">i8")).view(
+                    "S%d" % (8 * take)).ravel().astype("S%d" % w))
+                continue
+            col = part[:, 0]
+            td = self.decoders.get(name)
+            if td is not None:
+                ids, inv = np.unique(col, return_inverse=True)
+                vals = np.empty(len(ids), object)
+                vals[:] = [td.decode(i) for i in ids.tolist()]
+                col = vals[inv]
+            cols.append(col)
+        leaves = [np.fromiter(accs if g["lower"] == "classified"
+                              else map(operator.itemgetter(i), accs),
+                              np.int64 if k == "i" else np.float64, n)
+                  for i, k in enumerate(plan["leaf_kinds"])]
+        for kind, ref in zip(g["kinds"], g["refs"]):
+            if kind != "avg":
+                cols.append(leaves[ref[0]])
+                continue
+            avg = np.empty(n, object)
+            avg[:] = [s / c if c else None for s, c in zip(
+                leaves[ref[0]].tolist(), leaves[ref[1]].tolist())]
+            cols.append(avg)
+        return cols
+
     def _egest(self, rows, fields):
-        """Result finishing with exact host eval semantics: HAVING
-        filters, post-aggregate projections, ORDER BY — one row per
-        group by now, driver-resident."""
+        """Result finishing with exact host eval semantics, a row at a
+        time: HAVING filters, post-aggregate projections, ORDER BY — one
+        row per group by now, driver-resident.  The row path of scan and
+        join plans, of a result-cache merge and of a grouped plan whose
+        decision `egest` is `rows`; _finish_columns gives the same rows
+        for the others."""
         from dpark_tpu.table import _SAFE_BUILTINS
         for kind, meta in self.egest_ops:
             if kind == "filter":
@@ -1739,7 +1875,7 @@ def _rule_lower_group(pq):
             extra[:] = [(n, s) for n, s in extra if n != cname]
             env.pop(cname, None)
 
-        kinds, agg_args, arg_cols, agg_names, uda = _admit_aggs(
+        kinds, agg_args, arg_cols, agg_names, uda, arg_kinds = _admit_aggs(
             pq, g, nrows, lambda ce, nm:
             _group_col(pq, seg, env, ce, nm, extra), _extra_pop)
         if extra:
@@ -1754,6 +1890,10 @@ def _rule_lower_group(pq):
             "kinds": tuple(kinds), "key_names": key_names,
             "agg_names": agg_names, "encode": encode,
             "widths": widths, "leaves": leaves, "refs": refs,
+            "key_kinds": tuple(
+                "S" if w else "str" if c in encode else "i"
+                for c, w in zip(key_cols, widths)),
+            "arg_kinds": tuple(arg_kinds),
             # a resident table always combines on the map side: the
             # narrow stage's shuffle write is the aggregation
             "lower": ("uda" if uda is not None else
@@ -1790,7 +1930,7 @@ def _rule_lower_group(pq):
     # -- join_group: keys and arguments read from the last join's row --
     j = pq._join
     reads = j["reads"]          # text -> (index in the row, dtype, bounds)
-    key_idxs, key_names = [], []
+    key_idxs, key_names, key_kinds = [], [], []
     for name, ce in g.keys:
         idx, dt, _ = reads[ce.expr]
         bad = _key_decline(ce.expr, dt)
@@ -1798,13 +1938,14 @@ def _rule_lower_group(pq):
             raise _Decline("group-agg", bad)
         key_idxs.append(idx)
         key_names.append(name)
+        key_kinds.append("str" if dt == np.dtype(object) else "i")
     flat_of = {}
 
     def admit(ce, cname):
         flat_of[cname] = reads[ce.expr][0]
         return reads[ce.expr][1], None
 
-    kinds, agg_args, arg_cols, agg_names, _ = _admit_aggs(
+    kinds, agg_args, arg_cols, agg_names, _, arg_kinds = _admit_aggs(
         pq, g, j["rows"], admit, lambda cname: None,
         lambda arg, cname: reads[arg.expr][2], j["rows_why"])
     leaves, refs = _share_leaves(kinds, [
@@ -1812,6 +1953,8 @@ def _rule_lower_group(pq):
     pq._group = {"nk": len(key_idxs), "kinds": tuple(kinds),
                  "key_idxs": key_idxs, "leaves": leaves, "refs": refs,
                  "widths": (0,) * len(key_idxs),
+                 "key_kinds": tuple(key_kinds),
+                 "arg_kinds": tuple(arg_kinds),
                  "key_names": key_names, "agg_names": agg_names,
                  "lower": "reduce", "uda": None}
     pq.decide("lower-group-agg", "group-agg", "device",
@@ -1850,10 +1993,11 @@ def _admit_aggs(pq, g, nrows, admit_col, extra_pop, arg_bounds=None,
     overflow proofs over `nrows` rows (a group over a join: the chain's
     row bound, `rows_why` its reason), UDA traceability.  Returns
     (kinds, the index of each aggregate's argument column or None, the
-    argument columns, names, uda)."""
+    argument columns, names, uda, the kind of what each aggregate
+    folds: "i" an int, "f" a float, None any other value)."""
     arg_bounds = arg_bounds or (lambda arg, cname:
                                 _arg_bounds(pq, arg, cname))
-    kinds, agg_args, arg_cols, agg_names = [], [], [], []
+    kinds, agg_args, arg_cols, agg_names, arg_kinds = [], [], [], [], []
     by_text = {}
     uda = None
     for (name, fn, arg, uda_fn) in g.aggs:
@@ -1870,6 +2014,7 @@ def _admit_aggs(pq, g, nrows, admit_col, extra_pop, arg_bounds=None,
             _check_uda(uda_fn, dt)
             arg_cols.append(cname)
             agg_names.append(name)
+            arg_kinds.append(None)
             uda = uda_fn
             continue
         if fn not in DEVICE_AGGS:
@@ -1896,6 +2041,7 @@ def _admit_aggs(pq, g, nrows, admit_col, extra_pop, arg_bounds=None,
             kinds.append("count")
             agg_args.append(None)
             agg_names.append(name)
+            arg_kinds.append("i")
             continue
         shared = by_text.get(arg.expr)
         cname = "__a%d" % (len(arg_cols) if shared is None else shared[0])
@@ -1928,7 +2074,8 @@ def _admit_aggs(pq, g, nrows, admit_col, extra_pop, arg_bounds=None,
         kinds.append(fn)
         agg_args.append(shared[0])
         agg_names.append(name)
-    return kinds, agg_args, arg_cols, agg_names, uda
+        arg_kinds.append(dt.kind if dt.kind in "if" else None)
+    return kinds, agg_args, arg_cols, agg_names, uda, arg_kinds
 
 
 def _arg_bounds(pq, arg, cname):
@@ -2162,7 +2309,9 @@ def _rule_reuse(pq):
 def compile_egest(pq):
     """Turn the egest op list (leaf-to-top) into evaluated programs:
     code objects for filters/projects/sort keys (exact host eval
-    semantics at driver-side result finishing)."""
+    semantics at driver-side result finishing), and choose the path of
+    a grouped query's finish (_columnar_finish), recorded as the
+    decision `egest` -> columns or rows with its reason."""
     ops = []
     for op in pq._shape.get("egest", ()):
         if isinstance(op, Filter):
@@ -2177,10 +2326,112 @@ def compile_egest(pq):
             codes = [compile(k.expr, "<egest:%s>" % k.expr, "eval")
                      for k in op.keys]
             ops.append(("sort", (codes, op.reverse)))
-    if ops:
-        pq.decide("egest", "result", "egest",
+    pq.egest_ops = ops
+    if pq.mode in ("group", "join_group"):
+        pq._columnar, why = _columnar_finish(pq)
+        pq.decide("egest", "result",
+                  "rows" if pq._columnar is None else "columns", why)
+    elif ops:
+        pq.decide("egest", "result", "rows",
                   "%d result-finishing op(s) run at egest with host "
                   "eval semantics (rows are driver-resident)"
                   % len(ops))
-    pq.egest_ops = ops
     return pq
+
+
+# numpy dtype of a result column by its kind, for the admission of an
+# egest expression: "i" int64, "f" float64, "S" a byte-string key and
+# "str" a dictionary-decoded one (object: each value is the row's own);
+# an average ("avg") is None over a zero count and is no array's
+_RESULT_DTYPES = {"i": np.dtype(np.int64), "f": np.dtype(np.float64),
+                  "S": np.dtype(object), "str": np.dtype(object)}
+_INT64_RANGE = (-2 ** 63, 2 ** 63 - 1)
+
+
+class _RowFinish(Exception):
+    """Why a grouped query's finish keeps the row path."""
+
+
+def _columnar_finish(pq):
+    """The finish of a grouped query over columns (PlannedQuery.
+    _finish_columns): each step of the egest op list as it runs there.
+    A HAVING predicate or a projection is a pick of a column by name or
+    a program that exprs admits over the result's columns as the host's
+    exact value (`egest`: every int column taken at int64's full range,
+    no bound read); an ORDER BY key an int column or program, a
+    byte-string column or a string column (_sort_keys), never a float
+    or an average.  Returns (the plan, why) or (None, why the finish
+    keeps the row path)."""
+    g = pq._group
+    if g["lower"] == "uda":
+        return None, ("a UDA's result is any Python value: the groups "
+                      "finish row by row with host eval semantics")
+    fields = list(g["key_names"]) + list(g["agg_names"])
+    kinds = list(g["key_kinds"])
+    leaf_kinds = ["i"] * len(g["leaves"])
+    for name, kind, ref, ak in zip(g["agg_names"], g["kinds"], g["refs"],
+                                   g["arg_kinds"]):
+        if ak is None:
+            return None, ("aggregate %s folds a value that is neither int "
+                          "nor float: the groups finish row by row" % name)
+        if kind != "count":
+            leaf_kinds[ref[0]] = ak
+        kinds.append("avg" if kind == "avg" else
+                     "i" if kind == "count" else ak)
+    plan = {"fields": fields, "leaf_kinds": leaf_kinds, "steps": []}
+    try:
+        for op in pq._shape.get("egest", ()):
+            by_name = dict(zip(fields, kinds))
+            if isinstance(op, Filter):
+                plan["steps"].append(("filter", [_result_column(
+                    p, by_name, "HAVING", boolean=True)[0]
+                    for p in op.preds]))
+            elif isinstance(op, Project):
+                cols = [_result_column(ce, by_name, "projection")
+                        for _, ce in op.exprs]
+                fields = [n for n, _ in op.exprs]
+                kinds = [k for _, k in cols]
+                plan["steps"].append(("project",
+                                      (fields, [c for c, _ in cols])))
+            elif isinstance(op, Sort):
+                keys = []
+                for ce, rev in zip(op.keys, op.reverse):
+                    col, kind = _result_column(ce, by_name, "ORDER BY")
+                    if kind in ("f", "avg"):
+                        raise _RowFinish("ORDER BY %s: %s" % (
+                            ce.expr, "a float key (NaN orders as "
+                            "Python's sort leaves it)" if kind == "f"
+                            else "an average, None over a zero count"))
+                    keys.append((col, rev))
+                plan["steps"].append(("sort", keys))
+    except _RowFinish as e:
+        return None, "%s: the groups finish row by row" % e
+    return plan, ("the groups finish over numpy columns: keys, "
+                  "aggregates and %d result-finishing op(s) as picks, "
+                  "admitted programs and one stable lexsort"
+                  % len(plan["steps"]))
+
+
+def _result_column(ce, kinds, what, boolean=False):
+    """An egest expression over the result's columns (`kinds`: name ->
+    kind) as (the name it picks, its kind) or ((its admitted program,
+    the columns that reads), its kind); raises _RowFinish where exprs
+    does not admit it."""
+    t = ce.tree
+    if not boolean and t is not None and isinstance(t.body, ast.Name) \
+            and t.body.id in kinds:
+        return t.body.id, kinds[t.body.id]
+    reads = tuple(sorted({n.id for n in ast.walk(t) if isinstance(
+        n, ast.Name) and n.id in kinds})) if t is not None else ()
+    why = next(("it reads %s, an average (None over a zero count)" % n
+                for n in reads if kinds[n] == "avg"), None)
+    if why is None:
+        ve, why = E.vectorize(
+            ce, {n: _RESULT_DTYPES[k] for n, k in kinds.items()
+                 if k != "avg"},
+            {n: _INT64_RANGE for n, k in kinds.items() if k == "i"},
+            boolean=boolean, egest=True)
+        if ve is not None:
+            return (ve.prog, reads), ve.kind
+    raise _RowFinish("%s %s: %s" % (what, ce.expr, why))
+

@@ -186,7 +186,10 @@ Span taxonomy (name / cat):
                                        dictionary ids to strings),
                                        averages divided, HAVING /
                                        projections / ORDER BY evaluated
-                                       (args: rows); stamped with the
+                                       (args: rows; path — columns
+                                       where a grouped finish ran over
+                                       numpy columns, _finish_columns,
+                                       else rows); stamped with the
                                        id of the job it finishes.  The
                                        executor counts scan_rows_device
                                        (rows of a resident table that
@@ -195,12 +198,16 @@ Span taxonomy (name / cat):
                                        and scan_rows_host (rows
                                        _ScanSeg.run evaluated on the
                                        driver in numpy and reported to
-                                       note_host_scan), and
+                                       note_host_scan),
                                        query_joins_device (the joins
                                        of a job whose every side was a
                                        resident table, reported by
                                        PlannedQuery._job to
-                                       note_device_joins)
+                                       note_device_joins) and
+                                       query_finishes_columnar (the
+                                       finishes over columns, reported
+                                       by PlannedQuery._run to
+                                       note_columnar_finish)
     sort.sample              "exec"    the read of sortByKey's bounds
                                        sample, JAXExecutor._sample_keys
                                        (args: splits, rows, bytes: the

@@ -185,6 +185,35 @@ def test_query_parity_resident(seed):
     _run_cell("tpu:1" if seed % 2 else "tpu:2", 200 + seed, "resident")
 
 
+@pytest.mark.parametrize("seed", range(6))
+def test_query_finish_columns_equals_rows_resident(seed):
+    """A resident cell's grouped query finishes over columns, and that
+    finish equals the row finish on the same collected records, value
+    for value, type for type and in order."""
+    from dpark_tpu import DparkContext
+    rng = random.Random(300 + seed)
+    rows = [r[:3] + (r[3].encode(),) for r in make_rows(rng, 600)]
+    prog = build_query(rng, resident=True)
+    while not any(op[0] == "group" for op in prog):
+        prog = build_query(rng, resident=True)
+    ctx = DparkContext("tpu:1")
+    try:
+        ctx.start()
+        pq = apply_query(ctx, _resident_table(ctx, rows), prog,
+                         True)._planned()
+        assert pq is not None and pq._columnar is not None, prog
+        raw, _ = pq._job(lambda rdd: rdd.collect())
+        got = pq._finish(raw)
+        plan, pq._columnar = pq._columnar, None
+        want = pq._finish(raw)
+        pq._columnar = plan
+    finally:
+        ctx.stop()
+    assert got == want, (prog, got[:3], want[:3])
+    assert [tuple(map(type, r)) for r in got] \
+        == [tuple(map(type, r)) for r in want], prog
+
+
 @pytest.mark.parametrize("seed", range(12))
 def test_query_parity_local(seed):
     _run_cell("local", seed,

@@ -403,8 +403,9 @@ rebuilt as bytes at egest
   [lower-group-agg] group-agg -> device: lowered as reduce over the \
 tuple-key exchange (aggs: sum,sum,sum,sum,avg,avg,avg,count; 6 \
 accumulator leaves)
-  [egest] result -> egest: 2 result-finishing op(s) run at egest with \
-host eval semantics (rows are driver-resident)"""
+  [egest] result -> columns: the groups finish over numpy columns: keys, \
+aggregates and 2 result-finishing op(s) as picks, admitted programs and one \
+stable lexsort"""
 
 
 def test_q1_plans_as_before(masters):
@@ -435,3 +436,193 @@ def test_a_string_key_joined_twice_shares_one_dictionary(masters):
         ("k%d" % k, sum(i for i in range(40) if i % 5 == k), 80 * k, 8)
         for k in range(4)]
     assert got[dev][1].mode == "join_group"
+
+
+# -- the finish over columns -------------------------------------------------
+
+def _plant_q3_ties(pq, raw):
+    """Groups that tie on (revenue, o_orderdate), on revenue alone and on
+    the date alone, spread through the collected order: only that order
+    decides between them."""
+    out = []
+    for i, (key, acc) in enumerate(raw):
+        if i % 3 == 0:
+            key, acc = (key[0], DATE - 7, key[2]), (10 ** 9,)
+        elif i % 5 == 0:
+            key, acc = (key[0], DATE - 9 - i % 2, key[2]), (10 ** 9 + 1,)
+        elif i % 7 == 0:
+            key = (key[0], DATE - 7, key[2])
+        out.append((key, acc))
+    return out
+
+
+def _q1_sums_past_2_53(pq, raw):
+    """Q1's sums near 2**59 over counts of 3, 7 and 11: an average is the
+    correctly rounded quotient of two ints that float64 cannot hold."""
+    leaves = pq._group["leaves"]
+    out = []
+    for i, (key, acc) in enumerate(raw):
+        count = (3, 7, 11)[i % 3]
+        out.append((key, tuple(
+            count if op == "count" else 2 ** 59 + 12345 * i + 2 * j + 1
+            for j, (op, _) in enumerate(leaves))))
+    return out
+
+
+def _q3_table(ctx):
+    return ctx.sql(Q3, **tables(ctx, tpch(51, 150)))
+
+
+def _q1_table(ctx, sql=Q1 % CUTOFF, seed=52):
+    return ctx.sql(sql, lineitem=resident_table(ctx, lineitem(seed, 3000)))
+
+
+def _words_table(ctx):
+    rows = [("w%d" % (i % 9), i % 4, i, float(i % 13) / 4)
+            for i in range(400)]
+    return ctx.parallelize(rows, 2).asTable("s k a f", "t")
+
+
+def _names_table(ctx, order):
+    """A two-word S12 key whose bytes run high (negative signed words),
+    hold a NUL inside and share prefixes across the word boundary."""
+    names = np.array([b"\xffzz", b"A", b"A\x00B", b"ABCDEFGHIJKL",
+                      b"ABCDEFGHIJK", b"\x80", b"zz\xff", b"ABCDEFGH\xff",
+                      b"ABCDEFGH"], "S12")
+    rng = np.random.default_rng(54)
+    pick = rng.integers(0, len(names), 400)
+    cols = [names[pick], rng.integers(0, 100, 400).astype(np.int64)]
+    return ctx.sql("select name, sum(v) as s, count(*) as c from t "
+                   "group by name order by " + order,
+                   t=resident_table(ctx, cols, "name v"))
+
+
+def _q1_text(select, tail):
+    return ("select l_returnflag, l_linestatus, %s from lineitem "
+            "where l_shipdate <= %d group by l_returnflag, l_linestatus %s"
+            % (select, CUTOFF, tail))
+
+
+# name -> (the table, what the case does to the collected records, the
+# path the plan chooses, a part of that decision's reason)
+FINISH = {
+    "q3_ties_mixed_directions": (_q3_table, _plant_q3_ties, "columns",
+                                 "one stable lexsort"),
+    "q1_s1_keys_averages_past_2_53": (_q1_table, _q1_sums_past_2_53,
+                                      "columns", "2 result-finishing"),
+    "having_over_an_aggregate": (lambda ctx: _q1_table(ctx, _q1_text(
+        "sum(l_quantity) as q, count(*) as c",
+        "having count(*) > 600 and sum(l_tax) < 5000 order by q desc")),
+        None, "columns", "3 result-finishing"),
+    "projection_reorders": (lambda ctx: ctx.sql(
+        "select o_shippriority, o_orderdate, sum(l_extendedprice*"
+        "(100-l_discount)) as revenue, l_orderkey from customer "
+        "join orders on c_custkey = o_custkey "
+        "join lineitem on l_orderkey = o_orderkey "
+        "where o_orderdate < %d group by l_orderkey, o_orderdate, "
+        "o_shippriority order by o_orderdate desc, o_shippriority" % DATE,
+        **tables(ctx, tpch(53, 90))), None, "columns", "picks"),
+    "projection_computes": (lambda ctx: _q1_table(ctx, _q1_text(
+        "sum(l_quantity) % 7 as r, float(sum(l_tax)) * 0.5 as h, "
+        "count(*) // 3 as c3, -(sum(l_tax) % 5) as m",
+        "order by r desc, l_linestatus")), None, "columns",
+        "admitted programs"),
+    "desc_bytes_key": (lambda ctx: _q1_table(
+        ctx, Q1.replace("order by l_returnflag", "order by l_returnflag "
+                        "desc") % CUTOFF), _q1_sums_past_2_53, "columns",
+        "lexsort"),
+    "desc_decoded_string_key": (lambda ctx: ctx.sql(
+        "select s, k, sum(a) as sa, count(*) as c from t group by s, k "
+        "order by s desc, sa", t=_words_table(ctx)), None, "columns",
+        "lexsort"),
+    "two_word_bytes_key_desc": (lambda ctx: _names_table(
+        ctx, "name desc"), None, "columns", "lexsort"),
+    "two_word_bytes_key_after_a_count": (lambda ctx: _names_table(
+        ctx, "c desc, name"), None, "columns", "lexsort"),
+    "average_sort_key_keeps_rows": (lambda ctx: _q1_table(
+        ctx, (Q1 % CUTOFF).replace("order by l_returnflag, l_linestatus",
+                                   "order by avg_qty")), None, "rows",
+        "ORDER BY avg_qty: an average, None over a zero count"),
+    "float_sort_key_keeps_rows": (lambda ctx: ctx.sql(
+        "select k, max(f) as mf, count(*) as c from t group by k "
+        "order by mf", t=_words_table(ctx)), None, "rows",
+        "ORDER BY mf: a float key"),
+    "int_beside_float_keeps_rows": (lambda ctx: _q1_table(ctx, _q1_text(
+        "sum(l_quantity) as q", "having sum(l_quantity) > 1.5")), None,
+        "rows", "past 2**53"),
+}
+
+
+def finish_both_ways(pq, raw):
+    """One planned query's finish over the same collected records on
+    each path: (over columns, row by row), each with its fields."""
+    plan = pq._columnar
+    got = pq._finish(list(raw)), pq._out_fields
+    pq._columnar = None
+    try:
+        want = pq._finish(list(raw)), pq._out_fields
+    finally:
+        pq._columnar = plan
+    return got, want
+
+
+@pytest.mark.parametrize("case", sorted(FINISH))
+def test_the_finish_over_columns_is_the_rows_finish(masters, case):
+    dev, _ = masters
+    build, edit, path, why = FINISH[case]
+    pq = build(dev)._planned()
+    assert pq is not None and pq.mode in ("group", "join_group")
+    (decision,) = [d for d in pq.decisions if d["rule"] == "egest"]
+    assert decision["choice"] == path and why in decision["reason"], \
+        decision
+    assert (pq._columnar is not None) == (path == "columns")
+    raw, _ = pq._job(lambda rdd: rdd.collect())
+    if edit is not None:
+        raw = edit(pq, raw)
+    if path == "rows":
+        assert pq._finish(raw) == pq._egest(*pq._shape_rows(raw))
+        return
+    (got, got_fields), (want, want_fields) = finish_both_ways(pq, raw)
+    assert len(want) > 1 and got_fields == want_fields
+    assert got == want
+    assert [tuple(map(type, r)) for r in got] \
+        == [tuple(map(type, r)) for r in want]
+
+
+def test_ties_keep_the_collected_order(masters):
+    """Q3's planted ties on (revenue, o_orderdate) come out in the order
+    the job collected them, on both paths."""
+    dev, _ = masters
+    pq = _q3_table(dev)._planned()
+    raw = _plant_q3_ties(pq, pq._job(lambda rdd: rdd.collect())[0])
+    (got, _), (want, _) = finish_both_ways(pq, raw)
+    tied = [k for (k, d, _), (v,) in raw if (v, d) == (10 ** 9, DATE - 7)]
+    assert len(tied) > 3
+    assert [r[0] for r in got if (r[1], r[2]) == (10 ** 9, DATE - 7)] \
+        == tied == [r[0] for r in want if (r[1], r[2]) == (10 ** 9,
+                                                          DATE - 7)]
+
+
+@pytest.mark.parametrize("case,path,counted", [
+    ("q3_ties_mixed_directions", "columns", 1),
+    ("average_sort_key_keeps_rows", "rows", 0)])
+def test_a_columnar_finish_is_counted_and_its_span_says_the_path(
+        masters, case, path, counted):
+    from dpark_tpu import trace
+    dev, _ = masters
+    ex = dev.scheduler.executor
+    table = FINISH[case][0](dev)
+    trace.configure("ring")
+    try:
+        before = ex.query_finishes_columnar
+        rows = [tuple(r) for r in table.collect()]
+        spans = trace.snapshot()
+    finally:
+        trace.configure("off")
+    assert rows and ex.query_finishes_columnar - before == counted
+    # the scheduler's history keeps the last 100 jobs: the ring, opened
+    # for this one query, is where its job is read
+    (finish,) = [s for s in spans if s["name"] == "query.finish"]
+    assert finish.get("job") is not None
+    assert (finish["args"]["path"], finish["args"]["rows"]) \
+        == (path, len(rows))
