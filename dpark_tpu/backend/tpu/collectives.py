@@ -439,6 +439,43 @@ def bucketize(key, leaves, valid, n_dst, dst=None, r=None):
     return list(sorted_ops[1:]), counts, jnp.cumsum(counts) - counts
 
 
+def bucketize_by_key(leaves, valid, nk):
+    """bucketize on ONE device, followed by what the no-combine reader
+    does with the store: its rows key-sorted, valid first.  Every valid
+    row's destination is 0, so sorting by it only packs; the reader then
+    sorts the packed rows by their nk key columns with column 0 the
+    sentinel past the count.  ONE stable _lex_sort of the same operands
+    by the keys, column 0 the sentinel where `valid` is False, leaves
+    the same valid prefix: the valid rows, stably by key, in input
+    order, and padding after them.  It adds no operand, so a record of
+    8 words stays carried.
+
+    A valid row whose column 0 equals the sentinel (a hash write's keys
+    are integers: the dtype's maximum) would sort among the padding:
+    where one exists, lax.cond takes the two sorts instead, dst pack
+    then key sort, bit for bit what the store and its reader gave.
+
+    Returns (sorted_leaves, counts[1], offsets[1]) as bucketize does."""
+    leaves = list(leaves)
+    k0 = leaves[0]
+    sent = _sentinel(k0.dtype)
+
+    def by_key():
+        return list(_lex_sort(
+            tuple([jnp.where(valid, k0, sent)] + leaves[1:]), nk))
+
+    def by_dst():
+        packed, counts, _ = bucketize(
+            k0, leaves, valid, 1, dst=(~valid).astype(jnp.int32))
+        packed[0] = jnp.where(jnp.arange(k0.shape[0]) < counts[0],
+                              packed[0], sent)
+        return sort_by_key(packed, nk)
+
+    out = lax.cond(jnp.any(valid & (k0 == sent)), by_dst, by_key)
+    counts = jnp.sum(valid, dtype=jnp.int32).reshape(1)
+    return out, counts, jnp.zeros((1,), jnp.int32)
+
+
 def exchange_round(axis, leaves, offsets, counts, sent, slot,
                    narrow=None):
     """One all_to_all round: send up to `slot` records to each destination.

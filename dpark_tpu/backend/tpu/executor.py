@@ -51,7 +51,7 @@ def _combine_arg(merge_fn, monoid):
     return "none" if merge_fn is None else "user_scan"
 
 
-def _sort_arg(plan, sorts):
+def _sort_arg(plan, sorts, dst_word=True):
     """The `sort=` of a `compile` event: how the program's orderings
     (collectives._lex_sort: shuffle write, reduce, filter, sort) move
     their rows — every operand `carried` through the one sort,
@@ -60,11 +60,12 @@ def _sort_arg(plan, sorts):
     is wider than the sort carries (the keys and an iota ride it, the
     row follows as whole rows), `none` without an ordering.  Read off
     the plan's record specs, as the sort sees them at trace time (the
-    wider of the records in and out, and one word for `dst`)."""
+    wider of the records in and out, and one word for `dst` where the
+    write sorts by it)."""
     if not sorts:
         return "none"
     specs = tuple(plan.in_specs), tuple(plan.out_specs)
-    if 1 + max(sum(max(1, np.dtype(dt).itemsize // 4)
+    if int(dst_word) + max(sum(max(1, np.dtype(dt).itemsize // 4)
                    for dt, shape in side if not shape)
                for side in specs) > collectives._CARRIED_WORDS:
         return "keys+rows"
@@ -767,6 +768,10 @@ class JAXExecutor:
         # one device, an exact combine): the reduce stage's exchange and
         # reduce program not run, once a store
         self.stores_pre_reduced = 0
+        # in-core shuffle writes registered `key_ordered` (_finish_stage:
+        # one device, a write that combines nothing sorted by its key):
+        # the gather that reads such a store launches no program
+        self.stores_key_ordered = 0
         # launches of a program whose combining write packs to a proven
         # key bound (_pack_cap; bare `+= 1`): its store leaves the
         # program at the bound's capacity class, not the input's
@@ -1024,6 +1029,16 @@ class JAXExecutor:
                 plan.epi_spec)
 
     @staticmethod
+    def _orders_by_key(epi_spec, n_dst):
+        """Does a write that combines nothing leave its store key-sorted
+        (collectives.bucketize_by_key)?  On one device, partitioned by
+        hash over number keys: a destination orders nothing there, and
+        the reader's key sort is the one the write does.  A range write
+        keeps its bounds' order, a byte-string key its hash word's."""
+        return n_dst == 1 and fuse.epi_bytes_width(epi_spec) is None \
+            and not (epi_spec is not None and epi_spec[0] == "range")
+
+    @staticmethod
     def _epilogue_block(epi, lv, valid, n_dst, merge_fn, monoid, bounds,
                         out_cap=None):
         """Shared shuffle-write tail: destination assignment (hash or
@@ -1034,9 +1049,15 @@ class JAXExecutor:
         first nk columns: destinations hash over all of them with the
         pair-extended phash, and the combine merges rows equal in every
         key column.  `out_cap` (_pack_cap; the narrow program's alone)
-        packs the combine's output to that many slots."""
+        packs the combine's output to that many slots.  A write that
+        combines nothing sorts by key where _orders_by_key says so."""
         nk, r, epi_spec = epi
         k = lv[0]
+        combines = merge_fn is not None or monoid is not None
+        if not combines and JAXExecutor._orders_by_key(epi_spec, n_dst):
+            sorted_lv, cnts, offs = collectives.bucketize_by_key(
+                lv, valid, nk)
+            return (cnts, offs) + tuple(sorted_lv)
         if epi_spec is not None and epi_spec[0] == "range":
             if nk == 1 and not fuse.epi_unsigned(epi_spec):
                 dst = collectives.range_dst(k, bounds, epi_spec[1],
@@ -1050,7 +1071,7 @@ class JAXExecutor:
             dst = collectives.hash_dst_cols(
                 lv[:nk], n_dst, valid, r=r,
                 bytes_width=fuse.epi_bytes_width(epi_spec))
-        if merge_fn is not None or monoid is not None:
+        if combines:
             k2s, v2, cnts, offs = collectives.bucketize_combine_keys(
                 lv[:nk], lv[nk:], valid, n_dst, merge_fn, monoid=monoid,
                 dst=dst, r=r, out_cap=out_cap)
@@ -1096,13 +1117,19 @@ class JAXExecutor:
         if trace._PLANE is not None:
             # a filter orders rows only where it packs, and it folds
             # only ahead of a write, whose own sort is counted anyway
-            pack = {} if epilogue is None else {"pack": out_cap or "full"}
+            # of a write that combines nothing, the order it leaves
+            pack = {}
+            if epilogue is not None:
+                pack["pack"] = out_cap or "full"
+                if merge_fn is None and monoid is None:
+                    pack["pack"] = "key" if self._orders_by_key(
+                        plan.epi_spec, n_dst) else "dst"
             trace.event("compile", "exec", program="narrow", cap=cap,
                         sig=_plan_sig(plan),
                         combine=_combine_arg(merge_fn, monoid),
                         sort=_sort_arg(plan, epilogue is not None or any(
                             isinstance(op, (fuse.SortOp, fuse.FilterOp))
-                            for op in ops)),
+                            for op in ops), pack.get("pack") != "key"),
                         filter=_filter_arg(ops, epilogue is not None),
                         **pack, **_order_args(plan))
         in_specs = plan.in_specs
@@ -2082,11 +2109,22 @@ class JAXExecutor:
         if pre_reduced:
             self.stores_pre_reduced += 1
             leaves = self._trim_combined(leaves, cnts)
+        # ONE device, and the write combined nothing: its program sorted
+        # the rows by their key columns (_orders_by_key), which is the
+        # order the gather of a join or a cogroup would give them
+        key_ordered = None
+        if self._orders_by_key(plan.epi_spec, self.ndev) \
+                and not self._write_combines(plan):
+            key_ordered = getattr(plan, "epi_nk", 1) or 1
+            self.stores_key_ordered += 1
         return self._register_shuffle(dep, plan, {
-            "leaves": leaves,            # (ndev, cap, ...) dst-sorted
+            # (ndev, cap, ...) dst-sorted; a key_ordered store's one
+            # bucket is key-sorted, valid rows first
+            "leaves": leaves,
             "counts": cnts,              # (ndev, R)
             "offsets": offs,             # (ndev, R)
             "pre_reduced": pre_reduced,
+            "key_ordered": key_ordered,  # nk, or None
             "no_combine": fuse.is_list_agg(dep.aggregator),
             "encoded_keys": getattr(plan, "encoded_keys", False),
             # text ingest, union concat, and resliced ingest all
@@ -3370,9 +3408,10 @@ class JAXExecutor:
             # program, and every blocking readback (this runs per
             # wave, and a sync stalls the dispatch queue).  Who still
             # comes here: streamed waves, no-combine stores (groupByKey,
-            # the join's sides, sortByKey's range write) and raw
-            # combiners; an in-core COMBINED store is pre_reduced and
-            # its reader skips this call too (_source_outs)
+            # sortByKey's range write, a join's byte-string sides) and
+            # raw combiners; an in-core COMBINED store is pre_reduced
+            # and a join's other sides are key_ordered, and their
+            # readers skip this call too (_source_outs, _exchange_sorted)
             self._note_identity_exchange(counts, cap)
             # consumers expect per-device (R=1, slot, ...) receive
             # buffers and (R=1,) counts — counts is already the (1, 1)
@@ -3559,8 +3598,19 @@ class JAXExecutor:
         `by_hash` the rows are ordered by one int64 word instead, a
         hash of the key's columns (collectives.key_hash64), which is
         returned as the leading leaf: a byte-string key of 13 words
-        orders and matches as one."""
+        orders and matches as one.
 
+        A `key_ordered` store on one device is that already: its write
+        sorted by the same key columns (_orders_by_key), so its counts
+        and leaves are returned as they stand and no program runs."""
+        nk = store.get("key_cols", 1) or 1
+        if self.ndev == 1 and not by_hash \
+                and store.get("key_ordered") == nk:
+            store["seq"] = self._next_seq()          # LRU touch
+            self._note_identity_exchange(store["counts"],
+                                         store["leaves"][0].shape[1])
+            # (ndev, R=1) bucket counts -> the gather's (ndev,)
+            return store["counts"].reshape(self.ndev), list(store["leaves"])
         # an instance, not a class made per call: a class is part of
         # reference cycles (its __dict__, its mro), so only the cyclic
         # collector would free it, and `source` holds the dependency
@@ -3575,7 +3625,7 @@ class JAXExecutor:
             # sort gathered rows by the FULL key (tuple keys span
             # key_cols columns) so cogroup/join consumers see the same
             # lexicographic order the host merge expects
-            src_nk=store.get("key_cols", 1) or 1, src_hash=by_hash,
+            src_nk=nk, src_hash=by_hash,
             in_treedef=store["out_treedef"],
             in_specs=store["out_specs"],
             out_treedef=store["out_treedef"],
